@@ -1,12 +1,13 @@
-"""Shared bench provenance + mx.ledger glue.
+"""Shared bench prologue, device provenance + mx.ledger glue.
 
-PR 11 gave bench.py's rows the platform / devices / smoke_mode
-provenance triple so tools/bench_diff.py could refuse cross-platform
-comparisons; this helper factors that contract so ALL eight bench
-entrypoints emit it identically, and adds the mx.ledger hook: when
-`ledger_dir` is armed each bench appends one provenance-keyed run
-record to the cross-run ledger. Off is the zero-overhead fast path —
-one bool check, zero record_run calls (asserted by ci/run.sh).
+Every bench entry point runs in ONE process on the device JAX gives it
+and starts with `start()`: persistent compile cache on, device printed,
+and a hard stop without a TPU — a measuring script has no CPU fallback,
+because a number it prints goes under a device metric's name. Rows carry
+the device they were measured on (`device_fields()`); when `ledger_dir`
+is armed each bench appends one provenance-keyed run record to the
+cross-run ledger (off: one bool check, zero record_run calls — asserted
+by ci/run.sh).
 """
 import os
 import sys
@@ -15,34 +16,42 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
                                 ".."))
 
 
-def provenance_fields(on_tpu=None, platform=None, devices=None,
-                      smoke_mode=None):
-    """The three contract fields every bench row carries. jax must
-    already be pinned to its final platform (subprocess probe first,
-    clear_backends + cpu on the fallback path) before calling this —
-    or pass platform/devices explicitly to stay jax-free."""
-    if platform is None or devices is None:
-        import jax
-        if platform is None:
-            platform = jax.default_backend()
-        if devices is None:
-            devices = len(jax.devices())
-    if smoke_mode is None:
-        smoke_mode = not (on_tpu if on_tpu is not None
-                          else platform == "tpu")
-    return {"platform": platform, "devices": devices,
-            "smoke_mode": bool(smoke_mode)}
+def device_fields():
+    """Where a row was measured, as JAX reports it. `smoke_mode` stays in
+    the contract (always False: these scripts only run on a chip) because
+    mx.ledger and tools/bench_diff.py key their series on it."""
+    import jax
+    dev = jax.devices()[0]
+    return {"platform": dev.platform, "device_kind": dev.device_kind,
+            "devices": len(jax.devices()), "smoke_mode": False}
 
 
-def annotate(rows, fields=None, **kwargs):
-    """Stamp the contract fields onto every row; existing values win
-    (a row that already says where it was measured is not rewritten)."""
-    if fields is None:
-        fields = provenance_fields(**kwargs)
-    for row in rows:
-        for k, v in fields.items():
-            row.setdefault(k, v)
-    return rows
+def start():
+    """The prologue: turn the persistent compile cache on, print the
+    device to stderr, and exit non-zero unless it is a TPU whose peak is
+    in mx.inspect's table. Returns `device_fields()`."""
+    import jax
+
+    from mxnet_tpu import dataflow
+    from mxnet_tpu import inspect as mxinspect
+
+    cache = dataflow.ensure_compile_cache()
+    fields = device_fields()
+    print(f"# jax {jax.__version__} device: {fields['platform']} "
+          f"{fields['device_kind']!r} x{fields['devices']}; "
+          f"compile cache: {cache}", file=sys.stderr)
+    if fields["platform"] != "tpu":
+        raise SystemExit(
+            f"{os.path.basename(sys.argv[0])}: needs a TPU, jax found "
+            f"platform {fields['platform']!r} (JAX_PLATFORMS="
+            f"{os.environ.get('JAX_PLATFORMS')!r}). There is no CPU "
+            "fallback: run it through the chip tool.")
+    if mxinspect.peak_flops_per_chip() is None:
+        raise SystemExit(
+            f"device_kind {fields['device_kind']!r} is not in mx.inspect's "
+            "peak table (mxnet_tpu/inspect.py); add it with its source "
+            "before benchmarking on it")
+    return fields
 
 
 def ledger_append(bench, rows, **extra):

@@ -12,14 +12,12 @@ One JSON line per kernel, paired across runs by `metric`
 
   {"metric": "kernel_int8_matmul", "pallas_ms": ..., "xla_ms": ...,
    "speedup": ..., "roofline_xla": ..., "roofline_pallas": ...,
-   "shape": ..., "platform": ..., "devices": ..., "smoke_mode": ...}
+   "shape": ..., "platform": ..., "device_kind": ..., "devices": ...,
+   "smoke_mode": false}
 
-CPU smoke: the Pallas path runs through the interpreter
-(MXNET_TPU_PALLAS_INTERPRET=1 is set for the kernel side) at tiny
-shapes — the row exists so the contract is exercised, but it is marked
-smoke_mode and carries platform 'cpu', so bench_diff refuses to compare
-it against TPU rows (interpreter time is not kernel time; roofline
-verdicts are null without the TPU peak tables)."""
+One process, one chip (a one-device mesh even on a four-chip host: the
+global-view kernels engage only on a single-device step). Needs a TPU;
+exits non-zero without one — interpreter time is not kernel time."""
 import functools
 import json
 import os
@@ -43,8 +41,7 @@ def _time_ms(fn, reps):
 
 
 def _roofline(name, jitted, args):
-    """mx.inspect roofline verdict for one jitted path (None on
-    backends without peak tables — CPU)."""
+    """mx.inspect roofline verdict for one jitted path."""
     from mxnet_tpu import inspect as mxi
     was = mxi.enabled()
     mxi.enable()
@@ -56,44 +53,16 @@ def _roofline(name, jitted, args):
             mxi.disable()
 
 
-def _interp_ctx(on_tpu):
-    """The kernel side runs interpreted on CPU smoke (the only way the
-    kernel CODE runs off-TPU); real TPUs run the compiled kernel."""
-    import contextlib
-
-    @contextlib.contextmanager
-    def ctx():
-        if on_tpu:
-            yield
-            return
-        old = os.environ.get("MXNET_TPU_PALLAS_INTERPRET")
-        os.environ["MXNET_TPU_PALLAS_INTERPRET"] = "1"
-        try:
-            yield
-        finally:
-            if old is None:
-                del os.environ["MXNET_TPU_PALLAS_INTERPRET"]
-            else:
-                os.environ["MXNET_TPU_PALLAS_INTERPRET"] = old
-    return ctx
-
-
 def main():
-    import bench
-    on_tpu = bench.probe_tpu() \
-        if os.environ.get("MXNET_TPU_BENCH_FORCE_CPU") != "1" else False
-    if on_tpu:
-        bench.acquire_bench_lock()
+    from benchmarks import _provenance
+    provenance = _provenance.start()
 
     import numpy as np
     import jax
     import jax.numpy as jnp
 
-    if not on_tpu:
-        from jax.extend.backend import clear_backends
-        clear_backends()
-        jax.config.update("jax_platforms", "cpu")
-    bench.enable_compile_cache()
+    from mxnet_tpu import parallel
+    parallel.make_mesh(devices=jax.devices()[:1])
 
     import importlib
     from mxnet_tpu import config
@@ -102,11 +71,7 @@ def main():
     mk = importlib.import_module("mxnet_tpu.pallas_ops.moe_kernels")
     pa = importlib.import_module("mxnet_tpu.pallas_ops.paged_attention")
 
-    from benchmarks import _provenance
-
-    reps = 20 if on_tpu else 2
-    interp = _interp_ctx(on_tpu)
-    provenance = _provenance.provenance_fields(on_tpu=on_tpu)
+    reps = 20
     config.set("kernels_min_elements", 1)
     rng = np.random.RandomState(0)
     rows = []
@@ -116,11 +81,10 @@ def main():
         jx = jax.jit(xla_fn)
         xla_ms = _time_ms(lambda: jx(*xla_args), reps)
         roof_x = _roofline(f"{name}_xla", jx, xla_args)
-        config.set("kernels", "auto")
-        with interp():
-            jp = jax.jit(pallas_fn)
-            pallas_ms = _time_ms(lambda: jp(*pallas_args), reps)
-            roof_p = _roofline(f"{name}_pallas", jp, pallas_args)
+        config.set("kernels", "on")
+        jp = jax.jit(pallas_fn)
+        pallas_ms = _time_ms(lambda: jp(*pallas_args), reps)
+        roof_p = _roofline(f"{name}_pallas", jp, pallas_args)
         config.set("kernels", "off")
         row = {
             "metric": f"kernel_{name}",
@@ -136,7 +100,7 @@ def main():
         print(json.dumps(row), flush=True)
 
     # -- int8 serving matmul ------------------------------------------
-    M, K, O = (1024, 1024, 4096) if on_tpu else (64, 128, 256)
+    M, K, O = 1024, 1024, 4096
     xq = jnp.asarray(rng.randint(-127, 128, (M, K)), jnp.int8)
     wq = jnp.asarray(rng.randint(-127, 128, (K, O)), jnp.int8)
     ws = jnp.asarray(rng.rand(O).astype(np.float32) * 0.1)
@@ -148,7 +112,7 @@ def main():
          (xq, wq, jnp.float32(0.02), ws, bias))
 
     # -- fused Adam update --------------------------------------------
-    n = (8 << 20) if on_tpu else (1 << 16)
+    n = 8 << 20
     w = jnp.asarray(rng.randn(n).astype(np.float32))
     g = jnp.asarray(rng.randn(n).astype(np.float32))
     m = jnp.zeros(n, jnp.float32)
@@ -163,7 +127,10 @@ def main():
          upd_args)
 
     # -- fused MoE dispatch/combine -----------------------------------
-    N, D, E = (8192, 1024, 8) if on_tpu else (256, 128, 4)
+    # both MoE kernels hold a whole operand in one VMEM block, so the
+    # shape stays under the 16 MB scoped-VMEM limit (README, "Kernel
+    # library": N8192 D2048 E16 is refused at compile time)
+    N, D, E = 1024, 512, 8
     C = max(N // E, 1)
     x = jnp.asarray(rng.randn(N, D).astype(np.float32))
     expert = jnp.asarray(rng.randint(0, E, N), jnp.int32)
@@ -189,8 +156,7 @@ def main():
          roundtrip_pallas, (x, expert, pos, gate))
 
     # -- paged decode attention (mx.pages serving hot loop) ------------
-    B, H, D, ps, n_pg = (32, 16, 128, 16, 128) if on_tpu \
-        else (4, 4, 16, 8, 4)
+    B, H, D, ps, n_pg = 32, 16, 128, 16, 128
     P = B * n_pg + 1
     q = jnp.asarray(rng.randn(B, H, 1, D).astype(np.float32))
     k_pg = jnp.asarray(rng.randn(P, H, ps, D).astype(np.float32))

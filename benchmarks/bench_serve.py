@@ -9,15 +9,17 @@ One JSON line:
    "slo_violations": ..., "requests": ..., "completed": ..., "rejected":
    ..., "shed": ..., "deadline_missed": ..., "cancelled": ...,
    "degraded": ..., "requeues": ..., "slots": ..., "queue_depth": ...,
-   "offered_rps": ..., "platform": ..., "devices": ..., "smoke_mode":
-   ...}
+   "offered_rps": ..., "platform": ..., "device_kind": ..., "devices":
+   ..., "smoke_mode": false}
 
-The row contract (and zero deadline misses at low load) is asserted by
-ci/run.sh sanity. tokens_per_sec counts GENERATED tokens over the
+tokens_per_sec counts GENERATED tokens over the
 span from first submit to last completion; ttft is submit-to-first-
 token. Knobs via env: MXNET_TPU_BENCH_SERVE_REQUESTS / _RATE (req/s) /
-_DEADLINE_MS. CPU smoke mode (tiny model) when no TPU; GPT-2 117m bf16
-on the chip. Rides the persistent compile cache like every bench.
+_DEADLINE_MS. GPT-2 117m bf16, one process, one chip: the server places
+nothing itself, so the bench installs a one-device mesh (on a four-chip
+host a wider mesh would silently turn the paged-attention kernel off
+while the model sits on the first device). Needs a TPU; exits non-zero
+without one. Rides the persistent compile cache like every bench.
 
 mx.slo journals the measured window (MXNET_TPU_BENCH_SERVE_SLO=0 opts
 out; the three slo fields are then null): tbt_p99_ms is the p99 gap
@@ -47,21 +49,7 @@ BOTH passes to the shared-prefix workload — every prompt opens with
 one common system prefix and diverges in a short tail, the traffic
 shape the prefix tree exists for ('workload' records which shape the
 row measured).
-
-`--replicas N` (or MXNET_TPU_BENCH_SERVE_REPLICAS=N) switches to the
-mx.fleet multi-process mode: N replica worker processes (each its own
-`python -m mxnet_tpu.fleet` server, pinned to CPU — replicas of one
-bench host must not fight over the chip) behind the in-process fleet
-router. The row then reports `fleet_tokens_per_sec` (N replicas under
-N-times the offered load), `single_tokens_per_sec` (the SAME router
-path over one replica — protocol overhead included, so the pairing is
-honest), `fleet_scaling_efficiency` (fleet over N-times single; the
-acceptance target on real hardware is >=0.9) and
-`failover_dropped_requests` from a kill drill: one replica is
-SIGKILLed mid-load and the row counts accepted requests that failed
-to complete (the router's deterministic replay should keep this at
-ZERO). All three are registered direction-aware in
-tools/bench_diff.py and mx.ledger."""
+"""
 import json
 import os
 import sys
@@ -80,166 +68,12 @@ def _percentile(sorted_vals, q):
     return sorted_vals[idx]
 
 
-def _fleet_main(n_replicas):
-    """Multi-process fleet pass: N `mxnet_tpu.fleet` replica workers on
-    CPU behind the in-process router. Emits one row with
-    fleet_tokens_per_sec / single_tokens_per_sec /
-    fleet_scaling_efficiency / failover_dropped_requests."""
-    import signal
-    import subprocess
-
-    import numpy as np
-
-    from benchmarks import _provenance
-    from mxnet_tpu import fleet
-
-    slots = 4
-    n_requests = int(os.environ.get("MXNET_TPU_BENCH_SERVE_REQUESTS", 16))
-    rate = float(os.environ.get("MXNET_TPU_BENCH_SERVE_RATE", 40.0))
-    base_port = int(os.environ.get("MXNET_TPU_BENCH_FLEET_PORT", 8950))
-    lp_range, new_range, vocab = (4, 12), (4, 10), 128
-    rng = np.random.RandomState(0)
-
-    # one pre-drawn workload generator per pass, all from one seed
-    def draw_load(n, req_rate):
-        arrivals = np.cumsum(rng.exponential(1.0 / req_rate, n))
-        prompts = [[int(t) for t in
-                    rng.randint(0, vocab, (rng.randint(*lp_range),))]
-                   for _ in range(n)]
-        news = [int(rng.randint(*new_range)) for _ in range(n)]
-        return arrivals, prompts, news
-
-    env = dict(os.environ, JAX_PLATFORMS="cpu", MXNET_TPU_SERVE="on")
-    procs = {}
-    for rid in range(n_replicas):
-        env_r = dict(env, MXNET_TPU_FLEET_REPLICA=str(rid))
-        procs[rid] = subprocess.Popen(
-            [sys.executable, "-m", "mxnet_tpu.fleet",
-             "--port", str(base_port + 1 + rid),
-             "--slots", str(slots), "--seed", "0"],
-            env=env_r)
-
-    urls = {rid: f"http://127.0.0.1:{base_port + 1 + rid}"
-            for rid in range(n_replicas)}
-    router = fleet.Router(urls).start()
-    try:
-        deadline = time.time() + 300
-        while time.time() < deadline:
-            if all(v["ok"] for v in router.healthz()["replicas"].values()):
-                break
-            time.sleep(0.5)
-        else:
-            raise RuntimeError("fleet replicas never became healthy")
-
-        def run_pass(rtr, n, req_rate, kill_rid=None, kill_after=None):
-            arrivals, prompts, news = draw_load(n, req_rate)
-            reqs = []
-            t0 = time.perf_counter()
-            for i in range(n):
-                delay = arrivals[i] - (time.perf_counter() - t0)
-                if delay > 0:
-                    time.sleep(delay)
-                if kill_rid is not None and i == kill_after:
-                    procs[kill_rid].send_signal(signal.SIGKILL)
-                reqs.append(rtr.submit(prompts[i],
-                                       max_new_tokens=news[i]))
-            for r in reqs:
-                try:
-                    r.result(timeout=600)
-                except TimeoutError:
-                    pass
-            wall = time.perf_counter() - t0
-            done = [r for r in reqs if r.state == "done"]
-            tokens = sum(len(r.tokens) for r in reqs)
-            # dropped = ACCEPTED requests that failed to complete; an
-            # admission rejection (413/429) was never accepted, so it
-            # is load shedding, not a drop
-            dropped = sum(1 for r in reqs if r.state != "done"
-                          and not str(r.verdict or "").startswith(
-                              ("413", "429")))
-            return {"tokens_per_sec": round(tokens / wall, 1),
-                    "completed": len(done),
-                    "requests": n,
-                    "dropped": dropped,
-                    "failovers": sum(r.failovers for r in reqs)}
-
-        # warm every replica through a single-replica router so each
-        # process compiles its decode buckets OUTSIDE the measured
-        # windows (separate processes -> separate jit caches)
-        for rid, url in urls.items():
-            solo = fleet.Router({rid: url})
-            solo.poll_once()
-            run_pass(solo, 6, 100.0)
-
-        # single-replica baseline through the SAME router path
-        solo = fleet.Router({0: urls[0]})
-        solo.poll_once()
-        single = run_pass(solo, n_requests, rate)
-
-        # fleet pass: N replicas under N-times the offered load
-        flt = run_pass(router, n_requests * n_replicas, rate * n_replicas)
-
-        # failover drill: SIGKILL one replica mid-load; accepted
-        # requests must all still complete via the router's replay
-        drill_n = n_requests
-        victim = n_replicas - 1
-        drill = run_pass(router, drill_n, rate,
-                         kill_rid=victim, kill_after=drill_n // 3)
-
-        single_tps = single["tokens_per_sec"] or 0.0
-        row = {
-            "fleet_replicas": n_replicas,
-            "fleet_tokens_per_sec": flt["tokens_per_sec"],
-            "single_tokens_per_sec": single_tps,
-            "fleet_scaling_efficiency": round(
-                flt["tokens_per_sec"] / (n_replicas * single_tps), 3)
-            if single_tps else None,
-            "fleet_completed": flt["completed"],
-            "fleet_requests": flt["requests"],
-            "failover_dropped_requests": drill["dropped"],
-            "failover_count": drill["failovers"],
-            "slots": slots,
-            "offered_rps": round(rate, 2),
-            "workload": "fleet",
-        }
-        row.update(_provenance.provenance_fields(
-            platform="cpu", devices=n_replicas, smoke_mode=True))
-        print(json.dumps(row), flush=True)
-        _provenance.ledger_append("bench_serve", [row])
-    finally:
-        router.stop()
-        for pr in procs.values():
-            if pr.poll() is None:
-                pr.send_signal(signal.SIGTERM)
-        for pr in procs.values():
-            try:
-                pr.wait(timeout=60)
-            except subprocess.TimeoutExpired:
-                pr.kill()
-                pr.wait()
-
-
 def main():
-    argv = sys.argv[1:]
-    n_replicas = int(os.environ.get("MXNET_TPU_BENCH_SERVE_REPLICAS", 0))
-    if "--replicas" in argv:
-        n_replicas = int(argv[argv.index("--replicas") + 1])
-    if n_replicas:
-        return _fleet_main(n_replicas)
-    import bench
-    on_tpu = bench.probe_tpu() \
-        if os.environ.get("MXNET_TPU_BENCH_FORCE_CPU") != "1" else False
-    if on_tpu:
-        bench.acquire_bench_lock()
+    from benchmarks import _provenance
+    prov = _provenance.start()
 
     import jax
     import numpy as np
-
-    if not on_tpu:
-        from jax.extend.backend import clear_backends
-        clear_backends()
-        jax.config.update("jax_platforms", "cpu")
-    bench.enable_compile_cache()
 
     import mxnet_tpu as mx
     from mxnet_tpu import parallel, serve, slo
@@ -247,22 +81,17 @@ def main():
 
     slo_on = os.environ.get("MXNET_TPU_BENCH_SERVE_SLO", "1") == "1"
 
-    parallel.make_mesh(dp=-1)
-    if on_tpu:
-        cfg = gpt_mod.gpt2_117m_config(dtype="bfloat16")
-        n_requests, rate, slots = 64, 8.0, 8
-        lp_range, new_range = (16, 64), (16, 64)
-    else:
-        cfg = gpt_mod.gpt_tiny_config()
-        n_requests, rate, slots = 16, 40.0, 4
-        lp_range, new_range = (4, 12), (4, 10)
+    parallel.make_mesh(devices=jax.devices()[:1])
+    cfg = gpt_mod.gpt2_117m_config(dtype="bfloat16")
+    n_requests, rate, slots = 64, 8.0, 8
+    lp_range, new_range = (16, 64), (16, 64)
     prefix_mode = "--prefix" in sys.argv[1:] \
         or os.environ.get("MXNET_TPU_BENCH_SERVE_PREFIX") == "1"
     if prefix_mode:
         # the prefix workload is a CAPACITY comparison (pages-vs-dense
         # tokens/s): offer load well past dense capacity so tokens/s
         # measures the server, not the arrival process
-        n_requests, rate = (64, 32.0) if on_tpu else (24, 400.0)
+        n_requests, rate = 64, 32.0
     n_requests = int(os.environ.get("MXNET_TPU_BENCH_SERVE_REQUESTS",
                                     n_requests))
     rate = float(os.environ.get("MXNET_TPU_BENCH_SERVE_RATE", rate))
@@ -274,7 +103,7 @@ def main():
     model.initialize()
     rng = np.random.RandomState(0)
 
-    page_size = 16 if on_tpu else 8
+    page_size = 16
 
     # pre-drawn offered load, shared by every pass: Poisson interarrivals
     # so arrivals are independent of how the server keeps up
@@ -283,8 +112,8 @@ def main():
         # shared-prefix shape: one common system prefix (a whole number
         # of pages so the prefix tree can match it block-for-block) and
         # a short unique tail per request
-        pre_len = page_size * (6 if on_tpu else 4)
-        tail_range = (4, 16) if on_tpu else (2, 7)
+        pre_len = page_size * 6
+        tail_range = (4, 16)
         shared = rng.randint(0, cfg["vocab_size"],
                              (pre_len,)).astype(np.int32)
         prompts = [np.concatenate(
@@ -375,8 +204,6 @@ def main():
             "accepted_draft_rate": st.get("accepted_draft_rate"),
         }
 
-    from benchmarks import _provenance
-
     srv, stats = run_load(model)
     row = dict(stats)
     row.update({
@@ -386,7 +213,7 @@ def main():
         "offered_rps": round(rate, 2),
         "workload": "shared_prefix" if prefix_mode else "random",
     })
-    row.update(_provenance.provenance_fields(on_tpu=on_tpu))
+    row.update(prov)
 
     int8 = "--int8" in sys.argv[1:] \
         or os.environ.get("MXNET_TPU_BENCH_SERVE_INT8") == "1"

@@ -113,61 +113,30 @@ assert calls == {'analyze': 0, 'record': 0, 'note': 0}, calls
 assert mxi.records() == [], 'disabled fast path allocated CostRecords'
 print('inspect disabled fast path OK (no analysis calls, no records)')
 "
-    # the driver bench contract: the JSON line must carry the efficiency
-    # fields (nullable on CPU — mfu null, never 0/inf) so the BENCH_*
-    # trajectory can track MFU, not just throughput
-    # no pipe: a non-zero bench exit must fail this stage (set -e), not
-    # vanish behind tail's status
-    JAX_PLATFORMS=cpu MXNET_TPU_BENCH_FORCE_CPU=1 python bench.py \
-        > /tmp/_bench_sanity.out 2>/dev/null
-    tail -1 /tmp/_bench_sanity.out > /tmp/_bench_sanity.json
-    python -c "
-import json
-d = json.load(open('/tmp/_bench_sanity.json'))
-for k in ('mfu', 'achieved_tflops', 'peak_device_bytes',
-          'comm_bytes_per_step', 'memory_headroom_bytes',
-          'oom_recoveries', 'check_findings', 'step_skew_p99_ms',
-          'opt_state_bytes_per_device'):
-    assert k in d, f'bench JSON missing {k}: {sorted(d)}'
-    assert d[k] is None or isinstance(d[k], (int, float)), (k, d[k])
-# mx.zero provenance: always present; a default (zero=off) run reports
-# zero_enabled false and a positive unsharded opt-state byte count
-assert d.get('zero_enabled') is False, d.get('zero_enabled')
-assert d['opt_state_bytes_per_device'] is None \
-    or d['opt_state_bytes_per_device'] > 0, d['opt_state_bytes_per_device']
-assert d.get('remat_policy') in ('none', 'dots_saveable', 'layers',
-                                 'full'), d.get('remat_policy')
-assert d['mfu'] is None, 'CPU run must report mfu null, not a number'
-assert d['achieved_tflops'] is None or d['achieved_tflops'] > 0
-assert d['check_findings'] == 0, \
-    f'bench graph must lint clean, got {d[\"check_findings\"]} findings'
-# mx.trace gang fields: a single-process CPU run can measure neither
-# gang skew nor a gang critical path — both must be null, never 0
-assert d['step_skew_p99_ms'] is None, \
-    'single-process bench must report null skew, not a number'
-assert 'critical_path' in d, f'bench JSON missing critical_path'
-assert d['critical_path'] is None or isinstance(d['critical_path'],
-                                                dict), d['critical_path']
-assert d['critical_path'] is None, '1-device bench must report null'
-# the provenance triple every bench row carries (PR 11, factored into
-# benchmarks/_provenance.py): the mx.ledger series key is built on it
-for k in ('platform', 'devices', 'smoke_mode'):
-    assert k in d, f'bench JSON missing provenance {k}: {sorted(d)}'
-assert d['smoke_mode'] is True and d['platform'] == 'cpu', d
-# mx.goodput ride-along: every bench row reports what fraction of the
-# measured wall-clock produced kept progress and the top badput cause
-# (nullable, but the keys must exist for the ledger trend series)
-for k in ('goodput_fraction', 'badput_top_cause'):
-    assert k in d, f'bench JSON missing {k}: {sorted(d)}'
-assert d['goodput_fraction'] is None or \
-    0.0 <= d['goodput_fraction'] <= 1.0, d['goodput_fraction']
-assert d['badput_top_cause'] is None or \
-    isinstance(d['badput_top_cause'], str), d['badput_top_cause']
-print('bench efficiency fields OK:', {k: d[k] for k in
-      ('mfu', 'achieved_tflops', 'peak_device_bytes',
-       'comm_bytes_per_step', 'check_findings', 'step_skew_p99_ms',
-       'critical_path')})
-"
+    # the measuring scripts have no CPU fallback: on a machine without
+    # a TPU each must exit non-zero and print no row (a number they print
+    # goes under a device metric's name). bench_dataloader is the host
+    # benchmark and is exempt.
+    for script in chip_smoke.py bench.py benchmarks/bench_serve.py \
+            benchmarks/bench_generate.py benchmarks/bench_kernels.py \
+            benchmarks/bench_attention.py benchmarks/bench_step_profile.py \
+            benchmarks/bench_resnet.py; do
+        if JAX_PLATFORMS=cpu python "$script" \
+                > /tmp/_nochip.out 2>/tmp/_nochip.err; then
+            echo "$script exited 0 without a TPU" >&2
+            exit 1
+        fi
+        if grep -q '^{' /tmp/_nochip.out; then
+            echo "$script printed a result row without a TPU" >&2
+            exit 1
+        fi
+        grep -q "cpu" /tmp/_nochip.err /tmp/_nochip.out
+    done
+    echo "no-chip refusal OK (chip_smoke, bench.py, benchmarks/*)"
+    # the smoke's CPU rehearsal (slow-marked out of the tier-1 sweep):
+    # every phase end to end at tiny sizes through the interpreter
+    JAX_PLATFORMS=cpu python -m pytest -q -p no:cacheprovider \
+        tests/unittest/test_chip_smoke.py::test_rehearsal_runs_every_phase_and_claims_nothing
     # mx.check must be disabled by default: the trainer and block hot
     # paths make zero analyzer calls (one module-bool check each), no
     # jaxpr is traced, and no findings registry accumulates
@@ -557,84 +526,6 @@ print('slo disabled fast path OK (zero hook calls) + access.jsonl schema OK')
         tests/unittest/test_serve.py::test_overload_acceptance_smoke \
         tests/unittest/test_slo.py::test_two_rank_overload_smoke \
         -q -p no:cacheprovider
-    # bench_serve row contract: the Poisson open-loop load generator
-    # reports throughput, TTFT percentiles and every overload counter —
-    # and a low-load CPU smoke must complete everything with ZERO
-    # deadline misses
-    JAX_PLATFORMS=cpu MXNET_TPU_BENCH_FORCE_CPU=1 \
-        python benchmarks/bench_serve.py \
-        > /tmp/_bench_serve.out 2>/dev/null
-    tail -1 /tmp/_bench_serve.out > /tmp/_bench_serve.json
-    python -c "
-import json
-d = json.load(open('/tmp/_bench_serve.json'))
-for k in ('tokens_per_sec', 'requests_per_sec', 'ttft_p50_ms',
-          'ttft_p99_ms', 'tbt_p99_ms', 'queue_share', 'slo_violations',
-          'requests', 'completed', 'rejected', 'shed',
-          'deadline_missed', 'cancelled', 'degraded', 'requeues',
-          'slots', 'queue_depth', 'offered_rps', 'platform', 'devices',
-          'smoke_mode'):
-    assert k in d, f'bench_serve JSON missing {k}: {sorted(d)}'
-assert d['tokens_per_sec'] > 0 and d['requests_per_sec'] > 0, d
-assert d['ttft_p50_ms'] is not None and d['ttft_p99_ms'] >= d['ttft_p50_ms']
-assert d['completed'] == d['requests'], \
-    f'low-load smoke must complete everything: {d}'
-assert d['deadline_missed'] == 0, \
-    f'low-load smoke must miss zero deadlines: {d}'
-# the mx.slo journal rode the measured window: the per-token gaps and
-# the phase attribution are populated, and at this low offered load no
-# objective fires (the slo_* knobs default off -> only availability can
-# violate, and everything completed)
-assert d['tbt_p99_ms'] is not None and d['tbt_p99_ms'] > 0, d
-assert d['queue_share'] is not None and 0.0 <= d['queue_share'] <= 1.0, d
-assert d['slo_violations'] == 0, \
-    f'low-load smoke must violate zero objectives: {d}'
-assert d['smoke_mode'] is True and d['platform'] == 'cpu', d
-print('bench_serve contract OK:', {k: d[k] for k in
-      ('tokens_per_sec', 'ttft_p50_ms', 'ttft_p99_ms', 'tbt_p99_ms',
-       'queue_share', 'requests_per_sec', 'deadline_missed')})
-"
-    # bench_kernels row contract: one row per pallas_ops kernel with
-    # pallas-vs-XLA timing and the roofline verdicts; the CPU smoke runs
-    # the kernels through the interpreter and must be marked smoke_mode
-    # (bench_diff refuses to compare it against TPU rows)
-    JAX_PLATFORMS=cpu MXNET_TPU_BENCH_FORCE_CPU=1 \
-        python benchmarks/bench_kernels.py \
-        > /tmp/_bench_kernels.out 2>/dev/null
-    python -c "
-import json
-rows = [json.loads(l) for l in open('/tmp/_bench_kernels.out')
-        if l.strip().startswith('{')]
-names = {r.get('metric') for r in rows}
-assert names == {'kernel_int8_matmul', 'kernel_fused_adam',
-                 'kernel_moe_dispatch_combine'}, names
-for d in rows:
-    for k in ('pallas_ms', 'xla_ms', 'speedup', 'roofline_xla',
-              'roofline_pallas', 'shape', 'platform', 'devices',
-              'smoke_mode'):
-        assert k in d, f'bench_kernels row missing {k}: {sorted(d)}'
-    assert d['pallas_ms'] > 0 and d['xla_ms'] > 0, d
-    assert d['smoke_mode'] is True and d['platform'] == 'cpu', d
-    assert d['roofline_xla'] is None, 'CPU must report null roofline'
-print('bench_kernels contract OK:',
-      {d['metric']: d['speedup'] for d in rows})
-"
-    # bench_generate rows carry platform provenance like every bench row
-    # since PR 11 (smoke_mode=true CPU rows never compare against TPU)
-    JAX_PLATFORMS=cpu MXNET_TPU_BENCH_FORCE_CPU=1 \
-        python benchmarks/bench_generate.py \
-        > /tmp/_bench_gen.out 2>/dev/null
-    python -c "
-import json
-rows = [json.loads(l) for l in open('/tmp/_bench_gen.out')
-        if l.strip().startswith('{')]
-assert len(rows) == 2, rows
-for d in rows:
-    for k in ('platform', 'devices', 'smoke_mode', 'tokens_per_sec'):
-        assert k in d, f'bench_generate row missing {k}: {sorted(d)}'
-    assert d['smoke_mode'] is True and d['platform'] == 'cpu', d
-print('bench_generate provenance OK')
-"
     # scope must be disabled by default: the trainer hook site makes zero
     # on_step calls (one module-bool check), no introspection state or
     # HTTP thread is allocated, and nothing listens on scope_port — the
@@ -834,78 +725,6 @@ assert out is None and t1 is None, (out, t1)
 assert calls == {'record': 0, 'append': 0}, calls
 print('ledger disabled fast path OK (zero record calls, nothing written)')
 "
-    # all eight bench entrypoints emit the same provenance contract now:
-    # exercise the four that used to lack it (bench_resnet /
-    # bench_attention / bench_dataloader / bench_step_profile) on the
-    # CPU smoke path with the ledger armed, then assert both the row
-    # fields and the appended run records land in DISJOINT series from
-    # any TPU provenance
-    PROV_LDIR=$(mktemp -d)
-    JAX_PLATFORMS=cpu MXNET_TPU_BENCH_FORCE_CPU=1 \
-        MXNET_TPU_LEDGER_DIR="$PROV_LDIR" \
-        python benchmarks/bench_resnet.py \
-        > /tmp/_bench_resnet.out 2>/dev/null
-    JAX_PLATFORMS=cpu MXNET_TPU_BENCH_FORCE_CPU=1 \
-        MXNET_TPU_LEDGER_DIR="$PROV_LDIR" \
-        python benchmarks/bench_attention.py \
-        > /tmp/_bench_attn.out 2>/dev/null
-    JAX_PLATFORMS=cpu MXNET_TPU_BENCH_FORCE_CPU=1 \
-        MXNET_TPU_LEDGER_DIR="$PROV_LDIR" MXNET_TPU_BENCH_DL_IMAGES=96 \
-        MXNET_TPU_BENCH_DL_MIN=96 MXNET_TPU_BENCH_DL_MIN_DL=64 \
-        python benchmarks/bench_dataloader.py \
-        > /tmp/_bench_dl.out 2>/dev/null
-    JAX_PLATFORMS=cpu MXNET_TPU_BENCH_FORCE_CPU=1 \
-        MXNET_TPU_LEDGER_DIR="$PROV_LDIR" \
-        python benchmarks/bench_step_profile.py \
-        > /tmp/_bench_sp.out 2>/dev/null
-    MXNET_TPU_LEDGER_PROV_DIR="$PROV_LDIR" python -c "
-import importlib.util, json, os
-spec = importlib.util.spec_from_file_location('mx_ledger',
-                                              'mxnet_tpu/ledger.py')
-led = importlib.util.module_from_spec(spec)
-spec.loader.exec_module(led)
-for path in ('/tmp/_bench_resnet.out', '/tmp/_bench_attn.out',
-             '/tmp/_bench_dl.out', '/tmp/_bench_sp.out'):
-    rows = [json.loads(l) for l in open(path)
-            if l.strip().startswith('{')]
-    assert rows, f'{path}: no JSON rows'
-    for d in rows:
-        for k in ('platform', 'devices', 'smoke_mode'):
-            assert k in d, f'{path} row missing {k}: {sorted(d)}'
-        assert d['platform'] == 'cpu' and d['smoke_mode'] is True, d
-recs = led.read_records(os.environ['MXNET_TPU_LEDGER_PROV_DIR'])
-benches = sorted(r['bench'] for r in recs if r.get('kind') == 'run')
-assert benches == ['bench_attention', 'bench_dataloader',
-                   'bench_resnet', 'bench_step_profile'], benches
-for r in recs:
-    if r.get('kind') != 'run':
-        continue
-    key = led.provenance_key(r)
-    assert 'smoke=True' in key and 'platform=cpu' in key, key
-print('bench provenance contract OK (all four formerly-gapped'
-      ' entrypoints, ledger records in smoke-keyed series)')
-"
-    rm -rf "$PROV_LDIR"
-    # the real trend ledger: backfill the driver artifacts (idempotent),
-    # append the current run, render the trajectory (run 2's TPU anchor
-    # must survive), and gate — a confirmed like-provenance regression
-    # exits nonzero; smoke-only history and thin history only warn
-    CI_LDIR="${MXNET_TPU_LEDGER_DIR:-/tmp/_ci_ledger}"
-    python tools/ledger_report.py "$CI_LDIR" \
-        --import BENCH_r*.json MULTICHIP_r*.json
-    JAX_PLATFORMS=cpu MXNET_TPU_BENCH_FORCE_CPU=1 \
-        MXNET_TPU_LEDGER_DIR="$CI_LDIR" python bench.py \
-        > /tmp/_ledger_bench.out 2>/dev/null
-    python tools/ledger_report.py "$CI_LDIR" > /tmp/_ledger_report.out
-    cat /tmp/_ledger_report.out
-    grep -q "BENCH_r02.json" /tmp/_ledger_report.out
-    grep -q "TPU anchors" /tmp/_ledger_report.out
-    gate_rc=0
-    python tools/ledger_report.py "$CI_LDIR" --gate || gate_rc=$?
-    if [ "$gate_rc" -eq 1 ]; then
-        echo "ledger gate: CONFIRMED like-provenance regression" >&2
-        exit 1
-    fi
     # seeded-regression acceptance: a synthetic 30%-degraded
     # like-provenance run must turn the gate red NAMING the metric and
     # the first bad run, while the SAME degraded row under smoke-mode
@@ -952,7 +771,7 @@ print('seeded regression ledger at', path)
     MXNET_TPU_LEDGER_GATE=warn python tools/ledger_report.py \
         "$SEED_DIR" --gate > /dev/null
     rm -rf "$SEED_DIR"
-    echo "ledger stage OK: provenance contract, backfill+anchor, gate"
+    echo "ledger stage OK: off-path contract, seeded-regression gate"
 }
 
 pages_stage() {

@@ -400,7 +400,9 @@ def lint_jaxpr(name, closed, donated_flat=(), can_donate=False):
     for jaxpr, consts in _walk_jaxprs(closed):
         if const_thresh > 0:
             for c in consts:
-                nbytes = int(getattr(c, "nbytes", 0) or 0)
+                # size x itemsize, not .nbytes: closure constants arrive
+                # as jax TypedNdArray literals, which have no nbytes
+                nbytes = _aval_nbytes(c)
                 if nbytes >= const_thresh:
                     report_finding(
                         "large-constant", name,

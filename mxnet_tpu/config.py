@@ -132,21 +132,22 @@ register_option(
     "forked worker). 0 disables the watchdog.")
 register_option(
     "kernels", "auto", choices=("off", "auto", "on"),
-    doc="mx.kernels Pallas library gate (pallas_ops/: int8 serving "
-        "matmul with fused per-channel rescale, fused optimizer "
-        "updates, fused MoE dispatch/combine). 'off': every call site "
-        "runs its bit-exact XLA-native fallback and nothing imports "
+    doc="mx.kernels Pallas library gate (pallas_ops/: flash "
+        "attention, int8 serving matmul with fused per-channel "
+        "rescale, fused optimizer updates, paged decode attention, "
+        "fused MoE dispatch/combine). 'off': every call site runs its "
+        "XLA-native fallback and nothing imports "
         "jax.experimental.pallas (the trainer hot loop stays "
         "pallas-free — asserted by ci/run.sh sanity). 'auto' "
         "(default): a kernel engages when it can win — a TPU backend "
         "(or MXNET_TPU_PALLAS_INTERPRET=1, the interpreter path "
         "tier-1 tests ride), shape eligibility, and for the "
-        "fused-update kernels a single-device step (pallas_call has "
-        "no GSPMD rule; the MoE kernels run inside shard_map and "
-        "engage on any mesh). 'on' raises instead of silently falling "
-        "back when Pallas cannot run. Decided at trace time: 'off' "
-        "executables are byte-identical to a build without the "
-        "library.")
+        "fused-update and paged-attention kernels a single-device "
+        "step (jit cannot partition a pallas_call; flash attention "
+        "and the MoE kernels run inside shard_map and engage on any "
+        "mesh). 'on' raises instead of silently falling back when "
+        "Pallas cannot run. Decided at trace time: 'off' executables "
+        "are byte-identical to a build without the library.")
 register_option(
     "kernels_min_elements", 1 << 16,
     "Smallest buffer (elements) the fused optimizer-update kernels "
@@ -203,15 +204,6 @@ register_option(
     "fires (names the last-entered scope, dumps all-thread stacks and a "
     "post-mortem, then re-arms on the next step). 0 disables the "
     "watchdog thread entirely.")
-register_option(
-    "compile_cache_dir", "",
-    "Directory for jax's persistent XLA compilation cache, wired at first "
-    "trainer construction (mx.dataflow.ensure_compile_cache). Relaunches "
-    "then skip cold compiles: executables serialize to disk and reload in "
-    "milliseconds. Empty disables persistence. Cache hits/misses land in "
-    "the compile_cache_hits_total / compile_cache_misses_total telemetry "
-    "counters (tools/telemetry_report.py separates warm from cold "
-    "compiles).")
 register_option(
     "trainer_async_fence_every", 0,
     "Host-fence the trainers every N steps (block_until_ready on the "

@@ -36,13 +36,16 @@ class Context:
     # -- jax interop ------------------------------------------------------
     @property
     def jax_device(self):
-        """The concrete jax device this context names."""
+        """The concrete jax device this context names — or, when that
+        platform is absent (a CPU-only test run), the default backend's
+        device with the same index, so code written for `tpu()` still
+        runs. `mx.tpu(0)` is therefore NOT proof of a TPU: a script that
+        must run on the chip asserts `jax.devices()[0].platform` itself
+        (chip_smoke.py does)."""
         platform = self.device_type
         try:
             devs = jax.devices(platform)
         except RuntimeError:
-            # Accelerator not present (e.g. CPU-only test run): fall back to
-            # the default backend so code written for tpu() still runs.
             devs = jax.devices()
         return devs[self.device_id % len(devs)]
 

@@ -18,9 +18,10 @@ compute instead of serializing with it:
     power-of-two buckets (pairing each pad with a valid-length input) so a
     stream of novel sequence lengths compiles a handful of executables
     instead of one per length.
-  * `ensure_compile_cache()` — wires jax's persistent XLA compilation cache
-    from the `compile_cache_dir` knob at first trainer construction, so
-    relaunches skip cold compiles entirely.
+  * `ensure_compile_cache()` — turns on jax's persistent XLA compilation
+    cache at first trainer construction (`JAX_COMPILATION_CACHE_DIR` when
+    set, else the fixed `<checkout>/.jax_cache`), so relaunches skip cold
+    compiles entirely.
 
 Telemetry (all series degrade to a module-bool check when disabled):
 `dataloader_prefetch_depth{stage="device"}` (staged-batch depth, distinct
@@ -693,64 +694,49 @@ def _data_axis_extent(trainer):
 # persistent XLA compilation cache
 # ---------------------------------------------------------------------------
 
-# None = not attempted yet (knob may still be set later); "" = attempted
-# and failed (don't retry, don't claim success); path = wired
-_cache_state = None
+_cache_dir = None                 # wired cache directory, once decided
 _cache_lock = _locklint.make_lock("dataflow.compile_cache")
 
 
 def ensure_compile_cache():
-    """Wire jax's persistent compilation cache from the `compile_cache_dir`
-    knob (idempotent; called at first trainer construction). Relaunches
-    then deserialize executables instead of recompiling — the BERT-large
-    cold-compile killer. No-op when the knob is empty or the backend
-    cannot serialize executables. Returns the wired cache dir, or None
-    when the knob is empty or wiring failed."""
-    global _cache_state
+    """Turn on jax's persistent compilation cache and return its
+    directory (idempotent; called at first trainer construction and by
+    the entry-point scripts). THE one place that decides where the cache
+    lives: where `JAX_COMPILATION_CACHE_DIR` is set, jax has already
+    read it and nothing here moves it; otherwise the cache goes to the
+    fixed `<checkout>/.jax_cache` — never a temporary or per-process
+    path, because the path is part of what makes the next run hit.
+    Relaunches then deserialize executables instead of recompiling; hits
+    and misses land in the compile_cache_hits_total /
+    compile_cache_misses_total telemetry counters."""
+    global _cache_dir
     with _cache_lock:
-        if _cache_state is not None:
-            return _cache_state or None
-        cache_dir = _config.get("compile_cache_dir")
+        if _cache_dir is not None:
+            return _cache_dir
+        import jax
+        cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
         if not cache_dir:
-            return None          # knob empty: stays re-armable
-        try:
-            import jax
-            cache_dir = os.path.abspath(cache_dir)
-            os.makedirs(cache_dir, exist_ok=True)
+            cache_dir = os.path.join(
+                os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+                ".jax_cache")
             jax.config.update("jax_compilation_cache_dir", cache_dir)
-            jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-            jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                              0.1)
-            _register_cache_listener()
-            _cache_state = cache_dir
-            return cache_dir
-        except Exception as e:  # pragma: no cover - backend-dependent
-            _cache_state = ""    # don't retry, and never report success
-            import warnings
-            warnings.warn(f"persistent compile cache unavailable: {e}")
-            return None
-
-
-_listener_registered = False
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.1)
+        _register_cache_listener()
+        _cache_dir = cache_dir
+        return cache_dir
 
 
 def _register_cache_listener():
     """Mirror jax's compilation-cache hit/miss monitoring events into the
     telemetry counters, so reports can separate warm (deserialized) from
     cold (full XLA) compiles."""
-    global _listener_registered
-    if _listener_registered:
-        return
-    try:
-        from jax import monitoring
+    from jax import monitoring
 
-        def _on_event(event, **kwargs):
-            if event == "/jax/compilation_cache/cache_hits":
-                _M_CACHE_HITS.inc()
-            elif event == "/jax/compilation_cache/cache_misses":
-                _M_CACHE_MISSES.inc()
+    def _on_event(event, **kwargs):
+        if event == "/jax/compilation_cache/cache_hits":
+            _M_CACHE_HITS.inc()
+        elif event == "/jax/compilation_cache/cache_misses":
+            _M_CACHE_MISSES.inc()
 
-        monitoring.register_event_listener(_on_event)
-        _listener_registered = True
-    except Exception:  # pragma: no cover - older jax without monitoring
-        pass
+    monitoring.register_event_listener(_on_event)

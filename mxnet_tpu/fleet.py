@@ -1083,6 +1083,10 @@ def run_replica(argv=None):
             int(_config.get("fleet_port")) + 1 + replica))
     version = os.environ.get("MXNET_TPU_FLEET_VERSION", "v0")
 
+    # every visible device: fine for the CPU gang this is tested as; on
+    # a chip host two local replicas would both claim every chip, and a
+    # mesh wider than one device keeps the paged-attention kernel off
+    # (one replica per chip needs per-process device assignment — W2)
     _parallel.make_mesh(dp=-1)
     cfg_fn = getattr(_gpt, f"{args.model}_config")
     mx.random.seed(args.seed)

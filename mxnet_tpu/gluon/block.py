@@ -366,7 +366,7 @@ class HybridBlock(Block):
                 if (_check._enabled and _memsafe._enabled) else None
             if _memsafe._enabled:
                 # pre-flight budget check BEFORE the first dispatch: AOT
-                # lower+compile (warm via compile_cache_dir for the real
+                # lower+compile (warm via the persistent cache for the real
                 # call below) and compare predicted peak + resident
                 # params/inputs against device capacity — a predicted
                 # overrun raises MemoryBudgetError with nothing dispatched
@@ -428,7 +428,7 @@ class HybridBlock(Block):
             # first call and its telemetry/ring records so the analysis
             # compile neither inflates compile_seconds nor steals the
             # persistent-cache cold miss (it is served warm from the real
-            # compile when compile_cache_dir is set). A child block
+            # compile through the persistent cache). A child block
             # compiling INSIDE a parent trace (tracer inputs) is skipped —
             # the parent's executable subsumes its cost
             _inspect.analyze_jit(type(self).__name__, _inspect.key_repr(key),

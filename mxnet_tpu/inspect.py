@@ -38,7 +38,7 @@ Cost model: DISABLED (the default) is the production fast path — every
 hook site checks one module-level bool and falls through; no analysis
 compile, no allocation (`ci/run.sh sanity` asserts it). ENABLED costs one
 extra lower+compile per jit-cache miss (served warm from the persistent
-XLA cache when `compile_cache_dir` is set) and a per-step fence in the
+XLA compile cache) and a per-step fence in the
 trainers so recorded step time is device time. Backends that return
 partial or no cost analysis (CPU reports flops but little else) degrade
 to null fields, never a crash.
@@ -480,8 +480,8 @@ def record_compiled(name, key, compiled, collectives=None):
 def analyze_jit(name, key, jitted, *args, collectives=None):
     """Lower + compile `jitted` at `args`' signature purely for analysis
     and record the result (the execution path keeps its own lazily
-    compiled executable — with `compile_cache_dir` set the second compile
-    deserializes from the persistent cache instead of rebuilding).
+    compiled executable — the second compile deserializes from the
+    persistent cache instead of rebuilding).
     Returns the CostRecord, or one with an analysis_error when the
     backend cannot lower/compile out-of-line. Never raises."""
     if not _enabled:

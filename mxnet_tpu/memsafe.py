@@ -45,8 +45,8 @@ Cost model: DISABLED (the default) is the production fast path — the
 trainer/block hook sites check one module-level bool and fall through; no
 analysis compile, no capacity probe, no recovery handler (`ci/run.sh
 sanity` asserts it). ENABLED costs one extra lower+compile per jit-cache
-miss (served warm from the persistent XLA cache when `compile_cache_dir`
-is set) — the same trade `mx.inspect` makes.
+miss (served warm from the persistent XLA compile cache) — the same
+trade `mx.inspect` makes.
 """
 from __future__ import annotations
 
@@ -334,8 +334,8 @@ def check_budget(executable, exec_peak, resident, capacity=None):
 
 def _analyze(jitted, args, traced=None):
     """AOT lower+compile purely for memory analysis;
-    (exec_peak, compiled, error). With compile_cache_dir set the real
-    first call deserializes this same executable warm. Never raises — a
+    (exec_peak, compiled, error). The real first call deserializes
+    this same executable warm from the persistent cache. Never raises — a
     backend that cannot lower out of line degrades the check to
     resident-state accounting. `traced`: a pre-computed jax Traced (from
     mx.check's lint of the same miss) lowered directly, so check+memsafe
@@ -359,8 +359,8 @@ def aot_exec_peak(jitted, args):
     the analysis `_analyze` runs at every preflight, for callers that
     budget BEFORE building state (mx.serve admission control sizes KV
     caches this way; `ShardedTrainer.predict_step_bytes` is the training
-    twin). Nothing is dispatched; with compile_cache_dir set the real
-    first call deserializes the same executable warm. None when the
+    twin). Nothing is dispatched; the real first call deserializes
+    the same executable warm from the persistent cache. None when the
     backend withholds analysis — never raises."""
     peak, _compiled, _err = _analyze(jitted, args)
     return peak

@@ -208,7 +208,8 @@ def jit_flat_step(model, step_fn, n_state, donate_state=0):
     `run.aot_exec_peak(*leading_avals, state_avals)` — AOT lower+compile
     at those (shape, dtype)s purely for XLA memory analysis (mx.serve's
     admission control budgets KV-cache growth with it; nothing is
-    dispatched and no batch transfers)."""
+    dispatched and no batch transfers) — and `run.lower(...)`, the same
+    call stopped at the `jax.stages.Lowered` form."""
     import time
 
     import jax
@@ -264,31 +265,40 @@ def jit_flat_step(model, step_fn, n_state, donate_state=0):
             outs, _ = entry(gp_data, aux_data, rng, *leading, *state)
         return outs[0], list(outs[1:])
 
-    def aot_exec_peak(*args):
-        """Execution-peak bytes (beyond argument buffers) of a call with
-        these (shape, dtype) arguments — jax.ShapeDtypeStructs or arrays;
-        pure AOT analysis via mx.memsafe, no dispatch, no transfer, and
-        nothing installed into the call cache (the real first call still
-        runs the mx.check lint; with compile_cache_dir set it
-        deserializes this same executable warm). None when the backend
-        withholds memory analysis."""
-        from .. import memsafe as _memsafe
-
+    def _aot(args):
+        """(fresh jit, full argument tuple) for a call with these
+        (shape, dtype) arguments — jax.ShapeDtypeStructs or arrays.
+        Nothing is installed into the call cache: the real first call
+        still runs the mx.check lint, and deserializes this same
+        executable warm from the persistent compile cache."""
         leading, state = args[:-1], list(args[-1])
         gp_data = [p.data()._data for _, p in gp]
         aux_data = [p.data()._data for _, p in aux]
         base = 3 + len(leading)
         donate = tuple(range(base, base + int(donate_state)))
-        jitted = jax.jit(pure, donate_argnums=donate)
 
         def aval(x):
             if isinstance(x, jax.ShapeDtypeStruct):
                 return x
             return jax.ShapeDtypeStruct(tuple(x.shape), x.dtype)
 
-        full = (gp_data, aux_data, rng) + tuple(aval(a) for a in leading) \
+        return jax.jit(pure, donate_argnums=donate), \
+            (gp_data, aux_data, rng) + tuple(aval(a) for a in leading) \
             + tuple(aval(s) for s in state)
-        return _memsafe.aot_exec_peak(jitted, full)
 
+    def lower(*args):
+        """`jax.stages.Lowered` of a call with these arguments; no
+        dispatch, no transfer."""
+        jitted, full = _aot(args)
+        return jitted.lower(*full)
+
+    def aot_exec_peak(*args):
+        """Execution-peak bytes (beyond argument buffers) of a call with
+        these arguments — pure AOT analysis via mx.memsafe. None when the
+        backend withholds memory analysis."""
+        from .. import memsafe as _memsafe
+        return _memsafe.aot_exec_peak(*_aot(args))
+
+    run.lower = lower
     run.aot_exec_peak = aot_exec_peak
     return run

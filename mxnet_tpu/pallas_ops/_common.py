@@ -20,14 +20,16 @@ the compiled computation), so `off` runs are byte-identical to a build
 without this package: the fallback expression IS the pre-kernel code.
 
 SPMD caveat, shared by every kernel here: `pl.pallas_call` has no GSPMD
-partitioning rule, so inside an SPMD-jitted step on a multi-device mesh
-the partitioner would resolve it by gather-to-replicated — worse than
-the XLA lowering it replaces. Kernels that run inside `shard_map`
-(`parallel/moe.py` — per-device manual code) engage on any mesh; the
-global-view fused-update kernels engage only when one process sees one
-device (`multi_device()` is False). The per-shard MATH composes with
-mx.zero regardless — `tests/unittest/test_kernels.py` pins that a
-sharded application (kernel per flat shard) is bit-exact against the
+partitioning rule — on a TPU, jit refuses a Mosaic kernel whose operands
+are sharded ("Mosaic kernels cannot be automatically partitioned").
+Kernels therefore run as per-device manual code: `flash_attention`
+wraps itself in `shard_map` when the installed mesh spans more than one
+device, `parallel/moe.py` and the ring/pipeline paths already run inside
+one (`in_shard_map()`), and the global-view fused-update and
+paged-attention kernels engage only when the step sees one device
+(`multi_device()` is False). The per-shard MATH composes with mx.zero
+regardless — `tests/unittest/test_kernels.py` pins that a sharded
+application (kernel per flat shard) is bit-exact against the
 whole-vector kernel.
 """
 from __future__ import annotations
@@ -37,8 +39,8 @@ import os
 from .. import config as _config
 
 __all__ = ["interpret", "pallas_available", "use_pallas", "require",
-           "multi_device", "min_elements", "load_pallas",
-           "compiler_params", "round_up", "row8"]
+           "installed_mesh", "multi_device", "in_shard_map", "min_elements",
+           "load_pallas", "compiler_params", "round_up", "row8"]
 
 # the pallas module, bound by load_pallas() at first kernel engagement —
 # ONE copy of the lazy-import logic for the whole library (kernels=off /
@@ -55,12 +57,10 @@ def load_pallas():
 
 
 def compiler_params(**kw):
-    """TPU compiler params under the post-rename spelling: jax 0.4.x
-    calls it TPUCompilerParams, newer jax CompilerParams — resolved here
-    ONCE for every kernel module (a jax rename is a one-line fix)."""
+    """Mosaic compiler params — the one spelling every kernel module
+    uses (a jax rename is a one-line fix)."""
     from jax.experimental.pallas import tpu as pltpu
-    cp = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    return cp(**kw)
+    return pltpu.CompilerParams(**kw)
 
 
 def smem():
@@ -88,23 +88,17 @@ def interpret():
 
 
 def pallas_available():
-    """True when a TPU backend (or the interpreter) can run a kernel
-    AND the pallas import succeeds. The backend test comes FIRST: on a
-    CPU backend without the interpreter this returns False without ever
-    importing `jax.experimental.pallas`, so a kernels=auto process on
+    """True when a TPU backend (or the interpreter) can run a kernel.
+    Never imports `jax.experimental.pallas`: a kernels=auto process on
     CPU — and any kernels=off process — keeps pallas out of sys.modules
     entirely (ci/run.sh sanity asserts it after a trainer step +
-    QuantizedDense forward)."""
-    if not interpret():
-        import jax
-        if jax.default_backend() != "tpu":
-            return False
-    try:
-        from jax.experimental import pallas  # noqa: F401
-        from jax.experimental.pallas import tpu  # noqa: F401
-    except Exception:        # pragma: no cover - pallas ships with jax
-        return False
-    return True
+    QuantizedDense forward). The import happens at engagement
+    (`load_pallas`), and a failure there raises: on a TPU a missing
+    kernel library is an error, not a reason to run the L×L fallback."""
+    if interpret():
+        return True
+    import jax
+    return jax.default_backend() == "tpu"
 
 
 def use_pallas():
@@ -133,26 +127,33 @@ def require():
             "kernels='auto' to fall back to the XLA lowering silently.")
 
 
+def installed_mesh():
+    """The mesh `parallel.make_mesh`/`set_mesh` installed, or None —
+    read without creating one (`parallel.current_mesh()` would)."""
+    from ..parallel import mesh as _mesh
+    return _mesh._current["mesh"]
+
+
 def multi_device():
     """True when the step being traced spans more than one device — the
-    SPMD regime where a pallas_call inside a global-view jit would be
-    resolved by gather-to-replicated (see module docstring). The
-    installed parallel mesh is the authority when one exists (a 1-device
-    mesh on an 8-device host is still a single-device step); otherwise
-    the local device count decides. Checked at trace time; never
-    cold-inits a backend beyond what jit already did."""
-    try:
-        from ..parallel import mesh as _mesh
-        m = _mesh._current.get("mesh")
-        if m is not None:
-            return int(m.size) > 1
-    except Exception:        # pragma: no cover
-        pass
+    SPMD regime where jit refuses a global-view pallas_call (see module
+    docstring). The installed parallel mesh is the authority when one
+    exists (a 1-device mesh on a 4-chip host is still a single-device
+    step); otherwise the local device count decides. Checked at trace
+    time."""
+    m = installed_mesh()
+    if m is not None:
+        return int(m.size) > 1
     import jax
-    try:
-        return jax.local_device_count() > 1
-    except Exception:        # pragma: no cover
-        return True
+    return jax.local_device_count() > 1
+
+
+def in_shard_map():
+    """True while tracing the body of a `shard_map` (ring/ulysses
+    attention, MoE, the pipeline trainers): arrays are already
+    per-device there, so a kernel runs as it stands."""
+    import jax
+    return bool(jax.sharding.get_abstract_mesh().manual_axes)
 
 
 def min_elements():

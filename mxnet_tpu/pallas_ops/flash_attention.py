@@ -30,6 +30,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from . import _common
+
 _NEG = -1e30
 
 
@@ -57,47 +59,20 @@ def mha_reference(q, k, v, bias=None, causal=False, sm_scale=None,
     return jnp.einsum("bhqk,bhkd->bhqd", p, v)
 
 
-# pallas binds LAZILY at first use (mx.kernels hygiene: this module is
-# reachable from hot paths via the pallas_ops package, and a kernels=off
-# / CPU process must keep jax.experimental.pallas out of sys.modules —
-# ci/run.sh sanity asserts it). `has_pallas()` resolves the import once;
-# the legacy `_HAS_PALLAS` module global keeps its meaning after that.
+# pallas binds lazily at first kernel engagement (shared logic in
+# _common): this module is reachable from hot paths via the pallas_ops
+# package, and a kernels=off / CPU process must keep
+# jax.experimental.pallas out of sys.modules (ci/run.sh sanity asserts it)
 pl = None
 pltpu = None
-_CompilerParams = None
-_HAS_PALLAS = None
 
 
-def has_pallas():
-    """Resolve (once) whether pallas imports here. Replaces the old
-    import-time `_HAS_PALLAS` probe; callers that read the module global
-    directly must call this first (ring_attention does)."""
-    global pl, pltpu, _CompilerParams, _HAS_PALLAS
-    if _HAS_PALLAS is None:
-        try:
-            from jax.experimental import pallas as _pl
-            from jax.experimental.pallas import tpu as _pltpu
-            pl, pltpu = _pl, _pltpu
-            # jax 0.4.x spells it TPUCompilerParams; newer jax renamed
-            # it to CompilerParams. A module-LOCAL alias keeps the
-            # kernels on the new name without mutating jax's namespace
-            # (other libraries in the same process may feature-detect
-            # the rename via hasattr).
-            _CompilerParams = getattr(_pltpu, "CompilerParams", None) \
-                or _pltpu.TPUCompilerParams
-            _HAS_PALLAS = True
-        except Exception:  # pragma: no cover
-            _HAS_PALLAS = False
-    return _HAS_PALLAS
-
-
-def _interpret():
-    """MXNET_TPU_PALLAS_INTERPRET=1 runs the kernels through the Pallas
-    interpreter on any backend — the only way the kernel CODE (not the jnp
-    fallback) gets exercised off-TPU, used by
-    tests/unittest/test_flash_interpret.py."""
-    import os
-    return os.environ.get("MXNET_TPU_PALLAS_INTERPRET", "0") == "1"
+def load_pallas():
+    global pl, pltpu
+    pl = _common.load_pallas()
+    if pltpu is None:
+        from jax.experimental.pallas import tpu as _pltpu
+        pltpu = _pltpu
 
 
 # --------------------------------------------------------------------------
@@ -231,9 +206,10 @@ def _flash_fwd_pallas(q, k, v, bias, seed, causal, sm_scale, block_q, block_k,
             jax.ShapeDtypeStruct((B * H, Lq, D), q.dtype),
             jax.ShapeDtypeStruct((B * H, 8, Lq), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=_common.compiler_params(
             dimension_semantics=("parallel", "arbitrary")),
-        interpret=_interpret(),
+        interpret=_common.interpret(),
+        name="flash_fwd",
     )(qr, kr, vr, bias8, seed)
     return out.reshape(B, H, Lq, D), lse
 
@@ -370,9 +346,10 @@ def _flash_bwd_pallas(q, k, v, bias, seed, out, lse, g, causal, sm_scale,
         ],
         out_specs=pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B * H, Lq, D), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=_common.compiler_params(
             dimension_semantics=("parallel", "arbitrary")),
-        interpret=_interpret(),
+        interpret=_common.interpret(),
+        name="flash_dq",
     )(qr, kr, vr, bias8, gr, lse, delta8, seed)
 
     dk, dv = pl.pallas_call(
@@ -398,9 +375,10 @@ def _flash_bwd_pallas(q, k, v, bias, seed, out, lse, g, causal, sm_scale,
             jax.ShapeDtypeStruct((B * H, Lk, D), k.dtype),
             jax.ShapeDtypeStruct((B * H, Lk, D), v.dtype),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=_common.compiler_params(
             dimension_semantics=("parallel", "arbitrary")),
-        interpret=_interpret(),
+        interpret=_common.interpret(),
+        name="flash_dkv",
     )(qr, kr, vr, bias8, gr, lse, delta8, seed)
 
     return (dq.reshape(B, H, Lq, D), dk.reshape(B, H, Lk, D),
@@ -507,26 +485,69 @@ def flash_attention(q, k, v, mask=None, causal=False, sm_scale=None,
         dropout_key (a jax PRNG key); silently 0 when the key is absent so
         inference code never pays for RNG plumbing.
     Returns (batch, heads, q_seq, head_dim), q.dtype.
+
+    Engagement follows the `kernels` knob like every other kernel
+    (`_common.use_pallas`): off, or a non-TPU backend without the
+    interpreter, runs `mha_reference`. On a mesh of more than one device
+    the kernel runs per device under `shard_map` — batch on the data
+    axes, heads on `tp` when divisible, sequence whole (jit refuses to
+    partition a Mosaic kernel by itself) — and each shard folds its mesh
+    position into the dropout key so no two shards draw the same masks.
     """
     if sm_scale is None:
         sm_scale = 1.0 / (q.shape[-1] ** 0.5)
-    if dropout_key is None:
-        dropout = 0.0
-    B, H, Lq, D = q.shape
-    Lk = k.shape[2]
+    dropout = float(dropout)
+    if dropout_key is None or dropout == 0.0:
+        dropout, dropout_key = 0.0, None
 
-    # backend test FIRST: a CPU backend without the interpreter never
-    # triggers the pallas import at all (mx.kernels hygiene)
-    use_pallas = (jax.default_backend() == "tpu" or _interpret()) \
-        and has_pallas()
-    if not use_pallas:
+    if not _common.use_pallas():
         bias = None
         if mask is not None:
             bias = jnp.where(mask.astype(bool), 0.0, _NEG)[:, None, None, :]
         return mha_reference(q, k, v, bias=bias, causal=causal,
                              sm_scale=sm_scale, dropout=dropout,
                              dropout_key=dropout_key)
+    load_pallas()
 
+    def local(q, k, v, mask, key):
+        return _flash_local(q, k, v, mask, key, causal, sm_scale, block_q,
+                            block_k, dropout)
+
+    mesh = _common.installed_mesh()
+    if mesh is None or mesh.size == 1 or _common.in_shard_map():
+        return local(q, k, v, mask, dropout_key)
+
+    from jax.sharding import PartitionSpec as P
+    from ..parallel._compat import shard_map
+    from ..parallel.specs import attention_axes
+    bspec, hspec = attention_axes(mesh, q.shape[0], q.shape[1])
+    axes = (bspec or ()) + ((hspec,) if hspec else ())
+    qspec = P(bspec, hspec, None, None)
+    # optional operands ride as a dict so one body serves every
+    # (mask, dropout) combination
+    opt, opt_specs = {}, {}
+    if mask is not None:
+        opt["mask"], opt_specs["mask"] = mask, P(bspec, None)
+    if dropout_key is not None:
+        opt["key"], opt_specs["key"] = dropout_key, P()
+
+    def body(q, k, v, opt):
+        key = opt.get("key")
+        if key is not None and axes:
+            key = jax.random.fold_in(key, jax.lax.axis_index(axes))
+        return local(q, k, v, opt.get("mask"), key)
+
+    return shard_map(body, mesh=mesh,
+                     in_specs=(qspec, qspec, qspec, opt_specs),
+                     out_specs=qspec, check_vma=False)(q, k, v, opt)
+
+
+def _flash_local(q, k, v, mask, dropout_key, causal, sm_scale, block_q,
+                 block_k, dropout):
+    """The kernel call on one device's (B, H, L, D) operands: fit the
+    blocks, pad to them, derive the kernel PRNG seed."""
+    B, H, Lq, D = q.shape
+    Lk = k.shape[2]
     block_q = _fit_block(block_q, Lq)
     block_k = _fit_block(block_k, Lk)
     Lq_p, Lk_p = _round_up(Lq, block_q), _round_up(Lk, block_k)
@@ -540,13 +561,13 @@ def flash_attention(q, k, v, mask=None, causal=False, sm_scale=None,
         v = jnp.pad(v, ((0, 0), (0, 0), (0, Lk_p - Lk), (0, 0)))
     if Lq_p != Lq:
         q = jnp.pad(q, ((0, 0), (0, 0), (0, Lq_p - Lq), (0, 0)))
-    if dropout > 0.0:
+    if dropout_key is not None:
         seed = jax.lax.bitcast_convert_type(
             jax.random.bits(dropout_key, (1,), jnp.uint32), jnp.int32)
     else:
         seed = jnp.zeros((1,), jnp.int32)
     out = _flash(q, k, v, bias, seed, causal, sm_scale, block_q, block_k,
-                 float(dropout))
+                 dropout)
     if Lq_p != Lq:
         out = out[:, :, :Lq]
     return out

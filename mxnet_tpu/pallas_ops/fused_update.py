@@ -161,6 +161,7 @@ def adam_update(w, g, m, v, lr, beta1=0.9, beta2=0.999, epsilon=1e-8,
         compiler_params=_compiler_params(
             dimension_semantics=("parallel",)),
         interpret=_common.interpret(),
+        name="adam_update",
     )(lr1, w2, g2, m2, v2)
     return (new_w.reshape(-1)[:n].reshape(shape),
             new_m.reshape(-1)[:n].reshape(shape),
@@ -278,6 +279,7 @@ def lamb_pass1(W, G, m, v, wd_rows, c1, c2, *, beta1, beta2, epsilon,
         compiler_params=_compiler_params(
             dimension_semantics=("parallel",)),
         interpret=_common.interpret(),
+        name="lamb_pass1",
     )(sc, _pad_rc(W, Rp), _pad_rc(G, Rp),
       _pad_rc(m.reshape(R, C), Rp), _pad_rc(v.reshape(R, C), Rp),
       _pad_rc(wd_rows.reshape(R, 1), Rp))
@@ -315,6 +317,7 @@ def lamb_pass2(W, new_m, new_v, wd_rows, trust_rows, c1, c2, lr, *,
         compiler_params=_compiler_params(
             dimension_semantics=("parallel",)),
         interpret=_common.interpret(),
+        name="lamb_pass2",
     )(sc, _pad_rc(W, Rp), _pad_rc(new_m, Rp), _pad_rc(new_v, Rp),
       _pad_rc(wd_rows.reshape(R, 1), Rp),
       _pad_rc(trust_rows.reshape(R, 1), Rp))

@@ -131,6 +131,7 @@ def _int8_matmul_pallas(x_q, w_q_t, x_scale, w_scale, bias, relu):
         compiler_params=_compiler_params(
             dimension_semantics=("parallel", "parallel")),
         interpret=_common.interpret(),
+        name="int8_matmul",
     )(x2, w_q_t, _row8(s), _row8(b))
     return out[:M, :O].reshape(lead + (O,))
 

@@ -181,6 +181,7 @@ def _dispatch_pallas(x, expert, pos, num_experts, capacity):
         compiler_params=_compiler_params(
             dimension_semantics=("parallel",)),
         interpret=_common.interpret(),
+        name="moe_dispatch",
     )(x, _row8(expert.astype(jnp.int32)), _row8(pos.astype(jnp.int32)))
     return buf[:, :capacity, :D]
 
@@ -211,6 +212,7 @@ def _combine_pallas(buf, expert, pos, gate):
         compiler_params=_compiler_params(
             dimension_semantics=("arbitrary",)),
         interpret=_common.interpret(),
+        name="moe_combine",
     )(buf.astype(jnp.float32), _row8(expert.astype(jnp.int32)),
       _row8(pos.astype(jnp.int32)), _row8(gate.astype(jnp.float32)))
     return y[:N, :D]

@@ -91,10 +91,11 @@ def _kernel(tb_ref, t_ref, q_ref, k_ref, v_ref, o_ref, m_s, l_s, acc_s, *,
     k = k_ref[0].astype(jnp.float32)                     # (H, ps, D)
     v = v_ref[0].astype(jnp.float32)
     H, ps, _ = k.shape
-    # per-head single-query scores over this page's positions
-    s = jax.lax.dot_general(q, k, (((1,), (2,)), ((0,), (0,))),
-                            preferred_element_type=jnp.float32)
-    s = s * sm_scale                                     # (H, ps)
+    # per-head single-query scores over this page's positions. One
+    # query row per head is a batched matrix-VECTOR product, which
+    # Mosaic's dot_general refuses (no lhs non-contracting dim) — and
+    # the MXU would idle on it anyway; multiply-and-reduce on the VPU
+    s = jnp.sum(q[:, None, :] * k, axis=-1) * sm_scale   # (H, ps)
     pos = j * page_size + \
         jax.lax.broadcasted_iota(jnp.int32, (H, ps), 1)
     s = jnp.where(pos <= t_ref[b], s, _NEG)
@@ -105,9 +106,7 @@ def _kernel(tb_ref, t_ref, q_ref, k_ref, v_ref, o_ref, m_s, l_s, acc_s, *,
     p = jnp.exp(s - m_new)                               # (H, ps)
     alpha = jnp.exp(m_prev - m_new)
     l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-    acc = acc_s[...] * alpha + jax.lax.dot_general(
-        p, v, (((1,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32)              # (H, D)
+    acc = acc_s[...] * alpha + jnp.sum(p[:, :, None] * v, axis=1)
     m_s[...] = jnp.broadcast_to(m_new, m_s.shape)
     l_s[...] = jnp.broadcast_to(l_new, l_s.shape)
     acc_s[...] = acc
@@ -148,6 +147,7 @@ def _paged_attention_pallas(q, k_pages, v_pages, tables, t):
         compiler_params=_common.compiler_params(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=_common.interpret(),
+        name="paged_attention",
     )(tables.astype(jnp.int32), t.astype(jnp.int32), q2, k_pages, v_pages)
     return out.reshape(B, H, 1, D)
 

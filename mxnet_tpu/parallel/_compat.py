@@ -1,45 +1,9 @@
-"""jax version compatibility for the parallel layer.
+"""The one import site of `shard_map` for the package.
 
-Two drifts covered, so a jax upgrade/downgrade cannot take out the whole
-parallelism layer (ring/ulysses attention, MoE, pipeline) at call time:
-
-  * `shard_map` graduated from `jax.experimental.shard_map` to a
-    top-level `jax.shard_map` export — exactly one spelling exists per
-    version.
-  * its replication-check kwarg was renamed `check_rep` → `check_vma`;
-    the wrapper translates whichever spelling the installed jax lacks.
-
-Every shard_map call site in this package imports through here.
+Every shard_map call site imports through here (tools/lint_rules.py
+enforces it), so the day jax moves or renames it again is a one-line
+fix. Supported jax: see README "Supported versions".
 """
-from __future__ import annotations
-
-import inspect as _inspect
-
-try:
-    from jax import shard_map as _sm
-    # new jax: top-level export (a module in some versions, the function
-    # in others — normalize to the callable)
-    _shard_map = getattr(_sm, "shard_map", _sm)
-except ImportError:                      # pragma: no cover - version path
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-try:
-    _KWARGS = set(_inspect.signature(_shard_map).parameters)
-except (TypeError, ValueError):          # pragma: no cover - exotic builds
-    _KWARGS = None
-
-
-def shard_map(*args, **kwargs):
-    """jax's shard_map with the replication-check kwarg translated to
-    whatever the installed version accepts."""
-    if _KWARGS is not None:
-        if "check_vma" in kwargs and "check_vma" not in _KWARGS \
-                and "check_rep" in _KWARGS:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-        elif "check_rep" in kwargs and "check_rep" not in _KWARGS \
-                and "check_vma" in _KWARGS:
-            kwargs["check_vma"] = kwargs.pop("check_rep")
-    return _shard_map(*args, **kwargs)
-
+from jax import shard_map
 
 __all__ = ["shard_map"]

@@ -132,10 +132,13 @@ def _chunked_bwd(q, k, v, bias, g, lse, delta, causal, sm_scale, chunk):
 
 
 def _use_pallas(q, k):
-    from ..pallas_ops.flash_attention import has_pallas, _interpret
-    return ((jax.default_backend() == "tpu" or _interpret())
-            and has_pallas()
-            and q.shape[2] % 128 == 0 and k.shape[2] % 128 == 0)
+    from ..pallas_ops import _common
+    from ..pallas_ops.flash_attention import load_pallas
+    if not (q.shape[2] % 128 == 0 and k.shape[2] % 128 == 0
+            and _common.use_pallas()):
+        return False
+    load_pallas()
+    return True
 
 
 def _inner_fwd(q, k, v, bias, causal, sm_scale, chunk, use_pallas):
@@ -352,16 +355,8 @@ def sp_self_attention(q, k, v, mask=None, causal=False, mesh=None,
         raise ValueError(
             f"sequence length {L} not divisible by {axis_name} axis size "
             f"{mesh.shape.get(axis_name, 1)}")
-    import numpy as np
-
-    from .specs import DATA_AXES
-    data = [a for a in DATA_AXES if mesh.shape.get(a, 1) > 1]
-    # B must divide the PRODUCT of the included axes; drop axes until it does
-    while data and B % int(np.prod([mesh.shape[a] for a in data])):
-        data.pop()
-    bspec = tuple(data) if data else None
-    tp = mesh.shape.get("tp", 1)
-    hspec = "tp" if (tp > 1 and H % tp == 0) else None
+    from .specs import attention_axes
+    bspec, hspec = attention_axes(mesh, B, H)
     qspec = P(bspec, hspec, axis_name, None)
     mspec = P(bspec, axis_name)
     attn = inner or ring_attention

@@ -15,7 +15,7 @@ from .mesh import current_mesh
 
 __all__ = ["param_spec", "batch_spec", "replicated", "fsdp_spec",
            "apply_tp_rules", "constrain_batch", "constrain_seq", "DATA_AXES",
-           "spec_to_tree", "spec_from_tree"]
+           "attention_axes", "spec_to_tree", "spec_from_tree"]
 
 # both dp and fsdp are "data" axes from the batch's point of view
 DATA_AXES = ("dp", "fsdp")
@@ -35,6 +35,20 @@ def batch_spec(ndim, mesh=None, extra=None):
         for dim, ax in extra.items():
             spec[dim] = ax
     return NamedSharding(mesh, PartitionSpec(*spec))
+
+
+def attention_axes(mesh, batch, heads):
+    """How a (B, H, L, D) attention operand splits over `mesh` inside a
+    shard_map: (batch axes or None, head axis or None). Batch goes on the
+    data axes — dropped from the right until their product divides B —
+    and heads on `tp` when divisible. Shared by the sequence-parallel
+    wrapper and the per-device flash-attention kernel call."""
+    data = [a for a in DATA_AXES if mesh.shape.get(a, 1) > 1]
+    while data and batch % int(np.prod([mesh.shape[a] for a in data])):
+        data.pop()
+    tp = mesh.shape.get("tp", 1)
+    return (tuple(data) if data else None,
+            "tp" if (tp > 1 and heads % tp == 0) else None)
 
 
 # Only shard params with at least this many elements over fsdp (reference:

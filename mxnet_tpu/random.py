@@ -31,10 +31,7 @@ def _impl():
     choice = config.get("prng")
     if choice != "auto":
         return choice
-    try:
-        return "rbg" if jax.default_backend() == "tpu" else "threefry2x32"
-    except Exception:
-        return "threefry2x32"
+    return "rbg" if jax.default_backend() == "tpu" else "threefry2x32"
 
 
 _global = {"key": None, "lock": threading.Lock()}

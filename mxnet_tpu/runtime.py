@@ -34,8 +34,8 @@ def _detect():
     add("TPU", lambda: any(d.platform == "tpu" for d in jax.devices()))
     add("BF16", lambda: True)              # XLA bf16 everywhere
     add("PALLAS", lambda: __import__(
-        "mxnet_tpu.pallas_ops.flash_attention",
-        fromlist=["has_pallas"]).has_pallas())
+        "mxnet_tpu.pallas_ops._common",
+        fromlist=["pallas_available"]).pallas_available())
     add("DIST_KVSTORE", lambda: True)      # mesh/collective backend
     # io.native owns the .so path AND builds it on first use — ask it
     add("NATIVE_IO", lambda: __import__(

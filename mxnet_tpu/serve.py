@@ -1073,38 +1073,50 @@ class Server:
             self._runners[key] = r
         return r
 
+    def _bucket_step(self, bucket):
+        """(runner, argument avals) of the step executable `bucket`
+        runs. Paged servers name the HEAVIEST chunk executable the
+        bucket can run (the full-logits speculative verify step when a
+        drafter is attached, else the prefill chunk)."""
+        import jax
+        if self._paged:
+            if self._drafter is not None:
+                C, full = self._spec_k + 1, True
+            else:
+                C, full = self._prefill_chunk, False
+            n_pg = bucket // self._page_size
+            state = [jax.ShapeDtypeStruct(tuple(a.shape), a.dtype)
+                     for a in self._pool.state["target"]]
+            return self._paged_runner(bucket, C, full), (
+                jax.ShapeDtypeStruct((self._slots, C), np.int32),
+                jax.ShapeDtypeStruct((self._slots,), np.int32),
+                jax.ShapeDtypeStruct((self._slots,), np.int32),
+                jax.ShapeDtypeStruct((self._slots, n_pg), np.int32),
+                state)
+        return self._runner(bucket), (
+            jax.ShapeDtypeStruct((self._slots,), np.int32),
+            jax.ShapeDtypeStruct((self._slots,), np.int32),
+            self._cache_avals(bucket))
+
+    def lower_step(self, bucket):
+        """The `jax.stages.Lowered` form of `bucket`'s step executable
+        (see `_bucket_step`) — for ahead-of-time checks (which Pallas
+        kernels it holds: chip_smoke.py reads it). Dispatches nothing."""
+        run, avals = self._bucket_step(bucket)
+        return run.lower(*avals)
+
     def _exec_peak(self, bucket):
         """AOT execution-peak bytes of the bucket's step executable
         (beyond its argument buffers) — `predict_step_bytes`-style
         analysis, no dispatch. Cached per bucket; None when the backend
         withholds analysis (the budget then checks resident bytes
-        alone). Paged servers price the HEAVIEST chunk executable the
-        bucket can run (the full-logits speculative verify step when a
-        drafter is attached, else the prefill chunk) — the
-        `memsafe.aot_exec_peak` path pages are admitted through."""
+        alone) — the `memsafe.aot_exec_peak` path pages are admitted
+        through."""
         if bucket in self._exec_peaks:
             return self._exec_peaks[bucket]
-        import jax
         try:
-            if self._paged:
-                if self._drafter is not None:
-                    C, full = self._spec_k + 1, True
-                else:
-                    C, full = self._prefill_chunk, False
-                run = self._paged_runner(bucket, C, full)
-                n_pg = bucket // self._page_size
-                toks = jax.ShapeDtypeStruct((self._slots, C), np.int32)
-                t0 = jax.ShapeDtypeStruct((self._slots,), np.int32)
-                nn = jax.ShapeDtypeStruct((self._slots,), np.int32)
-                tb = jax.ShapeDtypeStruct((self._slots, n_pg), np.int32)
-                state = [jax.ShapeDtypeStruct(tuple(a.shape), a.dtype)
-                         for a in self._pool.state["target"]]
-                peak = run.aot_exec_peak(toks, t0, nn, tb, state)
-            else:
-                run = self._runner(bucket)
-                tok = jax.ShapeDtypeStruct((self._slots,), np.int32)
-                t = jax.ShapeDtypeStruct((self._slots,), np.int32)
-                peak = run.aot_exec_peak(tok, t, self._cache_avals(bucket))
+            run, avals = self._bucket_step(bucket)
+            peak = run.aot_exec_peak(*avals)
         except Exception:   # noqa: BLE001 — degrade to resident-only
             peak = None
         self._exec_peaks[bucket] = peak

@@ -580,40 +580,41 @@ def test_estimator_drives_prefetcher_for_dataloader():
 
 # -- persistent compile cache ------------------------------------------------
 
-def test_ensure_compile_cache_wires_jax_and_is_idempotent(tmp_path):
+@pytest.fixture
+def fresh_cache_decision(monkeypatch):
+    """ensure_compile_cache decides once per process; give the test a
+    clean slate and put jax's directory back afterwards."""
     import jax
-    prev_state = dataflow._cache_state
-    prev_dir = jax.config.jax_compilation_cache_dir
-    try:
-        dataflow._cache_state = None
-        mx.config.set("compile_cache_dir", "")
-        assert dataflow.ensure_compile_cache() is None  # knob empty: no-op
-        assert dataflow._cache_state is None            # still re-armable
-        cache = str(tmp_path / "xla_cache")
-        mx.config.set("compile_cache_dir", cache)
-        got = dataflow.ensure_compile_cache()
-        assert got == os.path.abspath(cache)
-        assert jax.config.jax_compilation_cache_dir == os.path.abspath(cache)
-        assert os.path.isdir(cache)
-        assert dataflow.ensure_compile_cache() == got   # idempotent
-    finally:
-        dataflow._cache_state = prev_state
-        mx.config.reset("compile_cache_dir")
-        jax.config.update("jax_compilation_cache_dir", prev_dir)
+    prev = jax.config.jax_compilation_cache_dir
+    monkeypatch.setattr(dataflow, "_cache_dir", None)
+    yield
+    jax.config.update("jax_compilation_cache_dir", prev)
 
 
-def test_ensure_compile_cache_failure_never_claims_success(tmp_path):
-    prev_state = dataflow._cache_state
-    blocker = tmp_path / "not_a_dir"
-    blocker.write_text("")             # makedirs under a FILE must fail
-    try:
-        dataflow._cache_state = None
-        mx.config.set("compile_cache_dir", str(blocker / "cache"))
-        with pytest.warns(UserWarning, match="compile cache unavailable"):
-            assert dataflow.ensure_compile_cache() is None
-        # later calls (every trainer construction) must keep reporting
-        # failure, not hand back a dir jax never wired
-        assert dataflow.ensure_compile_cache() is None
-    finally:
-        dataflow._cache_state = prev_state
-        mx.config.reset("compile_cache_dir")
+def test_ensure_compile_cache_defaults_to_fixed_checkout_path(
+        fresh_cache_decision, monkeypatch):
+    import jax
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(dataflow.__file__)))
+    got = dataflow.ensure_compile_cache()
+    # fixed, inside the checkout: never a temporary or per-process path
+    assert got == os.path.join(root, ".jax_cache")
+    assert jax.config.jax_compilation_cache_dir == got
+    assert dataflow.ensure_compile_cache() == got   # idempotent
+
+
+def test_ensure_compile_cache_leaves_env_directory_alone(
+        fresh_cache_decision, monkeypatch, tmp_path):
+    """Where JAX_COMPILATION_CACHE_DIR is set, the directory is jax's to
+    read from the environment: the repo must not update it in code."""
+    import jax
+    outside = str(tmp_path / "placed_from_outside")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", outside)
+    updates = []
+    real_update = jax.config.update
+    monkeypatch.setattr(
+        jax.config, "update",
+        lambda name, val: (updates.append(name), real_update(name, val))[1])
+    assert dataflow.ensure_compile_cache() == outside
+    assert "jax_compilation_cache_dir" not in updates
+    assert dataflow.ensure_compile_cache() == outside   # idempotent

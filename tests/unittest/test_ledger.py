@@ -327,13 +327,47 @@ def test_enable_without_dir_raises():
 # tools/ledger_report.py — backfill, report, tier-1 budget, gate CLI
 # ---------------------------------------------------------------------------
 
+def _driver_artifacts(dirpath):
+    """Five driver bench artifacts in the shape the driver wrote them: a
+    crash with no row, one TPU row behind its `# backend=` marker, and
+    three CPU rows annotated as smoke-mode."""
+    metric = "bert_base_pretrain_tokens_per_sec_per_chip"
+    cmd = "python bench.py"
+
+    def row(value, **extra):
+        return dict({"metric": metric, "value": value,
+                     "unit": "tokens/s/chip", "vs_baseline": 1.0}, **extra)
+
+    smoke = "tpu backend unavailable; CPU smoke-mode number"
+    specs = [
+        {"rc": 1, "tail": "Traceback (most recent call last):\n"
+                          "RuntimeError: backend init failed\n"},
+        {"rc": 0, "tail": "# backend=tpu devices=1 params=110.1M\n"
+                          + json.dumps(row(132473.34)) + "\n",
+         "parsed": row(132473.34)},
+    ] + [
+        {"rc": 0, "tail": "# backend=cpu devices=1 params=0.1M\n"
+                          + json.dumps(row(v, error=smoke)) + "\n",
+         "parsed": row(v, error=smoke)}
+        for v in (15869.92, 16010.5, 15790.1)]
+    paths = []
+    for n, spec in enumerate(specs, start=1):
+        path = os.path.join(dirpath, f"BENCH_r{n:02d}.json")
+        with open(path, "w") as f:
+            json.dump(dict({"n": n, "cmd": cmd}, **spec), f)
+        paths.append(path)
+    return paths
+
+
 def test_backfill_import_idempotent_and_anchor_renders(tmp_path):
-    """The real driver artifacts: BENCH_r02's 132k TPU row must come
-    back as a smoke=False TPU series (the anchor), the smoke runs as a
-    separate series, and a re-import must be a no-op."""
-    artifacts = [os.path.join(ROOT, f"BENCH_r{i:02d}.json")
-                 for i in range(1, 6)]
-    assert all(os.path.exists(p) for p in artifacts)
+    """Driver artifacts: BENCH_r02's 132k TPU row must come back as a
+    smoke=False TPU series (the anchor), the smoke runs as a separate
+    series, and a re-import must be a no-op."""
+    art_dir = tmp_path / "artifacts"
+    art_dir.mkdir()
+    artifacts = _driver_artifacts(str(art_dir))
+    tmp_path = tmp_path / "ledger"
+    tmp_path.mkdir()
     env = dict(os.environ, MXNET_TPU_LEDGER_GATE="")
     r = subprocess.run(
         [sys.executable, REPORT, str(tmp_path), "--import"] + artifacts,

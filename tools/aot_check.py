@@ -1,0 +1,193 @@
+#!/usr/bin/env python
+"""Compile for a TPU v5e without one: the step before any chip call.
+
+libtpu ships a compile-only description of the chip
+(`jax.experimental.topologies.get_topology_desc`), so this sandbox — CPUs
+only — can still run Mosaic and the XLA:TPU compiler over every Pallas
+kernel and over the whole train step, at the shapes `chip_smoke.py` uses,
+on one device and on the four-device `v5e:2x2` mesh. What it catches is
+what the compiler refuses (a dot Mosaic cannot parse, a block over the
+scoped-VMEM limit, a kernel jit cannot partition, a step that does not fit
+HBM); what it cannot catch is anything that only shows when the code RUNS
+— numerics, hangs, real memory — and that is the chip's word.
+
+    JAX_PLATFORMS=cpu python tools/aot_check.py            # everything
+    JAX_PLATFORMS=cpu python tools/aot_check.py kernels    # or: train
+
+Arguments are `jax.ShapeDtypeStruct`s whose shardings name the topology's
+devices; the two places the package asks JAX what it runs on (the kernel
+gate and the PRNG choice) are told "tpu" here, and the trainer's
+`device_put`s are answered with abstract arrays, because nothing can be
+placed on a device that is only a description. Exits non-zero on the
+first refusal.
+"""
+import os
+import sys
+import time
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+from jax.experimental import topologies  # noqa: E402
+from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
+
+import mxnet_tpu as mx  # noqa: E402
+from mxnet_tpu import config, nd, parallel  # noqa: E402
+from mxnet_tpu.models import bert as bert_mod  # noqa: E402
+from mxnet_tpu.pallas_ops import _common  # noqa: E402
+from mxnet_tpu import pallas_ops  # noqa: E402
+from tools.tpu_validate import pallas_kernels  # noqa: E402
+
+TOPOLOGY = "v5e:2x2"
+
+
+def sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def compile_(name, fn, *args):
+    t0 = time.perf_counter()
+    lowered = jax.jit(fn).lower(*args)
+    found = pallas_kernels(lowered)
+    lowered.compile()
+    print(f"  compiles: {name}  [{time.perf_counter() - t0:.1f}s]  "
+          f"kernels={found or 'none'}", flush=True)
+    return found
+
+
+def check_kernels(devices):
+    """Every kernel on the smoke's path at the smoke's shapes."""
+    one = parallel.make_mesh(devices=devices[:1])
+    s1 = NamedSharding(one, P())
+    bf, f32, i32 = jnp.bfloat16, jnp.float32, jnp.int32
+    key = jax.eval_shape(lambda: jax.random.key(0, impl="rbg"))
+
+    def flash_loss(q, k, v, mask, key):
+        return pallas_ops.flash_attention(
+            q, k, v, mask=mask, dropout=0.1, dropout_key=key) \
+            .astype(f32).sum()
+
+    def flash_args(qs, ms, ks, B=32, H=12, L=512, D=64):
+        return ([sds((B, H, L, D), bf, qs)] * 3
+                + [sds((B, L), jnp.bool_, ms), sds(key.shape, key.dtype, ks)])
+
+    grad = jax.value_and_grad(flash_loss, argnums=(0, 1, 2))
+    compile_("flash fwd+bwd, mask + dropout, B32 H12 L512 D64, one device",
+             grad, *flash_args(s1, s1, s1))
+    compile_("flash fwd+bwd causal, B8 H16 L512 D64, one device",
+             jax.grad(lambda q, k, v: pallas_ops.flash_attention(
+                 q, k, v, causal=True).astype(f32).sum(), argnums=(0, 1, 2)),
+             *[sds((8, 16, 512, 64), bf, s1)] * 3)
+
+    # GPT-2 345M decode: 16 heads of 64, page 16, a 512 bucket, 8 slots
+    B, H, D, ps, n_pg, n_pages = 8, 16, 64, 16, 32, 520
+    compile_("paged_attention B8 H16 D64 page16 n_pg32",
+             pallas_ops.paged_attention,
+             sds((B, H, 1, D), bf, s1), sds((n_pages, H, ps, D), bf, s1),
+             sds((n_pages, H, ps, D), bf, s1), sds((B, n_pg), i32, s1),
+             sds((B,), i32, s1))
+
+    # fused LAMB at BERT-base's flat size (110M f32), fused Adam f32 + bf16
+    from mxnet_tpu.parallel.fused_lamb import FusedLamb
+    shapes = [(1024, 1024)] * 84 + [(30522, 768), (768,)] * 2
+    fl = FusedLamb(shapes, [f32] * len(shapes), [0.01] * len(shapes),
+                   0.9, 0.999, 1e-6, True, 1.0, -1.0, -1.0, -1.0)
+    flat = sds((fl.total,), f32, s1)
+    compile_(f"lamb_pass1 + lamb_pass2, {fl.total / 1e6:.0f}M parameters",
+             fl.apply_flat, flat, flat, flat, flat,
+             sds((), f32, s1), sds((), f32, s1))
+    for dt in (f32, bf):
+        w = sds((8 << 20,), dt, s1)
+        compile_(f"adam_update {jnp.dtype(dt).name}",
+                 lambda w, g, m, v, lr: pallas_ops.fused_update.adam_update(
+                     w, g, m, v, lr, wd=0.01),
+                 w, w, sds(w.shape, f32, s1), sds(w.shape, f32, s1),
+                 sds((), f32, s1))
+
+    # int8 matmul at GPT-2 widths: MLP up-projection, vocabulary head
+    for K, O in ((768, 3072), (768, 50257)):
+        compile_(f"int8_matmul K{K} -> O{O}", pallas_ops.int8_matmul,
+                 sds((8, K), jnp.int8, s1), sds((K, O), jnp.int8, s1),
+                 sds((), f32, s1), sds((O,), f32, s1), sds((O,), f32, s1))
+
+    # flash with its operands sharded over the four-device mesh: what jit
+    # refuses to partition unless the kernel sits under shard_map
+    if len(devices) >= 4:
+        for axes in (dict(dp=4), dict(dp=2, tp=2)):
+            mesh = parallel.make_mesh(devices=devices[:4], **axes)
+            qs = NamedSharding(mesh, P(("dp",), "tp" if "tp" in axes
+                                       else None))
+            found = compile_(
+                f"flash fwd+bwd, mask + dropout, sharded over {axes}", grad,
+                *flash_args(qs, NamedSharding(mesh, P(("dp",))),
+                            NamedSharding(mesh, P())))
+            assert len(found) == 3, "flash kernels missing"
+
+
+def check_train(devices):
+    """The whole BERT-base bf16 LAMB step, as chip_smoke.py trains it, on
+    one device and on four."""
+    cfg = bert_mod.bert_base_config(dtype="bfloat16")
+    b = bert_mod.make_synthetic_batch(cfg, 32, 512, 76, seed=0)
+    data = [nd.array(b[k]) for k in
+            ("input_ids", "token_types", "valid_length", "masked_positions")]
+    labels = [nd.array(b[k]) for k in
+              ("mlm_labels", "mlm_weights", "nsp_labels")]
+    model = bert_mod.BERTForPretraining(cfg)
+    mx.random.seed(0)
+    model.initialize()
+
+    # a topology device cannot hold an array: answer every placement with
+    # the abstract array lowering needs (shape, dtype, sharding)
+    real_put, real_state = jax.device_put, mx.random.get_state
+    jax.device_put = lambda x, s=None, **kw: sds(
+        tuple(x.shape), x.dtype, s)
+    try:
+        for n, want in ((1, 5), (4, 3)):
+            if len(devices) < n:
+                continue
+            mesh = parallel.make_mesh(dp=-1, devices=devices[:n])
+            key = real_state()
+            mx.random.get_state = lambda: sds(
+                key.shape, key.dtype, NamedSharding(mesh, P()))
+            trainer = parallel.ShardedTrainer(
+                model, bert_mod.bert_pretrain_loss, "lamb",
+                {"learning_rate": 1e-3, "wd": 0.01})
+            t0 = time.perf_counter()
+            lowered = trainer.lower_step(data, labels)
+            found = pallas_kernels(lowered)
+            mem = lowered.compile().memory_analysis()
+            print(f"  compiles: BERT-base b32 L512 bf16 LAMB step on "
+                  f"{n} device(s)  [{time.perf_counter() - t0:.1f}s]  "
+                  f"kernels={found}  temp="
+                  f"{mem.temp_size_in_bytes / 2**30:.2f} GiB args="
+                  f"{mem.argument_size_in_bytes / 2**30:.2f} GiB", flush=True)
+            assert len(found) == want, \
+                f"expected {want} distinct kernels in the step, got {found}"
+    finally:
+        jax.device_put, mx.random.get_state = real_put, real_state
+
+
+def main():
+    which = sys.argv[1:] or ["kernels", "train"]
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name=TOPOLOGY)
+    devices = list(topo.devices)
+    print(f"jax {jax.__version__}; compile-only topology {TOPOLOGY}: "
+          f"{len(devices)} x {devices[0].device_kind!r}")
+    # the two places the package asks what it runs on
+    _common.pallas_available = lambda: True
+    config.set("prng", "rbg")
+    if os.environ.get("MXNET_TPU_PALLAS_INTERPRET") == "1":
+        sys.exit("unset MXNET_TPU_PALLAS_INTERPRET: the interpreter "
+                 "compiles nothing for the TPU")
+    for name in which:
+        print(f"== {name} ==")
+        {"kernels": check_kernels, "train": check_train}[name](devices)
+    print("aot_check: everything compiled for", devices[0].device_kind)
+
+
+if __name__ == "__main__":
+    main()

@@ -48,10 +48,8 @@ def main():
     elems -= elems % (n * n)   # reduce_scatter shards each shard n ways
     x = jnp.ones((elems,), args.dtype)
 
-    # routed through the version shim — `from jax import shard_map` binds
-    # the MODULE (not the function) on jax 0.4.37 and the experimental
-    # path no longer exists on newer jax: the exact breakage the mx.check
-    # `shard-map-import` AST rule exists to stop (it bit PR 5 and PR 6)
+    # the package's one shard_map import site (the mx.check
+    # `shard-map-import` AST rule keeps every caller on it)
     from mxnet_tpu.parallel._compat import shard_map
 
     def bench(name, fn, bytes_moved):

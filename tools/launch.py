@@ -144,6 +144,11 @@ ELASTIC_SETTLE_S = 3.0
 def build_env(rank, num_workers, coordinator, diagnostics_dir=None,
               restart_count=0, trace_dir=None, trace_epoch_ns=None,
               heartbeat_timeout=None, scope_port=0, goodput_dir=None):
+    # NOTE: workers get no device assignment. Local gangs (-n N,
+    # --serve-replicas) are CPU-tested only: on a chip host every local
+    # worker would claim every chip, and a chip belongs to one process at
+    # a time. One process per host drives all its chips (README,
+    # "Running on the CPU and on the chip").
     if ":" not in coordinator:
         coordinator = coordinator + ":9876"  # default coordination port
     env = dict(os.environ)

@@ -7,10 +7,9 @@ level so it fails the PR, not the pod:
 
   * `shard-map-import` — `jax.shard_map` / `jax.experimental.shard_map`
     imported or referenced anywhere but `parallel/_compat.py`. The
-    spelling moved between jax versions (`from jax import shard_map`
-    binds the MODULE on 0.4.37) and this exact breakage shipped twice
-    (PR 5 and PR 6, three dist tests each). Everything routes through
-    the `_compat` shim.
+    spelling has moved between jax releases and that breakage shipped
+    twice (PR 5 and PR 6, three dist tests each). Everything imports it
+    from `_compat`, so the next move is a one-line fix.
   * `signal-handler-blocking` — a blocking call (`.wait()`, `.join()`,
     `.acquire()`, `time.sleep`, `os.waitpid`, `select`) inside a
     function installed with `signal.signal(...)`. PR 5's launch.py
@@ -142,8 +141,8 @@ def rule_shard_map_import(path, tree, source):
     if path.endswith(SHARD_MAP_HOME):
         return []
     out = []
-    remed = ("import it from mxnet_tpu.parallel._compat (the version "
-             "shim owning the jax spelling)")
+    remed = ("import it from mxnet_tpu.parallel._compat (the one module "
+             "owning the jax spelling)")
     for node in ast.walk(tree):
         if isinstance(node, ast.ImportFrom):
             mod = node.module or ""

@@ -288,8 +288,8 @@ def report(path, label=None, data=None):
     cache_hits = _metric_sum(snapshot, "compile_cache_hits_total")
     cache_misses = _metric_sum(snapshot, "compile_cache_misses_total")
     if cache_hits or cache_misses:
-        # persistent XLA cache (compile_cache_dir knob): hits deserialized
-        # an executable instead of rebuilding it — warm, not cold, compiles
+        # persistent XLA cache (dataflow.ensure_compile_cache): hits
+        # deserialized an executable instead of rebuilding it — warm, not cold, compiles
         lines.append(f"  persistent cache: {int(cache_hits)} warm hits, "
                      f"{int(cache_misses)} cold misses")
     for e in recompiles:

@@ -1,39 +1,44 @@
-#!/usr/bin/env python
-"""On-TPU Pallas kernel validation (run manually: `python tools/tpu_validate.py`).
+"""Pallas kernels against their references — the kernel phase of
+`chip_smoke.py` (which is how this runs: every check here needs the chip,
+because the CPU interpreter lowers a kernel to plain XLA ops and cannot
+draw the TPU PRNG at all).
 
-The CI suite runs on a virtual CPU mesh where the Pallas kernels take the
-jnp fallback, so every flash-attention change must be validated here on the
-real chip:
-  1. dropout=0 parity vs mha_reference (fwd + grads, plain/mask/causal)
-  2. attention-dropout statistics (keep rate, inverted-scale mean)
+  1. flash attention, dropout=0: parity vs mha_reference (fwd + dq,
+     plain / mask / causal)
+  2. attention-dropout statistics (keep rate, inverted-scale mean,
+     determinism)
   3. explicit-mask oracle check of the dropout path — the actual keep mask
      is EXTRACTED from the kernel (uniform-attention probe with v=I reads
      z_ij/(L(1-r)) back out), then fwd and all three grads are compared
      against XLA autodiff of softmax-then-mask with that fixed mask. This
      proves the forward, dq, and dkv kernels regenerate bit-identical masks
      AND that the dropout backward math is right.
+  4. paged decode attention vs paged_attention_reference
+  5. the fused-LAMB kernel passes vs the same step with kernels=off
+  6. int8 matmul vs int8_matmul_reference
 
-Tolerances are calibrated to the MXU's reduced-precision f32 matmul
-(~1e-3 rel vs XLA), not to exact-f32 arithmetic.
+Each function raises AssertionError on the first check that fails (the
+smoke catches nothing) and prints one `agrees ...` line per check that
+holds. Flash tolerances are calibrated to the MXU's reduced-precision f32
+matmul (~1e-3 rel vs XLA), not to exact-f32 arithmetic.
 """
-import os
-import sys
-
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+import collections
+import re
 
 import numpy as np
 import jax
 import jax.numpy as jnp
 
-from mxnet_tpu.pallas_ops import flash_attention, mha_reference
-
-FAILED = []
+from mxnet_tpu import config
+from mxnet_tpu.pallas_ops import (flash_attention, mha_reference,
+                                  paged_attention, paged_attention_reference,
+                                  int8_matmul, int8_matmul_reference)
 
 
 def check(name, ok, detail=""):
-    print(f"{'PASS' if ok else 'FAIL'} {name} {detail}")
     if not ok:
-        FAILED.append(name)
+        raise AssertionError(f"kernel check failed: {name} {detail}")
+    print(f"  agrees: {name} {detail}", flush=True)
 
 
 def rel_err(a, b):
@@ -42,9 +47,27 @@ def rel_err(a, b):
     return float(np.max(np.abs(a - b)) / (np.max(np.abs(b)) + 1e-6))
 
 
-def parity_suite():
+def pallas_kernels(lowered):
+    """{kernel name: count} of the Mosaic custom calls in a
+    `jax.stages.Lowered` module — what the compiler is handed, and cannot
+    drop while the outputs depend on it. Every pallas_call in pallas_ops/
+    carries `name=`, which lowering keeps as the location's component
+    before `pallas_call` (`.../lamb_pass1/pallas_call`, or wrapped by
+    autodiff as `.../transpose(jvp(flash_dq))/pallas_call`)."""
+    text = lowered.as_text(debug_info=True)
+    locs = dict(re.findall(r'^#loc(\d+) = loc\("([^"]*)"', text, re.M))
+    found = collections.Counter()
+    for line in text.splitlines():
+        if "@tpu_custom_call" in line:
+            ref = re.search(r'loc\(#loc(\d+)\)\s*$', line)
+            name = re.search(r'([A-Za-z0-9_]+)\)*/pallas_call',
+                             locs.get(ref.group(1), "") if ref else "")
+            found[name.group(1) if name else "unnamed"] += 1
+    return dict(found)
+
+
+def flash_parity(B=2, H=4, L=512, D=64):
     rng = np.random.RandomState(0)
-    B, H, L, D = 2, 4, 512, 64
     q = jnp.asarray(rng.randn(B, H, L, D), jnp.float32)
     k = jnp.asarray(rng.randn(B, H, L, D), jnp.float32)
     v = jnp.asarray(rng.randn(B, H, L, D), jnp.float32)
@@ -57,19 +80,17 @@ def parity_suite():
             bias = jnp.where(mask, 0.0, -1e30)[:, None, None, :]
         out = flash_attention(q, k, v, block_q=128, block_k=128, **kw)
         ref = mha_reference(q, k, v, bias=bias, causal=kw.get("causal", False))
-        check(f"fwd parity {name}", rel_err(out, ref) < 5e-3,
+        check(f"flash fwd {name}", rel_err(out, ref) < 5e-3,
               f"rel={rel_err(out, ref):.2e}")
         g = jax.grad(lambda q: flash_attention(
             q, k, v, block_q=128, block_k=128, **kw).sum())(q)
         gr = jax.grad(lambda q: mha_reference(
             q, k, v, bias=bias, causal=kw.get("causal", False)).sum())(q)
-        check(f"dq parity {name}", rel_err(g, gr) < 1e-2,
+        check(f"flash dq {name}", rel_err(g, gr) < 1e-2,
               f"rel={rel_err(g, gr):.2e}")
 
 
-def dropout_stats():
-    rng = np.random.RandomState(1)
-    B, H, L, D = 2, 4, 512, 64
+def flash_dropout_stats(B=2, H=4, L=512, D=64):
     q = jnp.zeros((B, H, L, D), jnp.float32)   # uniform probs = 1/L
     k = jnp.zeros((B, H, L, D), jnp.float32)
     v = jnp.asarray(np.eye(L)[None, None].repeat(H, 1).repeat(B, 0)
@@ -83,30 +104,33 @@ def dropout_stats():
     check("dropout keep rate", abs(zero_frac - rate) < 0.02,
           f"dropped={zero_frac:.3f} want≈{rate}")
     clean = flash_attention(q, k, v, block_q=128, block_k=128)
-    check("dropout inverted mean", abs(float(out.mean() / clean.mean()) - 1.0) < 0.05,
-          f"ratio={float(out.mean()/clean.mean()):.3f}")
+    ratio = float(out.mean() / clean.mean())
+    check("dropout inverted mean", abs(ratio - 1.0) < 0.05,
+          f"ratio={ratio:.3f}")
     # determinism: same key → same output
     out2 = flash_attention(q, k, v, block_q=128, block_k=128, dropout=rate,
                            dropout_key=key)
     check("dropout deterministic", bool(jnp.all(out == out2)))
 
 
-def dropout_gradcheck():
-    rng = np.random.RandomState(2)
-    B, H, L, D = 1, 2, 512, 64
-    key = jax.random.key(11)
-    rate = 0.3
-
-    # extract the kernel's actual keep mask: uniform attention (q=k=0) with
-    # v=I makes out[b,h,i,j] = z_ij / (L*(1-rate)) — nonzero iff kept. The
-    # mask depends only on (seed, tile id), so the SAME mask applies to the
-    # real tensors below (same L and block sizes).
+def keep_masks(B, H, L, rate, key):
+    """The kernel's actual keep masks, (B, H, L, L) bool: uniform attention
+    (q=k=0) with v=I makes out[b,h,i,j] = z_ij / (L*(1-rate)) — nonzero iff
+    kept. The mask depends only on (seed, tile id), so the SAME mask
+    applies to real tensors of the same L and block sizes."""
     probe = flash_attention(jnp.zeros((B, H, L, L)), jnp.zeros((B, H, L, L)),
                             jnp.broadcast_to(jnp.eye(L)[None, None],
                                              (B, H, L, L)),
                             block_q=128, block_k=128, dropout=rate,
                             dropout_key=key)
-    Z = jnp.asarray(np.asarray(probe) > 0)
+    return np.asarray(probe) > 0
+
+
+def flash_dropout_oracle(B=1, H=2, L=512, D=64):
+    rng = np.random.RandomState(2)
+    key = jax.random.key(11)
+    rate = 0.3
+    Z = jnp.asarray(keep_masks(B, H, L, rate, key))
     frac = float(Z.mean())
     check("dropout keep-mask extraction", abs(frac - (1 - rate)) < 0.02,
           f"keep frac={frac:.3f}")
@@ -136,16 +160,81 @@ def dropout_gradcheck():
               f"rel={rel_err(gp, go):.2e}")
 
 
-def main():
-    assert jax.default_backend() == "tpu", "must run on the TPU"
-    parity_suite()
-    dropout_stats()
-    dropout_gradcheck()
-    if FAILED:
-        print(f"{len(FAILED)} FAILURES: {FAILED}")
-        sys.exit(1)
-    print("tpu_validate: ALL PASS")
+def paged_parity(B=8, H=16, D=64, page_size=16, n_pg=32,
+                 dtype=jnp.bfloat16, expect_kernel=True):
+    """Paged decode attention at the served model's shapes: the kernel
+    (kernels=auto) against paged_attention_reference on the same pool.
+    Tolerance: both paths do f32 math on the same inputs and round once to
+    `dtype`; the online softmax only reorders the sums, so one ulp of the
+    output dtype at the output's scale is expected and two are allowed."""
+    rng = np.random.RandomState(4)
+    P = B * n_pg + B
+    q = jnp.asarray(rng.randn(B, H, 1, D), dtype)
+    k_pg = jnp.asarray(rng.randn(P, H, page_size, D), dtype)
+    v_pg = jnp.asarray(rng.randn(P, H, page_size, D), dtype)
+    tables = jnp.asarray(
+        rng.permutation(P)[:B * n_pg].reshape(B, n_pg), jnp.int32)
+    # positions from the first page to the last, the last row full
+    t = jnp.asarray(np.linspace(3, n_pg * page_size - 1, B), jnp.int32)
+    kern = jax.jit(paged_attention)
+    if expect_kernel:
+        found = pallas_kernels(kern.lower(q, k_pg, v_pg, tables, t))
+        check("paged_attention kernel in the lowered call",
+              found.get("paged_attention", 0) == 1, f"found={found}")
+    got = kern(q, k_pg, v_pg, tables, t)
+    ref = jax.jit(paged_attention_reference)(q, k_pg, v_pg, tables, t)
+    tol = 2 * float(jnp.finfo(dtype).eps)
+    err = rel_err(got, ref)
+    check(f"paged attention B{B} H{H} D{D} page{page_size} n_pg{n_pg}",
+          err <= tol, f"rel={err:.2e} tol={tol:.1e}")
 
 
-if __name__ == "__main__":
-    main()
+def lamb_parity(shapes, expect_kernel=True):
+    """One fused-LAMB step (FusedLamb.apply_flat over the flat f32 master
+    built from `shapes`) with kernels=auto against the same step with
+    kernels=off. Tolerance: both are f32; the kernel sums each row's
+    squares in a different order than XLA's reduction, which moves the
+    trust ratios — and through them every weight — by a few f32 ulps."""
+    from mxnet_tpu.parallel.fused_lamb import FusedLamb
+    fl = FusedLamb(shapes, [jnp.float32] * len(shapes),
+                   [0.01] * len(shapes), 0.9, 0.999, 1e-6, True, 1.0,
+                   -1.0, -1.0, -1.0)
+    rng = np.random.RandomState(5)
+    n = fl.total
+    w = jnp.asarray(rng.randn(n).astype(np.float32) * 0.02)
+    g = jnp.asarray(rng.randn(n).astype(np.float32) * 1e-3)
+    m = jnp.asarray(rng.randn(n).astype(np.float32) * 1e-4)
+    v = jnp.asarray(np.square(rng.randn(n).astype(np.float32)) * 1e-6)
+    t, lr = jnp.asarray(3.0), jnp.asarray(1e-3)
+    outs = {}
+    for knob in ("off", "auto"):
+        config.set("kernels", knob)
+        step = jax.jit(fl.apply_flat)
+        found = pallas_kernels(step.lower(w, g, m, v, t, lr))
+        if knob == "off":
+            check("kernels=off LAMB step holds no kernel", not found,
+                  f"found={found}")
+        elif expect_kernel:
+            check("lamb_pass1 + lamb_pass2 in the kernels=auto step",
+                  found == {"lamb_pass1": 1, "lamb_pass2": 1},
+                  f"found={found}")
+        outs[knob] = step(w, g, m, v, t, lr)
+    config.reset("kernels")
+    for name, a, b in zip(("w", "m", "v"), outs["auto"], outs["off"]):
+        check(f"LAMB new_{name} ({n / 1e6:.1f}M elements)",
+              rel_err(a, b) < 1e-5, f"rel={rel_err(a, b):.2e}")
+
+
+def int8_parity(M=8, K=768, O=3072):
+    """int8 x int8 matmul with the fused per-channel rescale against the
+    XLA lowering: int32 accumulation is exact on both sides, so only the
+    f32 rescale can differ (by an ulp)."""
+    rng = np.random.RandomState(6)
+    xq = jnp.asarray(rng.randint(-127, 128, (M, K)), jnp.int8)
+    wq = jnp.asarray(rng.randint(-127, 128, (K, O)), jnp.int8)
+    ws = jnp.asarray(rng.rand(O).astype(np.float32) * 0.1)
+    bias = jnp.asarray(rng.randn(O).astype(np.float32))
+    got = jax.jit(int8_matmul)(xq, wq, jnp.float32(0.02), ws, bias)
+    ref = jax.jit(int8_matmul_reference)(xq, wq, jnp.float32(0.02), ws, bias)
+    check(f"int8 matmul M{M} K{K} O{O}", rel_err(got, ref) < 1e-5,
+          f"rel={rel_err(got, ref):.2e}")
