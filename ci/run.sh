@@ -353,21 +353,22 @@ print('resilience disabled fast path OK (no handlers, no hashing)')
     JAX_PLATFORMS=cpu python -m pytest \
         tests/unittest/test_resilience.py::test_kill_and_relaunch_resumes_bit_exact \
         -q -p no:cacheprovider
-    # trace must be disabled by default: the trainer/dataflow/block hook
-    # sites make zero recorder calls (one module-bool check each), no
-    # span buffer exists, and no skew probe or annotation runs — the
-    # zero-overhead fast path
+    # trace must not be live by default (off, and no profiler session):
+    # the trainer/dataflow/block hook sites read trace.live() and make
+    # zero span, recorder, skew-probe or annotation calls, and no span
+    # buffer exists — the zero-overhead fast path
     JAX_PLATFORMS=cpu python -c "
 import numpy as np
 import mxnet_tpu as mx
 from mxnet_tpu import nd, parallel, dataflow, trace
 from mxnet_tpu.gluon import nn, loss as gloss
-assert not trace.enabled(), 'trace must default to off'
-calls = {'span': 0, 'skew': 0, 'ann': 0}
-real = (trace.record_span, trace.skew_tick, trace.annotate)
+assert not trace.enabled() and not trace.live(), 'trace must default to off'
+calls = {'span': 0, 'skew': 0, 'ann': 0, 'live_span': 0}
+real = (trace.record_span, trace.skew_tick, trace.annotate, trace.span)
 trace.record_span = lambda *a, **k: (calls.__setitem__('span', calls['span'] + 1), real[0](*a, **k))[1]
 trace.skew_tick = lambda *a, **k: (calls.__setitem__('skew', calls['skew'] + 1), real[1](*a, **k))[1]
 trace.annotate = lambda *a, **k: (calls.__setitem__('ann', calls['ann'] + 1), real[2](*a, **k))[1]
+trace.span = lambda *a, **k: (calls.__setitem__('live_span', calls['live_span'] + 1), real[3](*a, **k))[1]
 parallel.make_mesh(dp=-1)
 net = nn.Dense(4, in_units=8); mx.random.seed(0); net.initialize()
 lfn = gloss.L2Loss()
@@ -379,8 +380,8 @@ for d, l in dataflow.prefetch_to_mesh(iter([([x], [y])] * 3), tr, depth=2):
     tr.step(d, l)
 net2 = nn.Dense(4, in_units=8); net2.initialize(); net2.hybridize()
 net2(x)
-trace.record_span, trace.skew_tick, trace.annotate = real
-assert calls == {'span': 0, 'skew': 0, 'ann': 0}, calls
+trace.record_span, trace.skew_tick, trace.annotate, trace.span = real
+assert calls == {'span': 0, 'skew': 0, 'ann': 0, 'live_span': 0}, calls
 assert trace._buf is None, 'disabled fast path allocated the span buffer'
 assert trace.spans() == [], 'disabled fast path recorded spans'
 print('trace disabled fast path OK (no recorder calls, no buffer)')

@@ -8,6 +8,10 @@ find everything where they expect it: `nd`, `autograd`, `gluon`, `optimizer`,
 """
 from __future__ import annotations
 
+import time as _time
+
+_t_import = _time.perf_counter()    # mx.trace.setup()["import_s"]
+
 __version__ = "0.1.0"
 
 from . import base
@@ -88,3 +92,6 @@ def __getattr__(name):
         globals()[name] = mod
         return mod
     raise AttributeError(f"module 'mxnet_tpu' has no attribute '{name}'")
+
+
+_import_s = _time.perf_counter() - _t_import

@@ -485,17 +485,21 @@ register_option(
     "<=0 disables the rule.")
 register_option(
     "trace", "off", choices=("off", "on"),
-    doc="mx.trace distributed step tracing. 'off' (default) is the "
-        "zero-overhead fast path: every hook site (dataflow batch-wait "
-        "and H2D staging, ShardedTrainer dispatch/fence, block compile, "
-        "checkpoint save) reduces to one module-bool check — no span "
-        "buffer, no recorder calls (asserted by ci/run.sh sanity). 'on' "
-        "records host-side spans tagged (rank, step) for every "
-        "trace_sample_every-th step, wraps sampled steps in "
-        "jax.profiler.TraceAnnotation so XLA device traces carry the "
-        "same step id, and runs the step-skew probe. tools/launch.py "
-        "--trace-dir arms every worker; merge the per-rank files with "
-        "tools/trace_report.py.")
+    doc="mx.trace step tracing. 'off' (default) with no jax.profiler "
+        "session recording is the fast path: Server.step, "
+        "ShardedTrainer.step_async and the prefetcher read "
+        "mx.trace.live() once a step and every span site tests that "
+        "local — no span, no recorder call, no buffer (asserted by "
+        "ci/run.sh sanity). The spans go live when this is 'on' OR a "
+        "profiler session records (start jax.profiler and the program's "
+        "spans are in the capture, on the device trace's clock): each "
+        "enters a jax.profiler.TraceAnnotation and is appended to the "
+        "span buffer, tagged (rank, step), for every "
+        "trace_sample_every-th step. A live span never fences. 'on' "
+        "additionally runs the step-skew probe and writes span files. "
+        "tools/launch.py --trace-dir arms every worker; merge the "
+        "per-rank files with tools/trace_report.py. docs/trace.md lists "
+        "the span names.")
 register_option(
     "trace_dir", "",
     "Base directory for mx.trace span files: each rank appends its "
@@ -509,8 +513,8 @@ register_option(
     "Record mx.trace spans for every N-th step (and every N-th record "
     "of step-less streams like the input batch-wait). 1 traces "
     "everything — right for short diagnostic windows; raise it for "
-    "always-on production tracing so the span volume and the sampled-"
-    "step fence cost shrink by N. Compile and checkpoint spans are "
+    "always-on production tracing so the span volume shrinks by N (a "
+    "traced step is never fenced). Compile and checkpoint spans are "
     "always recorded (rare, seconds-scale).")
 register_option(
     "trace_skew_every", 16,

@@ -33,6 +33,7 @@ eliminates).
 """
 from __future__ import annotations
 
+import contextlib
 import math
 import os
 import queue
@@ -49,6 +50,8 @@ from . import guard as _guard
 from . import resilience as _resilience
 from . import telemetry as _telemetry
 from . import trace as _trace
+
+_NULLCTX = contextlib.nullcontext()
 
 __all__ = ["prefetch_to_mesh", "MeshPrefetcher", "BucketPad",
            "bucket_length", "ensure_compile_cache", "autofit",
@@ -162,22 +165,26 @@ class MeshPrefetcher:
     def __next__(self):
         if self._exhausted or self._closed.is_set():
             raise StopIteration
-        if _telemetry._enabled or _trace._enabled or _goodput._enabled:
+        tr = _trace.live()
+        if _telemetry._enabled or tr or _goodput._enabled:
             t0 = time.perf_counter()
-            item = self._q.get()
-            if item is not _STOP and not isinstance(item, BaseException):
+            # the consumer-visible input stall: how long the train loop
+            # sat blocked waiting for a mesh-staged batch — the span
+            # trace_report's input-bound verdict sums
+            with (_trace.span("input.batch_wait", cat="input")
+                  if tr else _NULLCTX) as wait:
+                item = self._q.get()
+                stalled = item is not _STOP \
+                    and not isinstance(item, BaseException)
+                if tr:
+                    wait.keep = stalled
+            if stalled:
                 # waits that produced a batch are the H2D-staging stall;
                 # waiting for the end-of-stream marker is not a stall
                 t1 = time.perf_counter()
                 if _telemetry._enabled:
                     _M_STAGE_WAIT.observe(t1 - t0)
                     _M_DEPTH.labels(stage="device").set(self._q.qsize())
-                if _trace._enabled:
-                    # the consumer-visible input stall: how long the train
-                    # loop sat blocked waiting for a mesh-staged batch —
-                    # the span trace_report's input-bound verdict sums
-                    _trace.record_span("input.batch_wait", t0, t1,
-                                       cat="input")
                 if _goodput._enabled:
                     # the same consumer-visible wait, accounted as
                     # badput:input_stall wall-clock
@@ -295,33 +302,32 @@ class _Stager:
 
         from .ndarray import NDArray
 
-        t_trace = time.perf_counter() if _trace._enabled else None
-        leaves, treedef = jax.tree_util.tree_flatten(
-            item, is_leaf=lambda x: isinstance(x, NDArray))
-        raw = [_raw(x) for x in leaves]
-        targets = self._targets(item, raw)
-        if _telemetry._enabled:
-            moved = 0
-            for r, s in zip(raw, targets or [None] * len(raw)):
-                if isinstance(r, np.ndarray):
-                    moved += r.nbytes
-                elif s is not None and getattr(r, "sharding", None) != s:
-                    moved += getattr(r, "nbytes", 0)
-            if moved:
-                _M_H2D_BYTES.inc(moved)
-        if targets is None:
-            staged = [jax.device_put(r) for r in raw]
-        else:
-            staged = [r if getattr(r, "sharding", None) == t
-                      else jax.device_put(r, t)
-                      for r, t in zip(raw, targets)]
-        out = jax.tree_util.tree_unflatten(
-            treedef, [NDArray(s) for s in staged])
-        if t_trace is not None:
-            # producer-side H2D staging (runs in the prefetch worker
-            # thread, overlapped with device compute — a long span here
-            # that never surfaces as batch_wait means the overlap worked)
-            _trace.record_span("input.h2d_stage", t_trace, cat="input")
+        # producer-side H2D staging (runs in the prefetch worker thread,
+        # overlapped with device compute — a long span here that never
+        # surfaces as batch_wait means the overlap worked)
+        with (_trace.span("input.h2d_stage", cat="input")
+              if _trace.live() else _NULLCTX):
+            leaves, treedef = jax.tree_util.tree_flatten(
+                item, is_leaf=lambda x: isinstance(x, NDArray))
+            raw = [_raw(x) for x in leaves]
+            targets = self._targets(item, raw)
+            if _telemetry._enabled:
+                moved = 0
+                for r, s in zip(raw, targets or [None] * len(raw)):
+                    if isinstance(r, np.ndarray):
+                        moved += r.nbytes
+                    elif s is not None and getattr(r, "sharding", None) != s:
+                        moved += getattr(r, "nbytes", 0)
+                if moved:
+                    _M_H2D_BYTES.inc(moved)
+            if targets is None:
+                staged = [jax.device_put(r) for r in raw]
+            else:
+                staged = [r if getattr(r, "sharding", None) == t
+                          else jax.device_put(r, t)
+                          for r, t in zip(raw, targets)]
+            out = jax.tree_util.tree_unflatten(
+                treedef, [NDArray(s) for s in staged])
         return out
 
     def _targets(self, item, raw):

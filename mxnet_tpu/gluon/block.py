@@ -104,8 +104,10 @@ class Block:
 
     # -- lifecycle ------------------------------------------------------
     def initialize(self, init=None, ctx=None, verbose=False, force_reinit=False):
+        t0 = time.perf_counter()
         for _, p in self._iter_params():
             p.initialize(init=init, ctx=ctx, force_reinit=force_reinit)
+        _trace.note_setup("initialize_s", time.perf_counter() - t0)
 
     def cast(self, dtype):
         for _, p in self._iter_params():
@@ -338,10 +340,9 @@ class HybridBlock(Block):
                len(grad_params), len(aux_params))
         entry = self._cache.get(key)
         is_miss = entry is None
-        t0 = time.perf_counter() if (
-            is_miss and (_telemetry._enabled or _diagnostics._enabled
-                         or _trace._enabled)) \
-            else None
+        # a miss is always stamped: mx.trace.setup()["compile_s"] sums
+        # the builds (seconds each; a hit takes no stamp)
+        t0 = time.perf_counter() if is_miss else None
         if is_miss:
             entry = self._build_cached(args, grad_params, aux_params, train)
             self._cache[key] = entry
@@ -400,6 +401,7 @@ class HybridBlock(Block):
         out_flat, new_aux = jitted(gp_data, aux_data, rng, *in_data)
         if t0 is not None:
             dt = time.perf_counter() - t0
+            _trace.note_setup("compile_s", dt)
             if _telemetry._enabled:
                 self._tele_record_compile(args, train, dt,
                                           len(grad_params), len(aux_params))
@@ -411,7 +413,7 @@ class HybridBlock(Block):
                     "compile", block=type(self).__name__,
                     compile_time_s=round(dt, 6),
                     shapes=[list(a.shape) for a in args])
-            if _trace._enabled:
+            if _trace.live():
                 # every compile is a span (always=True: compiles are rare
                 # and seconds-scale — sampling away the exact event a
                 # trace exists to show would be self-defeating)

@@ -100,19 +100,25 @@ def paged_attention_step(q, k_new, v_new, k_pages, v_pages, tables, wp, wo,
     q/k_new/v_new (B,H,1,D); k_pages/v_pages (P,H,ps,D); tables
     (B,n_pg) int32; wp/wo/t (B,) traced int. Returns
     (out (B,1,H*D), new_k_pages, new_v_pages)."""
+    import jax
     import jax.numpy as jnp
 
     from ..ndarray import apply_op
     from ..pallas_ops import paged_attention as _paged_attn
 
     def f(q_, kn, vn, kp, vp, tb, wp_, wo_, tt):
-        wpi = wp_.astype(jnp.int32)
-        woi = wo_.astype(jnp.int32)
-        kp = kp.at[wpi, :, woi, :].set(kn[:, :, 0, :].astype(kp.dtype))
-        vp = vp.at[wpi, :, woi, :].set(vn[:, :, 0, :].astype(vp.dtype))
+        # named scopes: the device trace knows the arena's copies by
+        # instruction number only; these names survive a refactor
+        # (mx.trace.scope_map reads them back from the executable)
+        with jax.named_scope("kv_arena_update"):
+            wpi = wp_.astype(jnp.int32)
+            woi = wo_.astype(jnp.int32)
+            kp = kp.at[wpi, :, woi, :].set(kn[:, :, 0, :].astype(kp.dtype))
+            vp = vp.at[wpi, :, woi, :].set(vn[:, :, 0, :].astype(vp.dtype))
         B, H, _, D = q_.shape
-        o = _paged_attn(q_, kp, vp, tb.astype(jnp.int32),
-                        tt.astype(jnp.int32))
+        with jax.named_scope("page_gather"):
+            o = _paged_attn(q_, kp, vp, tb.astype(jnp.int32),
+                            tt.astype(jnp.int32))
         return o.transpose(0, 2, 1, 3).reshape(B, 1, H * D), kp, vp
 
     return apply_op(f, q, k_new, v_new, k_pages, v_pages, tables, wp, wo, t)
@@ -182,7 +188,7 @@ def beam_search_loop(logits0, step, reorder, B, beam, eos, max_steps,
     return seqs[idx, best], norm[idx, best]
 
 
-def jit_flat_step(model, step_fn, n_state, donate_state=0):
+def jit_flat_step(model, step_fn, n_state, donate_state=0, label=None):
     """step_fn(*leading, flat_state: list) -> (primary, new_state: list).
 
     `model` MUST be the block whose parameters step_fn uses: registering
@@ -209,13 +215,20 @@ def jit_flat_step(model, step_fn, n_state, donate_state=0):
     at those (shape, dtype)s purely for XLA memory analysis (mx.serve's
     admission control budgets KV-cache growth with it; nothing is
     dispatched and no batch transfers) — and `run.lower(...)`, the same
-    call stopped at the `jax.stages.Lowered` form."""
+    call stopped at the `jax.stages.Lowered` form.
+
+    `label`: hand the executable of the first call to
+    `mx.trace.note_executable` under this name (the jit and the call's
+    avals; nothing is lowered or read until a reader asks
+    `mx.trace.scope_map()`), and add the first call's seconds, which are
+    the compile's, to `mx.trace.setup()["compile_s"]`."""
     import time
 
     import jax
 
     from .. import check as _check
     from .. import serve as _serve
+    from .. import trace as _trace
     from ..gluon.block import functional_call
 
     class _Step(HybridBlock):
@@ -257,12 +270,20 @@ def jit_flat_step(model, step_fn, n_state, donate_state=0):
             except _check.CheckError:
                 cache.pop(len(leading), None)
                 raise
+        if is_miss and label is not None:
+            # before the call: the state buffers are donated to it
+            _trace.note_executable(
+                label, entry,
+                (gp_data, aux_data, rng) + leading + tuple(state))
+            t_compile = time.perf_counter()
         if _serve._enabled:
             t0 = time.perf_counter()
             outs, _ = entry(gp_data, aux_data, rng, *leading, *state)
             _serve.note_dispatch(type(model).__name__, t0)
         else:
             outs, _ = entry(gp_data, aux_data, rng, *leading, *state)
+        if is_miss and label is not None:
+            _trace.note_setup("compile_s", time.perf_counter() - t_compile)
         return outs[0], list(outs[1:])
 
     def _aot(args):

@@ -364,6 +364,7 @@ class GPTForCausalLM(HybridBlock):
         pooled (P,H,ps,D) page arrays per layer.
 
         Returns (f32 logits (B,V), new_ks, new_vs)."""
+        import jax
         import jax.numpy as jnp
         from ..ndarray import apply_op
 
@@ -383,11 +384,12 @@ class GPTForCausalLM(HybridBlock):
             nk.append(k._data)
             nv.append(v._data)
         x = g.ln_f(x)
-        logits = apply_op(
-            lambda hh, w: jnp.matmul(hh, w.T.astype(hh.dtype)),
-            x, g.word_embed.weight.data())
-        lg = logits.reshape(shape=(tok.shape[0], -1))._data \
-            .astype(jnp.float32)
+        with jax.named_scope("lm_head"):
+            logits = apply_op(
+                lambda hh, w: jnp.matmul(hh, w.T.astype(hh.dtype)),
+                x, g.word_embed.weight.data())
+            lg = logits.reshape(shape=(tok.shape[0], -1))._data \
+                .astype(jnp.float32)
         return lg, tuple(nk), tuple(nv)
 
     def _paged_write_targets(self, pos_d, active_d, tb_d, page_size):

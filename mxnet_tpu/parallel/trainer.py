@@ -323,12 +323,19 @@ class ShardedTrainer:
             data, labels = batch[:n_data], batch[n_data:]
 
             def loss_of(ps, aux_in, data, labels, rng):
-                if fused:
-                    # per-tensor model-dtype views of the flat f32 master;
-                    # the vjp of this unflatten returns the gradient FLAT
-                    ps = fl.unflatten(ps)
-                outs, new_aux = fn(ps, aux_in, rng, *data)
-                loss = call_loss(loss_fn, rng, outs, labels)
+                # named scopes: a device trace knows the step's fusions,
+                # copies and pads by instruction number only; under
+                # value_and_grad the backward pass shows up as
+                # `transpose(jvp(forward))` (mx.trace.scope_map reads
+                # the names back from the executable)
+                with jax.named_scope("forward"):
+                    if fused:
+                        # per-tensor model-dtype views of the flat f32
+                        # master; the vjp of this unflatten returns the
+                        # gradient FLAT
+                        ps = fl.unflatten(ps)
+                    outs, new_aux = fn(ps, aux_in, rng, *data)
+                    loss = call_loss(loss_fn, rng, outs, labels)
                 return loss, new_aux
 
             fwd_params = params
@@ -378,9 +385,11 @@ class ShardedTrainer:
                 if zflat is not None:
                     # reduce-scatter the flat gradient: each device lands
                     # the shard matching its resident master/moments
-                    grads = _zero.constrain(grads, zflat)
-                new_params, new_m, new_v = fl.apply_flat(
-                    params, grads, opt_state[0], opt_state[1], tf, lr)
+                    with jax.named_scope("grad_reduce"):
+                        grads = _zero.constrain(grads, zflat)
+                with jax.named_scope("optimizer"):
+                    new_params, new_m, new_v = fl.apply_flat(
+                        params, grads, opt_state[0], opt_state[1], tf, lr)
                 new_opt = (new_m, new_v)
             elif zspecs is not None:
                 # mx.zero weight-update sharding (arxiv 2004.13336):
@@ -391,18 +400,22 @@ class ShardedTrainer:
                 # layout. XLA emits the collectives from the constraints
                 # and can overlap the all-gather with the tail of
                 # backward; non-zero'd params (tiny state) keep the psum
-                grads = [g if zs is None else _zero.constrain(g, zs)
-                         for g, zs in zip(grads, zspecs)]
-                w_upd = [p if zs is None else _zero.constrain(p, zs)
-                         for p, zs in zip(params, zspecs)]
-                new_params, new_opt = fopt.apply(w_upd, grads, opt_state,
-                                                 tf, lr)
-                new_params = [w if zs is None else _zero.constrain(w, ps)
-                              for w, zs, ps in zip(new_params, zspecs,
-                                                   pshard_l)]
+                with jax.named_scope("grad_reduce"):
+                    grads = [g if zs is None else _zero.constrain(g, zs)
+                             for g, zs in zip(grads, zspecs)]
+                with jax.named_scope("optimizer"):
+                    w_upd = [p if zs is None else _zero.constrain(p, zs)
+                             for p, zs in zip(params, zspecs)]
+                    new_params, new_opt = fopt.apply(w_upd, grads,
+                                                     opt_state, tf, lr)
+                    new_params = [w if zs is None
+                                  else _zero.constrain(w, ps)
+                                  for w, zs, ps in zip(new_params, zspecs,
+                                                       pshard_l)]
             else:
-                new_params, new_opt = fopt.apply(params, grads, opt_state,
-                                                 tf, lr)
+                with jax.named_scope("optimizer"):
+                    new_params, new_opt = fopt.apply(params, grads,
+                                                     opt_state, tf, lr)
             return loss, new_params, new_aux, new_opt, t
 
         donate = (0, 1, 2, 3) if self._donate else (3,)
@@ -578,8 +591,13 @@ class ShardedTrainer:
             if isinstance(v, (int, float, str, list, tuple)))
 
     def _step_impl(self, data, labels, fence_every):
+        # mx.trace: read once a step; every span site tests this local
+        tr = _trace.live()
         try:
-            return self._step_once(data, labels, fence_every)
+            with (_trace.span("train.step", cat="phase",
+                              step=self.num_update + 1)
+                  if tr else _NULLCTX):
+                return self._step_once(data, labels, fence_every, tr)
         except Exception as e:  # noqa: BLE001 — classified below
             # mx.memsafe graceful OOM degradation: RESOURCE_EXHAUSTED and
             # pre-flight MemoryBudgetError walk the ladder under
@@ -591,7 +609,9 @@ class ShardedTrainer:
             return _memsafe.recover_trainer(self, e, data, labels,
                                             fence_every)
 
-    def _step_once(self, data, labels, fence_every):
+    def _step_once(self, data, labels, fence_every, tr=False):
+        """One step. `tr` is the caller's `mx.trace.live()` reading for
+        this step (memsafe's retry after an OOM runs untraced)."""
         data, labels, batch = _batch_arrays(data, labels)
         if not self._ready:
             with jax.default_device(jax.devices()[0]):
@@ -621,16 +641,19 @@ class ShardedTrainer:
         # mx.config.set("nan_sentinel", ...) takes effect mid-run
         sentinel = _config.get("nan_sentinel")
         # mx.trace: decided up front so an unsampled step pays nothing
-        # beyond the module bool + one modulo (disabled: the bool alone).
-        # A cache-miss step traces regardless of sampling — compiles are
-        # always-record events (rare, seconds-scale)
-        tracing = _trace._enabled and (is_miss or _trace.sampled(step_no))
+        # beyond one modulo (not live: the local bool alone). A cache-miss
+        # step traces regardless of sampling — compiles are always-record
+        # events (rare, seconds-scale). A traced step is never fenced for
+        # the trace's sake: device time is the device trace's to give
+        tracing = tr and (is_miss or _trace.sampled(step_no))
         # mx.goodput accounts every completed step (replay-aware) — one
         # module bool here, like the other observers
         accounting = _goodput._enabled
         observing = (_telemetry._enabled or _diagnostics._enabled or sentinel
                      or _inspect._enabled or tracing or accounting)
-        t_build = time.perf_counter() if (is_miss and observing) else None
+        # a miss is always stamped: mx.trace.setup()["compile_s"] sums the
+        # builds (seconds each, never on a steady step)
+        t_build = time.perf_counter() if is_miss else None
         if is_miss:
             self._step_cache[key] = self._build_step(len(data), len(labels), shapes)
         if is_miss:
@@ -735,22 +758,36 @@ class ShardedTrainer:
                     # dispatch past the check
                     del self._step_cache[key]
                     raise
+            if is_miss:
+                # before the call (the train state is donated to it): the
+                # jit and this call's avals, for mx.trace.scope_map()
+                _trace.note_executable(
+                    "train.step", self._step_cache[key],
+                    (self.params, self.aux, self.opt_state, self._t_dev)
+                    + tuple(scalars) + (rngk,) + tuple(batch))
             # sampled steps also carry an mx.trace annotation so the XLA
             # device trace groups this step's kernels under the same
-            # (rank, step) tag as the host spans
+            # (rank, step) tag as the host spans; `step.dispatch` is the
+            # jitted call itself, argument handling through enqueue (a
+            # miss records `step.compile` instead)
             ann = _trace.annotate(step_no) if tracing else _NULLCTX
+            disp = _trace.span("step.dispatch", cat="step", step=step_no) \
+                if tracing and not is_miss else _NULLCTX
             with jax.profiler.StepTraceAnnotation("train_step",
-                                                  step_num=step_no), ann:
+                                                  step_num=step_no), \
+                    ann, disp:
                 loss, self.params, self.aux, self.opt_state, self._t_dev = \
                     self._step_cache[key](
                         self.params, self.aux, self.opt_state, self._t_dev,
                         *scalars, rngk, *batch)
             t_disp = time.perf_counter() if tracing else None
+            if is_miss:
+                _trace.note_setup("compile_s", time.perf_counter() - t_build)
             self.num_update = step_no
             fenced = False
             if observing:
                 if _telemetry._enabled or sentinel or _inspect._enabled \
-                        or tracing or accounting:
+                        or accounting:
                     # fence on the loss (one output of the step executable
                     # fences the whole executable) so the histogram records
                     # device step time, not just async dispatch.
@@ -758,7 +795,9 @@ class ShardedTrainer:
                     # skips the fence — a ring append must not cost the
                     # host/device overlap — so its records mean "step
                     # dispatched" there. Inspect fences too: its step time
-                    # is the MFU denominator and must be device time
+                    # is the MFU denominator and must be device time.
+                    # mx.trace never fences: it would serialise the
+                    # prefetch/dispatch overlap it is there to show
                     jax.block_until_ready(loss)
                     fenced = True
                 t_done = time.perf_counter()
@@ -771,8 +810,8 @@ class ShardedTrainer:
                         else self.fopt.lr_at(self.num_update),
                         shapes, t_build, sentinel)
                 if tracing:
-                    self._trace_record_step(step_no, t_build, t_step,
-                                            t_disp, t_done)
+                    self._trace_record_step(step_no, t_build, t_disp,
+                                            t_done, fenced)
                 if accounting:
                     # before inspect (whose miss-path analysis takes
                     # real wall time): the step's interval must end at
@@ -819,22 +858,21 @@ class ShardedTrainer:
             _scope.on_step(self, step_no)
         return NDArray(loss)
 
-    def _trace_record_step(self, step_no, t_build, t_step, t_disp, t_done):
-        """mx.trace spans for one SAMPLED step: host dispatch
-        (t_step→t_disp) and the fence (t_disp→t_done — the device-time
-        share; tracing forces the fence exactly so this span means device
-        time, the same trade telemetry makes), plus the skew-probe tick at the
+    def _trace_record_step(self, step_no, t_build, t_disp, t_done, fenced):
+        """What mx.trace records after the call of one SAMPLED step
+        (`train.step` and `step.dispatch` are live spans around it): the
+        fence (t_disp→t_done), only where another observer fenced, since a
+        trace never fences for itself; and the skew-probe tick at the
         collective boundary. A cache-miss step records ONE compile span
-        (build through fenced first call) instead — its dispatch is
+        (build through the first call's return, or through the fence
+        where there was one) instead of a dispatch: it is
         compile-dominated and would poison the step category the verdict
         sums, the same exclusion the telemetry step histogram makes."""
         if t_build is not None:
             _trace.record_span("step.compile", t_build, t_done,
                                step=step_no, cat="compile", always=True,
                                block=type(self.block).__name__)
-        else:
-            _trace.record_span("step.dispatch", t_step, t_disp,
-                               step=step_no, cat="step")
+        elif fenced:
             _trace.record_span("step.fence", t_disp, t_done, step=step_no,
                                cat="step")
         _trace.skew_tick(step_no)

@@ -671,7 +671,7 @@ class CheckpointManager:
             _M_SAVE_SECONDS.observe(dt)
             _telemetry.event("checkpoint", step=step, path=path,
                              dur_s=round(dt, 6))
-        if _trace._enabled:
+        if _trace.live():
             # checkpoint saves serialize with the step loop on this rank:
             # a gang whose straggler's timeline shows checkpoint.save where
             # the peers show step spans is checkpoint-bound, not slow

@@ -82,6 +82,7 @@ asserts zero `note_dispatch` calls). Constructing a `Server` arms it.
 from __future__ import annotations
 
 import collections
+import contextlib
 import os
 import queue as _pyqueue
 import signal as _sig
@@ -122,12 +123,22 @@ CANCELLED = "cancelled"  # client/injected cancellation (499-style)
 FAILED = "failed"        # scheduler error surfaced to the request (500)
 TERMINAL = frozenset({DONE, REJECTED, SHED, EXPIRED, CANCELLED, FAILED})
 
+_NULLCTX = contextlib.nullcontext()
 _lock = _locklint.make_lock("serve.module")
 _enabled = False          # the fast-path bool; the decode hook reads it
 _dispatches = 0           # decode dispatches seen at the shared hook site
 # live Server objects (weak: a dropped server must not be pinned by the
 # registry) — mx.scope's /statusz surfaces each one's stats()
 _servers = weakref.WeakSet()
+
+
+def _close_round(step_span, stream_span, chunk, tokens):
+    """Late attrs of one LIVE decode round: the tokens its `serve.stream`
+    emitted, and on the step's `serve.step` span the longest chunk any of
+    its rounds ran (`chunk` > 1: the step ran a prefill-chunk executable)."""
+    stream_span.attrs["tokens"] = tokens
+    if chunk > step_span.attrs.get("chunk", 0):
+        step_span.attrs["chunk"] = chunk
 
 
 def servers():
@@ -548,7 +559,8 @@ class Server:
         self._tree = _pages.PrefixTree(self._pool)
         self._stats.update({
             "prompt_tokens": 0, "prefix_tokens": 0, "prefix_hits": 0,
-            "chunk_dispatches": 0, "spec_rounds": 0,
+            "chunk_dispatches": 0, "chunk_steps": 0, "token_steps": 0,
+            "spec_rounds": 0,
             "drafts_proposed": 0, "drafts_accepted": 0,
         })
         from . import check as _check
@@ -816,44 +828,61 @@ class Server:
         ladder), run one batched decode step per active bucket, stream
         the new tokens. Returns True while work remains. Overload never
         raises out of here — only scheduler bugs do."""
-        with self._lock:
-            self._sched_step += 1
-            n = self._sched_step
-        self._fire_faults(n)
-        if _guard._enabled:
-            _guard.heartbeat(phase="serve")
-        # bucket executables and their AOT peaks are built OUTSIDE the
-        # lock (an XLA compile is seconds on a real model; submit/cancel
-        # from client threads must not block behind it)
-        self._prewarm_buckets()
-        with self._lock:
-            self._apply_cancels()
-            self._evict_expired()
-            # reclaim drained buckets BEFORE admission: caches freed by
-            # a cancel/expiry this very step must not count against the
-            # incoming request's budget (a spurious 429/shrink otherwise)
-            self._gc_groups()
-            self._admit()
-            groups = [g for g in self._groups.values() if g.active()]
-        for grp in groups:
-            if not _goodput._enabled:
-                self._decode_group(grp, n)
-                continue
-            # decode time for a batch holding any degraded/requeued
-            # request is "serve_degraded" — capacity spent delivering
-            # below-contract service rather than clean goodput
+        # mx.trace: read once a step; every span site below tests this
+        # local (off: one call and a handful of bool tests, nothing else)
+        tr = _trace.live()
+        with (_trace.span("serve.step", cat="phase",
+                          step=self._sched_step + 1)
+              if tr else _NULLCTX) as sp:
+            with (_trace.span("serve.schedule", cat="phase")
+                  if tr else _NULLCTX) as sched:
+                with self._lock:
+                    self._sched_step += 1
+                    n = self._sched_step
+                self._fire_faults(n)
+                if _guard._enabled:
+                    _guard.heartbeat(phase="serve")
+                # bucket executables and their AOT peaks are built OUTSIDE
+                # the lock (an XLA compile is seconds on a real model;
+                # submit/cancel from client threads must not block behind
+                # it)
+                self._prewarm_buckets()
+                with self._lock:
+                    self._apply_cancels()
+                    self._evict_expired()
+                    # reclaim drained buckets BEFORE admission: caches
+                    # freed by a cancel/expiry this very step must not
+                    # count against the incoming request's budget (a
+                    # spurious 429/shrink otherwise)
+                    self._gc_groups()
+                    if tr:
+                        sched.attrs["admitted"] = len(self._queue)
+                    self._admit()
+                    if tr:
+                        sched.attrs["admitted"] -= len(self._queue)
+                        sched.step = n
+                    groups = [g for g in self._groups.values()
+                              if g.active()]
+            for grp in groups:
+                if not _goodput._enabled:
+                    self._decode_group(grp, n, sp)
+                    continue
+                # decode time for a batch holding any degraded/requeued
+                # request is "serve_degraded" — capacity spent delivering
+                # below-contract service rather than clean goodput
+                with self._lock:
+                    degr = any(grp.slots[i].degraded or grp.slots[i].requeues
+                               for i in grp.active())
+                t0 = time.perf_counter()
+                self._decode_group(grp, n, sp)
+                _goodput.note("serve_degraded" if degr else "serve_decode",
+                              t0)
             with self._lock:
-                degr = any(grp.slots[i].degraded or grp.slots[i].requeues
-                           for i in grp.active())
-            t0 = time.perf_counter()
-            self._decode_group(grp, n)
-            _goodput.note("serve_degraded" if degr else "serve_decode", t0)
-        with self._lock:
-            self._gc_groups()
-            if _telemetry._enabled:
-                _M_QDEPTH.set(len(self._queue))
-                _M_ACTIVE.set(sum(len(g.active())
-                                  for g in self._groups.values()))
+                self._gc_groups()
+                if _telemetry._enabled:
+                    _M_QDEPTH.set(len(self._queue))
+                    _M_ACTIVE.set(sum(len(g.active())
+                                      for g in self._groups.values()))
         return self.busy()
 
     def _prewarm_buckets(self):
@@ -1045,7 +1074,13 @@ class Server:
                 return mdl.decode_paged_chunk(toks, t0, n, tables, flat,
                                               ps, full=full)
 
-            r = jit_flat_step(mdl, step, 2 * n_l, donate_state=2 * n_l)
+            # the label joins a device trace's instructions to this
+            # program's named scopes (mx.trace.scope_map); the
+            # `serve.decode_step` span's `bucket` and `chunk` name it
+            r = jit_flat_step(
+                mdl, step, 2 * n_l, donate_state=2 * n_l,
+                label=f"serve.paged/bucket={bucket}/chunk={C}"
+                + ("/full" if full else "") + ("/draft" if draft else ""))
             self._runners[key] = r
         return r
 
@@ -1488,60 +1523,62 @@ class Server:
             del self._groups[L]
 
     # -- decode ----------------------------------------------------------
-    def _decode_group(self, grp, sched_step):
+    def _decode_group(self, grp, sched_step, sp=None):
+        """One decode round of a bucket group. `sp` is the step's live
+        `serve.step` span, None while mx.trace is not live: the span
+        sites below test it and nothing else."""
         if self._paged:
-            return self._decode_group_paged(grp, sched_step)
+            return self._decode_group_paged(grp, sched_step, sp)
         import jax.numpy as jnp
-        tok = np.zeros((self._slots,), np.int32)
-        t = np.zeros((self._slots,), np.int32)
-        active = grp.active()
-        if not active:
-            return
-        for i in active:
-            r = grp.slots[i]
-            p = grp.pos[i]
-            lp = r.prompt.size
-            tok[i] = r.prompt[p] if p < lp else r.tokens[p - lp]
-            t[i] = p
-        if _slo._enabled:
+        with (_trace.span("serve.prepare", cat="phase", step=sched_step,
+                          chunk=1) if sp else _NULLCTX):
+            tok = np.zeros((self._slots,), np.int32)
+            t = np.zeros((self._slots,), np.int32)
+            active = grp.active()
+            if not active:
+                return
             for i in active:
                 r = grp.slots[i]
-                if r._slo_j is not None:
-                    _slo.note_first_dispatch(r)
-        t0 = time.perf_counter()
-        logits, new_state = self._dispatch(grp, jnp.asarray(tok),
-                                           jnp.asarray(t))
-        grp.caches = new_state
-        lg = np.asarray(logits, np.float32)     # host fetch = the fence
-        t1 = time.perf_counter()
-        if _trace._enabled:
-            # request ids ride in the span args so mx.slo journals and
-            # trace spans join on one timeline
-            _trace.record_span("serve.decode_step", t0, t1, cat="serve",
-                               step=sched_step, bucket=grp.bucket,
-                               slots=len(active),
-                               reqs=[grp.slots[i].id for i in active
-                                     if grp.slots[i] is not None])
-        t_emit = time.perf_counter()
-        with self._lock:
-            self._stats["steps"] += 1
-            for i in active:
-                r = grp.slots[i]
-                if r is None or r.state in TERMINAL:
-                    continue        # evicted/cancelled under the dispatch
                 p = grp.pos[i]
-                grp.pos[i] = p + 1
-                if p < r.prompt.size - 1:
-                    continue        # still prefilling the prompt
-                nxt = self._sample(r, lg[i])
-                self._emit(r, nxt)
-                if (r.eos is not None and nxt == r.eos) \
-                        or len(r.tokens) >= r.max_new_tokens:
-                    grp.slots[i] = None
-                    self._finish(r, DONE, "200 ok")
-        if _trace._enabled:
-            _trace.record_span("serve.stream", t_emit, cat="serve",
-                               step=sched_step)
+                lp = r.prompt.size
+                tok[i] = r.prompt[p] if p < lp else r.tokens[p - lp]
+                t[i] = p
+            if _slo._enabled:
+                for i in active:
+                    r = grp.slots[i]
+                    if r._slo_j is not None:
+                        _slo.note_first_dispatch(r)
+        with (_trace.span("serve.decode_step", cat="serve", step=sched_step,
+                          chunk=1, bucket=grp.bucket, slots=len(active))
+              if sp else _NULLCTX):
+            logits, new_state = self._dispatch(grp, jnp.asarray(tok),
+                                               jnp.asarray(t))
+            grp.caches = new_state
+            with (_trace.span("serve.fetch", cat="phase", step=sched_step,
+                              chunk=1) if sp else _NULLCTX):
+                lg = np.asarray(logits, np.float32)  # host fetch = the fence
+        with (_trace.span("serve.stream", cat="serve", step=sched_step)
+              if sp else _NULLCTX) as stream:
+            if sp:
+                tokens0 = self._stats["tokens"]
+            with self._lock:
+                self._stats["steps"] += 1
+                for i in active:
+                    r = grp.slots[i]
+                    if r is None or r.state in TERMINAL:
+                        continue    # evicted/cancelled under the dispatch
+                    p = grp.pos[i]
+                    grp.pos[i] = p + 1
+                    if p < r.prompt.size - 1:
+                        continue    # still prefilling the prompt
+                    nxt = self._sample(r, lg[i])
+                    self._emit(r, nxt)
+                    if (r.eos is not None and nxt == r.eos) \
+                            or len(r.tokens) >= r.max_new_tokens:
+                        grp.slots[i] = None
+                        self._finish(r, DONE, "200 ok")
+            if sp:
+                _close_round(sp, stream, 1, self._stats["tokens"] - tokens0)
 
     def _dispatch(self, grp, tok, t):
         """One batched decode dispatch under the transient-fault
@@ -1576,7 +1613,7 @@ class Server:
                                 on_retry=on_retry)
 
     # -- paged decode ----------------------------------------------------
-    def _decode_group_paged(self, grp, sched_step):
+    def _decode_group_paged(self, grp, sched_step, sp=None):
         """One scheduler round for a paged bucket group. Mode per round:
         a SPECULATIVE round (draft chain + one k+1-token verify chunk)
         when a drafter is attached, every active slot is past its
@@ -1604,9 +1641,9 @@ class Server:
                 if r._slo_j is not None:
                     _slo.note_first_dispatch(r)
         if self._drafter is not None and all_decoding and any_greedy:
-            self._spec_round(grp, active, sched_step)
+            self._spec_round(grp, active, sched_step, sp)
         else:
-            self._chunk_round(grp, active, max_need, sched_step)
+            self._chunk_round(grp, active, max_need, sched_step, sp)
 
     def _paged_inputs(self, grp, C):
         """Blank leading arrays for one chunk dispatch: empty slots run
@@ -1619,69 +1656,72 @@ class Server:
         tables = np.zeros((B, grp.n_pg), np.int32)
         return toks, t0, n, tables
 
-    def _chunk_round(self, grp, active, max_need, sched_step):
+    def _chunk_round(self, grp, active, max_need, sched_step, sp=None):
         import jax.numpy as jnp
         C = self._prefill_chunk if max_need > 1 else 1
-        toks, t0, n, tables = self._paged_inputs(grp, C)
-        for i in active:
-            r = grp.slots[i]
-            lp = r.prompt.size
-            p = grp.pos[i]
-            if p < lp:
-                ni = min(C, lp - p)
-                toks[i, :ni] = r.prompt[p:p + ni]
-            else:
-                ni = 1
-                toks[i, 0] = r.tokens[p - lp]
-            t0[i] = p
-            n[i] = ni
-            tables[i, :len(grp.pages[i])] = grp.pages[i]
-        run = self._paged_runner(grp.bucket, C, False)
-        lead = (jnp.asarray(toks), jnp.asarray(t0), jnp.asarray(n),
-                jnp.asarray(tables))
-        tdec = time.perf_counter()
-        logits = self._dispatch_paged(grp, run, lead, "target")
-        if self._drafter is not None:
-            # mirror the chunk on the drafter so its cache tracks the
-            # target position-for-position (gap-0: a later speculative
-            # round can start its chain with no catch-up work)
-            drun = self._paged_runner(grp.bucket, C, False, draft=True)
-            self._dispatch_paged(grp, drun, lead, "draft")
-        lg = np.asarray(logits, np.float32)     # host fetch = the fence
-        t1 = time.perf_counter()
-        if _trace._enabled:
-            _trace.record_span("serve.decode_step", tdec, t1, cat="serve",
-                               step=sched_step, bucket=grp.bucket,
-                               slots=len(active), chunk=C,
-                               reqs=[grp.slots[i].id for i in active
-                                     if grp.slots[i] is not None])
-        t_emit = time.perf_counter()
-        with self._lock:
-            self._stats["steps"] += 1
-            self._stats["chunk_dispatches"] += 1
+        with (_trace.span("serve.prepare", cat="phase", step=sched_step,
+                          chunk=C, slots=len(active))
+              if sp else _NULLCTX):
+            toks, t0, n, tables = self._paged_inputs(grp, C)
             for i in active:
                 r = grp.slots[i]
-                if r is None or r.state in TERMINAL:
-                    continue        # evicted/cancelled under the dispatch
-                p = grp.pos[i]
-                ni = int(n[i])
-                grp.pos[i] = p + ni
                 lp = r.prompt.size
-                if p + ni >= lp and not grp.inserted[i]:
-                    self._tree_insert(grp, i, r)
-                if p + ni < lp:
-                    continue        # still prefilling the prompt
-                nxt = self._sample(r, lg[i])
-                self._emit(r, nxt)
-                if (r.eos is not None and nxt == r.eos) \
-                        or len(r.tokens) >= r.max_new_tokens:
-                    self._vacate(grp, i)
-                    self._finish(r, DONE, "200 ok")
-        if _trace._enabled:
-            _trace.record_span("serve.stream", t_emit, cat="serve",
-                               step=sched_step)
+                p = grp.pos[i]
+                if p < lp:
+                    ni = min(C, lp - p)
+                    toks[i, :ni] = r.prompt[p:p + ni]
+                else:
+                    ni = 1
+                    toks[i, 0] = r.tokens[p - lp]
+                t0[i] = p
+                n[i] = ni
+                tables[i, :len(grp.pages[i])] = grp.pages[i]
+            run = self._paged_runner(grp.bucket, C, False)
+            lead = (jnp.asarray(toks), jnp.asarray(t0), jnp.asarray(n),
+                    jnp.asarray(tables))
+        with (_trace.span("serve.decode_step", cat="serve", step=sched_step,
+                          chunk=C, bucket=grp.bucket, slots=len(active))
+              if sp else _NULLCTX):
+            logits = self._dispatch_paged(grp, run, lead, "target")
+            if self._drafter is not None:
+                # mirror the chunk on the drafter so its cache tracks the
+                # target position-for-position (gap-0: a later speculative
+                # round can start its chain with no catch-up work)
+                drun = self._paged_runner(grp.bucket, C, False, draft=True)
+                self._dispatch_paged(grp, drun, lead, "draft")
+            with (_trace.span("serve.fetch", cat="phase", step=sched_step,
+                              chunk=C) if sp else _NULLCTX):
+                lg = np.asarray(logits, np.float32)  # host fetch = the fence
+        with (_trace.span("serve.stream", cat="serve", step=sched_step)
+              if sp else _NULLCTX) as stream:
+            if sp:
+                tokens0 = self._stats["tokens"]
+            with self._lock:
+                self._stats["steps"] += 1
+                self._stats["chunk_dispatches"] += 1
+                self._stats["chunk_steps" if C > 1 else "token_steps"] += 1
+                for i in active:
+                    r = grp.slots[i]
+                    if r is None or r.state in TERMINAL:
+                        continue    # evicted/cancelled under the dispatch
+                    p = grp.pos[i]
+                    ni = int(n[i])
+                    grp.pos[i] = p + ni
+                    lp = r.prompt.size
+                    if p + ni >= lp and not grp.inserted[i]:
+                        self._tree_insert(grp, i, r)
+                    if p + ni < lp:
+                        continue    # still prefilling the prompt
+                    nxt = self._sample(r, lg[i])
+                    self._emit(r, nxt)
+                    if (r.eos is not None and nxt == r.eos) \
+                            or len(r.tokens) >= r.max_new_tokens:
+                        self._vacate(grp, i)
+                        self._finish(r, DONE, "200 ok")
+            if sp:
+                _close_round(sp, stream, C, self._stats["tokens"] - tokens0)
 
-    def _spec_round(self, grp, active, sched_step):
+    def _spec_round(self, grp, active, sched_step, sp=None):
         """One speculative decoding round: the drafter chains k greedy
         proposals per eligible slot, the target verifies them all in ONE
         k+1-token chunk (full logits), and the host keeps the longest
@@ -1690,82 +1730,86 @@ class Server:
         Non-greedy slots ride along with a single ordinary token."""
         import jax.numpy as jnp
         k = self._spec_k
-        tok0 = np.zeros((self._slots,), np.int32)
-        spec_row = np.zeros((self._slots,), bool)
-        toks, t0, n, tables = self._paged_inputs(grp, k + 1)
-        for i in active:
-            r = grp.slots[i]
-            p = grp.pos[i]
-            tok0[i] = r.tokens[p - r.prompt.size]
-            t0[i] = p
-            tables[i, :len(grp.pages[i])] = grp.pages[i]
-            spec_row[i] = r.temperature == 0.0
-        drafts_out = self._dispatch_paged(
-            grp, self._draft_runner(grp.bucket),
-            (jnp.asarray(tok0), jnp.asarray(t0), jnp.asarray(spec_row),
-             jnp.asarray(tables)), "draft")
-        drafts = np.asarray(drafts_out, np.int32)[:, :k]   # (B, k)
-        for i in active:
-            toks[i, 0] = tok0[i]
-            if spec_row[i]:
-                toks[i, 1:] = drafts[i]
-                n[i] = k + 1
-            else:
-                n[i] = 1
-        run = self._paged_runner(grp.bucket, k + 1, True)
-        tdec = time.perf_counter()
-        logits = self._dispatch_paged(
-            grp, run, (jnp.asarray(toks), jnp.asarray(t0),
-                       jnp.asarray(n), jnp.asarray(tables)), "target")
-        lgs = np.asarray(logits, np.float32)               # (B, k+1, V)
-        t1 = time.perf_counter()
-        if _trace._enabled:
-            _trace.record_span("serve.decode_step", tdec, t1, cat="serve",
-                               step=sched_step, bucket=grp.bucket,
-                               slots=len(active), spec_k=k,
-                               reqs=[grp.slots[i].id for i in active
-                                     if grp.slots[i] is not None])
-        t_emit = time.perf_counter()
-        with self._lock:
-            self._stats["steps"] += 1
-            self._stats["spec_rounds"] += 1
+        with (_trace.span("serve.prepare", cat="phase", step=sched_step,
+                          chunk=k + 1, slots=len(active))
+              if sp else _NULLCTX):
+            tok0 = np.zeros((self._slots,), np.int32)
+            spec_row = np.zeros((self._slots,), bool)
+            toks, t0, n, tables = self._paged_inputs(grp, k + 1)
             for i in active:
                 r = grp.slots[i]
-                if r is None or r.state in TERMINAL:
-                    continue
                 p = grp.pos[i]
-                if not spec_row[i]:
-                    grp.pos[i] = p + 1
-                    nxt = self._sample(r, lgs[i, 0])
-                    self._emit(r, nxt)
-                    if (r.eos is not None and nxt == r.eos) \
-                            or len(r.tokens) >= r.max_new_tokens:
+                tok0[i] = r.tokens[p - r.prompt.size]
+                t0[i] = p
+                tables[i, :len(grp.pages[i])] = grp.pages[i]
+                spec_row[i] = r.temperature == 0.0
+            drafts_out = self._dispatch_paged(
+                grp, self._draft_runner(grp.bucket),
+                (jnp.asarray(tok0), jnp.asarray(t0), jnp.asarray(spec_row),
+                 jnp.asarray(tables)), "draft")
+            drafts = np.asarray(drafts_out, np.int32)[:, :k]   # (B, k)
+            for i in active:
+                toks[i, 0] = tok0[i]
+                if spec_row[i]:
+                    toks[i, 1:] = drafts[i]
+                    n[i] = k + 1
+                else:
+                    n[i] = 1
+            run = self._paged_runner(grp.bucket, k + 1, True)
+        with (_trace.span("serve.decode_step", cat="serve", step=sched_step,
+                          chunk=k + 1, bucket=grp.bucket,
+                          slots=len(active), spec_k=k)
+              if sp else _NULLCTX):
+            logits = self._dispatch_paged(
+                grp, run, (jnp.asarray(toks), jnp.asarray(t0),
+                           jnp.asarray(n), jnp.asarray(tables)), "target")
+            with (_trace.span("serve.fetch", cat="phase", step=sched_step,
+                              chunk=k + 1) if sp else _NULLCTX):
+                lgs = np.asarray(logits, np.float32)       # (B, k+1, V)
+        with (_trace.span("serve.stream", cat="serve", step=sched_step)
+              if sp else _NULLCTX) as stream:
+            if sp:
+                tokens0 = self._stats["tokens"]
+            with self._lock:
+                self._stats["steps"] += 1
+                self._stats["spec_rounds"] += 1
+                for i in active:
+                    r = grp.slots[i]
+                    if r is None or r.state in TERMINAL:
+                        continue
+                    p = grp.pos[i]
+                    if not spec_row[i]:
+                        grp.pos[i] = p + 1
+                        nxt = self._sample(r, lgs[i, 0])
+                        self._emit(r, nxt)
+                        if (r.eos is not None and nxt == r.eos) \
+                                or len(r.tokens) >= r.max_new_tokens:
+                            self._vacate(grp, i)
+                            self._finish(r, DONE, "200 ok")
+                        continue
+                    self._stats["drafts_proposed"] += k
+                    emitted = 0
+                    done = False
+                    for j in range(k + 1):
+                        # same argmax as _sample's greedy path — exact
+                        # acceptance means verify-then-keep, never trust
+                        nxt = int(lgs[i, j].argmax())
+                        self._emit(r, nxt)
+                        emitted += 1
+                        if (r.eos is not None and nxt == r.eos) \
+                                or len(r.tokens) >= r.max_new_tokens:
+                            done = True
+                            break
+                        if j >= k or int(drafts[i, j]) != nxt:
+                            break
+                        self._stats["drafts_accepted"] += 1
+                    grp.pos[i] = p + emitted
+                    if done:
                         self._vacate(grp, i)
                         self._finish(r, DONE, "200 ok")
-                    continue
-                self._stats["drafts_proposed"] += k
-                emitted = 0
-                done = False
-                for j in range(k + 1):
-                    # same argmax as _sample's greedy path — exact
-                    # acceptance means verify-then-keep, never trust
-                    nxt = int(lgs[i, j].argmax())
-                    self._emit(r, nxt)
-                    emitted += 1
-                    if (r.eos is not None and nxt == r.eos) \
-                            or len(r.tokens) >= r.max_new_tokens:
-                        done = True
-                        break
-                    if j >= k or int(drafts[i, j]) != nxt:
-                        break
-                    self._stats["drafts_accepted"] += 1
-                grp.pos[i] = p + emitted
-                if done:
-                    self._vacate(grp, i)
-                    self._finish(r, DONE, "200 ok")
-        if _trace._enabled:
-            _trace.record_span("serve.stream", t_emit, cat="serve",
-                               step=sched_step)
+            if sp:
+                _close_round(sp, stream, k + 1,
+                             self._stats["tokens"] - tokens0)
 
     def _tree_insert(self, grp, i, req):
         """One-time prefix-tree registration of a slot's fully-prefilled

@@ -1,56 +1,81 @@
-"""mx.trace — cross-rank distributed step tracing with straggler and
-critical-path attribution.
+"""mx.trace — the program's own spans and scopes, on the device trace's
+clock, with cross-rank straggler and critical-path attribution.
 
-The observability stack so far explains ONE process: `mx.inspect`'s
-MFU/roofline and telemetry's input-stall attribution are static estimates
-or single-rank aggregates, so "why is the GANG slow" — a straggler rank,
-collective arrival skew, a host input stall on one worker — was answered
-by eyeballing per-rank JSONL files. Data-parallel collectives serialize on
-the slowest arriver (PAPERS.md arxiv 2004.13336: weight-update collectives
-dominate as replicas scale), which makes the gang-wide timeline the unit
-of diagnosis, not the rank. This module is that measured timeline layer:
+A device trace says what the chip did and when; only the program can say
+what the host was doing meanwhile and which part of the model an
+anonymous `copy.602` belongs to. This module is both halves:
 
-  * **sampling span recorder** — host-side spans tagged `(rank, step)` at
-    the hook sites that already exist: dataflow batch-wait and H2D
-    staging, ShardedTrainer dispatch and fence, block/step compile,
-    resilience checkpoint save. Every `trace_sample_every`-th step is
-    recorded (compiles/checkpoints always — rare and seconds-scale);
-    sampled steps are additionally wrapped in
-    `jax.profiler.TraceAnnotation` so XLA device traces carry the same
-    step id as the host spans.
-  * **skew probe** — every `trace_skew_every` sampled steps, each rank
-    wall-stamps its arrival at the collective boundary (a tiny
-    timestamped all-gather when jax runs multi-process), measuring
-    per-rank clock offset and step-arrival spread. Feeds the
-    `step_skew_seconds` / `straggler_rank` telemetry gauges, a
-    flight-ring "trace" entry, and the post-mortem "trace" section.
-  * **per-rank span files** — with `trace_dir` set, spans append to
-    `<dir>/<rank>/trace.jsonl` behind a meta line carrying this rank's
-    wall-clock epoch (and the gang epoch tools/launch.py --trace-dir
-    exports), so `tools/trace_report.py` can merge all ranks into one
-    clock-aligned Perfetto/chrome trace (one track per rank) and print a
-    measured gang-wide verdict: input-bound / compute-bound /
-    comm-skew-bound, naming the straggler rank and its dominant span.
+  * **live spans** — `span(name, **attrs)` at the phase boundaries of
+    `Server.step` (`serve.step` ⊃ `serve.schedule`, `serve.prepare`,
+    `serve.decode_step` ⊃ `serve.fetch`, `serve.stream`; per request
+    `serve.queue_wait`, `serve.admit`), of `ShardedTrainer.step_async`
+    (`train.step` ⊃ `step.dispatch`; `step.compile` on a miss;
+    `step.fence` where another observer fenced), of the prefetcher
+    (`input.batch_wait`, `input.h2d_stage`), plus `compile` and
+    `checkpoint.save`. A live span enters a
+    `jax.profiler.TraceAnnotation` of the same name, so it is in the
+    profiler's capture on the device trace's clock, and appends to a
+    bounded buffer (`spans()`), tagged `(rank, step)`, for every
+    `trace_sample_every`-th step. docs/trace.md has the table.
+  * **device scopes** — `jax.named_scope`s inside the jitted steps
+    (`kv_arena_update`, `page_gather`, `lm_head`; `forward`, whose
+    backward autodiff names `transpose(jvp(forward))`, `grad_reduce`,
+    `optimizer`). `note_executable()` remembers each step executable at
+    compile time and `scope_map()` reads, on demand, which HLO
+    instruction was traced under which scope: the join from a device
+    trace's event names to names a refactor keeps.
+  * **set-up table** — `setup()`: always-on sums of where the seconds
+    before the first step went (import, `initialize()`, jit misses,
+    compile-cache hits and misses).
+  * **skew probe** (under `enable()` only) — every `trace_skew_every`
+    sampled steps, each rank wall-stamps its arrival at the collective
+    boundary (a tiny timestamped all-gather when jax runs
+    multi-process), feeding the `step_skew_seconds` / `straggler_rank`
+    telemetry gauges, a flight-ring "trace" entry, and the post-mortem
+    "trace" section.
+  * **per-rank span files** (under `enable()` with `trace_dir`) — spans
+    append to `<dir>/<rank>/trace.jsonl` behind a meta line carrying
+    this rank's wall-clock epoch (and the gang epoch tools/launch.py
+    --trace-dir exports), so `tools/trace_report.py` can merge all ranks
+    into one clock-aligned Perfetto/chrome trace and print a measured
+    gang-wide verdict: input-bound / compute-bound / comm-skew-bound,
+    naming the straggler rank and its dominant span.
 
-Clock model: spans timestamp against the process-wide monotonic epoch in
-`mxnet_tpu.util` — the SAME epoch mx.profiler's chrome events and
-telemetry's event mirror use — and the meta line maps that epoch to wall
-time, so merged multi-rank timelines align without per-file clock math.
+Clock model: the buffer's spans timestamp against the process-wide
+monotonic epoch in `mxnet_tpu.util` — the SAME epoch mx.profiler's chrome
+events and telemetry's event mirror use — and the meta line maps that
+epoch to wall time, so merged multi-rank timelines align without per-file
+clock math. The TraceAnnotation half of a span is stamped by the profiler
+itself; a reader joins the two clocks through a span that encloses, or
+lies in, an event it knows on the profiler's clock.
 
-Cost model: DISABLED (the default) is the production fast path — every
-hook site checks one module-level bool and falls through; no span buffer
-exists, no locks are taken, nothing allocates (`ci/run.sh sanity` asserts
-the hook sites make zero recorder calls). Enable with
-`mx.trace.enable()` / `MXNET_TPU_TRACE=on` / `tools/launch.py
---trace-dir`.
+Cost model. `live()` is `enable()`d OR a `jax.profiler` session is
+recording: start the profiler and the program's spans are in the
+capture; nothing else to switch on. NOT live (the default) is the
+production fast path: `Server.step`, `step_async` and the prefetcher read
+`live()` once a step (two bools) into a local, every span site is
+`with (span(...) if tr else _NULLCTX):`, and nothing else runs — no span
+object, no TraceAnnotation, no buffer, no lock, no clock read
+(`ci/run.sh sanity` and tests/unittest/test_trace.py count the calls).
+Live, a span costs two clock reads, one TraceAnnotation and one locked
+append: microseconds. A live span NEVER fences, fetches or waits: device
+time is the device trace's to give, and a fence per traced step would
+serialise the very prefetch/dispatch overlap the trace is there to show.
+Spans armed by a profiler session alone stay in memory; files, the skew
+probe and sampling strides need `mx.trace.enable()` /
+`MXNET_TPU_TRACE=on` / `tools/launch.py --trace-dir`.
 """
 from __future__ import annotations
 
 import atexit
 import json
 import os
+import re
 import sys
 import time
+
+from jax import monitoring as _monitoring
+from jax.profiler import TraceAnnotation as _TraceMe
 
 from . import _locklint
 from . import config as _config
@@ -58,8 +83,9 @@ from . import telemetry as _telemetry
 from . import util as _util
 
 __all__ = [
-    "enable", "disable", "enabled", "reset",
-    "sampled", "record_span", "annotate", "skew_tick",
+    "enable", "disable", "enabled", "live", "reset",
+    "sampled", "span", "record_span", "annotate", "skew_tick",
+    "setup", "note_setup", "note_executable", "scope_map",
     "flush", "trace_path", "spans", "skews", "snapshot",
     "skew_p99_ms", "skew_verdict", "critical_path",
 ]
@@ -102,6 +128,18 @@ def enabled():
     return _enabled
 
 
+_session_live = _TraceMe.is_enabled   # jaxlib TraceMe: a session records
+
+
+def live():
+    """True when spans have somewhere to go: an operator called
+    `enable()`, or a `jax.profiler` session is recording (start the
+    profiler and the program's spans are in the capture). `Server.step`
+    and `ShardedTrainer.step_async` read this once a step into a local;
+    every span site tests that local."""
+    return _enabled or _session_live()
+
+
 def enable(trace_dir=None, rank=None, sample_every=None, skew_every=None):
     """Arm the recorder. Arguments override the `trace_dir` /
     `trace_sample_every` / `trace_skew_every` knobs (read once here — the
@@ -132,7 +170,7 @@ def disable():
 def reset():
     """Drop recorded state (tests and run boundaries). While disabled the
     buffer itself is released, restoring the zero-allocation fast path."""
-    global _buf, _recorded, _dropped
+    global _buf, _recorded, _dropped, _sample_every, _skew_every
     global _skew_failed, _dir, _rank_override, _next_flush_try
     with _lock:
         _next_flush_try = 0.0
@@ -145,8 +183,11 @@ def reset():
         _dropped = 0
         _skew_failed = False
         if not _enabled:
+            # what enable() set goes back to its default, so a later
+            # profiler session alone records every step
             _dir = ""
             _rank_override = None
+            _sample_every, _skew_every = 1, 16
 
 
 def _rank():
@@ -237,14 +278,19 @@ def record_span(name, t0, t1=None, step=None, cat="host", always=False,
     the same stride. Returns True iff the span was recorded. Callers
     gate on the module bool — this function is never reached while
     disabled (ci sanity counts the calls)."""
-    global _recorded, _dropped
-    if not _enabled:
+    global _recorded, _dropped, _buf
+    if not (_enabled or _session_live()):
         return False
     if t1 is None:
         t1 = time.perf_counter()
     with _lock:
         if _buf is None:
-            return False    # disabled+reset raced a recording thread
+            # armed by a profiler session alone: the buffer is made on
+            # first use and keeps the usual bound. disable()+reset()
+            # racing a recording thread leaves it released
+            if not (_enabled or _session_live()):
+                return False
+            _buf = []
         if not always:
             if step is not None:
                 if step % _sample_every:
@@ -274,6 +320,37 @@ def record_span(name, t0, t1=None, step=None, cat="host", always=False,
     if due:
         _safe_flush()
     return True
+
+
+class span:
+    """Context manager for one LIVE span (callers test their step's
+    `live()` local first: `with (span(...) if tr else _NULLCTX):`). It
+    enters a `jax.profiler.TraceAnnotation` of the same name, so the span
+    is in the profiler's capture on the device trace's clock, and on exit
+    appends to the buffer through `record_span`, so a reader in this
+    process finds it in `spans()`. It never waits for the device. What is
+    known only at the end (`admitted`, `tokens`) goes into `.attrs` before
+    the block closes and reaches the buffer alone."""
+    __slots__ = ("name", "cat", "step", "attrs", "keep", "_ann", "_t0")
+
+    def __init__(self, name, cat="host", step=None, **attrs):
+        self.name, self.cat, self.step, self.attrs = name, cat, step, attrs
+        self.keep = True    # False: leave the buffer out (a wait for
+        #                     end-of-stream is not an input stall)
+
+    def __enter__(self):
+        self._ann = _TraceMe(self.name, **self.attrs)
+        self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        t1 = time.perf_counter()
+        self._ann.__exit__(*exc)
+        if self.keep:
+            record_span(self.name, self._t0, t1, step=self.step,
+                        cat=self.cat, **self.attrs)
+        return False
 
 
 def annotate(step):
@@ -552,6 +629,98 @@ def critical_path():
     return {"span": name, "cat": cat, "fraction": round(t / total, 4),
             "total_s": round(t / 1e6, 6), "count": count}
 
+# ---------------------------------------------------------------------------
+# set-up table and device scopes
+# ---------------------------------------------------------------------------
+
+_setup = {"import_s": 0.0, "initialize_s": 0.0, "compile_s": 0.0,
+          "compile_cache_hits": 0, "compile_cache_misses": 0}
+_cache_events = {"/jax/compilation_cache/cache_hits": "compile_cache_hits",
+                 "/jax/compilation_cache/cache_misses":
+                 "compile_cache_misses"}
+_executables = {}         # label -> Compiled, or (jitted, avals); newest
+# `%copy.602 = ... metadata={op_name="jit(step)/.../kv_arena_update/..."`
+_HLO_LINE = re.compile(
+    r'^\s*(?:ROOT\s+)?%?([\w.\-]+) = .*metadata=\{[^}]*op_name="([^"]*)"')
+
+
+def note_setup(key, amount):
+    """Add to one row of the set-up table. Set-up happens before any
+    profiler session, so these are always-on sums of a handful of
+    `perf_counter` differences taken where the work is seconds-scale
+    (package import, `Block.initialize()`, a jit miss): never on a
+    steady step."""
+    _setup[key] += amount
+
+
+def _on_cache_event(event, **_kw):
+    key = _cache_events.get(event)
+    if key is not None:
+        _setup[key] += 1
+
+
+def setup():
+    """{"import_s", "initialize_s", "compile_s", "compile_cache_hits",
+    "compile_cache_misses"}: where the process's set-up went, so far.
+    `import_s` is the package's own `__init__`, start to end (jax and
+    the array layer; submodules load lazily and are not in it)."""
+    out = dict(_setup)
+    out["import_s"] = getattr(sys.modules[__package__], "_import_s", 0.0)
+    return out
+
+
+def _avals_of(args):
+    """The (shape, dtype, sharding) pytree of a jitted call's arguments:
+    it holds no buffer, and it keeps which arguments were committed to
+    their device, so `jit.lower(*avals).compile()` answers from jax's
+    in-memory cache with the executable the call itself built (no second
+    compile)."""
+    import jax
+
+    def aval(x):
+        if not isinstance(x, jax.Array):
+            return x
+        return jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=x.sharding if x.committed else None)
+
+    return jax.tree.map(aval, args)
+
+
+def note_executable(label, compiled, args=None):
+    """Remember a step executable for `scope_map()` under `label` (a
+    label keeps its newest). `compiled` is a `jax.stages.Compiled`; or,
+    with `args`, the jitted function about to be called with them for the
+    first time: the compile sites pass that, BEFORE the call (it donates
+    its state), and only the arguments' avals are kept. Called at compile
+    time only; nothing is lowered, compiled or read here."""
+    _executables[label] = compiled if args is None \
+        else (compiled, _avals_of(args))
+
+
+def scope_map(label=None):
+    """{label: {HLO instruction name: op_name}} from the optimised HLO
+    text of the executables handed to `note_executable` (all labels, or
+    `label` alone). `op_name` is jax's path of named scopes
+    (`jit(step)/.../kv_arena_update/scatter`): a device trace names events
+    by instruction (`copy.602`), and this is the program's own map from
+    there to the `jax.named_scope` the instruction was traced under.
+    Built on demand, after the measurement: reading the text of a large
+    executable takes seconds."""
+    out = {}
+    for lab, compiled in list(_executables.items()):
+        if label is not None and lab != label:
+            continue
+        if isinstance(compiled, tuple):
+            jitted, avals = compiled
+            compiled = jitted.lower(*avals).compile()
+        names = {}
+        for line in compiled.as_text().splitlines():
+            m = _HLO_LINE.match(line)
+            if m:
+                names[m.group(1)] = m.group(2)
+        out[lab] = names
+    return out
+
 
 @atexit.register
 def _flush_at_exit():
@@ -561,6 +730,8 @@ def _flush_at_exit():
         except OSError:
             pass  # nothing useful to do with a write error at interpreter exit
 
+
+_monitoring.register_event_listener(_on_cache_event)
 
 if _config.get("trace") == "on":
     enable()

@@ -18,6 +18,7 @@ import threading
 import time
 import urllib.error
 import urllib.request
+import weakref
 
 import numpy as np
 import pytest
@@ -25,7 +26,7 @@ import pytest
 import mxnet_tpu as mx
 from mxnet_tpu import config, diagnostics, nd, parallel
 from mxnet_tpu import profiler as mxprofiler
-from mxnet_tpu import scope, serve, telemetry
+from mxnet_tpu import scope, serve, telemetry, trace
 from mxnet_tpu.gluon import loss as gloss
 from mxnet_tpu.gluon import nn
 
@@ -44,7 +45,13 @@ def _load_launch():
 
 
 @pytest.fixture(autouse=True)
-def _clean_scope():
+def _clean_scope(monkeypatch):
+    # /statusz and /tracez read the live-server registry and the span
+    # buffer: start each test from an empty one, whatever an earlier test
+    # of this worker left uncollected (a stopped Server stays registered
+    # until it is garbage) or recorded
+    monkeypatch.setattr(serve, "_servers", weakref.WeakSet())
+    trace.reset()
     yield
     scope.reset()
     telemetry.disable()

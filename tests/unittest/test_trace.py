@@ -171,6 +171,9 @@ def test_disabled_fast_path_zero_calls_and_zero_alloc(monkeypatch):
 def test_trainer_and_dataflow_spans(tmp_path):
     config.set("trace_skew_every", 2)
     trace.enable(trace_dir=str(tmp_path), rank=0, sample_every=1)
+    # a trace never fences for itself: `step.fence` is recorded where
+    # another observer (telemetry here) already fenced
+    telemetry.enable()
     tr = _trainer()
     x, y = _xy()
     for d, l in dataflow.prefetch_to_mesh(iter([([x], [y])] * 4), tr,
@@ -188,6 +191,7 @@ def test_trainer_and_dataflow_spans(tmp_path):
     # compile-dominated); warm steps record dispatch + fence pairs
     assert names["step.compile"] == 1
     assert names["step.dispatch"] == 3 and names["step.fence"] == 3
+    assert names["train.step"] == 4
     assert names["input.batch_wait"] == 4
     assert names["input.h2d_stage"] == 4
     steps = sorted({rec["step"] for rec in lines
@@ -227,6 +231,210 @@ def test_block_compile_and_checkpoint_spans(tmp_path):
     assert "checkpoint.save" in names, names
     # nothing ELSE recorded at this sampling stride
     assert "step.dispatch" not in names and "input.batch_wait" not in names
+
+
+# ---------------------------------------------------------------------------
+# live(): the off path, arming by a profiler session, never fencing
+# ---------------------------------------------------------------------------
+
+def _paged_server(slots=2):
+    from mxnet_tpu import serve
+    from mxnet_tpu.models import gpt as gpt_mod
+    parallel.make_mesh(dp=-1)
+    model = gpt_mod.GPTForCausalLM(gpt_mod.gpt_tiny_config())
+    mx.random.seed(0)
+    model.initialize()
+    srv = serve.Server(model, slots=slots, pages="on", page_size=8,
+                       prefill_chunk=8, buckets=[32])
+    rng = np.random.RandomState(0)
+    for n_prompt in (11, 5, 9):
+        srv.submit(rng.randint(0, 128, (n_prompt,)).astype(np.int32),
+                   max_new_tokens=6, eos=None, temperature=0.0)
+    return srv
+
+
+def _count_calls(monkeypatch, *names):
+    calls = dict.fromkeys(names, 0)
+
+    def counting(name, real):
+        def wrapper(*a, **k):
+            calls[name] += 1
+            return real(*a, **k)
+        return wrapper
+
+    for name in names:
+        monkeypatch.setattr(trace, name, counting(name, getattr(trace, name)))
+    return calls
+
+
+@pytest.mark.parametrize("path", ["serve", "train"])
+def test_off_path_is_one_live_call_a_step(monkeypatch, path):
+    """With tracing off and no profiler session a step costs one
+    `live()` and nothing else of mx.trace: no span, no record, no
+    annotation, no TraceAnnotation, no buffer."""
+    assert not trace.live() and trace._buf is None
+    if path == "serve":
+        srv = _paged_server()
+        srv.step()                       # compile outside the count
+
+        def step():
+            srv.step()
+    else:
+        tr = _trainer()
+        x, y = _xy()
+        tr.step_async(x, y)
+
+        def step():
+            tr.step_async(x, y)
+    calls = _count_calls(monkeypatch, "live", "span", "record_span",
+                         "annotate", "skew_tick", "_TraceMe")
+    n = 5
+    for _ in range(n):
+        step()
+    if path == "serve":
+        st = srv.stats()
+        srv.stop()
+        assert st["chunk_steps"] + st["token_steps"] \
+            == st["chunk_dispatches"] == n + 1
+        assert st["chunk_steps"] >= 2 and st["token_steps"] >= 1
+    assert calls == {"live": n, "span": 0, "record_span": 0, "annotate": 0,
+                     "skew_tick": 0, "_TraceMe": 0}
+    assert trace._buf is None and trace.spans() == []
+
+
+def _inside(inner, outer, slack=0.0):
+    return outer[0] - slack <= inner[0] \
+        and inner[0] + inner[1] <= outer[0] + outer[1] + slack
+
+
+def test_profiler_session_arms_the_spans(tmp_path):
+    """`jax.profiler.start_trace` alone (mx.trace never enabled) puts the
+    program's spans into `spans()` and into the .xplane.pb, nested as
+    documented; they stop when the session ends."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+    srv = _paged_server()
+    tr = _trainer()
+    x, y = _xy()
+    srv.step()
+    tr.step_async(x, y)                          # compiles, untraced
+    assert not trace.enabled() and trace.spans() == []
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        assert trace.live()
+        for _ in range(3):
+            srv.step()
+        for d, l in dataflow.prefetch_to_mesh(iter([([x], [y])] * 3), tr,
+                                              depth=2):
+            loss = tr.step_async(d, l)
+        loss.asnumpy()
+    finally:
+        jax.profiler.stop_trace()
+    assert not trace.live()
+    recorded = trace.spans()
+    srv.step()
+    tr.step_async(x, y)
+    srv.stop()
+    assert len(trace.spans()) == len(recorded), "spans after the session"
+    serving = ["serve.step", "serve.schedule", "serve.prepare",
+               "serve.decode_step", "serve.fetch", "serve.stream"]
+    training = ["train.step", "step.dispatch", "input.batch_wait",
+                "input.h2d_stage"]
+    by_name = {}
+    for sp in recorded:
+        by_name.setdefault(sp["name"], []).append(sp)
+    for name in serving + training:
+        assert len(by_name.get(name, ())) == 3, (name, sorted(by_name))
+    assert "step.fence" not in by_name
+    # the buffer's nesting, step by step
+    for k in range(3):
+        iv = {n: (by_name[n][k]["ts_us"], by_name[n][k]["dur_us"])
+              for n in serving + training[:2]}
+        for child in ("serve.schedule", "serve.prepare",
+                      "serve.decode_step", "serve.stream"):
+            assert _inside(iv[child], iv["serve.step"], 0.2), child
+        assert _inside(iv["serve.fetch"], iv["serve.decode_step"], 0.2)
+        assert _inside(iv["step.dispatch"], iv["train.step"], 0.2)
+        assert by_name["serve.step"][k]["step"] \
+            == by_name["serve.fetch"][k]["step"]
+    chunks = [sp["chunk"] for sp in by_name["serve.step"]]
+    assert chunks == [sp["chunk"] for sp in by_name["serve.decode_step"]]
+    assert all(sp["tokens"] >= 0 for sp in by_name["serve.stream"])
+    assert all("admitted" in sp for sp in by_name["serve.schedule"])
+    # the same spans in the profiler's own file, on its clock
+    (path,) = glob.glob(os.path.join(str(tmp_path), "plugins", "profile",
+                                     "*", "*.xplane.pb"))
+    events = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name == "/host:CPU":
+            for line in plane.lines:
+                for e in line.events:
+                    if e.name in serving + training:
+                        events.setdefault(e.name, []).append(
+                            (e.start_ns, e.duration_ns))
+    # (the wait for the end of the stream is in the file too; the buffer
+    # leaves it out, as it is no input stall)
+    assert len(events.pop("input.batch_wait")) == 4
+    for name in set(serving + training) - {"input.batch_wait"}:
+        assert len(events.get(name, ())) == 3, (name, sorted(events))
+    for k in range(3):
+        iv = {n: sorted(events[n])[k] for n in serving + training[:2]}
+        for child in ("serve.schedule", "serve.prepare",
+                      "serve.decode_step", "serve.stream"):
+            assert _inside(iv[child], iv["serve.step"]), child
+        assert _inside(iv["serve.fetch"], iv["serve.decode_step"])
+        assert _inside(iv["step.dispatch"], iv["train.step"])
+
+
+def test_a_live_span_does_not_fence(monkeypatch):
+    """mx.trace on, every fencing observer off: `step_async` waits for
+    nothing and records no `step.fence`."""
+    import jax
+    from mxnet_tpu.parallel import trainer as trainer_mod
+    trace.enable(sample_every=1)
+    tr = _trainer()
+    x, y = _xy()
+    tr.step_async(x, y)
+    waits = []
+    real = jax.block_until_ready
+    monkeypatch.setattr(trainer_mod.jax, "block_until_ready",
+                        lambda v: (waits.append(1), real(v))[1])
+    for _ in range(3):
+        tr.step_async(x, y)
+    monkeypatch.undo()
+    names = [sp["name"] for sp in trace.spans()]
+    assert waits == [] and "step.fence" not in names
+    assert names.count("train.step") == 4
+    assert names.count("step.dispatch") == 3
+    assert names.count("step.compile") == 1
+
+
+def test_setup_table_and_scope_map():
+    """The always-on set-up sums, and the program's map from HLO
+    instruction names to its named scopes, built on demand."""
+    before = trace.setup()
+    assert set(before) == {"import_s", "initialize_s", "compile_s",
+                           "compile_cache_hits", "compile_cache_misses"}
+    srv = _paged_server()
+    srv.step()
+    tr = _trainer()
+    x, y = _xy()
+    tr.step_async(x, y)
+    srv.stop()
+    after = trace.setup()
+    assert after["compile_s"] > before["compile_s"]
+    assert after["initialize_s"] > before["initialize_s"]
+    assert after["import_s"] == before["import_s"] > 0
+    label = "serve.paged/bucket=32/chunk=8"
+    ops = trace.scope_map(label)[label]
+    for scope in ("kv_arena_update", "page_gather", "lm_head"):
+        assert any(f"/{scope}/" in op for op in ops.values()), scope
+    ops = trace.scope_map("train.step")["train.step"]
+    joined = " ".join(ops.values())
+    assert "jvp(forward)" in joined and "optimizer" in joined
+    assert "transpose(jvp(forward))" in joined
 
 
 def test_skew_cadence_is_step_keyed():
