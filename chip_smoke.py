@@ -324,9 +324,10 @@ def serve_one_chip():
         say(f"bucket {bucket}: Pallas kernels in the lowered chunk step: "
             f"{found or 'none'}")
         if not REHEARSAL:
-            require(found.get("paged_attention", 0) >= 1,
-                    f"paged_attention missing from the bucket-{bucket} "
-                    f"step: {found}")
+            for kernel in ("paged_attention", "kv_page_write"):
+                require(found.get(kernel, 0) >= 1,
+                        f"{kernel} missing from the bucket-{bucket} "
+                        f"step: {found}")
 
     rng = np.random.RandomState(0)
     for wave in ("first wave (compiles included)", "second wave (warm)"):
@@ -367,6 +368,8 @@ def kernels():
             "draw the TPU PRNG)")
         tpu_validate.paged_parity(B=2, H=4, D=16, page_size=8, n_pg=4,
                                   dtype=jnp.float32, expect_kernel=False)
+        tpu_validate.kv_write_parity(B=4, H=4, D=16, page_size=8, n_pages=24,
+                                     dtype=jnp.float32, expect_kernel=False)
         tpu_validate.lamb_parity([(64, 64)] * 4 + [(64,)],
                                  expect_kernel=False)
         tpu_validate.int8_parity(M=8, K=128, O=256)
@@ -376,6 +379,8 @@ def kernels():
         tpu_validate.flash_dropout_oracle()
         # GPT-2 345M's decode shapes: 16 heads of 64, page 16, a 512 bucket
         tpu_validate.paged_parity(B=8, H=16, D=64, page_size=16, n_pg=32)
+        # the benchmark's serving cell: 32 slots over a pool of 2,080 pages
+        tpu_validate.kv_write_parity()
         # BERT-base's own parameter layout (110M)
         cfg = bert_case()[0]
         model = bert_mod.BERTForPretraining(cfg)

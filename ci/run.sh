@@ -842,12 +842,12 @@ print('pages shared-prefix smoke OK: bit-identical, hit_rate=%.2f,'
       ' %d dispatches for %d prompt tokens' %
       (st['prefix_hit_rate'], st['chunk_dispatches'], st['prompt_tokens']))
 "
-    # the paged-attention kernel: interpret-mode parity against the
-    # XLA reference (the only way the kernel CODE runs off-TPU) plus
-    # the kernels=off jaxpr-identity contract
+    # the paged-attention and arena-write kernels: interpret-mode parity
+    # against the XLA references (the only way the kernel CODE runs
+    # off-TPU) plus the kernels=off jaxpr-identity contract
     JAX_PLATFORMS=cpu python -m pytest \
         tests/unittest/test_kernels.py -q -p no:cacheprovider \
-        -k "paged_attention"
+        -k "paged_attention or kv_page_write or arena_head_dim or paged_server"
     # the speculative-decoding exactness gate (slow-marked out of the
     # tier-1 sweep for its ~13s drafter drive; covered here every pass)
     JAX_PLATFORMS=cpu python -m pytest \

@@ -92,29 +92,33 @@ def paged_attention_step(q, k_new, v_new, k_pages, v_pages, tables, wp, wo,
     score/softmax/PV expression at the gathered (B,H,L,D) shapes — the
     pages=on bit-identity guarantee composes from there.
 
-    The scatter targets (wp[b], wo[b]) are distinct by construction:
+    The write is `pallas_ops.kv_page_write`: where the paged kernels run
+    (one device) it replaces row wo[b] of page wp[b] in place, in the
+    row-major layout the attention kernel reads and — the pool's head
+    dimension being padded to the lane width there, `arena_head_dim` —
+    the arenas rest in, so no arena is copied or re-laid around it;
+    elsewhere it is the `.at[wp, :, wo, :].set` scatter, the pre-kernel
+    expression. Either way the targets are distinct by construction:
     every serve slot owns its write page exclusively (masked-out rows
-    write their private scratch page), so `.at[].set` never sees
-    duplicate indices.
+    write their private scratch page), so no two rows name one page.
 
-    q/k_new/v_new (B,H,1,D); k_pages/v_pages (P,H,ps,D); tables
-    (B,n_pg) int32; wp/wo/t (B,) traced int. Returns
+    q/k_new/v_new (B,H,1,D); k_pages/v_pages (P,H,ps,Dp), Dp >= D (the
+    lanes past D hold zeros); tables (B,n_pg) int32; wp/wo/t (B,) traced
+    int. Returns
     (out (B,1,H*D), new_k_pages, new_v_pages)."""
     import jax
     import jax.numpy as jnp
 
     from ..ndarray import apply_op
+    from ..pallas_ops import kv_page_write as _kv_write
     from ..pallas_ops import paged_attention as _paged_attn
 
     def f(q_, kn, vn, kp, vp, tb, wp_, wo_, tt):
-        # named scopes: the device trace knows the arena's copies by
+        # named scopes: the device trace knows an XLA operation by its
         # instruction number only; these names survive a refactor
         # (mx.trace.scope_map reads them back from the executable)
         with jax.named_scope("kv_arena_update"):
-            wpi = wp_.astype(jnp.int32)
-            woi = wo_.astype(jnp.int32)
-            kp = kp.at[wpi, :, woi, :].set(kn[:, :, 0, :].astype(kp.dtype))
-            vp = vp.at[wpi, :, woi, :].set(vn[:, :, 0, :].astype(vp.dtype))
+            kp, vp = _kv_write(kp, vp, kn, vn, wp_, wo_)
         B, H, _, D = q_.shape
         with jax.named_scope("page_gather"):
             o = _paged_attn(q_, kp, vp, tb.astype(jnp.int32),

@@ -109,8 +109,11 @@ class PagePool:
     `streams` maps a tag ('target', and 'draft' when a speculative
     drafter is attached) to a list of (heads, head_dim, dtype) specs —
     one per pooled array (2 * n_layers: K then V). Every array is
-    allocated as (pages, heads, page_size, head_dim) zeros; page id p
-    is physical row p in all of them.
+    allocated as (pages, heads, page_size, head_dim) zeros — the last
+    dimension padded to the lane width where the paged kernels run
+    (`pallas_ops.kv_page_write.arena_head_dim`), so that the arenas'
+    default layout is the one the kernels read; page id p is physical
+    row p in all of them.
 
     Page-table metadata (refcounts, free list) lives host-side and is
     guarded by the owning Server's lock; the device arrays in
@@ -130,9 +133,11 @@ class PagePool:
         self.state = {}
         self._specs = {tag: list(specs) for tag, specs in streams.items()}
         import jax.numpy as jnp
+        from .pallas_ops.kv_page_write import arena_head_dim
         for tag, specs in self._specs.items():
             self.state[tag] = [
-                jnp.zeros((self.num_pages, h, self.page_size, d), dt)
+                jnp.zeros((self.num_pages, h, self.page_size,
+                           arena_head_dim(d)), dt)
                 for (h, d, dt) in specs]
         self.stats = {"allocs": 0, "frees": 0, "cow_copies": 0,
                       "peak_used": 0}

@@ -18,6 +18,11 @@ generic lowering loses (the TVM/Relay argument, PAPERS.md 1802.04799):
                             an mx.pages block table (the paged serve
                             path), scalar-prefetch indexed so the dense
                             gathered operand never hits HBM
+  * `kv_page_write`       — that step's write of the new keys and values
+                            into the page arenas, in place and in the
+                            layout `paged_attention` reads (and
+                            `arena_head_dim`, the lane-width last
+                            dimension the arenas rest row-major in)
 
 Every kernel sits behind the `kernels=off|auto|on` knob with a bit-exact
 XLA-native fallback (see `pallas_ops/_common.py`), ships an
@@ -40,9 +45,11 @@ from . import moe_kernels
 # importlib.import_module (see tests/unittest/test_flash_interpret.py)
 from .flash_attention import flash_attention, mha_reference
 from .int8_matmul import int8_matmul, int8_matmul_reference
+from .kv_page_write import kv_page_write, kv_page_write_reference
 from .paged_attention import paged_attention, paged_attention_reference
 
 __all__ = ["flash_attention", "mha_reference", "int8_matmul",
            "int8_matmul_reference", "paged_attention",
-           "paged_attention_reference", "fused_update", "moe_kernels",
+           "paged_attention_reference", "kv_page_write",
+           "kv_page_write_reference", "fused_update", "moe_kernels",
            "_common"]

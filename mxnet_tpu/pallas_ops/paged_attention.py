@@ -37,8 +37,9 @@ _NEG = -1e30
 def paged_attention_reference(q, k_pages, v_pages, tables, t):
     """Pure-XLA paged decode attention (the pre-kernel lowering).
 
-    q (B,H,1,D); k_pages/v_pages (P,H,ps,D); tables (B,n_pg) int32 page
-    ids; t (B,) traced int positions. Returns (B,H,1,D) in q.dtype.
+    q (B,H,1,D); k_pages/v_pages (P,H,ps,Dp), Dp >= D (lanes past D are
+    ignored); tables (B,n_pg) int32 page ids; t (B,) traced int
+    positions. Returns (B,H,1,D) in q.dtype.
 
     Gathers the pages into the dense (B,H,L,D) layout (L = n_pg*ps) and
     then runs VERBATIM the masked f32 score/softmax/PV expression of the
@@ -46,6 +47,9 @@ def paged_attention_reference(q, k_pages, v_pages, tables, t):
     reductions, so a paged cache whose tables enumerate a sequence's
     pages in order produces bit-identical logits to the dense cache."""
     ti = t.astype(jnp.int32)
+    if k_pages.shape[3] != q.shape[3]:      # lane-padded arenas
+        k_pages = k_pages[..., :q.shape[3]]
+        v_pages = v_pages[..., :q.shape[3]]
     kc = k_pages[tables]                         # (B, n_pg, H, ps, D)
     B, n_pg, H, ps, D = kc.shape
     kc = kc.transpose(0, 2, 1, 3, 4).reshape(B, H, n_pg * ps, D)
@@ -118,9 +122,12 @@ def _kernel(tb_ref, t_ref, q_ref, k_ref, v_ref, o_ref, m_s, l_s, acc_s, *,
 
 def _paged_attention_pallas(q, k_pages, v_pages, tables, t):
     B, H, _, D = q.shape
-    ps = k_pages.shape[2]
+    ps, Dp = k_pages.shape[2:]
     n_pg = tables.shape[1]
-    q2 = q.reshape(B, H, D)
+    # arenas wider than the heads (kv_page_write.arena_head_dim) hold
+    # zeros past D: zeros in q's lanes there leave every score as it is,
+    # and the output's lanes there are dropped
+    q2 = jnp.pad(q.reshape(B, H, D), ((0, 0), (0, 0), (0, Dp - D)))
     out = pl.pallas_call(
         functools.partial(_kernel, page_size=ps,
                           sm_scale=1.0 / (D ** 0.5)),
@@ -128,28 +135,28 @@ def _paged_attention_pallas(q, k_pages, v_pages, tables, t):
             num_scalar_prefetch=2,
             grid=(B, n_pg),
             in_specs=[
-                pl.BlockSpec((1, H, D),
+                pl.BlockSpec((1, H, Dp),
                              lambda b, j, tb, tt: (b, 0, 0)),
-                pl.BlockSpec((1, H, ps, D),
+                pl.BlockSpec((1, H, ps, Dp),
                              lambda b, j, tb, tt: (tb[b, j], 0, 0, 0)),
-                pl.BlockSpec((1, H, ps, D),
+                pl.BlockSpec((1, H, ps, Dp),
                              lambda b, j, tb, tt: (tb[b, j], 0, 0, 0)),
             ],
-            out_specs=pl.BlockSpec((1, H, D),
+            out_specs=pl.BlockSpec((1, H, Dp),
                                    lambda b, j, tb, tt: (b, 0, 0)),
             scratch_shapes=[
                 pltpu.VMEM((H, 128), jnp.float32),       # running max
                 pltpu.VMEM((H, 128), jnp.float32),       # denominator
-                pltpu.VMEM((H, D), jnp.float32),         # value acc
+                pltpu.VMEM((H, Dp), jnp.float32),        # value acc
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((B, H, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, H, Dp), q.dtype),
         compiler_params=_common.compiler_params(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=_common.interpret(),
         name="paged_attention",
     )(tables.astype(jnp.int32), t.astype(jnp.int32), q2, k_pages, v_pages)
-    return out.reshape(B, H, 1, D)
+    return out[:, :, :D].reshape(B, H, 1, D)
 
 
 # --------------------------------------------------------------------------
@@ -161,8 +168,8 @@ def paged_attention(q, k_pages, v_pages, tables, t):
 
     Args:
       q: (B, H, 1, D) queries (model dtype).
-      k_pages, v_pages: (P, H, page_size, D) pooled KV pages (cache
-        dtype) — page id p is physical row p.
+      k_pages, v_pages: (P, H, page_size, Dp) pooled KV pages (cache
+        dtype), Dp >= D with zeros past D — page id p is physical row p.
       tables: (B, n_pg) int32 page ids; row b's logical position range
         [0, n_pg*page_size) maps page-major onto its table entries.
       t: (B,) traced int — row b attends positions <= t[b].

@@ -26,6 +26,7 @@ im = importlib.import_module("mxnet_tpu.pallas_ops.int8_matmul")
 fu = importlib.import_module("mxnet_tpu.pallas_ops.fused_update")
 mk = importlib.import_module("mxnet_tpu.pallas_ops.moe_kernels")
 pa = importlib.import_module("mxnet_tpu.pallas_ops.paged_attention")
+kw = importlib.import_module("mxnet_tpu.pallas_ops.kv_page_write")
 _common = importlib.import_module("mxnet_tpu.pallas_ops._common")
 
 
@@ -337,6 +338,27 @@ def test_moe_ffn_kernel_path_matches_einsum_path():
 # paged attention
 # --------------------------------------------------------------------------
 
+@pytest.fixture
+def one_device():
+    """A one-device mesh: the two paged kernels are global-view calls and
+    stay off while the step spans the harness's eight virtual devices."""
+    from mxnet_tpu.parallel import mesh as mesh_mod
+    before = mesh_mod._current["mesh"]
+    mesh_mod.make_mesh(devices=jax.devices()[:1])
+    yield
+    mesh_mod.set_mesh(before)
+
+
+def _jaxpr(fn, *args):
+    # a fresh callable each time: jax caches a traced function by
+    # identity, and the kernel gate is read while tracing
+    return str(jax.make_jaxpr(lambda *a: fn(*a))(*args))
+
+
+def _holds_kernel(fn, *args):
+    return "pallas_call" in _jaxpr(fn, *args)
+
+
 def _paged_case(B=3, H=4, D=16, ps=8, n_pg=4, P=20, dtype=np.float32,
                 seed=0):
     rng = np.random.RandomState(seed)
@@ -348,15 +370,16 @@ def _paged_case(B=3, H=4, D=16, ps=8, n_pg=4, P=20, dtype=np.float32,
     return q, kp, vp, tables, t
 
 
-def test_paged_attention_interpret_parity():
+def test_paged_attention_interpret_parity(one_device):
     q, kp, vp, tables, t = _paged_case()
+    assert _holds_kernel(pa.paged_attention, q, kp, vp, tables, t)
     got = pa.paged_attention(q, kp, vp, tables, t)
     ref = pa.paged_attention_reference(q, kp, vp, tables, t)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                rtol=2e-6, atol=2e-6)
 
 
-def test_paged_attention_parity_bf16():
+def test_paged_attention_parity_bf16(one_device):
     q, kp, vp, tables, t = _paged_case(dtype=np.float32)
     q, kp, vp = (a.astype(jnp.bfloat16) for a in (q, kp, vp))
     got = pa.paged_attention(q, kp, vp, tables, t)
@@ -367,13 +390,181 @@ def test_paged_attention_parity_bf16():
                                rtol=2e-2, atol=2e-2)
 
 
-def test_paged_attention_kernels_off_is_reference_path():
+def test_paged_attention_kernels_off_is_reference_path(one_device):
     config.set("kernels", "off")
+    case = _paged_case()
+    assert _jaxpr(pa.paged_attention, *case) \
+        == _jaxpr(pa.paged_attention_reference, *case)
+
+
+# --------------------------------------------------------------------------
+# the arena write of the paged step
+# --------------------------------------------------------------------------
+
+def _write_case(dtype, P=24, H=4, D=16, ps=8, n_pg=3, seed=0, Dp=None):
+    """Arenas (of last dimension Dp >= D, zeros past D), new vectors, and
+    the targets `_paged_write_targets` makes for five rows: an active row
+    mid-page, one at offset 0, one at offset ps-1, a masked row and a
+    position past the table's range (both to the row's scratch page,
+    offset 0)."""
+    from mxnet_tpu.models.gpt import GPTForCausalLM
+    rng = np.random.RandomState(seed)
+    B = 5
+    pad = ((0, 0),) * 3 + ((0, (Dp or D) - D),)
+    kp = jnp.pad(jnp.asarray(rng.randn(P, H, ps, D), dtype), pad)
+    vp = jnp.pad(jnp.asarray(rng.randn(P, H, ps, D), dtype), pad)
+    kn = jnp.asarray(rng.randn(B, H, 1, D), jnp.float32)
+    vn = jnp.asarray(rng.randn(B, H, 1, D), jnp.float32)
+    tables = jnp.asarray(B + rng.permutation(P - B)[:B * n_pg]
+                         .reshape(B, n_pg), jnp.int32)
+    pos = jnp.asarray([ps + 3, 2 * ps, ps - 1, 5, n_pg * ps + 1], jnp.int32)
+    active = jnp.asarray([True, True, True, False, True])
+    wp, wo = GPTForCausalLM._paged_write_targets(None, pos, active, tables,
+                                                 ps)
+    assert wo.tolist() == [3, 0, ps - 1, 0, 0]
+    assert wp.tolist()[3:] == [3, 4]
+    return kp, vp, kn, vn, wp, wo
+
+
+@pytest.mark.parametrize("Dp", [None, 128], ids=["head_dim", "lane_width"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_kv_page_write_bit_equal_to_scatter(one_device, dtype, Dp):
+    case = _write_case(dtype, Dp=Dp)
+    assert _holds_kernel(kw.kv_page_write, *case)
+    got = kw.kv_page_write(*case)
+    ref = kw.kv_page_write_reference(*case)
+    for g, r, before in zip(got, ref, case[:2]):
+        assert g.dtype == dtype
+        assert np.array_equal(np.asarray(g, np.float32),
+                              np.asarray(r, np.float32))
+        # pages no row names are as they were
+        idle = np.setdiff1d(np.arange(before.shape[0]), np.asarray(case[4]))
+        assert np.array_equal(np.asarray(g, np.float32)[idle],
+                              np.asarray(before, np.float32)[idle])
+
+
+def test_kv_page_write_under_jit_with_donation(one_device):
+    """The served form: arenas donated to a jitted step and threaded."""
+    kp, vp, kn, vn, wp, wo = _write_case(jnp.bfloat16, seed=1)
+    ref = kw.kv_page_write_reference(kp, vp, kn, vn, wp, wo)
+    got = jax.jit(kw.kv_page_write, donate_argnums=(0, 1))(
+        kp + 0, vp + 0, kn, vn, wp, wo)
+    for g, r in zip(got, ref):
+        assert np.array_equal(np.asarray(g, np.float32),
+                              np.asarray(r, np.float32))
+
+
+def test_kv_page_write_kernels_off_is_reference_path(one_device):
+    """kernels=off: the write is the scatter, and the whole
+    paged_attention_step holds no kernel — the pre-kernel program."""
+    from mxnet_tpu import nd
+    from mxnet_tpu.models._decode import paged_attention_step
+    case = _write_case(jnp.float32)
     q, kp, vp, tables, t = _paged_case()
-    j1 = jax.make_jaxpr(pa.paged_attention)(q, kp, vp, tables, t)
-    j2 = jax.make_jaxpr(pa.paged_attention_reference)(q, kp, vp,
-                                                      tables, t)
-    assert str(j1) == str(j2)
+    B = q.shape[0]
+    step_args = [nd.array(np.asarray(a)) for a in
+                 (q, q, q, kp, vp, tables, jnp.arange(B), jnp.zeros(B), t)]
+
+    def step(*a):
+        return [o._data for o in paged_attention_step(
+            *[nd.NDArray(x) for x in a])]
+
+    raw = [a._data for a in step_args]
+    assert _holds_kernel(step, *raw)
+    config.set("kernels", "off")
+    assert _jaxpr(kw.kv_page_write, *case) \
+        == _jaxpr(kw.kv_page_write_reference, *case)
+    text = _jaxpr(step, *raw)
+    assert "pallas_call" not in text and text.count(" = scatter[") == 2
+
+
+def test_kv_page_write_off_a_single_device_is_reference_path():
+    """On a step that spans devices (the harness's eight, no mesh) the
+    gate keeps the scatter, as it keeps `paged_attention` off, and the
+    pool keeps the heads' own width."""
+    assert _common.multi_device()
+    assert not _holds_kernel(kw.kv_page_write, *_write_case(jnp.float32))
+    assert kw.arena_head_dim(64) == 64
+
+
+def test_arena_head_dim_follows_the_kernel_gate(one_device, monkeypatch):
+    """The lane width where the kernels run; the heads' own elsewhere: a
+    CPU without the interpreter, kernels=off, a step across devices. The
+    pool allocates by it."""
+    from mxnet_tpu import pages
+    assert [kw.arena_head_dim(d) for d in (16, 64, 128, 160)] \
+        == [128, 128, 128, 256]
+    specs = {"target": [(2, 8, np.float32)] * 2}
+    assert pages.PagePool(4, 8, 2, specs).state["target"][0].shape \
+        == (10, 2, 4, 128)
+    config.set("kernels", "off")
+    assert kw.arena_head_dim(64) == 64
+    assert pages.PagePool(4, 8, 2, specs).state["target"][0].shape \
+        == (10, 2, 4, 8)
+    config.set("kernels", "auto")
+    monkeypatch.setattr(_common, "multi_device", lambda: True)
+    assert kw.arena_head_dim(64) == 64
+    monkeypatch.setattr(_common, "multi_device", lambda: False)
+    monkeypatch.delenv("MXNET_TPU_PALLAS_INTERPRET")
+    assert kw.arena_head_dim(64) == 64
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-6),
+                                       (jnp.bfloat16, 2e-2)])
+def test_paged_attention_over_lane_padded_arenas(one_device, dtype, tol):
+    """Arenas wider than the heads (zeros past D): the kernel and the
+    reference both give what the unpadded arenas give."""
+    q, kp, vp, tables, t = _paged_case()
+    q, kp, vp = (a.astype(dtype) for a in (q, kp, vp))
+    pad = ((0, 0),) * 3 + ((0, 128 - kp.shape[3]),)
+    wide = (q, jnp.pad(kp, pad), jnp.pad(vp, pad), tables, t)
+    assert _holds_kernel(pa.paged_attention, *wide)
+    ref = pa.paged_attention_reference(q, kp, vp, tables, t)
+    assert np.array_equal(
+        np.asarray(pa.paged_attention_reference(*wide), np.float32),
+        np.asarray(ref, np.float32))
+    got = pa.paged_attention(*wide)
+    assert got.shape == ref.shape and got.dtype == dtype
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(ref, np.float32),
+                               rtol=tol, atol=tol)
+
+
+def test_paged_server_tokens_equal_on_kernel_and_scatter_write(
+        one_device, monkeypatch):
+    """Greedy tokens of a paged server whose steps write through the
+    kernel equal those of the same server writing through the scatter
+    (the attention kernel and lane-padded arenas on both sides): the
+    write is bit-exact, so pages=on stays bit-identical to pages=off."""
+    import mxnet_tpu as mx
+    from mxnet_tpu import pages, pallas_ops, serve
+    from mxnet_tpu.models import gpt as gpt_mod
+
+    model = gpt_mod.GPTForCausalLM(gpt_mod.gpt_tiny_config())
+    mx.random.seed(0)
+    model.initialize()
+    rng = np.random.RandomState(5)
+    prompts = [rng.randint(0, 128, (n,)).astype(np.int32)
+               for n in (5, 9, 14, 17)]
+
+    def tokens():
+        srv = serve.Server(model, slots=4, pages="on", page_size=4,
+                           prefill_chunk=4)
+        reqs = [srv.submit(p, max_new_tokens=8) for p in prompts]
+        srv.drain()
+        srv.stop()
+        assert all(r.verdict == "200 ok" for r in reqs)
+        return [list(r.tokens) for r in reqs]
+
+    try:
+        on_kernel = tokens()
+        monkeypatch.setattr(pallas_ops, "kv_page_write",
+                            pallas_ops.kv_page_write_reference)
+        on_scatter = tokens()
+    finally:
+        serve.disable()
+        pages.disable()
+    assert on_kernel == on_scatter
 
 
 def test_paged_attention_reference_matches_dense_gather():

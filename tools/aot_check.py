@@ -12,7 +12,7 @@ HBM); what it cannot catch is anything that only shows when the code RUNS
 — numerics, hangs, real memory — and that is the chip's word.
 
     JAX_PLATFORMS=cpu python tools/aot_check.py            # everything
-    JAX_PLATFORMS=cpu python tools/aot_check.py kernels    # or: train
+    JAX_PLATFORMS=cpu python tools/aot_check.py kernels    # or: train, serve
 
 Arguments are `jax.ShapeDtypeStruct`s whose shardings name the topology's
 devices; the two places the package asks JAX what it runs on (the kernel
@@ -21,7 +21,9 @@ gate and the PRNG choice) are told "tpu" here, and the trainer's
 placed on a device that is only a description. Exits non-zero on the
 first refusal.
 """
+import json
 import os
+import re
 import sys
 import time
 
@@ -34,8 +36,9 @@ from jax.experimental import topologies  # noqa: E402
 from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 
 import mxnet_tpu as mx  # noqa: E402
-from mxnet_tpu import config, nd, parallel  # noqa: E402
+from mxnet_tpu import config, nd, parallel, serve  # noqa: E402
 from mxnet_tpu.models import bert as bert_mod  # noqa: E402
+from mxnet_tpu.models import gpt as gpt_mod  # noqa: E402
 from mxnet_tpu.pallas_ops import _common  # noqa: E402
 from mxnet_tpu import pallas_ops  # noqa: E402
 from tools.tpu_validate import pallas_kernels  # noqa: E402
@@ -87,6 +90,21 @@ def check_kernels(devices):
              pallas_ops.paged_attention,
              sds((B, H, 1, D), bf, s1), sds((n_pages, H, ps, D), bf, s1),
              sds((n_pages, H, ps, D), bf, s1), sds((B, n_pg), i32, s1),
+             sds((B,), i32, s1))
+
+    # the write beside it, at the benchmark cell's 32 slots and 2,080
+    # pages, arenas at the lane width as the pool allocates them
+    B, n_pages, Dp = 32, 2080, 128
+    compile_("kv_page_write B32 H16 D64->128 page16 pool2080",
+             pallas_ops.kv_page_write,
+             sds((n_pages, H, ps, Dp), bf, s1),
+             sds((n_pages, H, ps, Dp), bf, s1),
+             sds((B, H, 1, D), bf, s1), sds((B, H, 1, D), bf, s1),
+             sds((B,), i32, s1), sds((B,), i32, s1))
+    compile_("paged_attention B32 H16 D64->128 page16 n_pg16",
+             pallas_ops.paged_attention,
+             sds((B, H, 1, D), bf, s1), sds((n_pages, H, ps, Dp), bf, s1),
+             sds((n_pages, H, ps, Dp), bf, s1), sds((B, 16), i32, s1),
              sds((B,), i32, s1))
 
     # fused LAMB at BERT-base's flat size (110M f32), fused Adam f32 + bf16
@@ -170,8 +188,65 @@ def check_train(devices):
         jax.device_put, mx.random.get_state = real_put, real_state
 
 
+def check_serve(devices):
+    """The benchmark's serving cell (chipbench/configs/gpt2-medium-serve.json:
+    GPT-2 medium, 32 slots over a pool of 2,080 pages of 16): the bucket-256
+    chunk-8 and chunk-1 step executables as the server builds them. Beyond
+    compiling, each must keep the arenas where they are: both paged kernels
+    in all 24 layers, every arena parameter aliased to an output, and no
+    `copy` of an arena-shaped operand left anywhere — scan body, entry or
+    exit. That is the counter that says the in-place write engaged."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "chipbench", "configs",
+                           "gpt2-medium-serve.json")) as f:
+        cell = json.load(f)
+    model_cfg = dict(cell["model"])
+    model_cfg.pop("family")
+    cfg = gpt_mod.gpt2_117m_config(**model_cfg)
+    mesh = parallel.make_mesh(devices=devices[:1])
+    s1 = NamedSharding(mesh, P())
+    model = gpt_mod.GPTForCausalLM(cfg)
+    mx.random.seed(0)
+    model.initialize()
+    n_arenas = 2 * cfg["num_layers"]
+    bucket = min(cell["server"]["buckets"])
+
+    for chunk in (8, 1):
+        srv = serve.Server(model, prefill_chunk=chunk, **cell["server"])
+        run, avals = srv._bucket_step(bucket)
+        arena = avals[-1][0]
+        avals = jax.tree.map(lambda a: sds(a.shape, a.dtype, s1), avals)
+        t0 = time.perf_counter()
+        lowered = run.lower(*avals)
+        found = pallas_kernels(lowered)
+        compiled = lowered.compile()
+        mem = compiled.memory_analysis()
+        srv.stop()
+        text = compiled.as_text()
+        shape = "[%s]" % ",".join(map(str, arena.shape))
+        copies = [line.strip()[:160] for line in text.splitlines()
+                  if re.search(r" = \w+%s\{[^}]*\} copy\(" % re.escape(shape),
+                               line)]
+        aliased = re.findall(r"\{\d+\}: \(\d+, \{\}, (?:may|must)-alias\)",
+                             text.split("\n", 1)[0])
+        print(f"  compiles: GPT-2 medium paged step, bucket {bucket} "
+              f"chunk {chunk}  [{time.perf_counter() - t0:.1f}s]  "
+              f"kernels={found}  copies of {shape}: {len(copies)}  "
+              f"aliased parameters: {len(aliased)}  temp="
+              f"{mem.temp_size_in_bytes / 2**30:.2f} GiB args="
+              f"{mem.argument_size_in_bytes / 2**30:.2f} GiB", flush=True)
+        want = {"kv_page_write": cfg["num_layers"],
+                "paged_attention": cfg["num_layers"]}
+        assert found == want, f"expected {want} in the step, got {found}"
+        assert not copies, \
+            f"{len(copies)} arena-shaped copies left, the first: " \
+            f"{copies[0]}"
+        assert len(aliased) == n_arenas, \
+            f"{len(aliased)} of {n_arenas} arenas aliased to outputs"
+
+
 def main():
-    which = sys.argv[1:] or ["kernels", "train"]
+    which = sys.argv[1:] or ["kernels", "train", "serve"]
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name=TOPOLOGY)
     devices = list(topo.devices)
@@ -185,7 +260,8 @@ def main():
                  "compiles nothing for the TPU")
     for name in which:
         print(f"== {name} ==")
-        {"kernels": check_kernels, "train": check_train}[name](devices)
+        {"kernels": check_kernels, "train": check_train,
+         "serve": check_serve}[name](devices)
     print("aot_check: everything compiled for", devices[0].device_kind)
 
 

@@ -14,8 +14,10 @@ draw the TPU PRNG at all).
      proves the forward, dq, and dkv kernels regenerate bit-identical masks
      AND that the dropout backward math is right.
   4. paged decode attention vs paged_attention_reference
-  5. the fused-LAMB kernel passes vs the same step with kernels=off
-  6. int8 matmul vs int8_matmul_reference
+  5. the in-place write of new keys and values into the page arenas vs the
+     scatter it replaces
+  6. the fused-LAMB kernel passes vs the same step with kernels=off
+  7. int8 matmul vs int8_matmul_reference
 
 Each function raises AssertionError on the first check that fails (the
 smoke catches nothing) and prints one `agrees ...` line per check that
@@ -32,7 +34,9 @@ import jax.numpy as jnp
 from mxnet_tpu import config
 from mxnet_tpu.pallas_ops import (flash_attention, mha_reference,
                                   paged_attention, paged_attention_reference,
+                                  kv_page_write, kv_page_write_reference,
                                   int8_matmul, int8_matmul_reference)
+from mxnet_tpu.pallas_ops.kv_page_write import arena_head_dim
 
 
 def check(name, ok, detail=""):
@@ -187,6 +191,58 @@ def paged_parity(B=8, H=16, D=64, page_size=16, n_pg=32,
     err = rel_err(got, ref)
     check(f"paged attention B{B} H{H} D{D} page{page_size} n_pg{n_pg}",
           err <= tol, f"rel={err:.2e} tol={tol:.1e}")
+
+
+def kv_write_parity(B=32, H=16, D=64, page_size=16, n_pages=2080,
+                    dtype=jnp.bfloat16, expect_kernel=True):
+    """The arena write of a paged decode step as the server runs it —
+    arenas of the width the pool allocates (`arena_head_dim`), donated —
+    against the scatter on the same pools: every element of both arenas
+    equal, bit for bit, and the paged-attention kernel reading the written
+    arenas against its reference. Rows as `_paged_write_targets` makes
+    them: every fourth row masked (its scratch page b, offset 0), the
+    others on pages of their own at offsets that cover 0 and
+    page_size-1."""
+    rng = np.random.RandomState(7)
+    Dp = arena_head_dim(D)
+    pad = ((0, 0),) * 3 + ((0, Dp - D),)
+    k_pg = jnp.pad(jnp.asarray(rng.randn(n_pages, H, page_size, D), dtype),
+                   pad)
+    v_pg = jnp.pad(jnp.asarray(rng.randn(n_pages, H, page_size, D), dtype),
+                   pad)
+    k_new = jnp.asarray(rng.randn(B, H, 1, D), dtype)
+    v_new = jnp.asarray(rng.randn(B, H, 1, D), dtype)
+    rows = np.arange(B)
+    masked = rows % 4 == 3
+    real = B + rng.permutation(n_pages - B)[:B]
+    wp = jnp.asarray(np.where(masked, rows, real), jnp.int32)
+    wo = jnp.asarray(np.where(masked, 0, (page_size - 1 - rows) % page_size),
+                     jnp.int32)
+    want = jax.jit(kv_page_write_reference)(k_pg, v_pg, k_new, v_new, wp, wo)
+    write = jax.jit(kv_page_write, donate_argnums=(0, 1))
+    if expect_kernel:
+        found = pallas_kernels(write.lower(k_pg, v_pg, k_new, v_new, wp, wo))
+        check("kv_page_write kernel in the lowered call",
+              found.get("kv_page_write", 0) == 1, f"found={found}")
+        check("arenas are allocated at the lane width",
+              Dp % 128 == 0, f"head_dim {D} -> {Dp}")
+    got = write(k_pg, v_pg, k_new, v_new, wp, wo)
+    for name, a, b in zip("KV", got, want):
+        check(f"kv page write {name} B{B} H{H} D{D}->{Dp} page{page_size} "
+              f"pool{n_pages}", bool(jnp.array_equal(a, b)),
+              "every element equal")
+    # the attention kernel over the written, lane-padded arenas
+    n_pg = min(16, (n_pages - B) // B)
+    tables = jnp.asarray(B + rng.permutation(n_pages - B)[:B * n_pg]
+                         .reshape(B, n_pg), jnp.int32)
+    q = jnp.asarray(rng.randn(B, H, 1, D), dtype)
+    t = jnp.asarray(np.linspace(1, n_pg * page_size - 1, B), jnp.int32)
+    out = jax.jit(paged_attention)(q, *got, tables, t)
+    ref = jax.jit(paged_attention_reference)(q, *got, tables, t)
+    tol = 2 * float(jnp.finfo(dtype).eps)
+    err = rel_err(out, ref)
+    check(f"paged attention over arenas of width {Dp}", err <= tol,
+          f"rel={err:.2e} tol={tol:.1e}")
 
 
 def lamb_parity(shapes, expect_kernel=True):
