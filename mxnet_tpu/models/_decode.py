@@ -6,7 +6,54 @@ throwaway HybridBlock taking flat positional state, functionalize it
 (`gluon.functional_call`), `jax.jit` it, and return a runner that re-reads
 the model's parameters on every call — parameters are jit ARGUMENTS, not
 baked constants, so decoding stays correct after further training."""
+import collections
+
 from ..gluon import HybridBlock
+
+# What `serve.Server` asks of a model (`model.serving_spec()`), so that it
+# reaches into no model's attributes:
+#   vocab_size, max_length   ids the logits cover; longest position served
+#   streams                  one `(*lead, width, dtype)` per paged arena, in
+#                            the order of the chunk step's flat state: the
+#                            pool allocates `(pages, *lead, page_size,
+#                            width)` for each (GPT: `(heads, head_dim, dt)`,
+#                            K per layer then V; a latent cache: `(width,
+#                            dt)`, no head axis)
+#   dense_streams            `(heads, head_dim, dtype)` per dense cache of
+#                            the `pages="off"` path, `(slots, heads, bucket,
+#                            head_dim)` each; None: the model serves paged
+#                            only
+#   index_topk               tokens a learned sparse attention keeps, None
+#                            for full attention (the `sparse_tokens` counter)
+#   chunk_step               `decode_paged_chunk(toks, t0, n, tables, flat,
+#                            page_size, full=)`
+#   draft_step               `decode_paged_draft(...)`, None: cannot draft
+ServingSpec = collections.namedtuple(
+    "ServingSpec", "vocab_size max_length streams dense_streams "
+    "index_topk chunk_step draft_step")
+
+
+def paged_write_targets(pos_d, active_d, tb_d, page_size):
+    """Write page/offset for one chunk step: active rows write page
+    tables[b, pos//ps] at offset pos%ps; masked rows write their
+    private scratch page (page id == batch row — mx.pages reserves
+    pages 0..slots-1 as per-slot scratch), so a batched step never
+    scatters two rows into one (page, offset) cell and never pollutes
+    a real page of an inactive request. Positions past the table's
+    range also divert to scratch: a speculative round that starts
+    near the bucket's last position feeds its fixed k+1 tokens past
+    the end, and clipping those writes back into the last real page
+    would corrupt positions the row still attends."""
+    import jax.numpy as jnp
+
+    B, n_pg = tb_d.shape
+    idx = jnp.clip(pos_d // page_size, 0, n_pg - 1)
+    real = jnp.take_along_axis(tb_d, idx[:, None], axis=1)[:, 0]
+    scratch = jnp.arange(B, dtype=jnp.int32)
+    ok = active_d & (pos_d < n_pg * page_size)
+    wp = jnp.where(ok, real.astype(jnp.int32), scratch)
+    wo = jnp.where(ok, pos_d % page_size, 0).astype(jnp.int32)
+    return wp, wo
 
 
 def cached_self_attention_step(q, k_new, v_new, k_cache, v_cache, t):
@@ -245,7 +292,14 @@ def jit_flat_step(model, step_fn, n_state, donate_state=0, label=None):
             primary, new_state = step_fn(*leading, flat)
             return tuple([primary] + list(new_state))
 
-    pure, gp, aux = functional_call(_Step(), train=False)
+    whole, gp, aux = functional_call(_Step(), train=False)
+
+    def pure(*args):
+        # inference leaves auxiliary parameters as they are; returned, each
+        # would be copied to an output buffer every call (a model whose
+        # parameters carry no gradient holds ALL its weights there)
+        return whole(*args)[0]
+
     rng = jax.random.key(0)
     # donate_argnums are positional, so the jit is built per leading
     # arity (fixed per call site in practice) on the first call
@@ -282,10 +336,10 @@ def jit_flat_step(model, step_fn, n_state, donate_state=0, label=None):
             t_compile = time.perf_counter()
         if _serve._enabled:
             t0 = time.perf_counter()
-            outs, _ = entry(gp_data, aux_data, rng, *leading, *state)
+            outs = entry(gp_data, aux_data, rng, *leading, *state)
             _serve.note_dispatch(type(model).__name__, t0)
         else:
-            outs, _ = entry(gp_data, aux_data, rng, *leading, *state)
+            outs = entry(gp_data, aux_data, rng, *leading, *state)
         if is_miss and label is not None:
             _trace.note_setup("compile_s", time.perf_counter() - t_compile)
         return outs[0], list(outs[1:])

@@ -19,6 +19,7 @@ from ..gluon import nn, HybridBlock
 from ..gluon.parameter import Parameter
 from ..ndarray import NDArray
 from ..ndarray import ndarray as F
+from ._decode import ServingSpec, paged_write_targets
 from .bert import BERTAttention, _positions, _scan_layers_call
 from .bert import tp_rules as _bert_tp_rules
 
@@ -393,26 +394,21 @@ class GPTForCausalLM(HybridBlock):
         return lg, tuple(nk), tuple(nv)
 
     def _paged_write_targets(self, pos_d, active_d, tb_d, page_size):
-        """Write page/offset for one chunk step: active rows write page
-        tables[b, pos//ps] at offset pos%ps; masked rows write their
-        private scratch page (page id == batch row — mx.pages reserves
-        pages 0..slots-1 as per-slot scratch), so a batched step never
-        scatters two rows into one (page, offset) cell and never pollutes
-        a real page of an inactive request. Positions past the table's
-        range also divert to scratch: a speculative round that starts
-        near the bucket's last position feeds its fixed k+1 tokens past
-        the end, and clipping those writes back into the last real page
-        would corrupt positions the row still attends."""
-        import jax.numpy as jnp
+        return paged_write_targets(pos_d, active_d, tb_d, page_size)
 
-        B, n_pg = tb_d.shape
-        idx = jnp.clip(pos_d // page_size, 0, n_pg - 1)
-        real = jnp.take_along_axis(tb_d, idx[:, None], axis=1)[:, 0]
-        scratch = jnp.arange(B, dtype=jnp.int32)
-        ok = active_d & (pos_d < n_pg * page_size)
-        wp = jnp.where(ok, real.astype(jnp.int32), scratch)
-        wo = jnp.where(ok, pos_d % page_size, 0).astype(jnp.int32)
-        return wp, wo
+    def serving_spec(self):
+        """What `serve.Server` asks of a model (`_decode.ServingSpec`)."""
+        g = self.gpt
+        n_l = len(g.layers)
+        heads = g.layers[0].attn._num_heads
+        vocab, units = g.word_embed.weight.shape
+        dtype = g.word_embed.weight.data()._data.dtype
+        kv = [(heads, units // heads, dtype)] * (2 * n_l)
+        return ServingSpec(
+            vocab_size=int(vocab), max_length=int(g.position_embed.shape[0]),
+            streams=kv, dense_streams=kv, index_topk=None,
+            chunk_step=self.decode_paged_chunk,
+            draft_step=self.decode_paged_draft)
 
     def decode_paged_chunk(self, toks, t0, n, tables, flat, page_size,
                            full=False):
@@ -449,8 +445,7 @@ class GPTForCausalLM(HybridBlock):
                 toks_d, j, axis=1, keepdims=False).astype(jnp.int32)
             pos = (t0_d + j).astype(jnp.int32)
             active = j < n_d
-            wp, wo = self._paged_write_targets(pos, active, tb_d,
-                                               page_size)
+            wp, wo = paged_write_targets(pos, active, tb_d, page_size)
             lg, ks, vs = self._paged_token_step(tokj, pos, tb_d, wp, wo,
                                                 ks, vs)
             last = jnp.where((j == n_d - 1)[:, None], lg, last)
@@ -491,7 +486,7 @@ class GPTForCausalLM(HybridBlock):
         def tok_step(carry, i):
             ks, vs, tok = carry
             pos = (t0_d + i).astype(jnp.int32)
-            wp, wo = self._paged_write_targets(pos, act_d, tb_d, page_size)
+            wp, wo = paged_write_targets(pos, act_d, tb_d, page_size)
             lg, ks, vs = self._paged_token_step(tok, pos, tb_d, wp, wo,
                                                 ks, vs)
             nxt = jnp.argmax(lg, -1).astype(jnp.int32)

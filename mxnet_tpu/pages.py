@@ -7,8 +7,9 @@ prefix each recompute and store it. This module is the vLLM/
 PagedAttention answer (PAPERS.md 2309.06180) adapted to this runtime:
 
   * **PagePool** — the KV store is one pooled (pages, H, page_size, D)
-    array per layer; a request owns a LIST of fixed-size pages instead
-    of a dense span. Pages are refcounted: the prefix tree and every
+    array per layer (or, for a latent cache, (pages, page_size, width)
+    arenas of two widths side by side); a request owns a LIST of
+    fixed-size pages instead of a dense span. Pages are refcounted: the prefix tree and every
     request sharing a block hold one reference each, and a page returns
     to the free list when the last reference drops. The pool is sized
     once at server construction and priced through the same
@@ -107,13 +108,17 @@ class PagePool:
     """Refcounted fixed-size KV pages over pooled per-layer arrays.
 
     `streams` maps a tag ('target', and 'draft' when a speculative
-    drafter is attached) to a list of (heads, head_dim, dtype) specs —
-    one per pooled array (2 * n_layers: K then V). Every array is
-    allocated as (pages, heads, page_size, head_dim) zeros — the last
-    dimension padded to the lane width where the paged kernels run
-    (`pallas_ops.kv_page_write.arena_head_dim`), so that the arenas'
-    default layout is the one the kernels read; page id p is physical
-    row p in all of them.
+    drafter is attached) to a list of `(*lead, width, dtype)` specs — one
+    per pooled array, in the order of the model's flat state
+    (`models/_decode.ServingSpec.streams`). Every array is allocated as
+    (pages, *lead, page_size, width) zeros: `(heads, head_dim, dtype)` is
+    a key or value arena with a head axis (GPT: 2 * n_layers of them, K
+    then V), `(width, dtype)` one row a token with none (a latent cache
+    beside its indexer keys). Arenas of different shapes lie side by side
+    under ONE allocator: page id p is physical row p in all of them. The
+    last dimension is padded to the lane width where the paged kernels
+    run (`pallas_ops.kv_page_write.arena_head_dim`), so that the arenas'
+    default layout is the one the kernels read.
 
     Page-table metadata (refcounts, free list) lives host-side and is
     guarded by the owning Server's lock; the device arrays in
@@ -136,9 +141,9 @@ class PagePool:
         from .pallas_ops.kv_page_write import arena_head_dim
         for tag, specs in self._specs.items():
             self.state[tag] = [
-                jnp.zeros((self.num_pages, h, self.page_size,
-                           arena_head_dim(d)), dt)
-                for (h, d, dt) in specs]
+                jnp.zeros((self.num_pages, *lead, self.page_size,
+                           arena_head_dim(width)), dt)
+                for (*lead, width, dt) in specs]
         self.stats = {"allocs": 0, "frees": 0, "cow_copies": 0,
                       "peak_used": 0}
 

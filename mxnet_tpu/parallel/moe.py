@@ -14,6 +14,14 @@ Layout contract (inside shard_map over `ep`, n = axis size):
   w1      (E_local, D, F)         this device's experts
   w2      (E_local, F, D)
   E = n * E_local total experts.
+
+Beside it, the share-aware top-k layer that today's sparse models use
+(`moe_topk_route` + `moe_share_ffn`): sigmoid scores with a selection
+bias, k experts a token, no capacity and no drops, and a device that is
+told WHICH experts it holds and computes their part of the result alone.
+On one chip it runs as it is; under an `ep` axis it is the same function
+inside the exchange (tokens gathered in, parts summed out), with
+`first_expert = lax.axis_index(ep) * E_local`.
 """
 from __future__ import annotations
 
@@ -24,7 +32,8 @@ from jax.sharding import PartitionSpec as P
 
 from .mesh import current_mesh
 
-__all__ = ["moe_dispatch", "moe_route", "moe_ffn", "moe_apply"]
+__all__ = ["moe_dispatch", "moe_route", "moe_ffn", "moe_apply",
+           "moe_topk_route", "moe_share_ffn"]
 
 
 def moe_dispatch(x, router_w, num_experts, capacity, axis_name=None):
@@ -159,3 +168,58 @@ def moe_apply(x, router_w, w1, w2, mesh=None, axis_name="ep",
         out_specs=(P(axis_name, None), P()),
         check_vma=False)
     return fn(x, router_w, w1, w2)
+
+
+# ---------------------------------------------------------------------------
+# share-aware top-k routing without drops
+# ---------------------------------------------------------------------------
+
+def moe_topk_route(x, router_w, select_bias, top_k, scale=1.0,
+                   norm_topk=True):
+    """Sigmoid top-k routing over ALL experts (`noaux_tc`, one group):
+    scores = sigmoid(x @ router_w) in float32; the `top_k` experts of
+    largest score + select_bias are chosen (the bias steers the choice
+    only); the gates are the chosen experts' scores, normalised over all
+    `top_k` chosen when `norm_topk` and multiplied by `scale`.
+
+    x (N, D); router_w (D, E); select_bias (E,). Returns (expert (N, k)
+    int32, gate (N, k) float32). Nothing is dropped: every token keeps
+    its k experts whatever the load."""
+    scores = jax.nn.sigmoid(jnp.matmul(
+        x.astype(jnp.float32), router_w.astype(jnp.float32),
+        precision=lax.Precision.HIGHEST))                       # (N, E)
+    _, expert = lax.top_k(scores + select_bias.astype(jnp.float32), top_k)
+    gate = jnp.take_along_axis(scores, expert, axis=-1)
+    if norm_topk:
+        gate = gate / gate.sum(-1, keepdims=True)
+    return expert.astype(jnp.int32), gate * scale
+
+
+def moe_share_ffn(x, expert, gate, w_gate, w_up, w_down, first_expert=0):
+    """This device's part of a top-k expert layer: the sum over the
+    experts it HOLDS, `[first_expert, first_expert + E_held)`, of gate *
+    SwiGLU expert. What experts held elsewhere would add is left out (the
+    caller sums the parts under a mesh axis, or passes the partial sum on
+    where it stands for one chip of a deployment).
+
+    x (N, D); expert/gate (N, k) from `moe_topk_route`; w_gate/w_up
+    (E_held, D, F); w_down (E_held, F, D). Returns (N, D) in x's dtype.
+
+    Every held expert sees every token, with a zero gate where the token
+    was not routed to it: three batched matmuls with static shapes, no
+    capacity, no sort. At a decode step's token count (tens of tokens a
+    chip) the experts' weights are read once either way and their bytes
+    bound the time; a prefill-sized N wants a grouped matmul over tokens
+    sorted by expert instead (ROADMAP Queue 2)."""
+    e_held = w_gate.shape[0]
+    # one_hot of an index outside [0, E_held) is a row of zeros: an
+    # expert held elsewhere adds nothing here
+    dense_gate = jnp.einsum(
+        "nke,nk->ne", jax.nn.one_hot(expert - first_expert, e_held,
+                                     dtype=jnp.float32), gate)  # (N, E_held)
+    f32 = jnp.float32
+    g = jnp.einsum("nd,edf->enf", x, w_gate, preferred_element_type=f32)
+    u = jnp.einsum("nd,edf->enf", x, w_up, preferred_element_type=f32)
+    h = (jax.nn.silu(g) * u).astype(x.dtype)
+    y = jnp.einsum("enf,efd->end", h, w_down, preferred_element_type=f32)
+    return jnp.einsum("end,ne->nd", y, dense_gate).astype(x.dtype)

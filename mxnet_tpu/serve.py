@@ -141,6 +141,24 @@ def _close_round(step_span, stream_span, chunk, tokens):
         step_span.attrs["chunk"] = chunk
 
 
+def _fetch(out, dtype):
+    """A step's result on the host: the scheduler's wait for the device.
+
+    It polls (`is_ready`, yielding the processor and the interpreter lock
+    between polls) where `np.asarray` alone would sleep until the runtime
+    wakes it. A thread woken by the runtime's thread can be left sharing
+    that thread's core, and then EVERY step of the process runs about 3 ms
+    longer (one scheduler timeslice; PERF.md section 6, PR 28: 8 of 27
+    serving processes on the chip's host began so and stayed so for as
+    long as they slept here; every one ran at the fast rate from its
+    first polled wait on, and no polled stretch was ever slow). A
+    scheduler thread that waits tens of milliseconds a step for its chip
+    spends one host core on that; other threads run whenever it yields."""
+    while not out.is_ready():
+        time.sleep(0)
+    return np.asarray(out, dtype)
+
+
 def servers():
     """The live Server objects of this process (construction registers
     them; garbage collection removes them)."""
@@ -225,8 +243,9 @@ class Request:
     verdicts are HTTP-flavored: '200 ok', '429 ...', '503 ...',
     '504 deadline ...', '499 cancelled', '500 ...'), `tokens` (generated
     so far), `max_new_tokens` (EFFECTIVE — the shrink rung may clamp it,
-    recorded in `degraded`), `requeues`, and the timing properties
-    `queue_wait_s` / `ttft_s`.
+    recorded in `degraded`), `logits` (under `submit(keep_logits=True)`:
+    the float32 row behind each token), `requeues`, and the timing
+    properties `queue_wait_s` / `ttft_s`.
 
     Consume results with `stream()` (yields tokens as they are
     generated; honors the `slow_client:ms` fault spec) or
@@ -236,7 +255,7 @@ class Request:
     """
 
     def __init__(self, seq, prompt, max_new_tokens, eos, temperature,
-                 top_k, seed, deadline):
+                 top_k, seed, deadline, keep_logits=False):
         self.id = seq
         self.prompt = np.asarray(prompt, np.int32).reshape(-1)
         self.max_new_tokens = int(max_new_tokens)
@@ -249,6 +268,9 @@ class Request:
         self.state = QUEUED
         self.verdict = None
         self.tokens = []
+        # the float32 logits row each token was sampled from, kept on
+        # request (`submit(keep_logits=True)`): the host fetched it anyway
+        self.logits = [] if keep_logits else None
         self.degraded = None
         self.requeues = 0
         self.evicted_once = False         # each request triggers <= 1 evict
@@ -332,6 +354,8 @@ class Request:
         reseeded here) — reproduces the same tokens; `_streamed` keeps
         already-delivered tokens from being re-sent."""
         self.tokens = []
+        if self.logits is not None:
+            self.logits = []
         self._rng = None
         self.requeues += 1
         self.state = QUEUED
@@ -414,8 +438,12 @@ class _PagedGroup:
 
 class Server:
     """Continuous-batching inference server over one autoregressive
-    model (the `GPTForCausalLM` decode surface: `decode_step_slots` +
-    `_alloc_caches`).
+    model. What it needs of the model it asks through
+    `model.serving_spec()` (`models/_decode.ServingSpec`: vocabulary,
+    longest position, the cache streams with their dtypes, the chunk step);
+    the dense path (`pages="off"`) is the `GPTForCausalLM` surface
+    (`decode_step_slots` + `_alloc_caches`), and a model whose spec has
+    no `dense_streams` is served paged only.
 
     `submit()` never raises for overload — rejection, shedding and
     expiry are VERDICTS on the returned Request, so the scheduler loop
@@ -434,17 +462,24 @@ class Server:
                  spec_k=None):
         enable()
         self.model = model
-        g = model.gpt
-        self._n_l = len(g.layers)
-        self._heads = g.layers[0].attn._num_heads
-        self._units = g.word_embed.weight.shape[1]
-        self._cache_dtype = g.word_embed.weight.data()._data.dtype
-        self._max_len = int(max_len or g.position_embed.shape[0])
+        self._spec = spec = model.serving_spec()
+        self._max_len = int(max_len or spec.max_length)
         pages = pages if pages is not None else _config.get("pages")
         if pages not in ("off", "on"):
             raise ValueError(f"pages must be 'off' or 'on', got {pages!r}")
         self._paged = pages == "on"
+        if not self._paged and spec.dense_streams is None:
+            raise ValueError(
+                f"{type(model).__name__} is served through the page pool "
+                "only: pass pages='on'")
         self._drafter = drafter
+        self._draft_spec = None
+        if drafter is not None:
+            self._draft_spec = drafter.serving_spec()
+            if self._draft_spec.draft_step is None:
+                raise ValueError(
+                    f"{type(drafter).__name__} cannot draft: its serving "
+                    "spec has no draft_step")
         self._slots = int(slots or _config.get("serve_slots"))
         self._queue_depth = int(queue_depth
                                 if queue_depth is not None
@@ -536,17 +571,9 @@ class Server:
                 f"pages_page_size {ps} exceeds the model's max_length "
                 f"{self._max_len} — no position fits a single page")
         self._max_len = max_paged
-        D = self._units // self._heads
-        streams = {"target": [(self._heads, D, self._cache_dtype)]
-                   * (2 * self._n_l)}
+        streams = {"target": list(self._spec.streams)}
         if self._drafter is not None:
-            dg = self._drafter.gpt
-            d_heads = dg.layers[0].attn._num_heads
-            d_units = dg.word_embed.weight.shape[1]
-            d_dtype = dg.word_embed.weight.data()._data.dtype
-            streams["draft"] = [(d_heads, d_units // d_heads, d_dtype)] \
-                * (2 * len(dg.layers))
-        if self._drafter is not None:
+            streams["draft"] = list(self._draft_spec.streams)
             try:
                 self._params_bytes += _memsafe.resident_bytes(
                     [p.data()._data
@@ -562,6 +589,11 @@ class Server:
             "chunk_dispatches": 0, "chunk_steps": 0, "token_steps": 0,
             "spec_rounds": 0,
             "drafts_proposed": 0, "drafts_accepted": 0,
+            # what attention was fed, worked out from positions alone:
+            # tokens, the sum of their context lengths, of the contexts
+            # cut to `index_topk`, and the tokens whose context passed it
+            "attn_tokens": 0, "attn_ctx_tokens": 0, "attn_sel_tokens": 0,
+            "sparse_tokens": 0,
         })
         from . import check as _check
         if _check._enabled:
@@ -569,18 +601,20 @@ class Server:
                 else max(1, int(_config.get("bucket_pad_min")))
             _check.lint_paging(
                 f"serve.Server(pages=on,page_size={ps})", ps, smallest,
-                int(self.model.gpt.word_embed.weight.shape[0]),
+                self._spec.vocab_size,
                 None if self._drafter is None
-                else int(self._drafter.gpt.word_embed.weight.shape[0]))
+                else self._draft_spec.vocab_size)
         _pages.enable()
 
     # -- client surface --------------------------------------------------
     def submit(self, prompt, max_new_tokens=32, eos=None, temperature=0.0,
-               top_k=0, seed=0, deadline_ms=None):
+               top_k=0, seed=0, deadline_ms=None, keep_logits=False):
         """Enqueue one generation request; returns a Request immediately
         (possibly already terminal: shed when the bounded queue is full
         under `serve_shed=reject`, or rejected when the request cannot
-        fit the device even alone). Never raises for overload."""
+        fit the device even alone). Never raises for overload.
+        `keep_logits` keeps on `Request.logits` the float32 row each
+        token was sampled from (an audit; nothing more is fetched)."""
         prompt = np.asarray(prompt, np.int32).reshape(-1)
         if prompt.size == 0 or int(max_new_tokens) <= 0:
             raise ValueError("submit needs a non-empty prompt and "
@@ -590,7 +624,7 @@ class Server:
         deadline = (self._clock() + float(ms) / 1000.0) if ms else None
         with self._lock:
             req = Request(self._seq, prompt, max_new_tokens, eos,
-                          temperature, top_k, seed, deadline)
+                          temperature, top_k, seed, deadline, keep_logits)
             self._seq += 1
             self._by_id[req.id] = req
             self._stats["submitted"] += 1
@@ -1025,17 +1059,17 @@ class Server:
         return sorted(cands, reverse=True)
 
     def _cache_bytes(self, bucket):
-        """Analytic KV bytes for one bucket's caches: 2*n_l arrays of
-        (slots, H, bucket, D)."""
-        D = self._units // self._heads
-        item = np.dtype(self._cache_dtype).itemsize
-        return 2 * self._n_l * self._slots * self._heads * bucket * D * item
+        """Analytic KV bytes for one bucket's dense caches: one
+        (slots, H, bucket, D) array per `dense_streams` entry."""
+        return sum(self._slots * h * bucket * d * np.dtype(dt).itemsize
+                   for h, d, dt in self._spec.dense_streams)
 
     def _runner(self, bucket):
         r = self._runners.get(bucket)
         if r is None:
             from .models._decode import jit_flat_step
-            model, n_l = self.model, self._n_l
+            model = self.model
+            n_l = len(self._spec.dense_streams) // 2
 
             def step(tok, t, flat):
                 logits, nk, nv = model.decode_step_slots(
@@ -1051,10 +1085,8 @@ class Server:
 
     def _cache_avals(self, bucket):
         import jax
-        D = self._units // self._heads
-        return [jax.ShapeDtypeStruct(
-            (self._slots, self._heads, bucket, D), self._cache_dtype)
-            for _ in range(2 * self._n_l)]
+        return [jax.ShapeDtypeStruct((self._slots, h, bucket, d), dt)
+                for h, d, dt in self._spec.dense_streams]
 
     def _paged_runner(self, bucket, C, full, draft=False):
         """Chunk-step executable for (bucket, chunk length C): the
@@ -1067,18 +1099,19 @@ class Server:
         if r is None:
             from .models._decode import jit_flat_step
             mdl = self._drafter if draft else self.model
-            n_l = len(mdl.gpt.layers)
+            spec = self._draft_spec if draft else self._spec
+            n_state = len(spec.streams)
             ps = self._page_size
 
             def step(toks, t0, n, tables, flat):
-                return mdl.decode_paged_chunk(toks, t0, n, tables, flat,
-                                              ps, full=full)
+                return spec.chunk_step(toks, t0, n, tables, flat, ps,
+                                       full=full)
 
             # the label joins a device trace's instructions to this
             # program's named scopes (mx.trace.scope_map); the
             # `serve.decode_step` span's `bucket` and `chunk` name it
             r = jit_flat_step(
-                mdl, step, 2 * n_l, donate_state=2 * n_l,
+                mdl, step, n_state, donate_state=n_state,
                 label=f"serve.paged/bucket={bucket}/chunk={C}"
                 + ("/full" if full else "") + ("/draft" if draft else ""))
             self._runners[key] = r
@@ -1096,15 +1129,16 @@ class Server:
         r = self._runners.get(key)
         if r is None:
             from .models._decode import jit_flat_step
-            mdl = self._drafter
-            n_l = len(mdl.gpt.layers)
+            spec = self._draft_spec
+            n_state = len(spec.streams)
             ps, k = self._page_size, self._spec_k
 
             def step(tok0, t0, act, tables, flat):
-                return mdl.decode_paged_draft(tok0, t0, act, tables,
-                                              flat, ps, k + 1)
+                return spec.draft_step(tok0, t0, act, tables, flat, ps,
+                                       k + 1)
 
-            r = jit_flat_step(mdl, step, 2 * n_l, donate_state=2 * n_l)
+            r = jit_flat_step(self._drafter, step, n_state,
+                              donate_state=n_state)
             self._runners[key] = r
         return r
 
@@ -1556,7 +1590,7 @@ class Server:
             grp.caches = new_state
             with (_trace.span("serve.fetch", cat="phase", step=sched_step,
                               chunk=1) if sp else _NULLCTX):
-                lg = np.asarray(logits, np.float32)  # host fetch = the fence
+                lg = _fetch(logits, np.float32)     # host fetch = the fence
         with (_trace.span("serve.stream", cat="serve", step=sched_step)
               if sp else _NULLCTX) as stream:
             if sp:
@@ -1572,7 +1606,7 @@ class Server:
                     if p < r.prompt.size - 1:
                         continue    # still prefilling the prompt
                     nxt = self._sample(r, lg[i])
-                    self._emit(r, nxt)
+                    self._emit(r, nxt, lg[i])
                     if (r.eos is not None and nxt == r.eos) \
                             or len(r.tokens) >= r.max_new_tokens:
                         grp.slots[i] = None
@@ -1691,7 +1725,7 @@ class Server:
                 self._dispatch_paged(grp, drun, lead, "draft")
             with (_trace.span("serve.fetch", cat="phase", step=sched_step,
                               chunk=C) if sp else _NULLCTX):
-                lg = np.asarray(logits, np.float32)  # host fetch = the fence
+                lg = _fetch(logits, np.float32)     # host fetch = the fence
         with (_trace.span("serve.stream", cat="serve", step=sched_step)
               if sp else _NULLCTX) as stream:
             if sp:
@@ -1707,13 +1741,14 @@ class Server:
                     p = grp.pos[i]
                     ni = int(n[i])
                     grp.pos[i] = p + ni
+                    self._note_fed(p, ni)
                     lp = r.prompt.size
                     if p + ni >= lp and not grp.inserted[i]:
                         self._tree_insert(grp, i, r)
                     if p + ni < lp:
                         continue    # still prefilling the prompt
                     nxt = self._sample(r, lg[i])
-                    self._emit(r, nxt)
+                    self._emit(r, nxt, lg[i])
                     if (r.eos is not None and nxt == r.eos) \
                             or len(r.tokens) >= r.max_new_tokens:
                         self._vacate(grp, i)
@@ -1747,7 +1782,7 @@ class Server:
                 grp, self._draft_runner(grp.bucket),
                 (jnp.asarray(tok0), jnp.asarray(t0), jnp.asarray(spec_row),
                  jnp.asarray(tables)), "draft")
-            drafts = np.asarray(drafts_out, np.int32)[:, :k]   # (B, k)
+            drafts = _fetch(drafts_out, np.int32)[:, :k]       # (B, k)
             for i in active:
                 toks[i, 0] = tok0[i]
                 if spec_row[i]:
@@ -1765,7 +1800,7 @@ class Server:
                            jnp.asarray(n), jnp.asarray(tables)), "target")
             with (_trace.span("serve.fetch", cat="phase", step=sched_step,
                               chunk=k + 1) if sp else _NULLCTX):
-                lgs = np.asarray(logits, np.float32)       # (B, k+1, V)
+                lgs = _fetch(logits, np.float32)           # (B, k+1, V)
         with (_trace.span("serve.stream", cat="serve", step=sched_step)
               if sp else _NULLCTX) as stream:
             if sp:
@@ -1778,10 +1813,11 @@ class Server:
                     if r is None or r.state in TERMINAL:
                         continue
                     p = grp.pos[i]
+                    self._note_fed(p, int(n[i]))
                     if not spec_row[i]:
                         grp.pos[i] = p + 1
                         nxt = self._sample(r, lgs[i, 0])
-                        self._emit(r, nxt)
+                        self._emit(r, nxt, lgs[i, 0])
                         if (r.eos is not None and nxt == r.eos) \
                                 or len(r.tokens) >= r.max_new_tokens:
                             self._vacate(grp, i)
@@ -1794,7 +1830,7 @@ class Server:
                         # same argmax as _sample's greedy path — exact
                         # acceptance means verify-then-keep, never trust
                         nxt = int(lgs[i, j].argmax())
-                        self._emit(r, nxt)
+                        self._emit(r, nxt, lgs[i, j])
                         emitted += 1
                         if (r.eos is not None and nxt == r.eos) \
                                 or len(r.tokens) >= r.max_new_tokens:
@@ -1872,8 +1908,27 @@ class Server:
             return int(req._rng.choice(p.size, p=p))
         return int(lg.argmax())
 
-    def _emit(self, req, tok):
+    def _note_fed(self, p, ni):
+        """Count `ni` tokens fed at positions p..: the token at position q
+        attends a context of q + 1 tokens, of which a learned sparse
+        attention keeps `index_topk`. Positions only; no device read."""
+        st, k = self._stats, self._spec.index_topk
+        ctx = ni * p + ni * (ni + 1) // 2
+        st["attn_tokens"] += ni
+        st["attn_ctx_tokens"] += ctx
+        if k is None or p + ni <= k:
+            st["attn_sel_tokens"] += ctx
+        else:
+            over = min(ni, p + ni - k)      # the last `over` contexts pass k
+            under = ni - over
+            st["attn_sel_tokens"] += under * p + under * (under + 1) // 2 \
+                + over * k
+            st["sparse_tokens"] += over
+
+    def _emit(self, req, tok, row=None):
         req.tokens.append(int(tok))
+        if req.logits is not None and row is not None:
+            req.logits.append(np.array(row, np.float32))
         self._stats["tokens"] += 1
         if _telemetry._enabled:
             _M_TOKENS.inc()

@@ -12,7 +12,7 @@ HBM); what it cannot catch is anything that only shows when the code RUNS
 — numerics, hangs, real memory — and that is the chip's word.
 
     JAX_PLATFORMS=cpu python tools/aot_check.py            # everything
-    JAX_PLATFORMS=cpu python tools/aot_check.py kernels    # or: train, serve
+    JAX_PLATFORMS=cpu python tools/aot_check.py kernels    # or: train, serve, serve_glm
 
 Arguments are `jax.ShapeDtypeStruct`s whose shardings name the topology's
 devices; the two places the package asks JAX what it runs on (the kernel
@@ -188,6 +188,18 @@ def check_train(devices):
         jax.device_put, mx.random.get_state = real_put, real_state
 
 
+def arena_copies_and_aliases(text, arenas):
+    """(lines of the optimised HLO `text` that copy an operand shaped like
+    one of `arenas`, parameters the module header aliases to outputs)."""
+    shapes = {"[%s]" % ",".join(map(str, a.shape)) for a in arenas}
+    copies = [line.strip()[:160] for line in text.splitlines()
+              if any(re.search(r" = \w+%s\{[^}]*\} copy\(" % re.escape(sh),
+                               line) for sh in shapes)]
+    aliased = re.findall(r"\{\d+\}: \(\d+, \{\}, (?:may|must)-alias\)",
+                         text.split("\n", 1)[0])
+    return copies, aliased
+
+
 def check_serve(devices):
     """The benchmark's serving cell (chipbench/configs/gpt2-medium-serve.json:
     GPT-2 medium, 32 slots over a pool of 2,080 pages of 16): the bucket-256
@@ -222,13 +234,9 @@ def check_serve(devices):
         compiled = lowered.compile()
         mem = compiled.memory_analysis()
         srv.stop()
-        text = compiled.as_text()
         shape = "[%s]" % ",".join(map(str, arena.shape))
-        copies = [line.strip()[:160] for line in text.splitlines()
-                  if re.search(r" = \w+%s\{[^}]*\} copy\(" % re.escape(shape),
-                               line)]
-        aliased = re.findall(r"\{\d+\}: \(\d+, \{\}, (?:may|must)-alias\)",
-                             text.split("\n", 1)[0])
+        copies, aliased = arena_copies_and_aliases(compiled.as_text(),
+                                                   [arena])
         print(f"  compiles: GPT-2 medium paged step, bucket {bucket} "
               f"chunk {chunk}  [{time.perf_counter() - t0:.1f}s]  "
               f"kernels={found}  copies of {shape}: {len(copies)}  "
@@ -245,8 +253,62 @@ def check_serve(devices):
             f"{len(aliased)} of {n_arenas} arenas aliased to outputs"
 
 
+def check_serve_glm(devices):
+    """The benchmark's GLM-5 cell (chipbench/configs/glm-5-serve-ep16.json:
+    4.7 B parameters in bf16, 32 slots, one bucket of 6,272 over a pool of
+    2,080 pages of 64): the chunk-8 and chunk-1 step executables as the
+    server builds them, from abstract parameters and arenas (nothing is
+    drawn or placed; `lower` takes shapes). Each must fit the chip and
+    keep its twelve arenas where they are: every arena aliased to an
+    output, no `copy` of an arena-shaped operand."""
+    from chipbench.kinds import serve_agent
+    from mxnet_tpu.models import glm
+    from mxnet_tpu.ndarray import NDArray
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "chipbench", "configs",
+                           "glm-5-serve-ep16.json")) as f:
+        cell = json.load(f)
+    cfg = serve_agent.model_config(cell)
+    mesh = parallel.make_mesh(devices=devices[:1])
+    s1 = NamedSharding(mesh, P())
+    model = glm.GLMForCausalLM(cfg)
+    for _, p in model._iter_params():
+        p._data = NDArray(sds(p.shape, jnp.dtype(p.dtype), s1))
+    bucket = cell["server"]["buckets"][0]
+    real_zeros = jnp.zeros
+    for chunk in (8, 1):
+        jnp.zeros = lambda shape, dtype: sds(shape, jnp.dtype(dtype), s1)
+        try:                # the pool's arenas, as shapes
+            srv = serve.Server(model, **dict(cell["server"],
+                                             prefill_chunk=chunk))
+        finally:
+            jnp.zeros = real_zeros
+        run, avals = srv._bucket_step(bucket)
+        arenas = avals[-1]
+        avals = jax.tree.map(lambda a: sds(a.shape, a.dtype, s1), avals)
+        t0 = time.perf_counter()
+        compiled = run.lower(*avals).compile()
+        mem = compiled.memory_analysis()
+        srv.stop()
+        copies, aliased = arena_copies_and_aliases(compiled.as_text(),
+                                                   arenas)
+        total = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+        print(f"  compiles: GLM-5 share ({glm.param_count(cfg) / 1e9:.3f} B "
+              f"parameters) paged step, bucket {bucket} chunk {chunk}  "
+              f"[{time.perf_counter() - t0:.1f}s]  arena copies: "
+              f"{len(copies)}  aliased parameters: {len(aliased)}  temp="
+              f"{mem.temp_size_in_bytes / 2**30:.2f} GiB args="
+              f"{mem.argument_size_in_bytes / 2**30:.2f} GiB", flush=True)
+        assert not copies, f"arena-shaped copies left: {copies[0]}"
+        assert len(aliased) == len(arenas), \
+            f"{len(aliased)} of {len(arenas)} arenas aliased to outputs"
+        assert total < 14 * 2**30, \
+            f"{total / 2**30:.2f} GiB leaves no room on a 16 GB chip"
+
+
 def main():
-    which = sys.argv[1:] or ["kernels", "train", "serve"]
+    which = sys.argv[1:] or ["kernels", "train", "serve", "serve_glm"]
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name=TOPOLOGY)
     devices = list(topo.devices)
@@ -261,7 +323,7 @@ def main():
     for name in which:
         print(f"== {name} ==")
         {"kernels": check_kernels, "train": check_train,
-         "serve": check_serve}[name](devices)
+         "serve": check_serve, "serve_glm": check_serve_glm}[name](devices)
     print("aot_check: everything compiled for", devices[0].device_kind)
 
 
