@@ -8,10 +8,12 @@ lowering of that gather (`k_pages[tables]` then a dense attention)
 materializes the gathered (B, H, L, D) operand in HBM before the matmul
 — an extra full-cache round-trip per token, on the executable mx.inspect
 already flags memory-bound. This kernel walks the page table inside the
-grid instead: scalar-prefetched block indices drive the BlockSpec
-index_map, so each (batch, page) program DMAs exactly one page from the
-pool into VMEM and accumulates online-softmax state — the gathered
-operand never exists.
+program instead: one grid program a batch row reads the row's table
+(scalar-prefetched) and DMAs the pages it names from the pool, which
+stays in HBM, into VMEM a few at a time, the next few in flight while
+these are reduced into online-softmax state — the gathered operand never
+exists. The walk ends at the row's position: a row at token 40 of a
+256-token bucket brings and reduces 3 pages, not 16.
 
 Fallback (`kernels=off`, non-TPU without the interpreter): the gather +
 the EXACT dense per-row attention expression
@@ -70,90 +72,176 @@ def paged_attention_reference(q, k_pages, v_pages, tables, t):
 # pallas kernel
 # --------------------------------------------------------------------------
 
-def _kernel(tb_ref, t_ref, q_ref, k_ref, v_ref, o_ref, m_s, l_s, acc_s, *,
-            page_size, sm_scale):
-    """One (batch row, page) program: online-softmax accumulate this
-    page's contribution to row b's single-query attention.
+# a wave is the pages of one row that are in flight together: enough of
+# them that their DMAs overlap each other, few enough that two waves of
+# K and V stay a small part of VMEM. Worked out from shapes, not a knob
+_WAVE_BYTES = 512 * 1024
 
-    The page-table gather happens OUTSIDE this body — the k/v BlockSpec
-    index_map reads the scalar-prefetched table, so k_ref/v_ref already
-    hold page tables[b, j] in VMEM. Scratch (m, l, acc) carries the
-    running max / denominator / value-sum across the page ('arbitrary')
-    grid dimension; lanes-broadcast (H, 128) carriers keep the row
-    vectors in Mosaic-friendly tiles."""
+
+def _pages_per_wave(n_pg, page_bytes, wave_bytes):
+    return max(1, min(n_pg, wave_bytes // page_bytes))
+
+
+def _kernel(tb_ref, t_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sem,
+            wave0, *, page_size, n_pg, wave, sm_scale):
+    """One batch row a program: walk the row's page table as far as its
+    position, `wave` pages at a time, and reduce them with an online
+    softmax whose state stays in registers.
+
+    K and V stay in HBM; each page tables[b, j] is one DMA into a
+    double-buffered VMEM scratch (slot = parity of a wave counter that
+    runs across rows, kept in SMEM). While a wave is reduced the next is
+    in flight: the row's own next wave, or after its last the FIRST wave
+    of row b + 1 — scratch and semaphores outlive a grid step, so a row
+    exposes no DMA latency but row 0's. Pages past t[b] are neither
+    fetched nor reduced: they would contribute exact zeros (p = 0,
+    alpha = 1), so no bit of the result depends on them."""
     b = pl.program_id(0)
-    j = pl.program_id(1)
-    n_pg = pl.num_programs(1)
+    B = pl.num_programs(0)
 
-    @pl.when(j == 0)
-    def _init():
-        m_s[...] = jnp.full(m_s.shape, _NEG, jnp.float32)
-        l_s[...] = jnp.zeros(l_s.shape, jnp.float32)
-        acc_s[...] = jnp.zeros(acc_s.shape, jnp.float32)
+    def n_pages(row):
+        return jnp.minimum(t_ref[row] // page_size + 1, n_pg)
 
-    q = q_ref[0].astype(jnp.float32)                     # (H, D)
-    k = k_ref[0].astype(jnp.float32)                     # (H, ps, D)
-    v = v_ref[0].astype(jnp.float32)
-    H, ps, _ = k.shape
-    # per-head single-query scores over this page's positions. One
-    # query row per head is a batched matrix-VECTOR product, which
-    # Mosaic's dot_general refuses (no lhs non-contracting dim) — and
-    # the MXU would idle on it anyway; multiply-and-reduce on the VPU
-    s = jnp.sum(q[:, None, :] * k, axis=-1) * sm_scale   # (H, ps)
-    pos = j * page_size + \
-        jax.lax.broadcasted_iota(jnp.int32, (H, ps), 1)
-    s = jnp.where(pos <= t_ref[b], s, _NEG)
+    def buffer(j, slot):
+        # page j of a row lies in the slot of its wave at j % wave.
+        # (Spelled with the remainder on purpose: handed `slot * wave -
+        # w * wave + j` Mosaic schedules the pair of page steps below
+        # 11 % slower, 226 against 203 us a call of full rows)
+        return slot * wave + j % wave
 
-    m_prev = m_s[:, 0:1]                                 # (H, 1)
-    l_prev = l_s[:, 0:1]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-    p = jnp.exp(s - m_new)                               # (H, ps)
-    alpha = jnp.exp(m_prev - m_new)
-    l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-    acc = acc_s[...] * alpha + jnp.sum(p[:, :, None] * v, axis=1)
-    m_s[...] = jnp.broadcast_to(m_new, m_s.shape)
-    l_s[...] = jnp.broadcast_to(l_new, l_s.shape)
-    acc_s[...] = acc
+    def wave_dmas(row, w, slot, n, go):
+        """Start (or wait for) the DMAs of wave w of `row` into `slot`:
+        one of K and one of V for each of its pages below n."""
+        def page(j, _):
+            for hbm, buf, kv in ((k_hbm, k_buf, 0), (v_hbm, v_buf, 1)):
+                go(pltpu.make_async_copy(hbm.at[tb_ref[row, j]],
+                                         buf.at[buffer(j, slot)],
+                                         sem.at[slot, kv]))
+        jax.lax.fori_loop(w * wave, jnp.minimum((w + 1) * wave, n), page,
+                          None)
 
-    @pl.when(j == n_pg - 1)
-    def _write():
-        o_ref[0] = (acc_s[...] / l_s[:, 0:1]).astype(o_ref.dtype)
+    def start(row, w, slot, n):
+        wave_dmas(row, w, slot, n, lambda dma: dma.start())
+
+    @pl.when(b == 0)
+    def _first():
+        wave0[0] = 0
+        start(0, 0, 0, n_pages(0))
+
+    n = n_pages(b)
+    n_w = (n + wave - 1) // wave
+    w0 = wave0[0]
+    q = q_ref[0].astype(jnp.float32)                     # (H, Dp)
+    H, Dp = q.shape
+
+    def page_step(j, slot, carry):
+        """Today's arithmetic of one page, in today's page order."""
+        m_prev, l_prev, acc = carry
+        g = buffer(j, slot)
+        k = k_buf[g].astype(jnp.float32)                 # (H, ps, Dp)
+        v = v_buf[g].astype(jnp.float32)
+        # per-head single-query scores over this page's positions. One
+        # query row per head is a batched matrix-VECTOR product, which
+        # Mosaic's dot_general refuses (no lhs non-contracting dim) — and
+        # the MXU would idle on it anyway; multiply-and-reduce on the VPU
+        s = jnp.sum(q[:, None, :] * k, axis=-1) * sm_scale   # (H, ps)
+        pos = j * page_size + \
+            jax.lax.broadcasted_iota(jnp.int32, (H, page_size), 1)
+        s = jnp.where(pos <= t_ref[b], s, _NEG)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)                           # (H, ps)
+        alpha = jnp.exp(m_prev - m_new)
+        l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc = acc * alpha + jnp.sum(p[:, :, None] * v, axis=1)
+        return m_new, l_new, acc
+
+    def wave_step(w, carry):
+        slot = (w0 + w) % 2
+        # the next wave in the order the grid runs: this row's, or the
+        # first of the next row (none after the last row's last)
+        more = w + 1 < n_w
+        nxt = jnp.where(more, b, jnp.minimum(b + 1, B - 1))
+        start(nxt, jnp.where(more, w + 1, 0), 1 - slot,
+              jnp.where(more | (b + 1 < B), n_pages(nxt), 0))
+        wave_dmas(b, w, slot, n, lambda dma: dma.wait())
+        # two pages a loop step, in order: the second page's scores do
+        # not wait for the first page's softmax, so the two overlap (a
+        # call at the benchmark's shapes: 88 -> 71 us; four buy no more)
+        lo = w * wave
+        hi = jnp.minimum(lo + wave, n)
+        pairs = (hi - lo) // 2
+
+        def pair(i, c):
+            c = page_step(lo + 2 * i, slot, c)
+            return page_step(lo + 2 * i + 1, slot, c)
+
+        carry = jax.lax.fori_loop(0, pairs, pair, carry)
+        return jax.lax.fori_loop(
+            lo + 2 * pairs, hi, lambda j, c: page_step(j, slot, c), carry)
+
+    _, l, acc = jax.lax.fori_loop(
+        0, n_w, wave_step,
+        (jnp.full((H, 1), _NEG, jnp.float32),
+         jnp.zeros((H, 1), jnp.float32),
+         jnp.zeros((H, Dp), jnp.float32)))
+    wave0[0] = w0 + n_w
+    o_ref[0] = (acc / l).astype(o_ref.dtype)
 
 
 def _paged_attention_pallas(q, k_pages, v_pages, tables, t):
+    return _paged_call(q, k_pages, v_pages, tables, t,
+                       wave_bytes=_WAVE_BYTES, interpret=_common.interpret())
+
+
+# A step executable calls this once a layer with the same shapes: under
+# an inlined jit the body is traced once a process and every call site
+# still gets a `pallas_call` of its own; what the trace depends on
+# besides shapes is a static argument.
+@functools.partial(jax.jit, static_argnames=("wave_bytes", "interpret"),
+                   inline=True)
+def _paged_call(q, k_pages, v_pages, tables, t, *, wave_bytes, interpret):
     B, H, _, D = q.shape
     ps, Dp = k_pages.shape[2:]
     n_pg = tables.shape[1]
-    # arenas wider than the heads (kv_page_write.arena_head_dim) hold
-    # zeros past D: zeros in q's lanes there leave every score as it is,
-    # and the output's lanes there are dropped
+    # a page is DMAed whole, and Mosaic slices an HBM operand only where
+    # its last dimension fills the lanes: arenas the pool allocated at
+    # the lane width (kv_page_write.arena_head_dim, zeros past D) come
+    # as they are; any other width is padded here, which copies the
+    # arena (tools/tpu_validate's bare pools, never a served one). Zeros
+    # in q's lanes past D leave every score as it is, and the output's
+    # lanes there are dropped
+    lanes = _common.round_up(Dp, 128) - Dp
+    if lanes:
+        pad = ((0, 0),) * 3 + ((0, lanes),)
+        k_pages, v_pages, Dp = \
+            jnp.pad(k_pages, pad), jnp.pad(v_pages, pad), Dp + lanes
+    wave = _pages_per_wave(n_pg, H * ps * Dp * k_pages.dtype.itemsize,
+                           wave_bytes)
     q2 = jnp.pad(q.reshape(B, H, D), ((0, 0), (0, 0), (0, Dp - D)))
     out = pl.pallas_call(
-        functools.partial(_kernel, page_size=ps,
+        functools.partial(_kernel, page_size=ps, n_pg=n_pg, wave=wave,
                           sm_scale=1.0 / (D ** 0.5)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(B, n_pg),
+            grid=(B,),
             in_specs=[
-                pl.BlockSpec((1, H, Dp),
-                             lambda b, j, tb, tt: (b, 0, 0)),
-                pl.BlockSpec((1, H, ps, Dp),
-                             lambda b, j, tb, tt: (tb[b, j], 0, 0, 0)),
-                pl.BlockSpec((1, H, ps, Dp),
-                             lambda b, j, tb, tt: (tb[b, j], 0, 0, 0)),
+                pl.BlockSpec((1, H, Dp), lambda b, tb, tt: (b, 0, 0)),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
             ],
-            out_specs=pl.BlockSpec((1, H, Dp),
-                                   lambda b, j, tb, tt: (b, 0, 0)),
+            out_specs=pl.BlockSpec((1, H, Dp), lambda b, tb, tt: (b, 0, 0)),
             scratch_shapes=[
-                pltpu.VMEM((H, 128), jnp.float32),       # running max
-                pltpu.VMEM((H, 128), jnp.float32),       # denominator
-                pltpu.VMEM((H, Dp), jnp.float32),        # value acc
+                pltpu.VMEM((2 * wave, H, ps, Dp), k_pages.dtype),
+                pltpu.VMEM((2 * wave, H, ps, Dp), v_pages.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),     # (slot, K or V)
+                pltpu.SMEM((1,), jnp.int32),         # waves before row b
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, H, Dp), q.dtype),
+        # rows run in order: each starts the next one's first wave
         compiler_params=_common.compiler_params(
-            dimension_semantics=("parallel", "arbitrary")),
-        interpret=_common.interpret(),
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
         name="paged_attention",
     )(tables.astype(jnp.int32), t.astype(jnp.int32), q2, k_pages, v_pages)
     return out[:, :, :D].reshape(B, H, 1, D)

@@ -390,6 +390,104 @@ def test_paged_attention_parity_bf16(one_device):
                                rtol=2e-2, atol=2e-2)
 
 
+def _walk_case(n_pg, lanes, dtype, B=5, H=4, D=16, ps=8, seed=3):
+    """Rows at the positions where the walk's length changes — the first
+    token, the last of a page, the first of the next, mid-page, the end
+    of the bucket — each on pages of its own, in arenas `lanes` wide
+    (zeros past D). Page 0 belongs to no row."""
+    rng = np.random.RandomState(seed)
+    P = 1 + B * n_pg
+    pad = ((0, 0),) * 3 + ((0, lanes - D),)
+    q = jnp.asarray(rng.randn(B, H, 1, D), dtype)
+    kp = jnp.pad(jnp.asarray(rng.randn(P, H, ps, D), dtype), pad)
+    vp = jnp.pad(jnp.asarray(rng.randn(P, H, ps, D), dtype), pad)
+    tables = 1 + rng.permutation(B * n_pg).reshape(B, n_pg).astype(np.int32)
+    t = np.array([0, ps - 1, ps, (n_pg // 2) * ps + 3, n_pg * ps - 1],
+                 np.int32)
+    return q, kp, vp, tables, t
+
+
+def _assert_paged_parity(q, kp, vp, tables, t, dtype):
+    tol = 2e-6 if dtype == jnp.float32 else 2e-2
+    args = (q, kp, vp, jnp.asarray(tables), jnp.asarray(t))
+    assert _holds_kernel(pa.paged_attention, *args)
+    got = pa.paged_attention(*args)
+    ref = pa.paged_attention_reference(*args)
+    assert got.shape == ref.shape and got.dtype == dtype
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(ref, np.float32),
+                               rtol=tol, atol=tol)
+    return got
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("lanes", [16, 128], ids=["Dp=D", "Dp=lanes"])
+@pytest.mark.parametrize("n_pg", [4, 16, 64])
+def test_paged_attention_walk_parity(one_device, n_pg, lanes, dtype):
+    """Kernel against reference with every length of walk in one batch,
+    at three buckets, over bare and lane-wide arenas."""
+    _assert_paged_parity(*_walk_case(n_pg, lanes, dtype), dtype)
+
+
+@pytest.mark.parametrize("pages_a_wave", [1, 2, 3, 5])
+def test_paged_attention_short_waves(one_device, monkeypatch, pages_a_wave):
+    """Waves shorter than the walk, and of a length that does not divide
+    it: the slots alternate across rows, the last wave of a row is
+    short, and every bit matches the one-wave kernel."""
+    q, kp, vp, tables, t = _walk_case(16, 128, jnp.float32)
+    whole = _assert_paged_parity(q, kp, vp, tables, t, jnp.float32)
+    page_bytes = kp[0].size * kp.dtype.itemsize
+    assert pa._pages_per_wave(16, page_bytes, pa._WAVE_BYTES) == 16
+    monkeypatch.setattr(pa, "_WAVE_BYTES", pages_a_wave * page_bytes)
+    assert pa._pages_per_wave(16, page_bytes, pa._WAVE_BYTES) \
+        == pages_a_wave
+    got = _assert_paged_parity(q, kp, vp, tables, t, jnp.float32)
+    assert np.array_equal(np.asarray(got), np.asarray(whole))
+
+
+@pytest.mark.parametrize("pages_a_wave", [16, 3])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_paged_attention_stops_at_the_position(one_device, monkeypatch,
+                                               dtype, pages_a_wave):
+    """Poison: every page past a row's position holds NaN, and the
+    table's entries past it name a page of NaN. A kernel that walked on
+    would multiply them by zero and return NaN; this one returns the
+    clean case's bits."""
+    q, kp, vp, tables, t = _walk_case(16, 128, dtype)
+    ps = kp.shape[2]
+    monkeypatch.setattr(pa, "_WAVE_BYTES",
+                        pages_a_wave * kp[0].size * kp.dtype.itemsize)
+    clean = _assert_paged_parity(q, kp, vp, tables, t, dtype)
+    kp, vp, tables = np.array(kp), np.array(vp), tables.copy()
+    for b in range(len(t)):
+        past = tables[b, t[b] // ps + 1:]
+        kp[past] = np.nan
+        vp[past] = np.nan
+        tables[b, t[b] // ps + 1:] = 0
+    kp[0] = np.nan
+    vp[0] = np.nan
+    got = pa.paged_attention(q, jnp.asarray(kp), jnp.asarray(vp),
+                             jnp.asarray(tables), jnp.asarray(t))
+    assert np.isfinite(np.asarray(got, np.float32)).all()
+    assert np.array_equal(np.asarray(got, np.float32),
+                          np.asarray(clean, np.float32))
+
+
+def test_paged_attention_idle_row_beside_live_rows(one_device):
+    """A slot nobody holds: a table of zeros and t = 0. It reads page 0
+    alone, and the live rows around it are what they are without it."""
+    q, kp, vp, tables, t = _walk_case(16, 128, jnp.float32)
+    alone = _assert_paged_parity(q, kp, vp, tables, t, jnp.float32)
+    tables, t = tables.copy(), t.copy()
+    tables[2], t[2] = 0, 0
+    got = _assert_paged_parity(q, kp, vp, tables, t, jnp.float32)
+    live = [0, 1, 3, 4]
+    assert np.array_equal(np.asarray(got)[live], np.asarray(alone)[live])
+    assert np.isfinite(np.asarray(got)[2]).all()
+
+
 def test_paged_attention_kernels_off_is_reference_path(one_device):
     config.set("kernels", "off")
     case = _paged_case()

@@ -101,11 +101,15 @@ def check_kernels(devices):
              sds((n_pages, H, ps, Dp), bf, s1),
              sds((B, H, 1, D), bf, s1), sds((B, H, 1, D), bf, s1),
              sds((B,), i32, s1), sds((B,), i32, s1))
-    compile_("paged_attention B32 H16 D64->128 page16 n_pg16",
-             pallas_ops.paged_attention,
-             sds((B, H, 1, D), bf, s1), sds((n_pages, H, ps, Dp), bf, s1),
-             sds((n_pages, H, ps, Dp), bf, s1), sds((B, 16), i32, s1),
-             sds((B,), i32, s1))
+    # the attention over those arenas at both buckets: at 1024 a row's
+    # walk is up to 64 pages in one program
+    for n_pg in (16, 64):
+        compile_(f"paged_attention B32 H16 D64->128 page16 n_pg{n_pg}",
+                 pallas_ops.paged_attention,
+                 sds((B, H, 1, D), bf, s1),
+                 sds((n_pages, H, ps, Dp), bf, s1),
+                 sds((n_pages, H, ps, Dp), bf, s1),
+                 sds((B, n_pg), i32, s1), sds((B,), i32, s1))
 
     # fused LAMB at BERT-base's flat size (110M f32), fused Adam f32 + bf16
     from mxnet_tpu.parallel.fused_lamb import FusedLamb
