@@ -19,7 +19,7 @@ seed, in ONE process that holds the chip(s) for the whole run:
                     chip. Reported as NOT RUN on a machine with fewer than
                     four devices.
   serve_one_chip    GPT-2 345M bf16 through serve.Server(slots=8,
-                    pages="on") on a one-device mesh: mixed-length prompts,
+                    page_size=16) on a one-device mesh: mixed-length prompts,
                     drain(), every request DONE with the tokens it asked
                     for; the paged-attention kernel in the executable.
   kernels           tools/tpu_validate.py: flash (fwd + grads; plain, mask,
@@ -312,12 +312,11 @@ def serve_one_chip():
         lens, new = [24, 57, 90, 130, 200, 310, 470], 24
     say(f"GPT {cfg['num_layers']}L/{cfg['units']} {cfg['dtype']} "
         f"scan_layers={cfg['scan_layers']} on {device}; slots={slots} "
-        f"pages=on page_size={page} buckets={buckets}")
+        f"page_size={page} buckets={buckets}")
     model = gpt_mod.GPTForCausalLM(cfg)
     mx.random.seed(0)
     model.initialize()
-    srv = serve.Server(model, slots=slots, pages="on", page_size=page,
-                       buckets=buckets)
+    srv = serve.Server(model, slots=slots, page_size=page, buckets=buckets)
 
     for bucket in buckets:
         found = tpu_validate.pallas_kernels(srv.lower_step(bucket))

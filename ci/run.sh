@@ -117,7 +117,7 @@ print('inspect disabled fast path OK (no analysis calls, no records)')
     # a TPU each must exit non-zero and print no row (a number they print
     # goes under a device metric's name). bench_dataloader is the host
     # benchmark and is exempt.
-    for script in chip_smoke.py bench.py benchmarks/bench_serve.py \
+    for script in chip_smoke.py bench.py \
             benchmarks/bench_generate.py benchmarks/bench_kernels.py \
             benchmarks/bench_attention.py benchmarks/bench_step_profile.py \
             benchmarks/bench_resnet.py; do
@@ -777,39 +777,8 @@ print('seeded regression ledger at', path)
 
 pages_stage() {
     echo "== pages =="
-    # pages=off (the default) must be the zero-overhead production
-    # path: a full dense request lifecycle constructs no PagePool, no
-    # PrefixTree, never arms the module bool, and surfaces none of the
-    # paged stats keys — the scheduler checks one attribute
-    JAX_PLATFORMS=cpu python -c "
-import numpy as np
-import mxnet_tpu as mx
-from mxnet_tpu import pages, parallel, serve
-from mxnet_tpu.models import gpt as gpt_mod
-assert not pages.enabled(), 'pages must default to off'
-calls = {'pool': 0, 'tree': 0, 'enable': 0}
-real = (pages.PagePool, pages.PrefixTree, pages.enable)
-pages.PagePool = lambda *a, **k: (calls.__setitem__('pool', calls['pool'] + 1), real[0](*a, **k))[1]
-pages.PrefixTree = lambda *a, **k: (calls.__setitem__('tree', calls['tree'] + 1), real[1](*a, **k))[1]
-pages.enable = lambda *a, **k: (calls.__setitem__('enable', calls['enable'] + 1), real[2](*a, **k))[1]
-parallel.make_mesh(dp=-1)
-model = gpt_mod.GPTForCausalLM(gpt_mod.gpt_tiny_config())
-mx.random.seed(0); model.initialize()
-srv = serve.Server(model, slots=2)
-r = srv.submit(np.arange(6, dtype=np.int32), max_new_tokens=4)
-srv.drain()
-srv.stop()
-pages.PagePool, pages.PrefixTree, pages.enable = real
-assert r.state == serve.DONE
-assert calls == {'pool': 0, 'tree': 0, 'enable': 0}, calls
-assert not pages.enabled(), 'dense serving armed mx.pages'
-st = srv.stats()
-assert 'prefix_hit_rate' not in st and 'pool_pages_total' not in st, \
-    sorted(st)
-print('pages disabled fast path OK (no pool, no tree, no paged stats)')
-"
-    # shared-prefix smoke: pages=on must emit BIT-IDENTICAL token
-    # streams to the dense path on prompts sharing a prefix, with the
+    # shared-prefix smoke: the server must emit BIT-IDENTICAL token
+    # streams to model.generate on prompts sharing a prefix, with the
     # prefix tree actually reusing blocks (hit rate > 0) and prefill
     # running chunked (fewer dispatches than prompt tokens)
     JAX_PLATFORMS=cpu python -c "
@@ -824,17 +793,16 @@ rng = np.random.RandomState(7)
 pre = rng.randint(0, 128, (16,)).astype(np.int32)
 prompts = [np.concatenate([pre, rng.randint(0, 128, (n,)).astype(np.int32)])
            for n in (3, 5, 2, 6)]
-def run(**kw):
-    srv = serve.Server(model, slots=2, **kw)
-    reqs = [srv.submit(p, max_new_tokens=6) for p in prompts]
-    srv.drain()
-    st = srv.stats()
-    srv.stop()
-    assert all(r.state == serve.DONE for r in reqs), [r.verdict for r in reqs]
-    return [list(r.tokens) for r in reqs], st
-dense, _ = run()
-paged, st = run(pages='on', page_size=8, prefill_chunk=4)
-assert paged == dense, 'paged tokens diverged from dense'
+srv = serve.Server(model, slots=2, page_size=8, prefill_chunk=4)
+reqs = [srv.submit(p, max_new_tokens=6) for p in prompts]
+srv.drain()
+st = srv.stats()
+srv.stop()
+assert all(r.state == serve.DONE for r in reqs), [r.verdict for r in reqs]
+want = [model.generate(p[None], max_new_tokens=6, on_device=False)[0].tolist()
+        for p in prompts]
+assert [list(r.tokens) for r in reqs] == want, \
+    'served tokens diverged from model.generate'
 assert st['prefix_hit_rate'] > 0, st['prefix_hit_rate']
 assert st['chunk_dispatches'] < st['prompt_tokens'], \
     (st['chunk_dispatches'], st['prompt_tokens'])

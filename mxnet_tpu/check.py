@@ -98,7 +98,7 @@ RULES = {
     "degenerate-sharding": "large fully-replicated params or batch "
                            "inputs on a mesh whose data axes span >1 "
                            "device (every device holds the full array)",
-    "degenerate-paging": "a pages=on server whose page size exceeds its "
+    "degenerate-paging": "a server whose page size exceeds its "
                          "smallest bucket (prefix sharing can never "
                          "engage) or whose drafter's vocabulary differs "
                          "from the target's (speculative proposals are "
@@ -752,7 +752,7 @@ def _lint_sharding(trainer, name, key, batch):
 
 def lint_paging(location, page_size, min_bucket, target_vocab,
                 drafter_vocab=None):
-    """Degenerate paging configuration lint, run once at pages=on
+    """Degenerate paging configuration lint, run once at
     Server construction (mirrors `degenerate-sharding`: a setup that
     silently voids the feature's benefit rather than crashing).
 

@@ -623,18 +623,6 @@ register_option(
     "model's max_length — either way a stream of novel request lengths "
     "compiles at most one step executable per bucket.")
 register_option(
-    "pages", "off", choices=("off", "on"),
-    doc="mx.pages paged KV serving. 'off' (default) keeps mx.serve on "
-        "its dense per-bucket slot caches — the zero-overhead fast "
-        "path (no pool, no tree, no paged code on the dispatch path; "
-        "asserted by ci/run.sh pages). 'on' replaces them with a "
-        "block-granular refcounted page pool plus a content-hashed "
-        "prefix tree: shared prompt prefixes prefill once, prompts "
-        "prefill in chunks of pages_prefill_chunk tokens per dispatch, "
-        "and a drafter model (Server(drafter=...)) adds exact-greedy "
-        "speculative decoding. Emitted tokens are bit-identical to "
-        "pages=off.")
-register_option(
     "fleet", "off", choices=("off", "on"),
     doc="mx.fleet replicated serving. 'off' (default) is the zero-"
         "overhead fast path: no replica endpoint, no router, no fleet "
@@ -700,32 +688,33 @@ register_option(
     "idle poll cannot flap the replica count.")
 register_option(
     "pages_page_size", 16,
-    "Tokens per mx.pages KV page. Paged buckets round up to a page "
-    "multiple (and the servable max_length rounds down to one), so a "
-    "bucket's gathered KV equals the dense cache's shape exactly. "
+    "Tokens per mx.pages KV page, the unit mx.serve's pool hands out "
+    "and its prefix tree shares. Buckets round up to a page multiple "
+    "(and the servable max_length rounds down to one), so a bucket's "
+    "page table covers it exactly. "
     "Smaller pages share prefixes at finer grain but deepen the "
     "per-step page-table walk; keep it at or below the smallest "
     "bucket (mx.check 'degenerate-paging' flags the inversion).")
 register_option(
     "pages_pool_pages", 0,
     "Data pages in the mx.pages pool (scratch pages for masked rows "
-    "are added on top, one per slot). 0 (default) sizes the pool to "
-    "slots * max_length/page_size — the dense scheduler's worst-case "
-    "KV footprint, so pages-vs-dense comparisons run at equal memory "
-    "budget. Admission under an exhausted pool walks the same "
-    "degradation ladder as the dense byte budget: evict unreferenced "
-    "prefix-tree leaves, shrink, evict-and-requeue, reject.")
+    "are added on top, one per slot), allocated once when a "
+    "serve.Server is built. 0 (default) sizes the pool to "
+    "slots * max_length/page_size: every slot at the longest servable "
+    "length. Admission under an exhausted pool walks the degradation "
+    "ladder: evict unreferenced prefix-tree leaves, shrink, "
+    "evict-and-requeue, reject.")
 register_option(
     "pages_prefill_chunk", 8,
-    "Prompt tokens per batched-prefill dispatch under pages=on. Each "
+    "Prompt tokens mx.serve feeds per batched-prefill dispatch. Each "
     "bucket compiles one chunk executable (a lax.scan of the one-token "
     "step, bit-identical to feeding tokens singly) — prompts reach "
-    "their first sampled token in ~1/chunk the dispatches of the "
-    "dense path's one-token prefill.")
+    "their first sampled token in ~1/chunk the dispatches of a "
+    "one-token prefill.")
 register_option(
     "pages_spec_k", 4,
-    "Draft tokens per speculative decoding round (pages=on with a "
-    "drafter). The drafter chains k greedy proposals, the target "
+    "Draft tokens per speculative decoding round (a serve.Server "
+    "with a drafter). The drafter chains k greedy proposals, the target "
     "verifies all of them plus the bonus token in one k+1-token "
     "chunk, and exact acceptance keeps the longest agreeing prefix — "
     "the emitted stream stays bit-identical to plain greedy decode, "

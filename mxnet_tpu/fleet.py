@@ -585,9 +585,8 @@ class Router:
     def predict_429(statusz, need):
         """True when the replica's PUBLISHED admission hints predict a
         429 for a request of `need` total tokens (prompt + max_new):
-        the dense bucket it would newly allocate costs more than the
-        published memsafe headroom, or — paged — the pool lacks the
-        pages. Unknown headroom (memsafe off) predicts nothing."""
+        the pool lacks the pages. Unknown headroom (memsafe off)
+        predicts nothing."""
         hints = (statusz or {}).get("admission") or {}
         max_len = hints.get("max_len")
         if max_len and need > int(max_len):
@@ -595,33 +594,11 @@ class Router:
         headroom = hints.get("headroom_bytes")
         if headroom is None:
             return False
-        if hints.get("pages") == "on":
-            ps = int(hints.get("page_size") or 0)
-            free = hints.get("pool_pages_free")
-            if ps and free is not None:
-                return (need + ps - 1) // ps > int(free)
-            return False
-        buckets = hints.get("buckets")
-        if buckets:
-            cands = [int(b) for b in buckets if int(b) >= need]
-            if not cands:
-                return True
-            bucket = min(cands)
-        else:
-            bucket = 1
-            while bucket < need:
-                bucket *= 2
-            if max_len:
-                bucket = min(bucket, int(max_len))
-        allocated = set(int(b) for b in
-                        (statusz.get("stats", {})
-                         .get("buckets_allocated") or []))
-        if bucket in allocated:
-            return False                     # cache exists; no new cost
-        cost = (hints.get("bucket_cost") or {}).get(str(bucket))
-        if cost is None:
-            return False
-        return int(cost) > int(headroom)
+        ps = int(hints.get("page_size") or 0)
+        free = hints.get("pool_pages_free")
+        if ps and free is not None:
+            return (need + ps - 1) // ps > int(free)
+        return False
 
     # -- placement -------------------------------------------------------
     def _place(self, need, exclude=()):

@@ -19,18 +19,14 @@ from ..gluon import HybridBlock
 #                            width)` for each (GPT: `(heads, head_dim, dt)`,
 #                            K per layer then V; a latent cache: `(width,
 #                            dt)`, no head axis)
-#   dense_streams            `(heads, head_dim, dtype)` per dense cache of
-#                            the `pages="off"` path, `(slots, heads, bucket,
-#                            head_dim)` each; None: the model serves paged
-#                            only
 #   index_topk               tokens a learned sparse attention keeps, None
 #                            for full attention (the `sparse_tokens` counter)
 #   chunk_step               `decode_paged_chunk(toks, t0, n, tables, flat,
 #                            page_size, full=)`
 #   draft_step               `decode_paged_draft(...)`, None: cannot draft
 ServingSpec = collections.namedtuple(
-    "ServingSpec", "vocab_size max_length streams dense_streams "
-    "index_topk chunk_step draft_step")
+    "ServingSpec", "vocab_size max_length streams index_topk chunk_step "
+    "draft_step")
 
 
 def paged_write_targets(pos_d, active_d, tb_d, page_size):
@@ -89,55 +85,18 @@ def cached_self_attention_step(q, k_new, v_new, k_cache, v_cache, t):
     return apply_op(f, q, k_new, v_new, k_cache, v_cache, t)
 
 
-def batched_cached_attention_step(q, k_new, v_new, k_cache, v_cache, t):
-    """`cached_self_attention_step` with PER-ROW positions — the
-    continuous-batching variant mx.serve's decode slots need: row b
-    writes its K/V at its own position t[b] and attends over positions
-    <= t[b]. The math per row is exactly the scalar-t version's
-    (f32 score/softmax/PV accumulation), so a request's logits do not
-    depend on what the other slots are doing — the property mx.serve's
-    bit-identical-under-load guarantee rests on.
-
-    q/k_new/v_new (B,H,1,D); caches (B,H,Lmax,D); t (B,) traced int.
-    Returns (out (B,1,H*D), new_k, new_v)."""
-    import jax
-    import jax.numpy as jnp
-    from jax import lax
-
-    from ..ndarray import apply_op
-
-    def f(q_, kn, vn, kc, vc, tt):
-        ti = tt.astype(jnp.int32)                      # (B,)
-
-        def write(c, n, t1):                           # (H,L,D),(H,1,D)
-            return lax.dynamic_update_slice(c, n.astype(c.dtype),
-                                            (0, t1, 0))
-
-        kc = jax.vmap(write)(kc, kn, ti)
-        vc = jax.vmap(write)(vc, vn, ti)
-        B, H, _, D = q_.shape
-        s = jnp.einsum("bhqd,bhkd->bhqk", q_.astype(jnp.float32),
-                       kc.astype(jnp.float32)) / (D ** 0.5)
-        valid = jnp.arange(kc.shape[2])[None, None, None, :] \
-            <= ti[:, None, None, None]
-        s = jnp.where(valid, s, -1e30)
-        p = jax.nn.softmax(s, axis=-1)
-        o = jnp.einsum("bhqk,bhkd->bhqd", p,
-                       vc.astype(jnp.float32)).astype(q_.dtype)
-        return o.transpose(0, 2, 1, 3).reshape(B, 1, H * D), kc, vc
-
-    return apply_op(f, q, k_new, v_new, k_cache, v_cache, t)
-
-
 def paged_attention_step(q, k_new, v_new, k_pages, v_pages, tables, wp, wo,
                          t):
-    """`batched_cached_attention_step` over an mx.pages block-table
-    cache: row b writes this token's K/V into page wp[b] at in-page
-    offset wo[b] and attends over positions <= t[b] gathered through its
-    page table. The attention math is `pallas_ops.paged_attention`,
-    whose XLA fallback is VERBATIM the dense step's f32
-    score/softmax/PV expression at the gathered (B,H,L,D) shapes — the
-    pages=on bit-identity guarantee composes from there.
+    """`cached_self_attention_step` with PER-ROW positions over an
+    mx.pages block-table cache — the continuous-batching variant
+    mx.serve's decode slots need: row b writes this token's K/V into
+    page wp[b] at in-page offset wo[b] and attends over positions <=
+    t[b] gathered through its page table. The attention math is
+    `pallas_ops.paged_attention`, whose XLA fallback is the scalar-t
+    version's f32 score/softmax/PV expression at the gathered (B,H,L,D)
+    shapes, so a request's logits do not depend on what the other slots
+    are doing — the property mx.serve's same-under-load-as-alone
+    guarantee rests on.
 
     The write is `pallas_ops.kv_page_write`: where the paged kernels run
     (one device) it replaces row wo[b] of page wp[b] in place, in the

@@ -232,7 +232,7 @@ class GLMLayer(HybridBlock):
 
 class GLMForCausalLM(HybridBlock):
     """Token ids -> logits over this chip's slice of the vocabulary,
-    through `serve.Server(pages="on")`."""
+    through `serve.Server`."""
 
     def __init__(self, cfg, **kwargs):
         super().__init__(**kwargs)
@@ -250,7 +250,7 @@ class GLMForCausalLM(HybridBlock):
 
     def forward(self, *args):
         raise NotImplementedError(
-            "GLMForCausalLM is served through serve.Server(pages='on'); "
+            "GLMForCausalLM is served through serve.Server; "
             "chipbench/reference/glm5.py is the full forward pass")
 
     # -- what serve.Server asks ------------------------------------------
@@ -264,7 +264,7 @@ class GLMForCausalLM(HybridBlock):
             vocab_size=cfg["vocab_size"],
             max_length=cfg["max_position_embeddings"],
             streams=[lat] * n + [(cfg["index_head_dim"], dt)] * n,
-            dense_streams=None, index_topk=cfg["index_topk"],
+            index_topk=cfg["index_topk"],
             chunk_step=self.decode_paged_chunk, draft_step=None)
 
     def layer_weights(self):

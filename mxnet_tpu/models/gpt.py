@@ -122,92 +122,52 @@ class GPTBlock(HybridBlock):
                                       act_type="gelu"))
         return x + h, k_cache, v_cache
 
+    def _token_step(self, x, attend):
+        """One token through the block, the cache access left to
+        `attend(q, k_new, v_new) -> (o (B,1,E), new_k, new_v)`: the body
+        `step` and `step_slots_paged` share. x (B,1,E)."""
+        from ..ndarray import apply_op
+
+        attn = self.attn
+        H = attn._num_heads
+        qkv = attn.qkv(self.ln1(x))             # (B, 1, 3E)
+        B, _, E3 = qkv.shape
+        D = E3 // 3 // H
+
+        def split(qkv_d):
+            r = qkv_d.reshape(B, 1, 3, H, D)
+            return (r[:, :, 0].transpose(0, 2, 1, 3),
+                    r[:, :, 1].transpose(0, 2, 1, 3),
+                    r[:, :, 2].transpose(0, 2, 1, 3))   # (B,H,1,D) each
+
+        q, k_new, v_new = apply_op(split, qkv)
+        o, k_cache, v_cache = attend(q, k_new, v_new)
+        x = x + attn.proj(o)
+        h2 = self.ffn_out(F.Activation(self.ffn_in(self.ln2(x)),
+                                       act_type="gelu"))
+        return x + h2, k_cache, v_cache
+
     def step(self, x, k_cache, v_cache, t):
         """One-token incremental step against a static-shape KV cache
         (inference; same scheme as transformer.TransformerLayer.step).
         x (B,1,E); caches (B,H,Lmax,D); t traced scalar — one compile
         serves every position."""
-        from ..ndarray import apply_op
         from ._decode import cached_self_attention_step
-
-        attn = self.attn
-        H = attn._num_heads
-        qkv = attn.qkv(self.ln1(x))             # (B, 1, 3E)
-        B, _, E3 = qkv.shape
-        D = E3 // 3 // H
-
-        def split(qkv_d):
-            r = qkv_d.reshape(B, 1, 3, H, D)
-            return (r[:, :, 0].transpose(0, 2, 1, 3),
-                    r[:, :, 1].transpose(0, 2, 1, 3),
-                    r[:, :, 2].transpose(0, 2, 1, 3))   # (B,H,1,D) each
-
-        q, k_new, v_new = apply_op(split, qkv)
-        o, k_cache, v_cache = cached_self_attention_step(
-            q, k_new, v_new, k_cache, v_cache, t)
-        x = x + attn.proj(o)
-        h2 = self.ffn_out(F.Activation(self.ffn_in(self.ln2(x)),
-                                       act_type="gelu"))
-        return x + h2, k_cache, v_cache
-
-    def step_slots(self, x, k_cache, v_cache, t):
-        """`step` with PER-SLOT positions t (B,) — the mx.serve
-        continuous-batching variant: each batch row is an independent
-        request at its own decode position. Row math is identical to
-        `step`'s, so a row's output never depends on its neighbors."""
-        from ..ndarray import apply_op
-        from ._decode import batched_cached_attention_step
-
-        attn = self.attn
-        H = attn._num_heads
-        qkv = attn.qkv(self.ln1(x))             # (B, 1, 3E)
-        B, _, E3 = qkv.shape
-        D = E3 // 3 // H
-
-        def split(qkv_d):
-            r = qkv_d.reshape(B, 1, 3, H, D)
-            return (r[:, :, 0].transpose(0, 2, 1, 3),
-                    r[:, :, 1].transpose(0, 2, 1, 3),
-                    r[:, :, 2].transpose(0, 2, 1, 3))   # (B,H,1,D) each
-
-        q, k_new, v_new = apply_op(split, qkv)
-        o, k_cache, v_cache = batched_cached_attention_step(
-            q, k_new, v_new, k_cache, v_cache, t)
-        x = x + attn.proj(o)
-        h2 = self.ffn_out(F.Activation(self.ffn_in(self.ln2(x)),
-                                       act_type="gelu"))
-        return x + h2, k_cache, v_cache
+        return self._token_step(
+            x, lambda q, k, v: cached_self_attention_step(
+                q, k, v, k_cache, v_cache, t))
 
     def step_slots_paged(self, x, k_pages, v_pages, tables, wp, wo, t):
-        """`step_slots` against an mx.pages block-table cache: the K/V
-        write lands in page wp[b] offset wo[b] instead of a dense slot
-        row, and attention gathers through tables (B,n_pg). Everything
-        around the cache access — qkv projection, split, proj, FFN — is
-        VERBATIM `step_slots`, and `paged_attention_step`'s fallback is
-        the dense step's attention math at the gathered shapes, which is
-        what makes pages=on serving bit-identical to pages=off."""
-        from ..ndarray import apply_op
+        """`step` with PER-ROW positions t (B,) against an mx.pages
+        block-table cache — mx.serve's continuous-batching step: each
+        batch row is an independent request at its own position, its K/V
+        write lands in page wp[b] offset wo[b], and attention gathers
+        through tables (B,n_pg). Row math is `step`'s, so a row's output
+        never depends on its neighbours."""
         from ._decode import paged_attention_step
-
-        attn = self.attn
-        H = attn._num_heads
-        qkv = attn.qkv(self.ln1(x))             # (B, 1, 3E)
-        B, _, E3 = qkv.shape
-        D = E3 // 3 // H
-
-        def split(qkv_d):
-            r = qkv_d.reshape(B, 1, 3, H, D)
-            return (r[:, :, 0].transpose(0, 2, 1, 3),
-                    r[:, :, 1].transpose(0, 2, 1, 3),
-                    r[:, :, 2].transpose(0, 2, 1, 3))   # (B,H,1,D) each
-
-        q, k_new, v_new = apply_op(split, qkv)
-        o, k_pages, v_pages = paged_attention_step(
-            q, k_new, v_new, k_pages, v_pages, tables, wp, wo, t)
-        x = x + attn.proj(o)
-        h2 = self.ffn_out(F.Activation(self.ffn_in(self.ln2(x)),
-                                       act_type="gelu"))
-        return x + h2, k_pages, v_pages
+        return self._token_step(
+            x, lambda q, k, v: paged_attention_step(
+                q, k, v, k_pages, v_pages, tables, wp, wo, t))
 
 
 class GPTModel(HybridBlock):
@@ -329,36 +289,10 @@ class GPTForCausalLM(HybridBlock):
             x, g.word_embed.weight.data())
         return logits.reshape(shape=(tok.shape[0], -1)), new_k, new_v
 
-    def decode_step_slots(self, tok, t, self_k, self_v):
-        """`decode_step` with PER-SLOT positions: tok (B,) int32, t (B,)
-        traced int32 — batch row b is an independent request at its own
-        position t[b] (mx.serve's continuous-batching decode). Returns
-        (logits (B,V), new_self_k, new_self_v); one compile serves every
-        position mix in a (B, cache-length) bucket."""
-        import jax.numpy as jnp
-        from ..ndarray import apply_op
-
-        g = self.gpt
-        x = g.word_embed(tok.reshape(shape=(-1, 1)))
-        pos = apply_op(
-            lambda pe, tt: pe[tt.astype(jnp.int32)][:, None, :],
-            NDArray(g.position_embed.data()._data), t)
-        x = x + pos
-        new_k, new_v = [], []
-        for i, layer in enumerate(g.layers):
-            x, k, v = layer.step_slots(x, self_k[i], self_v[i], t)
-            new_k.append(k)
-            new_v.append(v)
-        x = g.ln_f(x)
-        logits = apply_op(
-            lambda hh, w: jnp.matmul(hh, w.T.astype(hh.dtype)),
-            x, g.word_embed.weight.data())
-        return logits.reshape(shape=(tok.shape[0], -1)), new_k, new_v
-
     # -- paged decode (mx.pages block-table cache) -------------------------
     def _paged_token_step(self, tok_d, pos_d, tb_d, wp_d, wo_d, ks, vs):
         """Raw-jax one-token paged step (the lax.scan body of the chunk
-        and draft programs): the EXACT `decode_step_slots` computation —
+        and draft programs): `decode_step` with per-row positions —
         embed + pe[pos] + layer stack + ln_f + tied logits — with the
         layers' cache access routed through `step_slots_paged`. Takes and
         returns raw arrays (scan carries); ks/vs are tuples of the
@@ -406,7 +340,7 @@ class GPTForCausalLM(HybridBlock):
         kv = [(heads, units // heads, dtype)] * (2 * n_l)
         return ServingSpec(
             vocab_size=int(vocab), max_length=int(g.position_embed.shape[0]),
-            streams=kv, dense_streams=kv, index_topk=None,
+            streams=kv, index_topk=None,
             chunk_step=self.decode_paged_chunk,
             draft_step=self.decode_paged_draft)
 
@@ -416,13 +350,12 @@ class GPTForCausalLM(HybridBlock):
         its n[b] tokens toks[b, :n[b]] at positions t0[b].. — many prompt
         tokens per dispatch (batched prefill) or one (steady decode), in
         ONE executable per (bucket, chunk) shape. The body is a lax.scan
-        of C structurally identical one-token steps, each exactly the
-        dense `decode_step_slots` computation, so a chunk's logits are
-        bit-identical to feeding the same tokens one dispatch at a time.
+        of C structurally identical one-token steps
+        (`_paged_token_step`), so a chunk's logits are bit-identical to
+        feeding the same tokens one dispatch at a time.
 
         Rows past their count (j >= n[b]) run masked: writes land in the
-        row's scratch page and their logits are discarded — mirroring the
-        dense path's harmless pad-slot pollution argument.
+        row's scratch page and their logits are discarded.
 
         toks (B,C) int32; t0/n (B,) int32; tables (B,n_pg) int32; flat =
         2*n_l pooled page arrays (K per layer, then V). Returns

@@ -1,10 +1,10 @@
 """mx.pages — block-granular paged KV cache with prefix reuse.
 
-mx.serve's dense scheduler (PR 12) gives every request a slot in a
-(slots, H, bucket, D) cache per layer: memory is owned per-slot, whole
-prompts prefill one token per step, and two requests sharing a system
-prefix each recompute and store it. This module is the vLLM/
-PagedAttention answer (PAPERS.md 2309.06180) adapted to this runtime:
+mx.serve's cache manager. A dense (slots, H, bucket, D) cache per layer
+owns memory per slot at the bucket's full length, and two requests
+sharing a system prefix each recompute and store it. This module is the
+vLLM/PagedAttention answer (PAPERS.md 2309.06180) adapted to this
+runtime:
 
   * **PagePool** — the KV store is one pooled (pages, H, page_size, D)
     array per layer (or, for a latent cache, (pages, page_size, width)
@@ -12,9 +12,8 @@ PagedAttention answer (PAPERS.md 2309.06180) adapted to this runtime:
     fixed-size pages instead of a dense span. Pages are refcounted: the prefix tree and every
     request sharing a block hold one reference each, and a page returns
     to the free list when the last reference drops. The pool is sized
-    once at server construction and priced through the same
-    mx.memsafe admission path as the dense caches
-    (`Server._admit_budget` / `aot_exec_peak`).
+    once at server construction and priced through mx.memsafe at
+    every admission (`Server._admit_budget` / `aot_exec_peak`).
   * **PrefixTree** — a content-hashed radix tree over FULL prompt
     blocks (SGLang-style radix cache). A finished prefill inserts its
     full prompt pages; a later request walks its prompt block-by-block
@@ -40,12 +39,6 @@ speculative decoding) the drafter's arrays too. One allocator, one
 refcount, one page table per request covers the whole model stack.
 Pages `0..scratch-1` are per-slot scratch: masked-out lanes of a
 batched step write there so real pages are never polluted.
-
-Cost model: DISABLED (the default) is the production fast path —
-`pages=off` serving never constructs a pool and never calls into this
-module (ci/run.sh pages asserts zero calls across a full dense request
-lifecycle; the scheduler checks one attribute). Constructing a paged
-`serve.Server` arms it.
 """
 from __future__ import annotations
 
@@ -55,35 +48,14 @@ import itertools
 
 import numpy as np
 
-__all__ = [
-    "PagePool", "PrefixTree", "PagesExhausted",
-    "enable", "disable", "enabled",
-]
-
-_enabled = False
-
-
-def enabled():
-    """True while a paged server is armed (serve.Server(pages='on')
-    constructs the pool and flips this; the off path never reaches this
-    module)."""
-    return _enabled
-
-
-def enable():
-    global _enabled
-    _enabled = True
-
-
-def disable():
-    global _enabled
-    _enabled = False
+__all__ = ["PagePool", "PrefixTree", "PagesExhausted"]
 
 
 class PagesExhausted(RuntimeError):
     """The pool cannot satisfy an allocation — admission control's
     signal to walk the degradation ladder (tree eviction, shrink,
-    evict-and-requeue), never a device OOM."""
+    evict-and-requeue), never a device OOM. `PagePool.alloc` raises it;
+    `serve.Server._seat` returns one as its refusal."""
 
     def __init__(self, need, free):
         self.need = int(need)

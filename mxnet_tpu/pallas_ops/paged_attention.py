@@ -16,11 +16,10 @@ exists. The walk ends at the row's position: a row at token 40 of a
 256-token bucket brings and reduces 3 pages, not 16.
 
 Fallback (`kernels=off`, non-TPU without the interpreter): the gather +
-the EXACT dense per-row attention expression
-(`models/_decode.batched_cached_attention_step`'s f32 score/softmax/PV
-math) — when the page tables tile a contiguous [0, L) range this is
-bit-identical to the dense slot cache path, which is what serve's
-pages=on-vs-off bit-identity guarantee rests on.
+the per-row attention expression of a dense (B,H,L,D) cache
+(`models/_decode.cached_self_attention_step`'s f32 score/softmax/PV
+math with a position a row) — what the tests hold the served tokens to
+`model.generate`'s with.
 """
 from __future__ import annotations
 
@@ -45,9 +44,10 @@ def paged_attention_reference(q, k_pages, v_pages, tables, t):
 
     Gathers the pages into the dense (B,H,L,D) layout (L = n_pg*ps) and
     then runs VERBATIM the masked f32 score/softmax/PV expression of the
-    dense slot-cache step — identical operand shapes, identical
-    reductions, so a paged cache whose tables enumerate a sequence's
-    pages in order produces bit-identical logits to the dense cache."""
+    dense-cache step (`models/_decode.cached_self_attention_step`, with
+    a position a row) — identical reductions, so a paged cache whose
+    tables enumerate a sequence's pages in order produces the logits of
+    a dense cache of the same length."""
     ti = t.astype(jnp.int32)
     if k_pages.shape[3] != q.shape[3]:      # lane-padded arenas
         k_pages = k_pages[..., :q.shape[3]]
@@ -263,8 +263,8 @@ def paged_attention(q, k_pages, v_pages, tables, t):
       t: (B,) traced int — row b attends positions <= t[b].
 
     Returns (B, H, 1, D) in q.dtype. `kernels=off` (or no
-    TPU/interpreter) runs `paged_attention_reference` — bit-identical to
-    the dense slot-cache attention at the same gathered shapes. Like the
+    TPU/interpreter) runs `paged_attention_reference` — the dense-cache
+    attention expression at the gathered shapes. Like the
     fused-update kernels, the Pallas path is a global-view
     `pallas_call` with no GSPMD rule, so it engages only when the step
     sees a single device (serve's decode regime)."""

@@ -2,37 +2,54 @@
 
 The training runtime is production-grade (elastic, never-OOM, guarded)
 but a model that cannot answer a request serves nobody. This module is
-the request path, built robustness-first over the existing donated-KV
-decode machinery (`models/_decode.jit_flat_step`): a continuous-batching
-decode scheduler that never device-OOMs, never wedges on a slow client,
-and sheds load gracefully instead of falling over.
+the request path: a continuous-batching decode scheduler over ONE cache
+manager, the mx.pages page pool, that never device-OOMs, never wedges
+on a slow client, and sheds load gracefully instead of falling over.
 
-Mechanics — the Orca-style token-level continuous batching loop:
+Mechanics — the Orca-style token-level continuous batching loop over a
+vLLM-style block table:
 
-  * **fixed batch slots, bucketed KV caches** — requests are grouped by
-    the `dataflow.bucket_length` bucket of their total length
-    (prompt + max_new_tokens); each active bucket owns one KV cache of
-    shape (slots, H, bucket, D) per layer and ONE step executable
-    (per-slot positions, `GPTForCausalLM.decode_step_slots`), so a
-    stream of novel lengths compiles at most one executable per bucket
-    — never one per length. Caches are allocated when a bucket first
-    admits and freed when it drains ("pages" reclaimed).
-  * **admit/evict per decode step** — every scheduler step evicts
-    expired slots, admits queued requests into free slots, runs one
-    batched decode step per active bucket (prompt tokens are fed
-    through the same step: prefill IS decode, so under-load and
-    unloaded requests run the SAME executable and their outputs are
-    bit-identical), and streams freshly sampled tokens to each
-    request's consumer.
+  * **one page pool, allocated at construction** — the KV store is
+    `pages.PagePool`: refcounted fixed-size pages in one pooled arena
+    per cache stream of the model (`model.serving_spec().streams`),
+    `slots * max_len / page_size` pages unless `pool_pages` says
+    otherwise. A request owns the LIST of pages its prompt + token
+    budget needs, not a bucket-wide span.
+  * **a prefix tree** — `pages.PrefixTree`, content-hashed over full
+    prompt blocks: a finished prefill registers its pages, a later
+    request with the same prefix starts past it with those pages mapped
+    into its table (copy-on-write when its first write would land in a
+    shared page).
+  * **fixed batch slots, bucketed tables** — requests are grouped by
+    the `dataflow.bucket_length` bucket of their total length (prompt +
+    max_new_tokens, rounded up to whole pages); a bucket fixes the
+    width of the page table its executables take, so a stream of novel
+    lengths compiles a handful of executables per bucket — never one
+    per length.
+  * **the chunk step** — every scheduler step evicts expired slots,
+    admits queued requests into free slots, and runs one batched
+    dispatch per active bucket of the model's `chunk_step`: rows still
+    inside their prompt feed up to `pages_prefill_chunk` tokens, the
+    rest feed the token they sampled last, in one executable per
+    (bucket, chunk length). A row's logits never depend on its
+    neighbours, so a request's tokens are the same under load as alone
+    (the tests hold them to `model.generate` bit for bit on the CPU).
+    Freshly sampled tokens stream to each request's consumer.
+  * **a drafter** (`Server(drafter=...)`) adds draft-verify speculative
+    decoding with exact greedy acceptance: the drafter chains `spec_k`
+    proposals into the pool's `draft` stream, the target verifies them
+    in one `spec_k + 1`-token chunk, the host keeps the longest
+    agreeing prefix. It changes WHEN tokens are computed, never which.
 
 Robustness — the request lifecycle:
 
-  * **admission control** — every accept is gated on AOT KV-cache
-    budgeting (mx.memsafe `check_budget` over the bucket's cache bytes
-    + resident params + the step executable's AOT-compiled execution
-    peak, `jit_flat_step(...).aot_exec_peak`). A predicted overrun is a
-    `429`-style verdict on the request — never a device OOM, never a
-    dispatched predicted-overrun batch.
+  * **admission control** — every accept is gated twice: mx.memsafe
+    `check_budget` over resident parameters + the pool + the bucket's
+    step executable's AOT-compiled execution peak
+    (`jit_flat_step(...).aot_exec_peak`), then the pool's free pages
+    (after evicting unreferenced prefix-tree leaves). A predicted
+    overrun is a `429`-style verdict on the request — never a device
+    OOM, never a dispatched predicted-overrun batch.
   * **bounded queue, backpressure, load shedding** — the submit queue
     holds at most `serve_queue_depth` requests; beyond that the
     `serve_shed` policy rejects the newcomer (`reject`) or displaces
@@ -40,32 +57,22 @@ Robustness — the request lifecycle:
   * **deadlines with mid-generation cancellation** — a request carries
     an absolute deadline (`deadline_ms` or the `serve_deadline_ms`
     default); expired slots are evicted BETWEEN decode steps (partial
-    tokens already streamed stay delivered) and their KV pages
-    reclaimed. `Server.cancel` / the `cancel@req:N` fault do the same
-    on demand.
+    tokens already streamed stay delivered) and their pages returned
+    to the pool. `Server.cancel` / the `cancel@req:N` fault do the
+    same on demand.
   * **retry/backoff on transient dispatch faults** — each batched step
     dispatch runs under `resilience.RetryPolicy` (exponential backoff,
     retryable-exception classification); donated-buffer safety is
     checked before every retry.
-  * **graceful degradation under pressure** — when admission predicts
-    an overrun the server walks a ladder mirroring memsafe's: (1)
-    shrink the request's max_new_tokens to the largest bucket that
-    fits (floored at `serve_min_new_tokens`), (2) evict-and-requeue
-    the YOUNGEST running request (its replay is deterministic, already
-    -streamed tokens are not re-sent), each transition annotated in
-    telemetry, then (3) reject with the budget accounting only when
-    the request cannot fit even alone.
-
-Paged serving (`pages=on`, PR 18): the dense per-bucket caches are
-replaced by the mx.pages block-table pool — refcounted fixed-size KV
-pages, a content-hashed prefix tree so shared prompt prefixes prefill
-once, chunked prefill (many prompt tokens per dispatch), and optional
-draft-verify speculative decoding with exact greedy acceptance. The
-`pages=off` default never touches any of it: admission, placement and
-decode run the exact dense code above (ci/run.sh `pages` asserts zero
-mx.pages calls across a dense request lifecycle), and pages=on output
-is bit-identical to pages=off — prefix reuse, chunking and speculation
-change WHEN cache entries are computed, never their values.
+  * **graceful degradation under pressure** — one ladder, whichever
+    refusal (bytes or pages) started it: (1) shrink the request's
+    max_new_tokens to the largest smaller bucket it can be seated in
+    (floored at `serve_min_new_tokens`), (2) when pages are what is
+    short, evict-and-requeue the YOUNGEST running request (its replay
+    is deterministic, already-streamed tokens are not re-sent), each
+    transition annotated in telemetry, then (3) reject with the
+    accounting only when nothing is running whose pages a wait could
+    free.
 
 Every path is deterministically testable: `resilience.FaultInjector`
 grows `slow_client:ms` (stream consumer stalls; the scheduler must not
@@ -368,54 +375,28 @@ class Request:
 
 
 # ---------------------------------------------------------------------------
-# bucket group: one KV cache + one executable per total-length bucket
+# bucket group: the seats of one total-length bucket
 # ---------------------------------------------------------------------------
 
 class _Group:
-    """The decode state for one length bucket: `slots` requests sharing
-    one set of (slots, H, bucket, D) KV caches and one per-slot-position
-    step executable. `pos[i]` is the next position slot i writes — while
-    `pos < len(prompt)` the slot is prefilling (prompt tokens fed through
-    the same step), after that it consumes its own sampled tokens."""
-
-    __slots__ = ("bucket", "run", "slots", "pos", "caches", "cache_bytes")
-
-    def __init__(self, bucket, run, caches):
-        self.bucket = bucket
-        self.run = run
-        self.caches = caches
-        self.cache_bytes = sum(int(c.nbytes) for c in caches)
-        n = int(caches[0].shape[0])     # slots = the cache leading axis
-        self.slots = [None] * n
-        self.pos = [0] * n
-
-    def free_slot(self):
-        for i, r in enumerate(self.slots):
-            if r is None:
-                return i
-        return None
-
-    def active(self):
-        return [i for i, r in enumerate(self.slots) if r is not None]
-
-
-class _PagedGroup:
-    """The paged counterpart of `_Group`: same bucket/slots/pos duck
-    type for the scheduler, but no dense caches — slot i owns a LIST of
+    """The decode state for one length bucket: `slots` requests whose
+    page tables are `n_pg = bucket // page_size` wide, so they share the
+    bucket's step executables. It holds no cache — the pool is allocated
+    once at server construction and priced there. Slot i owns a LIST of
     mx.pages page ids (`pages[i]`, one pool reference each) whose order
-    IS its page table. `cache_bytes` is 0 because the pool is allocated
-    once at server construction and priced there, not per bucket.
-    `matched[i]` records how many prompt tokens arrived pre-filled from
-    the prefix tree; `inserted[i]` latches the one-time tree insertion
-    after the slot's prefill completes."""
+    IS its page table. `pos[i]` is the next position slot i writes —
+    while `pos < len(prompt)` the slot is prefilling, after that it
+    consumes its own sampled tokens. `matched[i]` records how many
+    prompt tokens arrived pre-filled from the prefix tree; `inserted[i]`
+    latches the one-time tree insertion after the slot's prefill
+    completes."""
 
     __slots__ = ("bucket", "n_pg", "slots", "pos", "pages", "matched",
-                 "inserted", "cache_bytes")
+                 "inserted")
 
     def __init__(self, bucket, n_slots, n_pg):
         self.bucket = bucket
         self.n_pg = n_pg
-        self.cache_bytes = 0
         self.slots = [None] * n_slots
         self.pos = [0] * n_slots
         self.pages = [[] for _ in range(n_slots)]
@@ -438,12 +419,11 @@ class _PagedGroup:
 
 class Server:
     """Continuous-batching inference server over one autoregressive
-    model. What it needs of the model it asks through
+    model and one page pool. What it needs of the model it asks through
     `model.serving_spec()` (`models/_decode.ServingSpec`: vocabulary,
-    longest position, the cache streams with their dtypes, the chunk step);
-    the dense path (`pages="off"`) is the `GPTForCausalLM` surface
-    (`decode_step_slots` + `_alloc_caches`), and a model whose spec has
-    no `dense_streams` is served paged only.
+    longest position, the cache streams with their dtypes, the chunk
+    step); the pool (`pages.PagePool`) and its prefix tree are built
+    here, once, from `page_size`/`pool_pages` (the `pages_*` knobs).
 
     `submit()` never raises for overload — rejection, shedding and
     expiry are VERDICTS on the returned Request, so the scheduler loop
@@ -452,26 +432,27 @@ class Server:
     `drain()` (tests inject `clock=` for deterministic deadlines).
 
     `slots`/`queue_depth`/`shed`/`default_deadline_ms`/`buckets` default
-    to the `serve_*` knobs. `on_burst(n)`, when set, is how the
-    `burst:N@step:K` fault spec materializes synthetic load."""
+    to the `serve_*` knobs, `page_size`/`pool_pages`/`prefill_chunk`/
+    `spec_k` to the `pages_*` ones. `pages` is what is left of a switch
+    between this server and a dense per-bucket one that is gone: None
+    and "on" are accepted (callers' configuration files pass "on").
+    `on_burst(n)`, when set, is how the `burst:N@step:K` fault spec
+    materializes synthetic load."""
 
     def __init__(self, model, slots=None, queue_depth=None, shed=None,
                  default_deadline_ms=None, buckets=None, max_len=None,
                  clock=None, retry=None, pages=None, drafter=None,
                  page_size=None, pool_pages=None, prefill_chunk=None,
                  spec_k=None):
+        if pages not in (None, "on"):
+            raise ValueError(
+                f"pages={pages!r}: the dense per-bucket serving path is "
+                "gone and every model is served through the page pool — "
+                "drop the keyword (None and 'on' are accepted)")
         enable()
         self.model = model
         self._spec = spec = model.serving_spec()
         self._max_len = int(max_len or spec.max_length)
-        pages = pages if pages is not None else _config.get("pages")
-        if pages not in ("off", "on"):
-            raise ValueError(f"pages must be 'off' or 'on', got {pages!r}")
-        self._paged = pages == "on"
-        if not self._paged and spec.dense_streams is None:
-            raise ValueError(
-                f"{type(model).__name__} is served through the page pool "
-                "only: pass pages='on'")
         self._drafter = drafter
         self._draft_spec = None
         if drafter is not None:
@@ -498,7 +479,7 @@ class Server:
         self._lock = _locklint.make_rlock("serve.server")
         self._queue = collections.deque()
         self._groups = {}          # bucket -> _Group
-        self._runners = {}         # bucket -> jit_flat_step runner
+        self._runners = {}         # (kind, bucket, ...) -> jit_flat_step runner
         self._exec_peaks = {}      # bucket -> AOT exec-peak bytes (or None)
         self._by_id = {}
         self._pending_cancels = []
@@ -508,12 +489,50 @@ class Server:
             "submitted": 0, "completed": 0, "rejected": 0, "shed": 0,
             "expired": 0, "cancelled": 0, "failed": 0, "tokens": 0,
             "steps": 0, "requeues": 0, "degraded": 0, "retries": 0,
+            "prompt_tokens": 0, "prefix_tokens": 0, "prefix_hits": 0,
+            "chunk_dispatches": 0, "chunk_steps": 0, "token_steps": 0,
+            "spec_rounds": 0,
+            "drafts_proposed": 0, "drafts_accepted": 0,
+            # what attention was fed, worked out from positions alone:
+            # tokens, the sum of their context lengths, of the contexts
+            # cut to `index_topk`, and the tokens whose context passed it
+            "attn_tokens": 0, "attn_ctx_tokens": 0, "attn_sel_tokens": 0,
+            "sparse_tokens": 0,
         }
-        self._params_bytes = self._measure_params()
-        self._pool = None
-        self._tree = None
-        if self._paged:
-            self._init_paged(page_size, pool_pages, prefill_chunk, spec_k)
+        # the pool and its prefix tree. The usable position range rounds
+        # DOWN to a page multiple and buckets round UP to one
+        # (`_bucket_for`), so a bucket's page table covers it exactly.
+        # The default pool holds `slots * max_len/page_size` data pages:
+        # every slot at the longest servable length.
+        ps = int(page_size or _config.get("pages_page_size"))
+        if ps < 1:
+            raise ValueError(f"pages_page_size must be >= 1, got {ps}")
+        self._page_size = ps
+        self._prefill_chunk = max(
+            1, int(prefill_chunk or _config.get("pages_prefill_chunk")))
+        self._spec_k = max(1, int(spec_k or _config.get("pages_spec_k")))
+        if self._max_len < ps:
+            raise ValueError(
+                f"pages_page_size {ps} exceeds the model's max_length "
+                f"{self._max_len} — no position fits a single page")
+        self._max_len = (self._max_len // ps) * ps
+        self._params_bytes = self._measure_params(model)
+        streams = {"target": list(spec.streams)}
+        if drafter is not None:
+            streams["draft"] = list(self._draft_spec.streams)
+            self._params_bytes += self._measure_params(drafter)
+        data = int(pool_pages or _config.get("pages_pool_pages")) \
+            or self._slots * (self._max_len // ps)
+        self._pool = _pages.PagePool(ps, data, self._slots, streams)
+        self._tree = _pages.PrefixTree(self._pool)
+        from . import check as _check
+        if _check._enabled:
+            smallest = self._buckets[0] if self._buckets is not None \
+                else max(1, int(_config.get("bucket_pad_min")))
+            _check.lint_paging(
+                f"serve.Server(page_size={ps})", ps, smallest,
+                spec.vocab_size,
+                None if drafter is None else self._draft_spec.vocab_size)
         self.on_burst = None
         self._thread = None
         self._stop = threading.Event()
@@ -539,72 +558,13 @@ class Server:
                 f"{self._max_len}")
         return bl
 
-    def _measure_params(self):
+    @staticmethod
+    def _measure_params(model):
         try:
-            leaves = [p.data()._data
-                      for p in self.model.collect_params().values()]
-            return _memsafe.resident_bytes(leaves)
+            return _memsafe.resident_bytes(
+                [p.data()._data for p in model.collect_params().values()])
         except Exception:
             return 0
-
-    def _init_paged(self, page_size, pool_pages, prefill_chunk, spec_k):
-        """Construct the mx.pages pool + prefix tree and arm the module.
-
-        The usable position range rounds DOWN to a page multiple and
-        buckets round UP to one (`_bucket_for`), so a paged bucket's
-        gathered KV length n_pg*page_size equals the bucket exactly —
-        the shape identity the pages=on-vs-off bit-identity rests on.
-        The default pool holds `slots * max_len/page_size` data pages:
-        the same worst-case KV footprint the dense scheduler would
-        allocate with every slot in the largest bucket, so pages-vs-
-        dense comparisons run at equal memory budget."""
-        ps = int(page_size or _config.get("pages_page_size"))
-        if ps < 1:
-            raise ValueError(f"pages_page_size must be >= 1, got {ps}")
-        self._page_size = ps
-        self._prefill_chunk = max(
-            1, int(prefill_chunk or _config.get("pages_prefill_chunk")))
-        self._spec_k = max(1, int(spec_k or _config.get("pages_spec_k")))
-        max_paged = (self._max_len // ps) * ps
-        if max_paged < 1:
-            raise ValueError(
-                f"pages_page_size {ps} exceeds the model's max_length "
-                f"{self._max_len} — no position fits a single page")
-        self._max_len = max_paged
-        streams = {"target": list(self._spec.streams)}
-        if self._drafter is not None:
-            streams["draft"] = list(self._draft_spec.streams)
-            try:
-                self._params_bytes += _memsafe.resident_bytes(
-                    [p.data()._data
-                     for p in self._drafter.collect_params().values()])
-            except Exception:
-                pass
-        data = int(pool_pages or _config.get("pages_pool_pages")) \
-            or self._slots * (self._max_len // ps)
-        self._pool = _pages.PagePool(ps, data, self._slots, streams)
-        self._tree = _pages.PrefixTree(self._pool)
-        self._stats.update({
-            "prompt_tokens": 0, "prefix_tokens": 0, "prefix_hits": 0,
-            "chunk_dispatches": 0, "chunk_steps": 0, "token_steps": 0,
-            "spec_rounds": 0,
-            "drafts_proposed": 0, "drafts_accepted": 0,
-            # what attention was fed, worked out from positions alone:
-            # tokens, the sum of their context lengths, of the contexts
-            # cut to `index_topk`, and the tokens whose context passed it
-            "attn_tokens": 0, "attn_ctx_tokens": 0, "attn_sel_tokens": 0,
-            "sparse_tokens": 0,
-        })
-        from . import check as _check
-        if _check._enabled:
-            smallest = self._buckets[0] if self._buckets is not None \
-                else max(1, int(_config.get("bucket_pad_min")))
-            _check.lint_paging(
-                f"serve.Server(pages=on,page_size={ps})", ps, smallest,
-                self._spec.vocab_size,
-                None if self._drafter is None
-                else self._draft_spec.vocab_size)
-        _pages.enable()
 
     # -- client surface --------------------------------------------------
     def submit(self, prompt, max_new_tokens=32, eos=None, temperature=0.0,
@@ -649,7 +609,7 @@ class Server:
                              f"max_new_tokens {max_new_tokens} exceeds "
                              f"max_length {self._max_len}")
                 return req
-            over = self._solo_overrun(req)
+            over = self._solo_overrun()
             if over is not None:
                 self._finish(req, REJECTED, over)
                 return req
@@ -692,27 +652,25 @@ class Server:
             out["buckets_allocated"] = sorted(self._groups)
             out["executables"] = len(self._runners)
             out["scheduler_steps"] = self._sched_step
-            if self._paged:
-                out["pages"] = "on"
-                out["page_size"] = self._page_size
-                out["pool_pages_total"] = self._pool.data_pages
-                out["pool_pages_free"] = self._pool.free_pages()
-                out["tree_nodes"] = len(self._tree.nodes)
-                out["cow_copies"] = self._pool.stats["cow_copies"]
-                pt = self._stats["prompt_tokens"]
-                out["prefix_hit_rate"] = (
-                    self._stats["prefix_tokens"] / pt if pt else 0.0)
-                dp = self._stats["drafts_proposed"]
-                out["accepted_draft_rate"] = (
-                    self._stats["drafts_accepted"] / dp if dp else 0.0)
+            out["pages"] = "on"     # readers' key from when it was a mode
+            out["page_size"] = self._page_size
+            out["pool_pages_total"] = self._pool.data_pages
+            out["pool_pages_free"] = self._pool.free_pages()
+            out["tree_nodes"] = len(self._tree.nodes)
+            out["cow_copies"] = self._pool.stats["cow_copies"]
+            pt = self._stats["prompt_tokens"]
+            out["prefix_hit_rate"] = (
+                self._stats["prefix_tokens"] / pt if pt else 0.0)
+            dp = self._stats["drafts_proposed"]
+            out["accepted_draft_rate"] = (
+                self._stats["drafts_accepted"] / dp if dp else 0.0)
         out["dispatches"] = dispatches()
         return out
 
     def admission_hints(self):
         """What a fleet router needs to PREDICT this server's admission
-        verdict without a round trip: memsafe headroom next to the
-        analytic cache cost of every bucket admission could newly
-        allocate (dense), or the free-page count (paged). A None
+        verdict without a round trip: the pool's page size and free-page
+        count, and memsafe's headroom beside parameters and pool. A None
         `headroom_bytes` means memsafe is off — nothing to predict.
         Published per replica via the mx.fleet /statusz payload; the
         router skips replicas whose hints predict a 429 (the
@@ -720,30 +678,15 @@ class Server:
         out = {"max_len": self._max_len, "slots": self._slots,
                "queue_depth": self._queue_depth,
                "buckets": self._buckets,       # None => pow2 policy
-               "pages": "on" if self._paged else "off"}
+               "page_size": self._page_size}
         cap = _memsafe.capacity_bytes()
         if cap is None:
             out["headroom_bytes"] = None
             return out
         with self._lock:
-            if self._paged:
-                resident = self._params_bytes + self._pool.pool_bytes()
-                out["page_size"] = self._page_size
-                out["pool_pages_free"] = self._pool.free_pages()
-            else:
-                resident = self._params_bytes + sum(
-                    g.cache_bytes for g in self._groups.values())
-                if self._buckets is not None:
-                    cands = list(self._buckets)
-                else:
-                    cands, b = [], max(1, int(_config.get("bucket_pad_min")))
-                    while b < self._max_len:
-                        cands.append(b)
-                        b *= 2
-                    cands.append(self._max_len)
-                out["bucket_cost"] = {str(b): self._cache_bytes(b)
-                                      for b in cands}
-        out["headroom_bytes"] = max(0, int(cap) - int(resident))
+            out["pool_pages_free"] = self._pool.free_pages()
+        out["headroom_bytes"] = max(
+            0, int(cap) - self._params_bytes - self._pool.pool_bytes())
         return out
 
     # -- lifecycle -------------------------------------------------------
@@ -776,12 +719,9 @@ class Server:
                 self._finish(r, CANCELLED, "499 server stopped")
             self._queue.clear()
             self._gc_groups()
-            if self._paged and self._tree is not None:
-                # drop the tree's page references so the pool drains
-                # fully (every page back on the free list), and disarm
-                # the module bool this server's construction set
-                self._tree.clear()
-                _pages.disable()
+            # drop the tree's page references so the pool drains fully
+            # (every page back on the free list)
+            self._tree.clear()
 
     def __enter__(self):
         return self.start()
@@ -884,10 +824,10 @@ class Server:
                 with self._lock:
                     self._apply_cancels()
                     self._evict_expired()
-                    # reclaim drained buckets BEFORE admission: caches
-                    # freed by a cancel/expiry this very step must not
-                    # count against the incoming request's budget (a
-                    # spurious 429/shrink otherwise)
+                    # forget drained buckets BEFORE admission (their
+                    # pages went back to the pool as each slot emptied,
+                    # so a cancel/expiry of this very step already
+                    # counts for the incoming request)
                     self._gc_groups()
                     if tr:
                         sched.attrs["admitted"] = len(self._queue)
@@ -929,19 +869,15 @@ class Server:
         cap = _memsafe.capacity_bytes()
         for r in pending:
             b = self._bucket_for(r.prompt.size + r.max_new_tokens)
-            if self._paged:
-                self._paged_runner(b, self._prefill_chunk, False)
-                self._paged_runner(b, 1, False)
-                if self._drafter is not None:
-                    # the drafter mirrors every target chunk (gap-0
-                    # sync), plus its own chain and the verify step
-                    self._paged_runner(b, self._prefill_chunk, False,
-                                       draft=True)
-                    self._paged_runner(b, 1, False, draft=True)
-                    self._paged_runner(b, self._spec_k + 1, True)
-                    self._draft_runner(b)
-            else:
-                self._runner(b)
+            self._runner(b, self._prefill_chunk, False)
+            self._runner(b, 1, False)
+            if self._drafter is not None:
+                # the drafter mirrors every target chunk (gap-0 sync),
+                # plus its own chain and the verify step
+                self._runner(b, self._prefill_chunk, False, draft=True)
+                self._runner(b, 1, False, draft=True)
+                self._runner(b, self._spec_k + 1, True)
+                self._draft_runner(b)
             if cap is not None:
                 self._exec_peak(b)
 
@@ -1031,70 +967,39 @@ class Server:
             b = _dataflow.bucket_length(need, self._buckets)
         else:
             b = _dataflow.bucket_length(need, "pow2")
-        b = min(int(b), self._max_len)
-        if self._paged:
-            # paged buckets are page multiples, so a bucket's gathered
-            # KV length (n_pg * page_size) equals the bucket exactly —
-            # identical operand shapes to the dense cache (pow2 buckets
-            # with a pow2 page size are already multiples; _init_paged
-            # rounded _max_len down, so the cap stays a multiple too)
-            ps = self._page_size
-            b = min(((b + ps - 1) // ps) * ps, self._max_len)
-        return b
+        # buckets are page multiples, so a bucket's page table
+        # (n_pg * page_size positions) covers it exactly (pow2 buckets
+        # with a pow2 page size are already multiples; construction
+        # rounded _max_len down, so the cap stays a multiple too)
+        ps = self._page_size
+        return min(-(-int(b) // ps) * ps, self._max_len)
 
     def _buckets_below(self, bucket, floor):
         """Candidate shrink buckets strictly below `bucket`, largest
-        first, each still holding `floor` total positions. The pow2
-        policy never goes below `bucket_pad_min` — shrinking must not
-        mint bucket sizes normal admission would never produce (each
-        would be one more executable)."""
+        first, each still holding `floor` total positions and each a
+        bucket `_bucket_for` gives. The pow2 policy never goes below
+        `bucket_pad_min` — shrinking must not mint bucket sizes normal
+        admission would never produce (each would be more
+        executables)."""
         if self._buckets is not None:
-            cands = [b for b in self._buckets if floor <= b < bucket]
+            cands = list(self._buckets)
         else:
             lo = max(1, int(_config.get("bucket_pad_min")))
             cands, b = [], bucket // 2
-            while b >= max(floor, lo):
+            while b >= lo:
                 cands.append(b)
                 b //= 2
-        return sorted(cands, reverse=True)
+        cands = {self._bucket_for(b) for b in cands}
+        return sorted((b for b in cands if floor <= b < bucket),
+                      reverse=True)
 
-    def _cache_bytes(self, bucket):
-        """Analytic KV bytes for one bucket's dense caches: one
-        (slots, H, bucket, D) array per `dense_streams` entry."""
-        return sum(self._slots * h * bucket * d * np.dtype(dt).itemsize
-                   for h, d, dt in self._spec.dense_streams)
-
-    def _runner(self, bucket):
-        r = self._runners.get(bucket)
-        if r is None:
-            from .models._decode import jit_flat_step
-            model = self.model
-            n_l = len(self._spec.dense_streams) // 2
-
-            def step(tok, t, flat):
-                logits, nk, nv = model.decode_step_slots(
-                    tok, t, flat[:n_l], flat[n_l:])
-                return logits, list(nk) + list(nv)
-
-            # the KV caches are threaded through every step: donate them
-            # (mx.check `donation-miss` — same rationale as generate)
-            r = jit_flat_step(model, step, 2 * n_l,
-                              donate_state=2 * n_l)
-            self._runners[bucket] = r
-        return r
-
-    def _cache_avals(self, bucket):
-        import jax
-        return [jax.ShapeDtypeStruct((self._slots, h, bucket, d), dt)
-                for h, d, dt in self._spec.dense_streams]
-
-    def _paged_runner(self, bucket, C, full, draft=False):
+    def _runner(self, bucket, C, full, draft=False):
         """Chunk-step executable for (bucket, chunk length C): the
-        `decode_paged_chunk` body under jit_flat_step with the pool
-        arrays donated — at most three C values ever exist per bucket
-        (prefill_chunk, 1, and spec_k+1 with full logits), so paged
-        serving compiles O(buckets) executables like the dense path."""
-        key = ("paged", bucket, C, full, draft)
+        model's `chunk_step` under jit_flat_step with the pool arrays
+        donated — at most three C values ever exist per bucket
+        (prefill_chunk, 1, and spec_k+1 with full logits), so serving
+        compiles O(buckets) executables, never one per length."""
+        key = ("chunk", bucket, C, full, draft)
         r = self._runners.get(key)
         if r is None:
             from .models._decode import jit_flat_step
@@ -1143,29 +1048,23 @@ class Server:
         return r
 
     def _bucket_step(self, bucket):
-        """(runner, argument avals) of the step executable `bucket`
-        runs. Paged servers name the HEAVIEST chunk executable the
-        bucket can run (the full-logits speculative verify step when a
-        drafter is attached, else the prefill chunk)."""
+        """(runner, argument avals) of the HEAVIEST step executable
+        `bucket` can run: the full-logits speculative verify step when a
+        drafter is attached, else the prefill chunk."""
         import jax
-        if self._paged:
-            if self._drafter is not None:
-                C, full = self._spec_k + 1, True
-            else:
-                C, full = self._prefill_chunk, False
-            n_pg = bucket // self._page_size
-            state = [jax.ShapeDtypeStruct(tuple(a.shape), a.dtype)
-                     for a in self._pool.state["target"]]
-            return self._paged_runner(bucket, C, full), (
-                jax.ShapeDtypeStruct((self._slots, C), np.int32),
-                jax.ShapeDtypeStruct((self._slots,), np.int32),
-                jax.ShapeDtypeStruct((self._slots,), np.int32),
-                jax.ShapeDtypeStruct((self._slots, n_pg), np.int32),
-                state)
-        return self._runner(bucket), (
+        if self._drafter is not None:
+            C, full = self._spec_k + 1, True
+        else:
+            C, full = self._prefill_chunk, False
+        n_pg = bucket // self._page_size
+        state = [jax.ShapeDtypeStruct(tuple(a.shape), a.dtype)
+                 for a in self._pool.state["target"]]
+        return self._runner(bucket, C, full), (
+            jax.ShapeDtypeStruct((self._slots, C), np.int32),
             jax.ShapeDtypeStruct((self._slots,), np.int32),
             jax.ShapeDtypeStruct((self._slots,), np.int32),
-            self._cache_avals(bucket))
+            jax.ShapeDtypeStruct((self._slots, n_pg), np.int32),
+            state)
 
     def lower_step(self, bucket):
         """The `jax.stages.Lowered` form of `bucket`'s step executable
@@ -1192,50 +1091,33 @@ class Server:
         return peak
 
     def _admit_budget(self, bucket):
-        """mx.memsafe budget check for admitting into `bucket`: resident
-        params + every allocated bucket's caches (+ this bucket's, if it
-        would be newly allocated) + the executable's AOT execution peak
-        vs device capacity. Raises MemoryBudgetError on predicted
-        overrun — BEFORE any cache allocation or dispatch."""
+        """mx.memsafe budget check for admitting into `bucket`. The pool
+        is the cache: one constant resident allocation made at
+        construction, so admission prices the bucket's step executable's
+        AOT execution peak on top of parameters and pool vs device
+        capacity. Raises MemoryBudgetError on predicted overrun —
+        BEFORE any page is taken or anything dispatched."""
         cap = _memsafe.capacity_bytes()
         if cap is None:
             return None
-        if self._paged:
-            # the pool is the cache: one constant resident allocation
-            # made at construction — per-bucket admission only prices
-            # the chunk executable's AOT peak on top of it
-            resident = self._params_bytes + self._pool.pool_bytes()
-            return _memsafe.check_budget(
-                f"serve.decode(bucket={bucket},slots={self._slots},"
-                f"pages=on)",
-                self._exec_peak(bucket), resident, capacity=cap)
-        new_bytes = 0 if bucket in self._groups \
-            else self._cache_bytes(bucket)
-        resident = self._params_bytes + new_bytes + sum(
-            g.cache_bytes for g in self._groups.values())
         return _memsafe.check_budget(
             f"serve.decode(bucket={bucket},slots={self._slots})",
-            self._exec_peak(bucket), resident, capacity=cap)
+            self._exec_peak(bucket),
+            self._params_bytes + self._pool.pool_bytes(), capacity=cap)
 
-    def _solo_overrun(self, req):
-        """Cheap submit-time check: a request whose SMALLEST shrunk
-        bucket cannot fit next to the params alone can never be admitted
-        — reject it immediately with the accounting (429), instead of
-        letting it age out in the queue."""
+    def _solo_overrun(self):
+        """Cheap submit-time check: where parameters and pool alone pass
+        the device's capacity no request can ever be admitted — reject
+        it immediately with the accounting (429), instead of letting it
+        age out in the queue."""
         cap = _memsafe.capacity_bytes()
         if cap is None:
             return None
-        floor_new = max(1, min(int(_config.get("serve_min_new_tokens")),
-                               req.max_new_tokens))
-        bucket = self._bucket_for(req.prompt.size + floor_new)
-        resident = self._params_bytes + (
-            self._pool.pool_bytes() if self._paged
-            else self._cache_bytes(bucket))
+        resident = self._params_bytes + self._pool.pool_bytes()
         if resident > cap:
-            return (f"429 over capacity: smallest viable KV bucket "
-                    f"{bucket} needs {_fmt_bytes(resident)} resident "
-                    f"(params + caches) but device capacity is "
-                    f"{_fmt_bytes(cap)}")
+            return (f"429 over capacity: parameters and the page pool "
+                    f"need {_fmt_bytes(resident)} resident but device "
+                    f"capacity is {_fmt_bytes(cap)}")
         return None
 
     def _admit(self):
@@ -1258,18 +1140,36 @@ class Server:
         grp = self._groups.get(bucket)
         if grp is not None and grp.free_slot() is None:
             return False                     # bucket full: wait
+        refusal = self._seat(req, bucket)
+        return refusal is None or self._admit_pressure(req, bucket, refusal)
+
+    def _seat(self, req, bucket, max_new=None):
+        """Try to seat `req` in `bucket` — with its token budget clamped
+        to `max_new`, if given: the byte budget of the bucket's step
+        executable beside parameters and pool, then the request's pages.
+        Every rung of the ladder comes through here. Returns None with
+        the request RUNNING in its slot, or the refusal: the
+        `MemoryBudgetError` (bytes) or a `PagesExhausted` (pages), with
+        nothing taken."""
         try:
             self._admit_budget(bucket)
         except _memsafe.MemoryBudgetError as e:
-            return self._admit_pressure(req, bucket, e)
-        if self._paged:
-            got = self._paged_alloc(req, bucket)
-            if got is None:
-                return self._paged_pressure(req, bucket)
-            self._place_paged(req, bucket, got)
-            return True
-        self._place(req, bucket)
-        return True
+            return e
+        got = self._paged_alloc(req, bucket, max_new)
+        if got is None:
+            mn = req.max_new_tokens if max_new is None else max_new
+            return _pages.PagesExhausted(
+                -(-(req.prompt.size + mn) // self._page_size),
+                self._pool.free_pages())
+        if max_new is not None:
+            was = req.max_new_tokens
+            req.max_new_tokens = max_new
+            req.degraded = f"shrink_max_new:{was}->{max_new}"
+            self._note_degraded("shrink_max_new", req,
+                                {"from": was, "to": max_new,
+                                 "bucket": bucket})
+        self._place(req, bucket, got)
+        return None
 
     def _paged_alloc(self, req, bucket, max_new=None):
         """Match the prompt against the prefix tree and allocate the
@@ -1312,78 +1212,19 @@ class Server:
         pages = matched_pages + self._pool.alloc(n_pg - len(matched_pages))
         return pages, matched, pos0
 
-    def _paged_pressure(self, req, bucket):
-        """The degradation ladder under PAGE exhaustion — the paged
-        analog of `_admit_pressure`, with the same rung semantics and
-        REQUEUED-request protections: (1) shrink max_new_tokens to a
-        smaller bucket needing fewer pages, (2) evict-and-requeue the
-        youngest running request (its `_vacate` returns exclusive pages
-        to the pool), (3) reject when nothing else holds pages."""
-        if req.requeues == 0 and self._paged_shrunk(req, bucket):
-            return True
-        if req.requeues == 0 and not req.evicted_once:
-            victim = self._youngest_running(exclude=req)
-            if victim is not None:
-                req.evicted_once = True
-                self._evict_requeue(victim, for_req=req)
-                self._gc_groups()
-                got = self._paged_alloc(req, bucket)
-                if got is not None:
-                    self._place_paged(req, bucket, got)
-                    return True
-                if self._paged_shrunk(req, bucket):
-                    return True
-        if not any(g.active() for g in self._groups.values()):
-            self._queue.remove(req)
-            self._finish(
-                req, REJECTED,
-                f"429 over capacity: page pool exhausted — request "
-                f"needs {-(-(req.prompt.size + req.max_new_tokens) // self._page_size)} "
-                f"pages but only {self._pool.free_pages()} of "
-                f"{self._pool.data_pages} are free with no running "
-                f"work to drain")
-            return True
-        return False
+    def _admit_pressure(self, req, bucket, refusal):
+        """The graceful-degradation ladder, walked when `_seat` refused
+        (mirrors memsafe's OOM ladder): (1) shrink max_new_tokens to the
+        largest smaller bucket the request can be seated in, (2)
+        evict-and-requeue the youngest running request, then (3) reject
+        with the accounting when no wait can help. Anything else stays
+        queued. Every transition is annotated in telemetry.
 
-    def _paged_shrunk(self, req, bucket):
-        """Degradation rung 1 (paged): clamp the token budget to the
-        largest smaller page-multiple bucket whose table the pool can
-        cover now."""
-        ps = self._page_size
-        floor_new = max(1, min(int(_config.get("serve_min_new_tokens")),
-                               req.max_new_tokens))
-        floor_total = req.prompt.size + floor_new
-        seen = set()
-        for L in self._buckets_below(bucket, floor_total):
-            L = min(((L + ps - 1) // ps) * ps, self._max_len)
-            if L >= bucket or L < floor_total or L in seen:
-                continue
-            seen.add(L)
-            grp = self._groups.get(L)
-            if grp is not None and grp.free_slot() is None:
-                continue
-            new_max = L - req.prompt.size
-            got = self._paged_alloc(req, L, max_new=new_max)
-            if got is None:
-                continue
-            was = req.max_new_tokens
-            req.max_new_tokens = new_max
-            req.degraded = f"shrink_max_new:{was}->{new_max}"
-            self._note_degraded("shrink_max_new", req,
-                                {"from": was, "to": new_max, "bucket": L})
-            self._place_paged(req, L, got)
-            return True
-        return False
-
-    def _admit_pressure(self, req, bucket, err):
-        """The graceful-degradation ladder, walked when admission
-        predicts a memory overrun (mirrors memsafe's OOM ladder):
-        (1) shrink max_new_tokens to the largest smaller bucket that
-        passes the budget, (2) evict-and-requeue the youngest running
-        request (frees its bucket's KV pages when it drains the group),
-        then (3) reject with the accounting if the request cannot fit
-        even alone. Anything else stays queued. Every transition is
-        annotated in telemetry.
+        What a wait or an eviction can cure is PAGES: a drained or
+        evicted request returns its exclusive pages to the pool. The
+        byte budget (parameters, pool, the executable's peak) is the
+        same whoever runs, so a byte refusal that shrinking did not
+        cure skips rung 2 and is rejected at once.
 
         A REQUEUED request is never shrunk and never evicts: its client
         is mid-stream on a promised token budget (shrinking below what
@@ -1392,51 +1233,35 @@ class Server:
         forever — it waits for the running work to drain instead."""
         if req.requeues == 0 and self._admit_shrunk(req, bucket):
             return True
-        if req.requeues == 0 and not req.evicted_once:
+        short_of_pages = isinstance(refusal, _pages.PagesExhausted)
+        if short_of_pages and req.requeues == 0 and not req.evicted_once:
             victim = self._youngest_running(exclude=req)
             if victim is not None:
                 req.evicted_once = True
                 self._evict_requeue(victim, for_req=req)
                 self._gc_groups()
-                try:
-                    self._admit_budget(bucket)
-                except _memsafe.MemoryBudgetError:
-                    if self._admit_shrunk(req, bucket):
-                        return True
-                else:
-                    self._place(req, bucket)
+                refusal = self._seat(req, bucket)
+                if refusal is None or self._admit_shrunk(req, bucket):
                     return True
-        if not any(g.active() for g in self._groups.values()):
-            # nothing else is holding memory: this request simply does
-            # not fit the device — a queue wait cannot save it
+        if not short_of_pages \
+                or not any(g.active() for g in self._groups.values()):
             self._queue.remove(req)
-            self._finish(req, REJECTED, f"429 over capacity: {err}")
+            self._finish(req, REJECTED, f"429 over capacity: {refusal}")
             return True
         return False
 
     def _admit_shrunk(self, req, bucket):
         """Degradation rung 1: clamp the request's token budget to the
-        largest smaller bucket that passes the memory budget (floored at
+        largest smaller bucket it can be seated in now (floored at
         serve_min_new_tokens)."""
         floor_new = max(1, min(int(_config.get("serve_min_new_tokens")),
                                req.max_new_tokens))
-        floor_total = req.prompt.size + floor_new
-        for L in self._buckets_below(bucket, floor_total):
+        for L in self._buckets_below(bucket, req.prompt.size + floor_new):
             grp = self._groups.get(L)
             if grp is not None and grp.free_slot() is None:
                 continue
-            try:
-                self._admit_budget(L)
-            except _memsafe.MemoryBudgetError:
-                continue
-            new_max = L - req.prompt.size
-            was = req.max_new_tokens
-            req.max_new_tokens = new_max
-            req.degraded = f"shrink_max_new:{was}->{new_max}"
-            self._note_degraded("shrink_max_new", req,
-                                {"from": was, "to": new_max, "bucket": L})
-            self._place(req, L)
-            return True
+            if self._seat(req, L, max_new=L - req.prompt.size) is None:
+                return True
         return False
 
     def _youngest_running(self, exclude=None):
@@ -1476,20 +1301,8 @@ class Server:
             _diagnostics.record_event("serve", action=action, req=req.id,
                                       **extra)
 
-    def _place(self, req, bucket):
-        grp = self._groups.get(bucket)
-        t0 = time.perf_counter()
-        if grp is None:
-            run = self._runner(bucket)
-            caches = self.model._alloc_caches(self._slots, bucket)
-            grp = self._groups[bucket] = _Group(bucket, run, caches)
-        i = grp.free_slot()
-        grp.slots[i] = req
-        grp.pos[i] = 0
-        self._note_admitted(req, bucket, t0)
-
-    def _place_paged(self, req, bucket, got):
-        """Seat an admitted request in its paged bucket group with the
+    def _place(self, req, bucket, got):
+        """Seat an admitted request in its bucket group with the
         page table `_paged_alloc` built; a prefix-tree match starts the
         request at the first unmatched position — the matched prefix's
         prefill is skipped outright."""
@@ -1497,7 +1310,7 @@ class Server:
         t0 = time.perf_counter()
         grp = self._groups.get(bucket)
         if grp is None:
-            grp = self._groups[bucket] = _PagedGroup(
+            grp = self._groups[bucket] = _Group(
                 bucket, self._slots, bucket // self._page_size)
         i = grp.free_slot()
         grp.slots[i] = req
@@ -1529,17 +1342,15 @@ class Server:
                                bucket=bucket)
 
     def _vacate(self, grp, i):
-        """Release slot i of `grp`. Dense groups just clear the slot
-        (their caches free when the group drains); paged slots drop one
-        pool reference per owned page — tree-shared pages survive with
-        the tree's reference, exclusive ones return to the free list."""
+        """Release slot i of `grp`: drop one pool reference per owned
+        page — tree-shared pages survive with the tree's reference,
+        exclusive ones return to the free list."""
         grp.slots[i] = None
-        if self._paged and isinstance(grp, _PagedGroup):
-            for p in grp.pages[i]:
-                self._pool.decref(p)
-            grp.pages[i] = []
-            grp.matched[i] = 0
-            grp.inserted[i] = False
+        for p in grp.pages[i]:
+            self._pool.decref(p)
+        grp.pages[i] = []
+        grp.matched[i] = 0
+        grp.inserted[i] = False
 
     def _remove_from_slots(self, req):
         for g in self._groups.values():
@@ -1550,105 +1361,17 @@ class Server:
         return False
 
     def _gc_groups(self):
-        """Free the KV caches of drained bucket groups — the 'pages
-        reclaimed' half of eviction (the jitted runner stays cached, so
-        re-admission into the bucket does not recompile)."""
+        """Forget drained bucket groups (`buckets_allocated` names the
+        live ones; the jitted runners stay cached, so re-admission into
+        the bucket does not recompile)."""
         for L in [L for L, g in self._groups.items() if not g.active()]:
             del self._groups[L]
 
     # -- decode ----------------------------------------------------------
     def _decode_group(self, grp, sched_step, sp=None):
-        """One decode round of a bucket group. `sp` is the step's live
-        `serve.step` span, None while mx.trace is not live: the span
-        sites below test it and nothing else."""
-        if self._paged:
-            return self._decode_group_paged(grp, sched_step, sp)
-        import jax.numpy as jnp
-        with (_trace.span("serve.prepare", cat="phase", step=sched_step,
-                          chunk=1) if sp else _NULLCTX):
-            tok = np.zeros((self._slots,), np.int32)
-            t = np.zeros((self._slots,), np.int32)
-            active = grp.active()
-            if not active:
-                return
-            for i in active:
-                r = grp.slots[i]
-                p = grp.pos[i]
-                lp = r.prompt.size
-                tok[i] = r.prompt[p] if p < lp else r.tokens[p - lp]
-                t[i] = p
-            if _slo._enabled:
-                for i in active:
-                    r = grp.slots[i]
-                    if r._slo_j is not None:
-                        _slo.note_first_dispatch(r)
-        with (_trace.span("serve.decode_step", cat="serve", step=sched_step,
-                          chunk=1, bucket=grp.bucket, slots=len(active))
-              if sp else _NULLCTX):
-            logits, new_state = self._dispatch(grp, jnp.asarray(tok),
-                                               jnp.asarray(t))
-            grp.caches = new_state
-            with (_trace.span("serve.fetch", cat="phase", step=sched_step,
-                              chunk=1) if sp else _NULLCTX):
-                lg = _fetch(logits, np.float32)     # host fetch = the fence
-        with (_trace.span("serve.stream", cat="serve", step=sched_step)
-              if sp else _NULLCTX) as stream:
-            if sp:
-                tokens0 = self._stats["tokens"]
-            with self._lock:
-                self._stats["steps"] += 1
-                for i in active:
-                    r = grp.slots[i]
-                    if r is None or r.state in TERMINAL:
-                        continue    # evicted/cancelled under the dispatch
-                    p = grp.pos[i]
-                    grp.pos[i] = p + 1
-                    if p < r.prompt.size - 1:
-                        continue    # still prefilling the prompt
-                    nxt = self._sample(r, lg[i])
-                    self._emit(r, nxt, lg[i])
-                    if (r.eos is not None and nxt == r.eos) \
-                            or len(r.tokens) >= r.max_new_tokens:
-                        grp.slots[i] = None
-                        self._finish(r, DONE, "200 ok")
-            if sp:
-                _close_round(sp, stream, 1, self._stats["tokens"] - tokens0)
-
-    def _dispatch(self, grp, tok, t):
-        """One batched decode dispatch under the transient-fault
-        RetryPolicy. Donated-buffer safety: a failure that consumed the
-        donated KV caches cannot be retried in place — that is re-raised
-        (non-retryable) instead of computing garbage."""
-        def call():
-            c0 = grp.caches[0]
-            if hasattr(c0, "is_deleted") and c0.is_deleted():
-                raise RuntimeError(
-                    "mx.serve: the failed dispatch consumed the donated "
-                    "KV buffers — cannot retry in place (bucket "
-                    f"{grp.bucket})")
-            return grp.run(tok, t, grp.caches)
-
-        def on_retry(exc, attempt, delay):
-            with self._lock:
-                self._stats["retries"] += 1
-                if _slo._enabled:
-                    for i in grp.active():
-                        r = grp.slots[i]
-                        if r is not None and r._slo_j is not None:
-                            _slo.note_event(r, "retry", attempt=attempt,
-                                            error=type(exc).__name__)
-            print(f"mx.serve: retrying decode dispatch after "
-                  f"{type(exc).__name__}: {exc} (attempt {attempt + 2}/"
-                  f"{self._retry.max_attempts}, backoff {delay:.2f}s)",
-                  file=sys.stderr)
-
-        return self._retry.call(call, site="serve-dispatch",
-                                abort=self._stop.is_set,
-                                on_retry=on_retry)
-
-    # -- paged decode ----------------------------------------------------
-    def _decode_group_paged(self, grp, sched_step, sp=None):
-        """One scheduler round for a paged bucket group. Mode per round:
+        """One scheduler round for a bucket group. `sp` is the step's
+        live `serve.step` span, None while mx.trace is not live: the
+        span sites below test it and nothing else. Mode per round:
         a SPECULATIVE round (draft chain + one k+1-token verify chunk)
         when a drafter is attached, every active slot is past its
         prompt, and at least one is greedy; otherwise a CHUNK round —
@@ -1710,19 +1433,19 @@ class Server:
                 t0[i] = p
                 n[i] = ni
                 tables[i, :len(grp.pages[i])] = grp.pages[i]
-            run = self._paged_runner(grp.bucket, C, False)
+            run = self._runner(grp.bucket, C, False)
             lead = (jnp.asarray(toks), jnp.asarray(t0), jnp.asarray(n),
                     jnp.asarray(tables))
         with (_trace.span("serve.decode_step", cat="serve", step=sched_step,
                           chunk=C, bucket=grp.bucket, slots=len(active))
               if sp else _NULLCTX):
-            logits = self._dispatch_paged(grp, run, lead, "target")
+            logits = self._dispatch(grp, run, lead, "target")
             if self._drafter is not None:
                 # mirror the chunk on the drafter so its cache tracks the
                 # target position-for-position (gap-0: a later speculative
                 # round can start its chain with no catch-up work)
-                drun = self._paged_runner(grp.bucket, C, False, draft=True)
-                self._dispatch_paged(grp, drun, lead, "draft")
+                drun = self._runner(grp.bucket, C, False, draft=True)
+                self._dispatch(grp, drun, lead, "draft")
             with (_trace.span("serve.fetch", cat="phase", step=sched_step,
                               chunk=C) if sp else _NULLCTX):
                 lg = _fetch(logits, np.float32)     # host fetch = the fence
@@ -1778,7 +1501,7 @@ class Server:
                 t0[i] = p
                 tables[i, :len(grp.pages[i])] = grp.pages[i]
                 spec_row[i] = r.temperature == 0.0
-            drafts_out = self._dispatch_paged(
+            drafts_out = self._dispatch(
                 grp, self._draft_runner(grp.bucket),
                 (jnp.asarray(tok0), jnp.asarray(t0), jnp.asarray(spec_row),
                  jnp.asarray(tables)), "draft")
@@ -1790,12 +1513,12 @@ class Server:
                     n[i] = k + 1
                 else:
                     n[i] = 1
-            run = self._paged_runner(grp.bucket, k + 1, True)
+            run = self._runner(grp.bucket, k + 1, True)
         with (_trace.span("serve.decode_step", cat="serve", step=sched_step,
                           chunk=k + 1, bucket=grp.bucket,
                           slots=len(active), spec_k=k)
               if sp else _NULLCTX):
-            logits = self._dispatch_paged(
+            logits = self._dispatch(
                 grp, run, (jnp.asarray(toks), jnp.asarray(t0),
                            jnp.asarray(n), jnp.asarray(tables)), "target")
             with (_trace.span("serve.fetch", cat="phase", step=sched_step,
@@ -1856,10 +1579,12 @@ class Server:
         self._tree.insert(req.prompt, grp.pages[i][:lp // self._page_size])
         grp.inserted[i] = True
 
-    def _dispatch_paged(self, grp, run, lead, tag):
-        """One paged dispatch under the RetryPolicy, threading the
-        pool's `tag` page-array stream through the donated state (same
-        donated-buffer safety rule as `_dispatch`)."""
+    def _dispatch(self, grp, run, lead, tag):
+        """One batched dispatch under the transient-fault RetryPolicy,
+        threading the pool's `tag` page-array stream through the donated
+        state. Donated-buffer safety: a failure that consumed the
+        donated arenas cannot be retried in place — that is re-raised
+        (non-retryable) instead of computing garbage."""
         pool = self._pool
 
         def call():
@@ -1882,7 +1607,7 @@ class Server:
                         if r is not None and r._slo_j is not None:
                             _slo.note_event(r, "retry", attempt=attempt,
                                             error=type(exc).__name__)
-            print(f"mx.serve: retrying paged dispatch after "
+            print(f"mx.serve: retrying decode dispatch after "
                   f"{type(exc).__name__}: {exc} (attempt {attempt + 2}/"
                   f"{self._retry.max_attempts}, backoff {delay:.2f}s)",
                   file=sys.stderr)

@@ -97,59 +97,43 @@ def gang(models):
 
 # -- admission prediction (pure) ---------------------------------------------
 
-def _dense_statusz(headroom, cost, buckets=None, allocated=(),
-                   max_len=64):
+def _pool_statusz(free, headroom=10**9, page_size=8, buckets=None,
+                  allocated=(), max_len=64):
     return {"admission": {"max_len": max_len, "slots": 2,
                           "queue_depth": 8, "buckets": buckets,
-                          "pages": "off", "headroom_bytes": headroom,
-                          "bucket_cost": cost},
+                          "page_size": page_size,
+                          "pool_pages_free": free,
+                          "headroom_bytes": headroom},
             "stats": {"buckets_allocated": list(allocated)}}
 
 
-def test_predict_429_dense_over_headroom():
-    st = _dense_statusz(headroom=100, cost={"16": 500})
-    assert fleet.Router.predict_429(st, need=10) is True
-
-
-def test_predict_429_dense_within_headroom():
-    st = _dense_statusz(headroom=1000, cost={"16": 500})
-    assert fleet.Router.predict_429(st, need=10) is False
-
-
-def test_predict_429_allocated_bucket_is_free():
-    # the pow2 bucket for need=10 is 16; if its cache already exists
-    # there is no new allocation to predict against
-    st = _dense_statusz(headroom=0, cost={"16": 500}, allocated=[16])
-    assert fleet.Router.predict_429(st, need=10) is False
-
-
-def test_predict_429_explicit_bucket_list():
-    st = _dense_statusz(headroom=100, cost={"24": 500, "48": 900},
-                        buckets=[24, 48])
-    assert fleet.Router.predict_429(st, need=20) is True
-    st = _dense_statusz(headroom=600, cost={"24": 500, "48": 900},
-                        buckets=[24, 48])
-    assert fleet.Router.predict_429(st, need=20) is False
+@pytest.mark.parametrize("st,need,want", [
+    # 32 tokens are 4 pages of 8: two free pages cannot hold them
+    (_pool_statusz(free=2), 32, True),
+    (_pool_statusz(free=2), 16, False),              # exactly 2
+    # a token past a page boundary is one more page
+    (_pool_statusz(free=2), 17, True),
+    # a live bucket group owns no cache: it promises a newcomer nothing
+    (_pool_statusz(free=0, allocated=[16]), 10, True),
+    # the bucket list does not enter: pages are counted by exact need
+    (_pool_statusz(free=3, buckets=[24, 48]), 20, False),
+    (_pool_statusz(free=2, buckets=[24, 48]), 20, True),
+], ids=["over", "exact", "page-boundary", "live-bucket", "buckets-fit",
+        "buckets-over"])
+def test_predict_429_pool(st, need, want):
+    assert fleet.Router.predict_429(st, need=need) is want
 
 
 def test_predict_429_over_max_len():
-    st = _dense_statusz(headroom=None, cost={})
+    st = _pool_statusz(free=100, headroom=None)
     assert fleet.Router.predict_429(st, need=100) is True
 
 
 def test_predict_429_unknown_headroom_predicts_nothing():
     # memsafe off -> headroom None -> never skip (admission control at
     # the replica stays the authority)
-    st = _dense_statusz(headroom=None, cost={"16": 500})
+    st = _pool_statusz(free=0, headroom=None)
     assert fleet.Router.predict_429(st, need=10) is False
-
-
-def test_predict_429_paged_pool():
-    st = {"admission": {"max_len": 64, "pages": "on", "page_size": 8,
-                        "pool_pages_free": 2, "headroom_bytes": 10**9},
-          "stats": {}}
-    assert fleet.Router.predict_429(st, need=32) is True   # needs 4 pages
-    assert fleet.Router.predict_429(st, need=16) is False  # exactly 2
 
 
 # -- routing -----------------------------------------------------------------
@@ -188,7 +172,7 @@ def test_statusz_publishes_admission_hints(gang):
     st = gang.eps[0].statusz()
     hints = st["admission"]
     assert hints["slots"] == 2 and hints["max_len"] >= 1
-    assert "headroom_bytes" in hints
+    assert "headroom_bytes" in hints and hints["page_size"] >= 1
     view = gang.router.statusz()
     assert set(view["replicas"]) == {0, 1}
 

@@ -34,7 +34,6 @@ LENGTHS = [(20, 14), (11, 25), (24, 16), (9, 31)]      # (prompt, new)
 def _clean():
     yield
     serve.disable()
-    pages.disable()
     config.reset()
 
 
@@ -49,7 +48,7 @@ def build(dtype, seed=3, **over):
 
 
 def server(model, **over):
-    args = dict(pages="on", page_size=4, slots=4, buckets=[48],
+    args = dict(page_size=4, slots=4, buckets=[48],
                 pool_pages=64, prefill_chunk=8)
     args.update(over)
     return serve.Server(model, **args)
@@ -251,14 +250,29 @@ def test_position_counters_are_what_a_walk_over_positions_counts():
     assert st["sparse_tokens"] == sum(c > 8 for c in contexts)
 
 
-def test_the_model_is_served_through_the_pool_only():
-    model, _ = build("float32")
-    with pytest.raises(ValueError, match="page pool"):
-        serve.Server(model, pages="off")
+def _gpt_tiny():
     gpt = gpt_mod.GPTForCausalLM(gpt_mod.gpt_tiny_config())
     gpt.initialize()
+    return gpt
+
+
+@pytest.mark.parametrize("which", ["glm", "gpt"])
+def test_the_model_is_served_through_the_pool_only(which):
+    """Every model, GPT like GLM: the dense path's name is refused with
+    an error that says it is gone; no keyword, or the "on" that
+    configuration files still pass, is the pool."""
+    model = build("float32")[0] if which == "glm" else _gpt_tiny()
+    with pytest.raises(ValueError, match="dense per-bucket .* is gone"):
+        serve.Server(model, pages="off")
+    for kw in ({}, {"pages": "on"}):
+        srv = serve.Server(model, page_size=4, slots=2, **kw)
+        assert srv.stats()["pages"] == "on" and srv._pool.data_pages > 0
+        srv.stop()
+
+
+def test_a_model_without_a_draft_step_cannot_draft():
     with pytest.raises(ValueError, match="cannot draft"):
-        serve.Server(gpt, pages="on", drafter=model)
+        serve.Server(_gpt_tiny(), drafter=build("float32")[0])
 
 
 def test_gpt_answers_the_serving_spec_with_what_the_server_read():
@@ -272,13 +286,11 @@ def test_gpt_answers_the_serving_spec_with_what_the_server_read():
     assert spec.vocab_size == cfg["vocab_size"]
     assert spec.max_length == cfg["max_length"]
     assert spec.streams == [(heads, d, jnp.float32)] * (2 * cfg["num_layers"])
-    assert spec.dense_streams == spec.streams and spec.index_topk is None
-    srv = serve.Server(gpt, pages="on", page_size=8, slots=4)
+    assert spec.index_topk is None
+    srv = serve.Server(gpt, page_size=8, slots=4)
     arenas = srv._pool.state["target"]
     assert len(arenas) == 2 * cfg["num_layers"]
     assert arenas[0].shape == (4 + 4 * (cfg["max_length"] // 8), heads, 8, d)
+    assert srv._pool.pool_bytes() == 2 * cfg["num_layers"] * 4 \
+        * (32 + 4 * cfg["max_length"]) * cfg["units"]
     srv.stop()
-    dense = serve.Server(gpt, slots=4)
-    assert dense._cache_bytes(32) == 2 * cfg["num_layers"] * 4 * 32 \
-        * cfg["units"] * 4
-    dense.stop()

@@ -630,12 +630,12 @@ def test_paged_attention_over_lane_padded_arenas(one_device, dtype, tol):
 
 def test_paged_server_tokens_equal_on_kernel_and_scatter_write(
         one_device, monkeypatch):
-    """Greedy tokens of a paged server whose steps write through the
-    kernel equal those of the same server writing through the scatter
-    (the attention kernel and lane-padded arenas on both sides): the
-    write is bit-exact, so pages=on stays bit-identical to pages=off."""
+    """Greedy tokens of a server whose steps write through the kernel
+    equal those of the same server writing through the scatter (the
+    attention kernel and lane-padded arenas on both sides): the write is
+    bit-exact."""
     import mxnet_tpu as mx
-    from mxnet_tpu import pages, pallas_ops, serve
+    from mxnet_tpu import pallas_ops, serve
     from mxnet_tpu.models import gpt as gpt_mod
 
     model = gpt_mod.GPTForCausalLM(gpt_mod.gpt_tiny_config())
@@ -646,8 +646,7 @@ def test_paged_server_tokens_equal_on_kernel_and_scatter_write(
                for n in (5, 9, 14, 17)]
 
     def tokens():
-        srv = serve.Server(model, slots=4, pages="on", page_size=4,
-                           prefill_chunk=4)
+        srv = serve.Server(model, slots=4, page_size=4, prefill_chunk=4)
         reqs = [srv.submit(p, max_new_tokens=8) for p in prompts]
         srv.drain()
         srv.stop()
@@ -661,15 +660,14 @@ def test_paged_server_tokens_equal_on_kernel_and_scatter_write(
         on_scatter = tokens()
     finally:
         serve.disable()
-        pages.disable()
     assert on_kernel == on_scatter
 
 
 def test_paged_attention_reference_matches_dense_gather():
     """Tables laid out contiguously (page i of row b = pool row holding
     positions [i*ps, (i+1)*ps)) reduce the paged computation to the
-    dense cached-attention expression — the shape identity serve's
-    pages=on-vs-off bit-identity rests on."""
+    dense cached-attention expression — the identity that holds served
+    tokens to `model.generate`'s."""
     rng = np.random.RandomState(3)
     B, H, D, ps, n_pg = 2, 4, 16, 8, 3
     L = n_pg * ps

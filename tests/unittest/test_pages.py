@@ -1,13 +1,13 @@
 """mx.pages tests: page pool alloc/free/refcount invariants, the
 content-hashed prefix tree (collision tolerance, partial-block tails,
 LRU leaf eviction returning pages under pressure), copy-on-write on a
-whole-prompt match, and the serve integration contracts — pages=on
-emits BIT-IDENTICAL tokens to the dense pages=off path (shared-prefix
-reuse included), speculative decoding is bit-identical to plain greedy
+whole-prompt match, and the serve integration contracts — the server
+emits BIT-IDENTICAL tokens to `model.generate` (shared-prefix reuse
+included), speculative decoding is bit-identical to plain greedy
 (exact acceptance, weak drafters included), admission under page
-exhaustion walks the degradation ladder, the pages=off fast path never
-calls into the module, and mx.check's `degenerate-paging` lint flags
-the configurations that silently void the feature."""
+exhaustion walks the degradation ladder, a server built with no
+keyword has a pool, and mx.check's `degenerate-paging` lint flags the
+configurations that silently void the feature."""
 import numpy as np
 import pytest
 
@@ -23,7 +23,6 @@ _VOCAB = 128
 def _clean():
     yield
     serve.disable()
-    pages.disable()
     mxcheck.disable()
     mxcheck.reset()
     config.reset()
@@ -197,21 +196,18 @@ def test_tree_clear_drains_every_reference():
 
 # -- serve integration: bit-identity ---------------------------------------
 
-def _dense_tokens(model, prompts, max_new=8, **submit_kw):
-    srv = serve.Server(model, slots=4)
-    reqs = [srv.submit(p, max_new_tokens=max_new, **submit_kw)
-            for p in prompts]
-    srv.drain()
-    out = [list(r.tokens) for r in reqs]
-    srv.stop()
-    return out
+def _generate_tokens(model, prompts, max_new=8):
+    """The reference: greedy `model.generate` on a dense cache, one
+    prompt at a time — code the server shares nothing with past the
+    block's token body."""
+    return [model.generate(p[None], max_new_tokens=max_new,
+                           on_device=False)[0].tolist() for p in prompts]
 
 
-def test_paged_bit_identical_to_dense(model):
+def test_served_bit_identical_to_generate(model):
     prompts = [_prompt(n, seed=n) for n in (5, 9, 14, 17)]
-    ref = _dense_tokens(model, prompts)
-    srv = serve.Server(model, slots=4, pages="on", page_size=4,
-                       prefill_chunk=4)
+    ref = _generate_tokens(model, prompts)
+    srv = serve.Server(model, slots=4, page_size=4, prefill_chunk=4)
     reqs = [srv.submit(p, max_new_tokens=8) for p in prompts]
     srv.drain()
     out = [list(r.tokens) for r in reqs]
@@ -231,8 +227,8 @@ def test_prefix_reuse_skips_prefill_bit_identical(model):
                                rng.randint(0, _VOCAB, (3,))
                                .astype(np.int32)])
                for _ in range(4)]
-    ref = _dense_tokens(model, prompts, max_new=6)
-    srv = serve.Server(model, slots=2, pages="on", page_size=4,
+    ref = _generate_tokens(model, prompts, max_new=6)
+    srv = serve.Server(model, slots=2, page_size=4,
                        prefill_chunk=4)
     out = []
     for p in prompts:                      # sequential: the tree is warm
@@ -249,8 +245,8 @@ def test_prefix_reuse_skips_prefill_bit_identical(model):
 
 def test_cow_on_whole_prompt_match(model):
     p = _prompt(16, seed=5)                # lp a page multiple: full match
-    ref = _dense_tokens(model, [p], max_new=4)[0]
-    srv = serve.Server(model, slots=2, pages="on", page_size=4,
+    ref = _generate_tokens(model, [p], max_new=4)[0]
+    srv = serve.Server(model, slots=2, page_size=4,
                        prefill_chunk=4)
     r1 = srv.submit(p, max_new_tokens=4)
     srv.drain()
@@ -268,9 +264,9 @@ def test_cow_on_whole_prompt_match(model):
 @pytest.mark.slow  # ~13s spec-decode drive; ci pages stage runs it by name
 def test_speculative_bit_identical_to_plain_greedy(model):
     prompts = [_prompt(n, seed=n) for n in (5, 9, 17)]
-    ref = _dense_tokens(model, prompts, max_new=16)
+    ref = _generate_tokens(model, prompts, max_new=16)
     # the target drafting for itself: near-total acceptance
-    srv = serve.Server(model, slots=4, pages="on", page_size=4,
+    srv = serve.Server(model, slots=4, page_size=4,
                        prefill_chunk=4, drafter=model, spec_k=3)
     reqs = [srv.submit(p, max_new_tokens=16) for p in prompts]
     srv.drain()
@@ -284,8 +280,8 @@ def test_speculative_bit_identical_to_plain_greedy(model):
 
 def test_weak_drafter_still_bit_identical(model, drafter):
     prompts = [_prompt(n, seed=100 + n) for n in (6, 11)]
-    ref = _dense_tokens(model, prompts, max_new=10)
-    srv = serve.Server(model, slots=2, pages="on", page_size=4,
+    ref = _generate_tokens(model, prompts, max_new=10)
+    srv = serve.Server(model, slots=2, page_size=4,
                        prefill_chunk=4, drafter=drafter, spec_k=3)
     reqs = [srv.submit(p, max_new_tokens=10) for p in prompts]
     srv.drain()
@@ -298,13 +294,15 @@ def test_weak_drafter_still_bit_identical(model, drafter):
 
 def test_spec_round_carries_sampled_rows(model):
     p1, p2 = _prompt(7, seed=21), _prompt(9, seed=22)
-    srv0 = serve.Server(model, slots=4)
+    # the reference for a SAMPLED row is a drafter-less server (its rng
+    # is the request's own); the greedy row is also held to generate
+    srv0 = serve.Server(model, slots=4, page_size=4, prefill_chunk=4)
     a = srv0.submit(p1, max_new_tokens=8, temperature=0.8, top_k=8, seed=3)
     b = srv0.submit(p2, max_new_tokens=8)
     srv0.drain()
     ref = [list(a.tokens), list(b.tokens)]
     srv0.stop()
-    srv = serve.Server(model, slots=4, pages="on", page_size=4,
+    srv = serve.Server(model, slots=4, page_size=4,
                        prefill_chunk=4, drafter=model, spec_k=3)
     a = srv.submit(p1, max_new_tokens=8, temperature=0.8, top_k=8, seed=3)
     b = srv.submit(p2, max_new_tokens=8)
@@ -312,6 +310,7 @@ def test_spec_round_carries_sampled_rows(model):
     out = [list(a.tokens), list(b.tokens)]
     srv.stop()
     assert out == ref
+    assert out[1] == _generate_tokens(model, [p2])[0]
 
 
 # -- serve integration: pressure, eviction, rejection -----------------------
@@ -322,8 +321,8 @@ def test_page_pressure_evicts_tree_and_completes(model):
     # every later distinct prompt must evict them to run
     prompts = [_prompt(10, seed=31), _prompt(10, seed=32),
                _prompt(10, seed=33)]
-    ref = _dense_tokens(model, prompts, max_new=4)
-    srv = serve.Server(model, slots=1, pages="on", page_size=4,
+    ref = _generate_tokens(model, prompts, max_new=4)
+    srv = serve.Server(model, slots=1, page_size=4,
                        prefill_chunk=4, pool_pages=5)
     out = []
     for p in prompts:
@@ -340,7 +339,7 @@ def test_page_pressure_evicts_tree_and_completes(model):
 
 def test_page_exhaustion_rejects_when_nothing_running(model):
     # a pool smaller than one table: the request can never fit
-    srv = serve.Server(model, slots=1, pages="on", page_size=4,
+    srv = serve.Server(model, slots=1, page_size=4,
                        prefill_chunk=4, pool_pages=3)
     r = srv.submit(_prompt(20, seed=41), max_new_tokens=8)
     srv.drain()
@@ -350,7 +349,7 @@ def test_page_exhaustion_rejects_when_nothing_running(model):
 
 
 def test_vacate_returns_exclusive_pages(model):
-    srv = serve.Server(model, slots=2, pages="on", page_size=4,
+    srv = serve.Server(model, slots=2, page_size=4,
                        prefill_chunk=4)
     total = srv._pool.free_pages()
     r = srv.submit(_prompt(9, seed=51), max_new_tokens=4)
@@ -363,27 +362,30 @@ def test_vacate_returns_exclusive_pages(model):
 
 # -- fast path + lint -------------------------------------------------------
 
-def test_pages_off_never_touches_module(model, monkeypatch):
-    calls = []
-    for name in ("PagePool", "PrefixTree", "enable"):
-        real = getattr(pages, name)
-        monkeypatch.setattr(
-            pages, name,
-            (lambda real_:
-             lambda *a, **k: calls.append(real_) or real_(*a, **k))(real))
-    srv = serve.Server(model, slots=2)     # pages defaults off
+def test_server_without_keyword_has_a_pool(model):
+    """There is one cache manager: `Server(model)` builds the pool at
+    construction (every slot at the longest servable length) and reports
+    the pool's keys; `pages="on"`, which configuration files still pass,
+    builds the same server, and the dense path's name is refused."""
+    srv = serve.Server(model, slots=2)
+    st = srv.stats()
+    assert st["pages"] == "on" and st["prefix_hit_rate"] == 0.0
+    assert st["pool_pages_total"] == 2 * (srv._max_len // st["page_size"])
+    assert st["pool_pages_free"] == st["pool_pages_total"]
+    assert serve.Server(model, slots=2, pages="on").stats()[
+        "pool_pages_total"] == st["pool_pages_total"]
+    with pytest.raises(KeyError):
+        config.get("pages")             # the switch is not an option
     r = srv.submit(_prompt(5), max_new_tokens=4)
     srv.drain()
     srv.stop()
     assert r.state == serve.DONE
-    assert calls == [] and not pages.enabled()
-    st = srv.stats()
-    assert "pages" not in st and "prefix_hit_rate" not in st
+    assert r.tokens == _generate_tokens(model, [_prompt(5)], 4)[0]
 
 
 def test_degenerate_paging_page_size_finding(model):
     mxcheck.enable()
-    srv = serve.Server(model, slots=1, pages="on", page_size=64,
+    srv = serve.Server(model, slots=1, page_size=64,
                        buckets=[32, 64])
     srv.stop()
     found = [f for f in mxcheck.findings()
@@ -398,7 +400,7 @@ def test_degenerate_paging_drafter_vocab_finding(model):
     mx.random.seed(9)
     mism.initialize()
     mxcheck.enable()
-    srv = serve.Server(model, slots=1, pages="on", page_size=4,
+    srv = serve.Server(model, slots=1, page_size=4,
                        drafter=mism)
     srv.stop()
     found = [f for f in mxcheck.findings()
@@ -409,7 +411,7 @@ def test_degenerate_paging_drafter_vocab_finding(model):
 
 def test_clean_paged_config_no_finding(model):
     mxcheck.enable()
-    srv = serve.Server(model, slots=1, pages="on", page_size=4)
+    srv = serve.Server(model, slots=1, page_size=4)
     srv.stop()
     assert [f for f in mxcheck.findings()
             if f["rule"] == "degenerate-paging"] == []

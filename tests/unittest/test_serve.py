@@ -1,6 +1,7 @@
-"""mx.serve tests: continuous-batching scheduler correctness
-(bit-identical under load, bucket-bounded executables), admission
-control (429 budget rejections riding mx.memsafe), bounded-queue
+"""mx.serve tests: continuous-batching scheduler correctness (tokens
+equal `model.generate`'s, bit-identical under load, bucket-bounded
+executables), admission control (429 budget rejections riding
+mx.memsafe over parameters + page pool + the step's peak), bounded-queue
 backpressure and both shed policies, per-request deadlines with
 mid-generation eviction, the graceful-degradation ladder (shrink,
 evict-and-requeue), transient-dispatch retry, serving fault injection
@@ -93,8 +94,8 @@ def test_bit_identical_under_load(model):
         srv.step()          # stagger: later requests join a running batch
     srv.drain()
     assert all(r.state == serve.DONE for r in reqs)
+    solo = serve.Server(model, slots=3)      # one at a time: never loaded
     for (lp, new, seed), r in zip(specs, reqs):
-        solo = serve.Server(model, slots=3)
         sr = solo.submit(_prompt(lp, seed), max_new_tokens=new)
         solo.drain()
         assert sr.tokens == r.tokens, f"load-dependent output for {r}"
@@ -144,8 +145,8 @@ def test_streaming_tokens_arrive_incrementally(model):
 
 
 def test_bucketing_bounds_executables_and_check_quiet(model):
-    """A stream of novel prompt/generation lengths compiles at most one
-    executable per bucket (two pow2 buckets here), and mx.check's
+    """A stream of novel prompt/generation lengths compiles at most two
+    executables per bucket (two pow2 buckets here), and mx.check's
     retrace-hazard rule stays quiet on the bucketed stream."""
     import jax
     mxcheck.enable("warn")
@@ -168,9 +169,10 @@ def test_bucketing_bounds_executables_and_check_quiet(model):
         jax.jit = real_jit
     assert all(r.state == serve.DONE for r in reqs)
     st = srv.stats()
-    assert st["executables"] <= 2, st          # one runner per bucket
-    assert jits["n"] <= 2, jits                # one jax.jit per bucket
-    assert set(srv._runners) == {32, 64}
+    # a chunk and a token executable per bucket, however many lengths
+    assert st["executables"] <= 2 * 2, st
+    assert jits["n"] <= 2 * 2, jits
+    assert {key[1] for key in srv._runners} == {32, 64}
     bad = [f for f in mxcheck.findings()
            if f["rule"] in ("retrace-hazard", "donation-miss")]
     assert bad == [], bad
@@ -214,7 +216,7 @@ def test_queue_shed_oldest(model):
 
 def test_admission_rejects_over_budget_429(model):
     srv = serve.Server(model, slots=2)
-    cap = srv._params_bytes + srv._cache_bytes(32) // 2
+    cap = srv._params_bytes + srv._pool.pool_bytes() // 2
     config.set("device_bytes_limit", cap)
     r = srv.submit(_prompt(8), max_new_tokens=16)
     assert r.state == serve.REJECTED
@@ -229,7 +231,7 @@ def test_admission_budget_rides_memsafe(model):
     the accounting in memsafe.last_check and raises nothing out of the
     scheduler."""
     srv = serve.Server(model, slots=2)
-    pred32 = srv._params_bytes + srv._cache_bytes(32) \
+    pred32 = srv._params_bytes + srv._pool.pool_bytes() \
         + (srv._exec_peak(32) or 0)
     config.set("device_bytes_limit", pred32 + 1)
     r = srv.submit(_prompt(4), max_new_tokens=4)
@@ -299,19 +301,31 @@ def test_default_deadline_knob(model):
 
 # -- graceful degradation ----------------------------------------------------
 
-def test_degrade_shrink_max_new(model):
+@pytest.mark.parametrize("short_of", ["bytes", "pages"])
+def test_degrade_shrink_max_new(model, short_of):
+    """One ladder, whichever refusal starts it. `bytes`: bucket 64's
+    step executable is over the byte budget beside parameters and pool,
+    bucket 32's fits (and `pages="on"`, the keyword two configuration
+    files still pass, is accepted: at PR 29 this server seated the
+    shrunk request in a DENSE group and the next step died with
+    AttributeError: '_Group' object has no attribute 'n_pg').
+    `pages`: the pool holds 32 tokens, the request wants 50."""
     telemetry.enable()
-    srv = serve.Server(model, slots=2)
-    pred32 = srv._params_bytes + srv._cache_bytes(32) \
-        + (srv._exec_peak(32) or 0)
-    pred64 = srv._params_bytes + srv._cache_bytes(64) \
-        + (srv._exec_peak(64) or 0)
-    config.set("device_bytes_limit", (pred32 + pred64) // 2)
+    if short_of == "bytes":
+        srv = serve.Server(model, slots=2, pages="on", page_size=4)
+        # pinned: XLA's CPU temp bytes hardly differ between the buckets
+        srv._exec_peaks.update({32: 4096, 64: 1 << 20})
+        config.set("device_bytes_limit",
+                   srv._params_bytes + srv._pool.pool_bytes() + (1 << 19))
+    else:
+        srv = serve.Server(model, slots=2, page_size=4, pool_pages=8)
     r = srv.submit(_prompt(10), max_new_tokens=40)    # wants bucket 64
     srv.drain()
     assert r.state == serve.DONE
     assert r.max_new_tokens == 22 and len(r.tokens) == 22
     assert r.degraded == "shrink_max_new:40->22"
+    assert r.tokens == model.generate(
+        _prompt(10)[None], max_new_tokens=22, on_device=False)[0].tolist()
     assert srv.stats()["degraded"] == 1
     evs = [e for e in telemetry.events("serve")
            if e.get("action") == "shrink_max_new"]
@@ -323,17 +337,12 @@ def test_degrade_evict_requeues_youngest_bit_exact_replay(model):
     ref = solo.submit(_prompt(4), max_new_tokens=50)
     solo.drain()
 
-    srv = serve.Server(model, slots=1)
-    # pin the executables' AOT peak: XLA's CPU temp bytes grow with the
-    # cache (jax 0.9: temp(64) - temp(32) == cache_bytes(32)), which made
-    # "one 64 bucket" and "a 64 and a 32 bucket" cost exactly the same
-    srv._exec_peaks.update({32: 4096, 64: 4096})
-    cap = srv._params_bytes + srv._cache_bytes(64) \
-        + srv._exec_peak(64) + 1000            # one 64 bucket, nothing more
-    config.set("device_bytes_limit", cap)
+    # 14 pages of 4: the 54 positions of `a`, nothing more
+    srv = serve.Server(model, slots=1, page_size=4, pool_pages=14)
     a = srv.submit(_prompt(4), max_new_tokens=50)     # bucket 64
     srv.step()
     assert a.state == serve.RUNNING
+    assert srv._pool.free_pages() == 0
     b = srv.submit(_prompt(4), max_new_tokens=4)      # bucket 32: pressure
     srv.drain()
     assert b.state == serve.DONE
@@ -346,21 +355,40 @@ def test_degrade_evict_requeues_youngest_bit_exact_replay(model):
     assert st["requeues"] == 1 and st["degraded"] >= 1
 
 
-def test_pages_freed_by_expiry_admit_same_step(model):
-    """KV pages reclaimed by an eviction must be reusable by admission
-    in the SAME scheduler step — a drained group's caches counting
-    against the budget would spuriously 429 a request the very next
-    line would have had room for."""
-    clk = _FakeClock()
-    srv = serve.Server(model, slots=1, clock=clk)
-    cap = srv._params_bytes + srv._cache_bytes(32) \
-        + (srv._exec_peak(32) or 0) + 1000     # exactly one 32 bucket
-    config.set("device_bytes_limit", cap)
-    a = srv.submit(_prompt(4), max_new_tokens=20, deadline_ms=50)
+def test_byte_refusal_evicts_nobody_and_rejects_at_once(model):
+    """An eviction frees pages, not bytes: a request whose step
+    executable does not fit beside parameters and pool, and that no
+    smaller bucket can hold, is rejected with the accounting while the
+    running request keeps its slot."""
+    srv = serve.Server(model, slots=2, page_size=4)
+    srv._exec_peaks.update({32: 4096, 64: 1 << 20})
+    config.set("device_bytes_limit",
+               srv._params_bytes + srv._pool.pool_bytes() + (1 << 19))
+    a = srv.submit(_prompt(4), max_new_tokens=20)     # bucket 32: fits
     srv.step()
     assert a.state == serve.RUNNING
+    b = srv.submit(_prompt(40), max_new_tokens=20)    # 64 or nothing
+    srv.step()
+    assert b.state == serve.REJECTED
+    assert "429" in b.verdict and "capacity" in b.verdict
+    assert a.state == serve.RUNNING and a.requeues == 0
+    srv.drain()
+    assert a.state == serve.DONE and srv.stats()["requeues"] == 0
+
+
+def test_pages_freed_by_expiry_admit_same_step(model):
+    """Pages returned by an eviction must be reusable by admission in
+    the SAME scheduler step — an expired request's pages still counting
+    against the pool would spuriously 429 (or shrink) a request the very
+    next line would have had room for."""
+    clk = _FakeClock()
+    # 6 pages of 4: exactly the 24 positions of `a`
+    srv = serve.Server(model, slots=1, clock=clk, page_size=4, pool_pages=6)
+    a = srv.submit(_prompt(4), max_new_tokens=20, deadline_ms=50)
+    srv.step()
+    assert a.state == serve.RUNNING and srv._pool.free_pages() == 0
     clk.t = 1.0                                # a's deadline passes
-    b = srv.submit(_prompt(4), max_new_tokens=4)
+    b = srv.submit(_prompt(4, seed=1), max_new_tokens=4)
     srv.step()              # one step: evict a AND admit b
     assert a.state == serve.EXPIRED
     assert b.state == serve.RUNNING, (b.state, b.verdict)
@@ -394,8 +422,8 @@ def test_cancel_spec_waits_for_target(model):
 def _flaky(srv, fails, exc=OSError("transient fabric glitch")):
     orig = srv._runner
 
-    def runner(bucket):
-        run = orig(bucket)
+    def runner(*key, **kw):
+        run = orig(*key, **kw)
 
         def wrapped(*args):
             if fails["n"] > 0:
@@ -404,6 +432,7 @@ def _flaky(srv, fails, exc=OSError("transient fabric glitch")):
             return run(*args)
 
         wrapped.aot_exec_peak = run.aot_exec_peak
+        wrapped.lower = run.lower
         return wrapped
 
     srv._runner = runner
@@ -620,15 +649,16 @@ def test_overload_acceptance_smoke(model):
     steps, and every COMPLETED request's tokens are bit-identical to
     its unloaded single-request generation."""
     config.set("fault_inject",
-               "slow_client:10,burst:2@step:6,cancel@req:1@step:8")
+               "slow_client:10,burst:2@step:6,cancel@req:1@step:5")
     resilience.install()
     telemetry.enable()
     clk = _FakeClock()
     srv = serve.Server(model, slots=3, queue_depth=3, shed="reject",
                        clock=clk)
-    cap = srv._params_bytes + srv._cache_bytes(32) \
-        + (srv._exec_peak(32) or 0) + 2000     # one 32 bucket only
-    config.set("device_bytes_limit", cap)
+    # bucket 32's step fits beside parameters and pool, bucket 64's not
+    srv._exec_peaks.update({32: 4096, 64: 1 << 20})
+    config.set("device_bytes_limit",
+               srv._params_bytes + srv._pool.pool_bytes() + (1 << 19))
     extra = []
     srv.on_burst = lambda n: extra.extend(
         srv.submit(_prompt(5, 50 + i), max_new_tokens=5) for i in range(n))
@@ -639,7 +669,7 @@ def test_overload_acceptance_smoke(model):
     # id2: wants bucket 64 -> MemoryBudgetError at admission; the shrink
     # rung clamps it into the free slot of the affordable 32 bucket
     big = srv.submit(_prompt(10, 7), max_new_tokens=40)
-    # id3: cannot fit the device even alone -> 429 immediately
+    # id3: no bucket under 64 holds it -> 429 at its first admission
     over = srv.submit(_prompt(40, 8), max_new_tokens=20)
     late = srv.submit(_prompt(3, 9), max_new_tokens=25, deadline_ms=300)
     flood = [srv.submit(_prompt(4, 20 + i), max_new_tokens=4)
@@ -737,7 +767,7 @@ def test_serve_int8_tokens_match_dequantized_reference():
 def test_serve_int8_memory_accounting_stays_correct():
     """Per-request KV/memory accounting on the quantized server: the
     resident-params measurement sees the int8 footprint (smaller than
-    fp32), KV cache bytes are unchanged (caches stay in the model
+    fp32), the pool's bytes are unchanged (arenas stay in the model
     dtype), and the admission budget check still runs pre-dispatch."""
     qmodel, _ = _quantized_models()
     fp = model_fp = gpt_mod.GPTForCausalLM(gpt_mod.gpt_tiny_config())
@@ -746,7 +776,7 @@ def test_serve_int8_memory_accounting_stays_correct():
     srv_fp = serve.Server(fp, slots=2)
     srv_q = serve.Server(qmodel, slots=2)
     assert 0 < srv_q._params_bytes < srv_fp._params_bytes
-    assert srv_q._cache_bytes(32) == srv_fp._cache_bytes(32)
+    assert srv_q._pool.pool_bytes() == srv_fp._pool.pool_bytes() > 0
     # the budget path still produces a verdict under a tiny simulated
     # capacity: a request that cannot fit is 429'd, never dispatched
     config.set("device_bytes_limit", srv_q._params_bytes + 1)

@@ -244,8 +244,8 @@ def _paged_server(slots=2):
     model = gpt_mod.GPTForCausalLM(gpt_mod.gpt_tiny_config())
     mx.random.seed(0)
     model.initialize()
-    srv = serve.Server(model, slots=slots, pages="on", page_size=8,
-                       prefill_chunk=8, buckets=[32])
+    srv = serve.Server(model, slots=slots, page_size=8, prefill_chunk=8,
+                       buckets=[32])
     rng = np.random.RandomState(0)
     for n_prompt in (11, 5, 9):
         srv.submit(rng.randint(0, 128, (n_prompt,)).astype(np.int32),
