@@ -319,14 +319,19 @@ def serve_one_chip():
     srv = serve.Server(model, slots=slots, page_size=page, buckets=buckets)
 
     for bucket in buckets:
-        found = tpu_validate.pallas_kernels(srv.lower_step(bucket))
-        say(f"bucket {bucket}: Pallas kernels in the lowered chunk step: "
-            f"{found or 'none'}")
+        lowered = srv.lower_step(bucket)
+        found = tpu_validate.pallas_kernels(lowered)
+        say(f"bucket {bucket}: Pallas kernels in the lowered wide step "
+            f"({srv._wide()} virtual rows): {found or 'none'}")
         if not REHEARSAL:
+            # once through the layers: each kernel once a layer, in no loop
             for kernel in ("paged_attention", "kv_page_write"):
-                require(found.get(kernel, 0) >= 1,
-                        f"{kernel} missing from the bucket-{bucket} "
-                        f"step: {found}")
+                require(found.get(kernel, 0) == cfg["num_layers"],
+                        f"{kernel} x{cfg['num_layers']} expected in the "
+                        f"bucket-{bucket} step: {found}")
+            require("stablehlo.while" not in lowered.as_text(),
+                    f"a loop in the bucket-{bucket} step, which feeds "
+                    "its tokens through the layers once")
 
     rng = np.random.RandomState(0)
     for wave in ("first wave (compiles included)", "second wave (warm)"):
@@ -347,7 +352,12 @@ def serve_one_chip():
                     f"token outside the vocabulary: {r!r}")
     st = srv.stats()
     say(f"{st['completed']} requests, {st['tokens']} tokens, "
-        f"{st['steps']} scheduler steps")
+        f"{st['steps']} scheduler steps; dispatches by width "
+        f"{st['width_dispatches']}, {st['rows_fed']} tokens fed in "
+        f"{st['rows_dispatched']} virtual rows; executables "
+        f"{st['executables']}")
+    require(st["chunk_steps"] > 0 and st["token_steps"] > 0,
+            f"both widths should have run: {st['width_dispatches']}")
     srv.stop()
     del srv, model
     gc.collect()
@@ -369,6 +379,9 @@ def kernels():
                                   dtype=jnp.float32, expect_kernel=False)
         tpu_validate.kv_write_parity(B=4, H=4, D=16, page_size=8, n_pages=24,
                                      dtype=jnp.float32, expect_kernel=False)
+        tpu_validate.kv_write_parity(B=16, H=4, D=16, page_size=8,
+                                     n_pages=48, dtype=jnp.float32,
+                                     expect_kernel=False)
         tpu_validate.lamb_parity([(64, 64)] * 4 + [(64,)],
                                  expect_kernel=False)
         tpu_validate.int8_parity(M=8, K=128, O=256)
@@ -378,8 +391,10 @@ def kernels():
         tpu_validate.flash_dropout_oracle()
         # GPT-2 345M's decode shapes: 16 heads of 64, page 16, a 512 bucket
         tpu_validate.paged_parity(B=8, H=16, D=64, page_size=16, n_pg=32)
-        # the benchmark's serving cell: 32 slots over a pool of 2,080 pages
+        # the benchmark's serving cell: 32 slots over a pool of 2,080
+        # pages; then its wide pass, 64 virtual rows
         tpu_validate.kv_write_parity()
+        tpu_validate.kv_write_parity(B=64)
         # BERT-base's own parameter layout (110M)
         cfg = bert_case()[0]
         model = bert_mod.BERTForPretraining(cfg)

@@ -706,18 +706,21 @@ register_option(
     "evict-and-requeue, reject.")
 register_option(
     "pages_prefill_chunk", 8,
-    "Prompt tokens mx.serve feeds per batched-prefill dispatch. Each "
-    "bucket compiles one chunk executable (a lax.scan of the one-token "
-    "step, bit-identical to feeding tokens singly) — prompts reach "
-    "their first sampled token in ~1/chunk the dispatches of a "
-    "one-token prefill.")
+    "Prompt tokens ONE request feeds in one mx.serve step (a per-"
+    "request cap, not the width of a step). A step is one pass over its "
+    "tokens: a token of every decoding request and up to this many "
+    "prompt tokens of every request still inside its prompt, in "
+    "admission order. The width of a pass is derived, not set: `slots` "
+    "rows when the tokens fit, else 2 x slots — two executables a "
+    "bucket; a burst with more prompt tokens than that takes further "
+    "passes in the same step.")
 register_option(
     "pages_spec_k", 4,
     "Draft tokens per speculative decoding round (a serve.Server "
     "with a drafter). The drafter chains k greedy proposals, the target "
-    "verifies all of them plus the bonus token in one k+1-token "
-    "chunk, and exact acceptance keeps the longest agreeing prefix — "
-    "the emitted stream stays bit-identical to plain greedy decode, "
+    "verifies all of them plus the bonus token in one pass of k+1 "
+    "rows a slot, and exact acceptance keeps the longest agreeing "
+    "prefix — the emitted stream stays plain greedy decode's, "
     "so k only trades dispatch count against wasted draft work.")
 register_option(
     "slo", "off", choices=("off", "on"),

@@ -21,35 +21,53 @@ from ..gluon import HybridBlock
 #                            dt)`, no head axis)
 #   index_topk               tokens a learned sparse attention keeps, None
 #                            for full attention (the `sparse_tokens` counter)
-#   chunk_step               `decode_paged_chunk(toks, t0, n, tables, flat,
-#                            page_size, full=)`
+#   chunk_step               `decode_paged_chunk(toks, pos, slot, last,
+#                            tables, flat, page_size, full=)`: one pass over
+#                            the step's tokens as virtual rows (see
+#                            `GPTForCausalLM.decode_paged_chunk`)
 #   draft_step               `decode_paged_draft(...)`, None: cannot draft
 ServingSpec = collections.namedtuple(
     "ServingSpec", "vocab_size max_length streams index_topk chunk_step "
     "draft_step")
 
 
-def paged_write_targets(pos_d, active_d, tb_d, page_size):
-    """Write page/offset for one chunk step: active rows write page
-    tables[b, pos//ps] at offset pos%ps; masked rows write their
-    private scratch page (page id == batch row — mx.pages reserves
-    pages 0..slots-1 as per-slot scratch), so a batched step never
-    scatters two rows into one (page, offset) cell and never pollutes
-    a real page of an inactive request. Positions past the table's
-    range also divert to scratch: a speculative round that starts
-    near the bucket's last position feeds its fixed k+1 tokens past
-    the end, and clipping those writes back into the last real page
-    would corrupt positions the row still attends."""
+def paged_write_targets(pos_d, active_d, tb_d, page_size, scratch=None):
+    """Write page/offset of each row of a paged step. Row b is one fed
+    token at position pos_d[b], `tb_d[b]` the page-table row it goes
+    through: it writes page tb_d[b, pos//ps] at offset pos%ps. Rows that
+    are not `active_d` (padding, a masked lane of the draft chain) write
+    offset 0 of the scratch page `scratch[b]` instead (mx.pages reserves
+    pages 0..slots-1 as per-slot scratch; the row's own index where
+    `scratch` is None), so a batched step never pollutes a real page of
+    an inactive request. Positions past the table's range also divert to
+    scratch: a speculative round that starts near the bucket's last
+    position feeds its fixed k+1 tokens past the end, and clipping those
+    writes back into the last real page would corrupt positions the row
+    still attends. Nothing reads a scratch page, so rows may share a
+    scratch cell; every other cell is named by one row."""
     import jax.numpy as jnp
 
     B, n_pg = tb_d.shape
     idx = jnp.clip(pos_d // page_size, 0, n_pg - 1)
     real = jnp.take_along_axis(tb_d, idx[:, None], axis=1)[:, 0]
-    scratch = jnp.arange(B, dtype=jnp.int32)
-    ok = active_d & (pos_d < n_pg * page_size)
-    wp = jnp.where(ok, real.astype(jnp.int32), scratch)
+    if scratch is None:
+        scratch = jnp.arange(B, dtype=jnp.int32)
+    ok = active_d & (pos_d >= 0) & (pos_d < n_pg * page_size)
+    wp = jnp.where(ok, real.astype(jnp.int32), scratch.astype(jnp.int32))
     wo = jnp.where(ok, pos_d % page_size, 0).astype(jnp.int32)
     return wp, wo
+
+
+def virtual_rows(pos_d, slot_d, tb_d, page_size):
+    """What the one-token body needs of a step's W virtual rows (each a
+    fed token: position pos_d[w] >= 0 of the request in slot slot_d[w],
+    or padding at position -1): the page-table row each goes through
+    (W, n_pg) and its write target. Padding writes its slot's scratch
+    page. Returns (rows, wp, wo)."""
+    rows = tb_d[slot_d]
+    wp, wo = paged_write_targets(pos_d, pos_d >= 0, rows, page_size,
+                                 scratch=slot_d)
+    return rows, wp, wo
 
 
 def cached_self_attention_step(q, k_new, v_new, k_cache, v_cache, t):
@@ -89,14 +107,20 @@ def paged_attention_step(q, k_new, v_new, k_pages, v_pages, tables, wp, wo,
                          t):
     """`cached_self_attention_step` with PER-ROW positions over an
     mx.pages block-table cache — the continuous-batching variant
-    mx.serve's decode slots need: row b writes this token's K/V into
-    page wp[b] at in-page offset wo[b] and attends over positions <=
-    t[b] gathered through its page table. The attention math is
-    `pallas_ops.paged_attention`, whose XLA fallback is the scalar-t
-    version's f32 score/softmax/PV expression at the gathered (B,H,L,D)
-    shapes, so a request's logits do not depend on what the other slots
-    are doing — the property mx.serve's same-under-load-as-alone
-    guarantee rests on.
+    mx.serve's steps need. A row is one fed token: row b writes its K/V
+    into page wp[b] at in-page offset wo[b] and attends over positions <=
+    t[b] gathered through ITS page-table row tables[b]. Several rows may
+    be one request's tokens at consecutive positions (the same table row
+    repeated): every row's write lands before any row attends, and a row
+    at position p attends positions <= p, so a prompt token sees the same
+    pass's earlier tokens of its own request and nothing later —
+    causality inside a pass comes from the cache. A row at t = -1 is
+    padding: it attends nothing and its output is not read. The attention
+    math is `pallas_ops.paged_attention`, whose XLA fallback is the
+    scalar-t version's f32 score/softmax/PV expression at the gathered
+    (B,H,L,D) shapes, so a request's logits do not depend on what the
+    other slots are doing — the property mx.serve's
+    same-under-load-as-alone guarantee rests on.
 
     The write is `pallas_ops.kv_page_write`: where the paged kernels run
     (one device) it replaces row wo[b] of page wp[b] in place, in the
@@ -104,9 +128,9 @@ def paged_attention_step(q, k_new, v_new, k_pages, v_pages, tables, wp, wo,
     dimension being padded to the lane width there, `arena_head_dim` —
     the arenas rest in, so no arena is copied or re-laid around it;
     elsewhere it is the `.at[wp, :, wo, :].set` scatter, the pre-kernel
-    expression. Either way the targets are distinct by construction:
-    every serve slot owns its write page exclusively (masked-out rows
-    write their private scratch page), so no two rows name one page.
+    expression. Rows may share a page (at different offsets); the cells
+    are distinct by construction but for scratch cells
+    (`paged_write_targets`), which nothing reads.
 
     q/k_new/v_new (B,H,1,D); k_pages/v_pages (P,H,ps,Dp), Dp >= D (the
     lanes past D hold zeros); tables (B,n_pg) int32; wp/wo/t (B,) traced

@@ -32,8 +32,8 @@ model's logits). `chipbench/reference/glm5.py` is the plain float32
 reference of the same equations.
 
 Serving only, paged only: `decode_paged_chunk` has the contract of
-`GPTForCausalLM.decode_paged_chunk` (a `lax.scan` of one-token passes,
-prefill is the same pass), against two arenas a layer: latents
+`GPTForCausalLM.decode_paged_chunk` (one pass over the step's tokens as
+virtual rows; prefill is the same pass), against two arenas a layer: latents
 `(pages, page_size, kv_lora_rank + qk_rope_head_dim)` and indexer keys
 `(pages, page_size, index_head_dim)`. Parameters carry no gradient
 buffers; matrices are stored (in, out), `y = x @ W`.
@@ -43,7 +43,7 @@ from ..gluon import HybridBlock
 from ..gluon.parameter import Parameter
 from ..ndarray import NDArray
 from ..parallel import moe as _moe
-from ._decode import ServingSpec, paged_write_targets
+from ._decode import ServingSpec, virtual_rows
 
 # the published config.json, without the keys that say nothing of shape
 GLM5_PUBLISHED = dict(
@@ -361,9 +361,12 @@ class GLMForCausalLM(HybridBlock):
                 + swiglu(v, _w(layer.s_gate), _w(layer.s_up), _w(layer.s_down))
 
     def _paged_token_step(self, tok, pos, tables, wp, wo, lats, idxs,
-                          page_size):
-        """One token a row through every layer (raw arrays; the scan body
-        of the chunk step). Returns (float32 logits (B, V), lats, idxs)."""
+                          page_size, head_rows=None):
+        """One token a row through every layer (raw arrays; the whole of
+        the chunk step). A row is one fed token and `tables[b]` the
+        page-table row it goes through. `head_rows` (R,): the rows whose
+        logits are wanted (all B where None). Returns (float32 logits
+        (R|B, V), lats, idxs)."""
         import jax
         import jax.numpy as jnp
         eps = self.cfg["rms_norm_eps"]
@@ -377,49 +380,39 @@ class GLMForCausalLM(HybridBlock):
             new_idx.append(idx)
             x = x + a
             x = x + self._ffn(layer, rms_norm(x, _w(layer.ffn_norm), eps))
+        if head_rows is not None:
+            x = x[head_rows]
         x = rms_norm(x, _w(self.final_norm), eps)
         with jax.named_scope("lm_head"):
             lg = jnp.matmul(x, _w(self.head),
                             preferred_element_type=jnp.float32)
         return lg, tuple(new_lat), tuple(new_idx)
 
-    def decode_paged_chunk(self, toks, t0, n, tables, flat, page_size,
-                           full=False):
-        """The chunk step `serve.Server` runs, with the contract of
-        `GPTForCausalLM.decode_paged_chunk`: row b feeds toks[b, :n[b]] at
-        positions t0[b].., each a whole one-token pass that writes its
-        latent and indexer key into page `wp[b]` at `wo[b]` (scratch pages
-        for masked rows), scores the row's pages, selects, gathers and
-        attends. flat = latent arenas per layer, then indexer-key arenas.
-        Returns (float32 logits of each row's last fed token (B, V), or
-        all (B, C, V) when `full`; the new arenas)."""
-        import jax
+    def decode_paged_chunk(self, toks, pos, slot, last, tables, flat,
+                           page_size, full=False):
+        """The serving step `serve.Server` runs, with the contract of
+        `GPTForCausalLM.decode_paged_chunk`: ONE pass over the step's W
+        virtual rows (token toks[w] at position pos[w] of the request in
+        slot slot[w]; pos = -1 pads). Within a layer every row's latent
+        and indexer key is written (page `wp`, offset `wo`; scratch for
+        padding) before any row scores its pages, selects, gathers and
+        attends positions <= its own. flat = latent arenas per layer, then
+        indexer-key arenas. Returns (float32 logits of row last[s] for
+        each slot s (slots, V), or of all rows (W, V) when `full`; the
+        new arenas)."""
         import jax.numpy as jnp
 
         n_l = len(self.layers)
-        toks_d, t0_d, n_d, tb_d = (toks._data, t0._data, n._data,
-                                   tables._data.astype(jnp.int32))
+        pos_d = pos._data.astype(jnp.int32)
+        slot_d = slot._data.astype(jnp.int32)
         flat_d = [f._data for f in flat]
-        B, C = toks_d.shape
-
-        def tok_step(carry, j):
-            lats, idxs, last = carry
-            tokj = jax.lax.dynamic_index_in_dim(
-                toks_d, j, axis=1, keepdims=False).astype(jnp.int32)
-            pos = (t0_d + j).astype(jnp.int32)
-            wp, wo = paged_write_targets(pos, j < n_d, tb_d, page_size)
-            lg, lats, idxs = self._paged_token_step(
-                tokj, pos, tb_d, wp, wo, lats, idxs, page_size)
-            last = jnp.where((j == n_d - 1)[:, None], lg, last)
-            return (lats, idxs, last), (lg if full
-                                        else jnp.zeros((), lg.dtype))
-
-        last0 = jnp.zeros((B, self.cfg["vocab_size"]), jnp.float32)
-        (lats, idxs, last), stack = jax.lax.scan(
-            tok_step, (tuple(flat_d[:n_l]), tuple(flat_d[n_l:]), last0),
-            jnp.arange(C))
-        out = stack.transpose(1, 0, 2) if full else last
-        return NDArray(out), [NDArray(a) for a in list(lats) + list(idxs)]
+        rows, wp, wo = virtual_rows(
+            pos_d, slot_d, tables._data.astype(jnp.int32), page_size)
+        lg, lats, idxs = self._paged_token_step(
+            toks._data.astype(jnp.int32), pos_d, rows, wp, wo,
+            tuple(flat_d[:n_l]), tuple(flat_d[n_l:]), page_size,
+            head_rows=None if full else last._data.astype(jnp.int32))
+        return NDArray(lg), [NDArray(a) for a in list(lats) + list(idxs)]
 
 
 def param_count(cfg):

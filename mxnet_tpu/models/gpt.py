@@ -19,7 +19,7 @@ from ..gluon import nn, HybridBlock
 from ..gluon.parameter import Parameter
 from ..ndarray import NDArray
 from ..ndarray import ndarray as F
-from ._decode import ServingSpec, paged_write_targets
+from ._decode import ServingSpec, paged_write_targets, virtual_rows
 from .bert import BERTAttention, _positions, _scan_layers_call
 from .bert import tp_rules as _bert_tp_rules
 
@@ -290,15 +290,19 @@ class GPTForCausalLM(HybridBlock):
         return logits.reshape(shape=(tok.shape[0], -1)), new_k, new_v
 
     # -- paged decode (mx.pages block-table cache) -------------------------
-    def _paged_token_step(self, tok_d, pos_d, tb_d, wp_d, wo_d, ks, vs):
-        """Raw-jax one-token paged step (the lax.scan body of the chunk
-        and draft programs): `decode_step` with per-row positions —
-        embed + pe[pos] + layer stack + ln_f + tied logits — with the
-        layers' cache access routed through `step_slots_paged`. Takes and
-        returns raw arrays (scan carries); ks/vs are tuples of the
-        pooled (P,H,ps,D) page arrays per layer.
+    def _paged_token_step(self, tok_d, pos_d, tb_d, wp_d, wo_d, ks, vs,
+                          head_rows=None):
+        """Raw-jax one-token paged step (the whole of the chunk program,
+        the lax.scan body of the draft chain): `decode_step` with per-row
+        positions — embed + pe[pos] + layer stack + ln_f + tied logits —
+        with the layers' cache access routed through `step_slots_paged`.
+        A row is one fed token with the page-table row it goes through
+        (tb_d (B,n_pg)); the batch is whatever the caller packed. Takes
+        and returns raw arrays; ks/vs are tuples of the pooled (P,H,ps,D)
+        page arrays per layer. `head_rows` (R,) int32: the rows whose
+        logits are wanted (all B where None).
 
-        Returns (f32 logits (B,V), new_ks, new_vs)."""
+        Returns (f32 logits (R|B, V), new_ks, new_vs)."""
         import jax
         import jax.numpy as jnp
         from ..ndarray import apply_op
@@ -318,12 +322,14 @@ class GPTForCausalLM(HybridBlock):
                 NDArray(wp_d), NDArray(wo_d), t)
             nk.append(k._data)
             nv.append(v._data)
+        if head_rows is not None:
+            x = NDArray(x._data[head_rows])
         x = g.ln_f(x)
         with jax.named_scope("lm_head"):
             logits = apply_op(
                 lambda hh, w: jnp.matmul(hh, w.T.astype(hh.dtype)),
                 x, g.word_embed.weight.data())
-            lg = logits.reshape(shape=(tok.shape[0], -1))._data \
+            lg = logits.reshape(shape=(x.shape[0], -1))._data \
                 .astype(jnp.float32)
         return lg, tuple(nk), tuple(nv)
 
@@ -344,52 +350,44 @@ class GPTForCausalLM(HybridBlock):
             chunk_step=self.decode_paged_chunk,
             draft_step=self.decode_paged_draft)
 
-    def decode_paged_chunk(self, toks, t0, n, tables, flat, page_size,
-                           full=False):
-        """Chunked paged decode body (jit_flat_step step_fn): row b feeds
-        its n[b] tokens toks[b, :n[b]] at positions t0[b].. — many prompt
-        tokens per dispatch (batched prefill) or one (steady decode), in
-        ONE executable per (bucket, chunk) shape. The body is a lax.scan
-        of C structurally identical one-token steps
-        (`_paged_token_step`), so a chunk's logits are bit-identical to
-        feeding the same tokens one dispatch at a time.
+    def decode_paged_chunk(self, toks, pos, slot, last, tables, flat,
+                           page_size, full=False):
+        """The serving step (jit_flat_step step_fn): ONE pass over the
+        step's tokens. The scheduler hands them as W virtual rows — row w
+        is token toks[w] at position pos[w] of the request in slot
+        slot[w], whose page-table row tables[slot[w]] it reads and writes
+        through; rows that pad the pass to its width have pos = -1 (they
+        walk no page and write their slot's scratch page). A decoding
+        request is one row, a request inside its prompt several, at
+        consecutive positions: the one-token body (`_paged_token_step`)
+        runs once at batch W, and within a layer every row's key and
+        value is written before any row attends, so a prompt token sees
+        the pass's earlier tokens of its own request and — attending
+        positions <= its own — nothing later.
 
-        Rows past their count (j >= n[b]) run masked: writes land in the
-        row's scratch page and their logits are discarded.
+        The head runs on row last[s] for each slot s, the slot's last fed
+        token (any row where the slot fed nothing: its logits are not
+        read), or on all W rows when `full`, the speculative verify
+        surface. Logits agree with feeding the same tokens one dispatch at
+        a time to rounding (a matmul at batch W rounds like a matmul at
+        batch W), not bit for bit.
 
-        toks (B,C) int32; t0/n (B,) int32; tables (B,n_pg) int32; flat =
-        2*n_l pooled page arrays (K per layer, then V). Returns
-        (last-active f32 logits (B,V) — or the full (B,C,V) stack when
-        `full`, the speculative verify surface — and the new pool
-        arrays)."""
-        import jax
+        toks/pos/slot (W,) int32; last (slots,) int32; tables (slots,
+        n_pg) int32; flat = 2*n_l pooled page arrays (K per layer, then
+        V). Returns (f32 logits (slots,V), or (W,V) when `full`; the new
+        pool arrays)."""
         import jax.numpy as jnp
 
         n_l = len(self.gpt.layers)
-        toks_d, t0_d, n_d, tb_d = (toks._data, t0._data, n._data,
-                                   tables._data)
+        pos_d = pos._data.astype(jnp.int32)
+        slot_d = slot._data.astype(jnp.int32)
         flat_d = [f._data for f in flat]
-        B, C = toks_d.shape
-        V = self.gpt.word_embed.weight.shape[0]
-
-        def tok_step(carry, j):
-            ks, vs, last = carry
-            tokj = jax.lax.dynamic_index_in_dim(
-                toks_d, j, axis=1, keepdims=False).astype(jnp.int32)
-            pos = (t0_d + j).astype(jnp.int32)
-            active = j < n_d
-            wp, wo = paged_write_targets(pos, active, tb_d, page_size)
-            lg, ks, vs = self._paged_token_step(tokj, pos, tb_d, wp, wo,
-                                                ks, vs)
-            last = jnp.where((j == n_d - 1)[:, None], lg, last)
-            return (ks, vs, last), (lg if full else jnp.zeros((), lg.dtype))
-
-        last0 = jnp.zeros((B, V), jnp.float32)
-        (ks, vs, last), stack = jax.lax.scan(
-            tok_step, (tuple(flat_d[:n_l]), tuple(flat_d[n_l:]), last0),
-            jnp.arange(C))
-        out = stack.transpose(1, 0, 2) if full else last   # (B,C,V)|(B,V)
-        return NDArray(out), [NDArray(a) for a in list(ks) + list(vs)]
+        rows, wp, wo = virtual_rows(pos_d, slot_d, tables._data, page_size)
+        lg, ks, vs = self._paged_token_step(
+            toks._data.astype(jnp.int32), pos_d, rows, wp, wo,
+            tuple(flat_d[:n_l]), tuple(flat_d[n_l:]),
+            head_rows=None if full else last._data.astype(jnp.int32))
+        return NDArray(lg), [NDArray(a) for a in list(ks) + list(vs)]
 
     def decode_paged_draft(self, tok0, t0, active, tables, flat, page_size,
                            n_draft):

@@ -13,7 +13,11 @@ program instead: one grid program a batch row reads the row's table
 stays in HBM, into VMEM a few at a time, the next few in flight while
 these are reduced into online-softmax state — the gathered operand never
 exists. The walk ends at the row's position: a row at token 40 of a
-256-token bucket brings and reduces 3 pages, not 16.
+256-token bucket brings and reduces 3 pages, not 16. A row is one fed
+TOKEN: a serving step hands it every token it feeds, several of one
+request at consecutive positions through the same table row among them
+(`models/_decode`), and the rows that pad the step to its width at
+position -1, which walk no page and come back as zeros.
 
 Fallback (`kernels=off`, non-TPU without the interpreter): the gather +
 the per-row attention expression of a dense (B,H,L,D) cache
@@ -95,12 +99,16 @@ def _kernel(tb_ref, t_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sem,
     of row b + 1 — scratch and semaphores outlive a grid step, so a row
     exposes no DMA latency but row 0's. Pages past t[b] are neither
     fetched nor reduced: they would contribute exact zeros (p = 0,
-    alpha = 1), so no bit of the result depends on them."""
+    alpha = 1), so no bit of the result depends on them. A row at
+    position -1 (padding) has no page: it hands the next row's first
+    wave on and writes zeros."""
     b = pl.program_id(0)
     B = pl.num_programs(0)
 
     def n_pages(row):
-        return jnp.minimum(t_ref[row] // page_size + 1, n_pg)
+        # positions -1 (no page), 0 .. page_size - 1 (one), ...
+        return jnp.minimum(
+            (jnp.maximum(t_ref[row], -1) + page_size) // page_size, n_pg)
 
     def buffer(j, slot):
         # page j of a row lies in the slot of its wave at j % wave.
@@ -179,13 +187,20 @@ def _kernel(tb_ref, t_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sem,
         return jax.lax.fori_loop(
             lo + 2 * pairs, hi, lambda j, c: page_step(j, slot, c), carry)
 
+    @pl.when((n_w == 0) & (b + 1 < B))
+    def _hand_on():
+        # no wave of this row's runs to start the next row's first: it
+        # goes where that row looks for it, the slot of wave counter w0
+        start(b + 1, 0, w0 % 2, n_pages(b + 1))
+
     _, l, acc = jax.lax.fori_loop(
         0, n_w, wave_step,
         (jnp.full((H, 1), _NEG, jnp.float32),
          jnp.zeros((H, 1), jnp.float32),
          jnp.zeros((H, Dp), jnp.float32)))
     wave0[0] = w0 + n_w
-    o_ref[0] = (acc / l).astype(o_ref.dtype)
+    # an empty walk leaves l = 0 and acc = 0: zeros, not 0 / 0
+    o_ref[0] = (acc / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
 
 
 def _paged_attention_pallas(q, k_pages, v_pages, tables, t):
@@ -260,7 +275,10 @@ def paged_attention(q, k_pages, v_pages, tables, t):
         dtype), Dp >= D with zeros past D — page id p is physical row p.
       tables: (B, n_pg) int32 page ids; row b's logical position range
         [0, n_pg*page_size) maps page-major onto its table entries.
-      t: (B,) traced int — row b attends positions <= t[b].
+      t: (B,) traced int — row b attends positions <= t[b]; -1 marks a
+        padding row, which reads nothing (the kernel returns zeros for
+        it, the reference a finite mean of whatever its table names:
+        nobody reads either).
 
     Returns (B, H, 1, D) in q.dtype. `kernels=off` (or no
     TPU/interpreter) runs `paged_attention_reference` — the dense-cache

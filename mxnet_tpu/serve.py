@@ -26,20 +26,26 @@ vLLM-style block table:
     width of the page table its executables take, so a stream of novel
     lengths compiles a handful of executables per bucket — never one
     per length.
-  * **the chunk step** — every scheduler step evicts expired slots,
-    admits queued requests into free slots, and runs one batched
-    dispatch per active bucket of the model's `chunk_step`: rows still
-    inside their prompt feed up to `pages_prefill_chunk` tokens, the
-    rest feed the token they sampled last, in one executable per
-    (bucket, chunk length). A row's logits never depend on its
-    neighbours, so a request's tokens are the same under load as alone
-    (the tests hold them to `model.generate` bit for bit on the CPU).
-    Freshly sampled tokens stream to each request's consumer.
+  * **the step is one pass over its tokens** — every scheduler step
+    evicts expired slots, admits queued requests into free slots, and
+    feeds, per active bucket, the last token of every decoding request
+    and up to `pages_prefill_chunk` prompt tokens of every request
+    still inside its prompt. The tokens are packed as VIRTUAL ROWS
+    (token, position, the slot whose page-table row it goes through)
+    and go through the model's `chunk_step` ONCE: a pass is `slots`
+    rows wide when the tokens fit, `2 * slots` otherwise
+    (`Server._wide`) — two executables per bucket, both compiled when
+    the bucket's first request is admitted. Only when more prompt
+    tokens are waiting than a wide pass holds (a burst of admissions)
+    does a step take further passes, in admission order. A row's logits
+    never depend on its neighbours, so a request's tokens are the same
+    under load as alone (the tests hold them to `model.generate`'s on
+    the CPU). Freshly sampled tokens stream to each request's consumer.
   * **a drafter** (`Server(drafter=...)`) adds draft-verify speculative
     decoding with exact greedy acceptance: the drafter chains `spec_k`
     proposals into the pool's `draft` stream, the target verifies them
-    in one `spec_k + 1`-token chunk, the host keeps the longest
-    agreeing prefix. It changes WHEN tokens are computed, never which.
+    in one pass of `slots * (spec_k + 1)` rows, the host keeps the
+    longest agreeing prefix. It changes WHEN tokens are computed, never which.
 
 Robustness — the request lifecycle:
 
@@ -141,8 +147,9 @@ _servers = weakref.WeakSet()
 
 def _close_round(step_span, stream_span, chunk, tokens):
     """Late attrs of one LIVE decode round: the tokens its `serve.stream`
-    emitted, and on the step's `serve.step` span the longest chunk any of
-    its rounds ran (`chunk` > 1: the step ran a prefill-chunk executable)."""
+    emitted, and on the step's `serve.step` span the largest `chunk` of
+    its rounds' executables (`chunk` > 1: the step ran a wide pass, or a
+    speculative verify)."""
     stream_span.attrs["tokens"] = tokens
     if chunk > step_span.attrs.get("chunk", 0):
         step_span.attrs["chunk"] = chunk
@@ -382,26 +389,63 @@ class _Group:
     """The decode state for one length bucket: `slots` requests whose
     page tables are `n_pg = bucket // page_size` wide, so they share the
     bucket's step executables. It holds no cache — the pool is allocated
-    once at server construction and priced there. Slot i owns a LIST of
-    mx.pages page ids (`pages[i]`, one pool reference each) whose order
-    IS its page table. `pos[i]` is the next position slot i writes —
-    while `pos < len(prompt)` the slot is prefilling, after that it
-    consumes its own sampled tokens. `matched[i]` records how many
-    prompt tokens arrived pre-filled from the prefix tree; `inserted[i]`
-    latches the one-time tree insertion after the slot's prefill
-    completes."""
+    once at server construction and priced there. Row i of `tables`, the
+    (slots, n_pg) array the executables take, IS slot i's page table:
+    its first `owned[i]` entries are the mx.pages page ids the slot
+    holds one pool reference each for (`pages_of`), the rest 0, a scratch
+    page. The array changes only as requests come and go (`seat` /
+    `clear`, the only writers) and is not rebuilt every step; its copy
+    on the device is made anew only after a change (`device_tables`).
+    `pos[i]` is the next position slot i writes — while `pos <
+    len(prompt)` the slot is prefilling, after that it consumes its own
+    sampled tokens. `matched[i]` records how many prompt tokens arrived
+    pre-filled from the prefix tree; `inserted[i]` latches the one-time
+    tree insertion after the slot's prefill completes."""
 
-    __slots__ = ("bucket", "n_pg", "slots", "pos", "pages", "matched",
-                 "inserted")
+    __slots__ = ("bucket", "n_pg", "slots", "pos", "owned", "matched",
+                 "inserted", "tables", "_tables_dev")
 
     def __init__(self, bucket, n_slots, n_pg):
         self.bucket = bucket
         self.n_pg = n_pg
         self.slots = [None] * n_slots
         self.pos = [0] * n_slots
-        self.pages = [[] for _ in range(n_slots)]
+        self.owned = [0] * n_slots
         self.matched = [0] * n_slots
         self.inserted = [False] * n_slots
+        self.tables = np.zeros((n_slots, n_pg), np.int32)
+        self._tables_dev = None
+
+    def seat(self, i, req, pos0, pages, matched):
+        self.slots[i] = req
+        self.pos[i] = pos0
+        self.matched[i] = matched
+        self.inserted[i] = False
+        self._set_pages(i, pages)
+
+    def clear(self, i):
+        self.slots[i] = None
+        self.matched[i] = 0
+        self.inserted[i] = False
+        self._set_pages(i, ())
+
+    def _set_pages(self, i, pages):
+        self.owned[i] = len(pages)
+        self.tables[i] = 0
+        self.tables[i, :len(pages)] = pages
+        self._tables_dev = None
+
+    def pages_of(self, i):
+        """The page ids slot i owns, in table order."""
+        return self.tables[i, :self.owned[i]].tolist()
+
+    def device_tables(self):
+        """The page tables on the device: a copy (the host array goes on
+        changing), made again only after a slot was seated or cleared."""
+        if self._tables_dev is None:
+            import jax.numpy as jnp
+            self._tables_dev = jnp.asarray(self.tables.copy())
+        return self._tables_dev
 
     def free_slot(self):
         for i, r in enumerate(self.slots):
@@ -480,6 +524,9 @@ class Server:
         self._queue = collections.deque()
         self._groups = {}          # bucket -> _Group
         self._runners = {}         # (kind, bucket, ...) -> jit_flat_step runner
+        self._warmed = set()       # buckets whose executables are compiled
+        self._unfit = {}           # bucket -> the device's refusal at _warm
+        self._width_dispatches = collections.Counter()   # width -> passes
         self._exec_peaks = {}      # bucket -> AOT exec-peak bytes (or None)
         self._by_id = {}
         self._pending_cancels = []
@@ -492,6 +539,9 @@ class Server:
             "prompt_tokens": 0, "prefix_tokens": 0, "prefix_hits": 0,
             "chunk_dispatches": 0, "chunk_steps": 0, "token_steps": 0,
             "spec_rounds": 0,
+            # the fill of the passes: virtual rows dispatched (the sum of
+            # the widths) and the tokens fed in them
+            "rows_dispatched": 0, "rows_fed": 0,
             "drafts_proposed": 0, "drafts_accepted": 0,
             # what attention was fed, worked out from positions alone:
             # tokens, the sum of their context lengths, of the contexts
@@ -651,6 +701,7 @@ class Server:
                                  for g in self._groups.values())
             out["buckets_allocated"] = sorted(self._groups)
             out["executables"] = len(self._runners)
+            out["width_dispatches"] = dict(self._width_dispatches)
             out["scheduler_steps"] = self._sched_step
             out["pages"] = "on"     # readers' key from when it was a mode
             out["page_size"] = self._page_size
@@ -837,6 +888,11 @@ class Server:
                         sched.step = n
                     groups = [g for g in self._groups.values()
                               if g.active()]
+                # a bucket's executables are compiled once its first
+                # request is seated, which is after `_seat` found its step
+                # within the byte budget and before its first dispatch;
+                # outside the lock, like every compile
+                groups = [g for g in groups if self._warm_seated(g)]
             for grp in groups:
                 if not _goodput._enabled:
                     self._decode_group(grp, n, sp)
@@ -860,26 +916,91 @@ class Server:
         return self.busy()
 
     def _prewarm_buckets(self):
-        """Build the runner (functional_call trace) and AOT exec-peak
-        probe for every bucket the queue will need, before the locked
-        admission pass. Only the scheduler thread touches _runners /
-        _exec_peaks, so no lock is required here."""
+        """The AOT exec-peak probe of every bucket the queue will ask
+        for, before the locked admission pass: what `_admit_budget`
+        reads. It compiles and analyses and dispatches nothing; the
+        executables are compiled for the device once a request has been
+        seated (`_warm_seated`). Only the scheduler thread touches
+        _runners / _exec_peaks, so no lock is required here."""
+        if _memsafe.capacity_bytes() is None:
+            return
         with self._lock:
             pending = [r for r in self._queue if r.state == QUEUED]
-        cap = _memsafe.capacity_bytes()
         for r in pending:
-            b = self._bucket_for(r.prompt.size + r.max_new_tokens)
-            self._runner(b, self._prefill_chunk, False)
-            self._runner(b, 1, False)
-            if self._drafter is not None:
-                # the drafter mirrors every target chunk (gap-0 sync),
-                # plus its own chain and the verify step
-                self._runner(b, self._prefill_chunk, False, draft=True)
-                self._runner(b, 1, False, draft=True)
-                self._runner(b, self._spec_k + 1, True)
-                self._draft_runner(b)
-            if cap is not None:
-                self._exec_peak(b)
+            self._exec_peak(
+                self._bucket_for(r.prompt.size + r.max_new_tokens))
+
+    def _wide(self):
+        """The wider of a bucket's two pass widths: `2 * slots` virtual
+        rows. Tokens that fit `slots` rows run the `slots`-wide
+        executable (every decode-only step does), any others the wide
+        one. Derived, not a knob. With `prefill_chunk` 1 no step has
+        more tokens than slots and there is one width."""
+        return self._slots * (2 if self._prefill_chunk > 1 else 1)
+
+    def _warm_seated(self, grp):
+        """`_warm` the bucket of a group that holds requests; True when
+        the group can be stepped. A device that refuses the bucket after
+        all (RESOURCE_EXHAUSTED from the compiler or from the padding
+        dispatch, the pool's buffers intact) says what the budget would
+        have said had its prediction been right, and is answered the
+        same way: the requests seated in the bucket get the 429 with the
+        device's words, and the bucket is struck (`_unfit`), so `_seat`
+        refuses it from now on and the ladder shrinks later requests
+        below it. Anything else is a scheduler error and raises."""
+        if grp.bucket in self._warmed:
+            return True
+        try:
+            self._warm(grp.bucket)
+            return True
+        except Exception as e:      # noqa: BLE001 — sorted just below
+            if not _memsafe.is_oom(e) or not self._pool_intact():
+                raise
+            refusal = e
+        print(f"mx.serve: bucket {grp.bucket} does not fit the device "
+              f"after all, refused from now on: {refusal}", file=sys.stderr)
+        with self._lock:
+            self._unfit[grp.bucket] = refusal
+            for i in grp.active():
+                r = grp.slots[i]
+                self._vacate(grp, i)
+                self._finish(r, REJECTED, f"429 over capacity: {refusal}")
+        return False
+
+    def _pool_intact(self):
+        """False once a failed dispatch has consumed donated arenas."""
+        return not any(hasattr(a, "is_deleted") and a.is_deleted()
+                       for arenas in self._pool.state.values()
+                       for a in arenas)
+
+    def _warm(self, bucket):
+        """Compile every executable `bucket` can run, once, when its
+        first request has been seated: each is dispatched on a pass of
+        padding rows (position -1: they walk no page and write slot 0's
+        scratch page, which nothing reads). So no executable is first
+        built inside a timed stretch of steps, whichever width a loop
+        needs first, and `stats()["executables"]` does not grow after
+        the step of the admission."""
+        import jax.numpy as jnp
+        S, n_pg = self._slots, bucket // self._page_size
+        widths = sorted({S, self._wide()})
+        passes = [(w, False, "target") for w in widths]
+        if self._drafter is not None:
+            # the drafter mirrors every target pass (gap-0 sync); the
+            # verify pass; the draft chain below
+            passes += [(w, False, "draft") for w in widths]
+            passes.append((S * (self._spec_k + 1), True, "target"))
+        idle = _Group(bucket, S, n_pg)      # nobody seated: all padding
+        for width, full, tag in passes:
+            self._dispatch(
+                idle, self._runner(bucket, width, full, draft=tag == "draft"),
+                (*self._pack_rows(width), idle.device_tables()), tag)
+        if self._drafter is not None:
+            blank = jnp.zeros((S,), jnp.int32)
+            self._dispatch(idle, self._draft_runner(bucket),
+                           (blank, blank, jnp.zeros((S,), bool),
+                            idle.device_tables()), "draft")
+        self._warmed.add(bucket)
 
     def _fire_faults(self, sched_step):
         inj = _resilience._injector if _resilience._enabled else None
@@ -993,13 +1114,24 @@ class Server:
         return sorted((b for b in cands if floor <= b < bucket),
                       reverse=True)
 
-    def _runner(self, bucket, C, full, draft=False):
-        """Chunk-step executable for (bucket, chunk length C): the
-        model's `chunk_step` under jit_flat_step with the pool arrays
-        donated — at most three C values ever exist per bucket
-        (prefill_chunk, 1, and spec_k+1 with full logits), so serving
-        compiles O(buckets) executables, never one per length."""
-        key = ("chunk", bucket, C, full, draft)
+    def _chunk_of(self, width, full=False):
+        """The `chunk` an executable is known by, in its jit label and on
+        the spans of the steps that run it: 1 for the `slots`-wide pass,
+        `prefill_chunk` (the prompt tokens a request may feed in one
+        step) for the wide one, `spec_k + 1` for the verify pass."""
+        if full:
+            return self._spec_k + 1
+        return 1 if width == self._slots else self._prefill_chunk
+
+    def _runner(self, bucket, width, full=False, draft=False):
+        """Step executable of `bucket` for a pass of `width` virtual
+        rows: the model's `chunk_step` under jit_flat_step with the pool
+        arrays donated. Three widths at most exist per bucket (`slots`,
+        `_wide()`, and `slots * (spec_k + 1)` with full logits under a
+        drafter), so serving compiles O(buckets) executables, never one
+        per length; `_warm` compiles them all at the bucket's first
+        admission."""
+        key = ("chunk", bucket, width, full, draft)
         r = self._runners.get(key)
         if r is None:
             from .models._decode import jit_flat_step
@@ -1008,16 +1140,17 @@ class Server:
             n_state = len(spec.streams)
             ps = self._page_size
 
-            def step(toks, t0, n, tables, flat):
-                return spec.chunk_step(toks, t0, n, tables, flat, ps,
-                                       full=full)
+            def step(toks, pos, slot, last, tables, flat):
+                return spec.chunk_step(toks, pos, slot, last, tables, flat,
+                                       ps, full=full)
 
             # the label joins a device trace's instructions to this
             # program's named scopes (mx.trace.scope_map); the
             # `serve.decode_step` span's `bucket` and `chunk` name it
             r = jit_flat_step(
                 mdl, step, n_state, donate_state=n_state,
-                label=f"serve.paged/bucket={bucket}/chunk={C}"
+                label=f"serve.paged/bucket={bucket}"
+                f"/chunk={self._chunk_of(width, full)}"
                 + ("/full" if full else "") + ("/draft" if draft else ""))
             self._runners[key] = r
         return r
@@ -1049,22 +1182,28 @@ class Server:
 
     def _bucket_step(self, bucket):
         """(runner, argument avals) of the HEAVIEST step executable
-        `bucket` can run: the full-logits speculative verify step when a
-        drafter is attached, else the prefill chunk."""
-        import jax
+        `bucket` can run: the speculative verify pass when a drafter is
+        attached (`slots * (spec_k + 1)` rows and float32 logits for
+        every one of them), else the wide pass."""
+        S = self._slots
         if self._drafter is not None:
-            C, full = self._spec_k + 1, True
+            width, full = S * (self._spec_k + 1), True
         else:
-            C, full = self._prefill_chunk, False
-        n_pg = bucket // self._page_size
-        state = [jax.ShapeDtypeStruct(tuple(a.shape), a.dtype)
-                 for a in self._pool.state["target"]]
-        return self._runner(bucket, C, full), (
-            jax.ShapeDtypeStruct((self._slots, C), np.int32),
-            jax.ShapeDtypeStruct((self._slots,), np.int32),
-            jax.ShapeDtypeStruct((self._slots,), np.int32),
-            jax.ShapeDtypeStruct((self._slots, n_pg), np.int32),
-            state)
+            width, full = self._wide(), False
+        return self._runner(bucket, width, full), \
+            self._step_avals(bucket, width)
+
+    def _step_avals(self, bucket, width):
+        """Argument avals of `bucket`'s pass of `width` virtual rows: the
+        rows' tokens, positions and slots and each slot's head row
+        (`_pack_rows`), the page tables, the pool's target arenas."""
+        import jax
+        S = self._slots
+        ints = lambda *shape: jax.ShapeDtypeStruct(shape, np.int32)  # noqa: E731
+        return (ints(width), ints(width), ints(width), ints(S),
+                ints(S, bucket // self._page_size),
+                [jax.ShapeDtypeStruct(tuple(a.shape), a.dtype)
+                 for a in self._pool.state["target"]])
 
     def lower_step(self, bucket):
         """The `jax.stages.Lowered` form of `bucket`'s step executable
@@ -1149,8 +1288,11 @@ class Server:
         executable beside parameters and pool, then the request's pages.
         Every rung of the ladder comes through here. Returns None with
         the request RUNNING in its slot, or the refusal: the
-        `MemoryBudgetError` (bytes) or a `PagesExhausted` (pages), with
-        nothing taken."""
+        `MemoryBudgetError` (bytes; for a bucket the device itself
+        refused, its error: `_warm_seated`) or a `PagesExhausted`
+        (pages), with nothing taken."""
+        if bucket in self._unfit:
+            return self._unfit[bucket]
         try:
             self._admit_budget(bucket)
         except _memsafe.MemoryBudgetError as e:
@@ -1312,12 +1454,7 @@ class Server:
         if grp is None:
             grp = self._groups[bucket] = _Group(
                 bucket, self._slots, bucket // self._page_size)
-        i = grp.free_slot()
-        grp.slots[i] = req
-        grp.pos[i] = pos0
-        grp.pages[i] = pages
-        grp.matched[i] = matched
-        grp.inserted[i] = False
+        grp.seat(grp.free_slot(), req, pos0, pages, matched)
         self._stats["prompt_tokens"] += req.prompt.size
         self._stats["prefix_tokens"] += pos0
         if matched:
@@ -1345,12 +1482,9 @@ class Server:
         """Release slot i of `grp`: drop one pool reference per owned
         page — tree-shared pages survive with the tree's reference,
         exclusive ones return to the free list."""
-        grp.slots[i] = None
-        for p in grp.pages[i]:
+        for p in grp.pages_of(i):
             self._pool.decref(p)
-        grp.pages[i] = []
-        grp.matched[i] = 0
-        grp.inserted[i] = False
+        grp.clear(i)
 
     def _remove_from_slots(self, req):
         for g in self._groups.values():
@@ -1372,24 +1506,20 @@ class Server:
         """One scheduler round for a bucket group. `sp` is the step's
         live `serve.step` span, None while mx.trace is not live: the
         span sites below test it and nothing else. Mode per round:
-        a SPECULATIVE round (draft chain + one k+1-token verify chunk)
-        when a drafter is attached, every active slot is past its
+        a SPECULATIVE round (draft chain + one verify pass of k+1 rows a
+        slot) when a drafter is attached, every active slot is past its
         prompt, and at least one is greedy; otherwise a CHUNK round —
-        chunked prefill for slots still inside their prompt, one token
-        for the rest, all in one dispatch."""
+        prompt tokens of the slots still inside their prompt, one token
+        for the rest, all in one pass."""
         active = grp.active()
         if not active:
             return
         all_decoding = True
         any_greedy = False
-        max_need = 1
         for i in active:
             r = grp.slots[i]
-            left = r.prompt.size - grp.pos[i]
-            if left > 0:
+            if grp.pos[i] < r.prompt.size:
                 all_decoding = False
-                max_need = max(max_need,
-                               min(self._prefill_chunk, left))
             if r.temperature == 0.0:
                 any_greedy = True
         if _slo._enabled:
@@ -1400,51 +1530,96 @@ class Server:
         if self._drafter is not None and all_decoding and any_greedy:
             self._spec_round(grp, active, sched_step, sp)
         else:
-            self._chunk_round(grp, active, max_need, sched_step, sp)
+            self._chunk_round(grp, active, sched_step, sp)
 
-    def _paged_inputs(self, grp, C):
-        """Blank leading arrays for one chunk dispatch: empty slots run
-        n=0 (every step masked into their scratch page) over table row
-        zeros — valid page ids whose reads feed discarded logits."""
-        B = self._slots
-        toks = np.zeros((B, C), np.int32)
-        t0 = np.zeros((B,), np.int32)
-        n = np.zeros((B,), np.int32)
-        tables = np.zeros((B, grp.n_pg), np.int32)
-        return toks, t0, n, tables
+    def _pack_rows(self, width, toks=(), pos=(), slot=(), last=None):
+        """The rows of one pass as the executable takes them: `width`
+        tokens, `width` positions, `width` slots, and for each slot the
+        row its head reads. The fed rows come first (lists of equal
+        length); the rest is padding: position -1 through slot 0 (it
+        walks no page and writes that slot's scratch page), and every
+        unfed slot's head on row 0."""
+        pad = [0] * (width - len(toks))
+        return (np.array([*toks, *pad], np.int32),
+                np.array([*pos, *([-1] * len(pad))], np.int32),
+                np.array([*slot, *pad], np.int32),
+                np.array(last or [0] * self._slots, np.int32))
 
-    def _chunk_round(self, grp, active, max_need, sched_step, sp=None):
-        import jax.numpy as jnp
-        C = self._prefill_chunk if max_need > 1 else 1
+    def _feeds(self, grp, active):
+        """What this step feeds, as (slot, first position, token ids):
+        the last sampled token of every decoding request, then, in
+        admission order, up to `prefill_chunk` prompt tokens of every
+        request still inside its prompt — what a step has always owed
+        each request, so none waits a step for another's prompt."""
+        cap = self._prefill_chunk
+        feeds, inside = [], []
+        for i in active:
+            r = grp.slots[i]
+            p = grp.pos[i]
+            if p < r.prompt.size:
+                inside.append((r.id, i, p, r.prompt[p:p + cap].tolist()))
+            else:
+                feeds.append((i, p, [r.tokens[p - r.prompt.size]]))
+        feeds.extend(feed[1:] for feed in sorted(inside))
+        return feeds
+
+    def _passes(self, feeds):
+        """The step's feeds cut into passes of at most `_wide()` virtual
+        rows, in order: one pass whenever the tokens fit it (every
+        steady step), further ones when more prompt tokens are waiting
+        than a pass holds (a burst of admissions). A request's chunk may
+        continue in the next pass: its earlier tokens are in the cache
+        by then."""
+        wide = self._wide()
+        passes, rows, n = [], [], 0
+        for i, p, ids in feeds:
+            while ids:
+                take = min(len(ids), wide - n)
+                rows.append((i, p, ids[:take]))
+                p, ids, n = p + take, ids[take:], n + take
+                if n == wide:
+                    passes.append(rows)
+                    rows, n = [], 0
+        if rows:
+            passes.append(rows)
+        return passes
+
+    def _chunk_round(self, grp, active, sched_step, sp=None):
+        for feeds in self._passes(self._feeds(grp, active)):
+            self._pass(grp, feeds, len(active), sched_step, sp)
+
+    def _pass(self, grp, feeds, n_active, sched_step, sp=None):
+        """One pass: pack `feeds` as virtual rows, dispatch the narrowest
+        executable that holds them, and emit for every request whose
+        last fed token ends or is past its prompt."""
         with (_trace.span("serve.prepare", cat="phase", step=sched_step,
-                          chunk=C, slots=len(active))
-              if sp else _NULLCTX):
-            toks, t0, n, tables = self._paged_inputs(grp, C)
-            for i in active:
-                r = grp.slots[i]
-                lp = r.prompt.size
-                p = grp.pos[i]
-                if p < lp:
-                    ni = min(C, lp - p)
-                    toks[i, :ni] = r.prompt[p:p + ni]
-                else:
-                    ni = 1
-                    toks[i, 0] = r.tokens[p - lp]
-                t0[i] = p
-                n[i] = ni
-                tables[i, :len(grp.pages[i])] = grp.pages[i]
-            run = self._runner(grp.bucket, C, False)
-            lead = (jnp.asarray(toks), jnp.asarray(t0), jnp.asarray(n),
-                    jnp.asarray(tables))
+                          slots=n_active)
+              if sp else _NULLCTX) as prep:
+            toks, pos, slot, last = [], [], [], [0] * self._slots
+            for i, p, ids in feeds:
+                toks.extend(ids)
+                pos.extend(range(p, p + len(ids)))
+                slot.extend([i] * len(ids))
+                last[i] = len(toks) - 1
+            fed = len(toks)
+            # the `slots`-wide pass whenever the tokens fit it
+            width = self._slots if fed <= self._slots else self._wide()
+            C = self._chunk_of(width)
+            run = self._runner(grp.bucket, width)
+            lead = (*self._pack_rows(width, toks, pos, slot, last),
+                    grp.device_tables())
+            if sp:
+                prep.attrs["chunk"] = C
         with (_trace.span("serve.decode_step", cat="serve", step=sched_step,
-                          chunk=C, bucket=grp.bucket, slots=len(active))
+                          chunk=C, bucket=grp.bucket, slots=n_active,
+                          width=width, fed=fed)
               if sp else _NULLCTX):
             logits = self._dispatch(grp, run, lead, "target")
             if self._drafter is not None:
-                # mirror the chunk on the drafter so its cache tracks the
+                # mirror the pass on the drafter so its cache tracks the
                 # target position-for-position (gap-0: a later speculative
                 # round can start its chain with no catch-up work)
-                drun = self._runner(grp.bucket, C, False, draft=True)
+                drun = self._runner(grp.bucket, width, draft=True)
                 self._dispatch(grp, drun, lead, "draft")
             with (_trace.span("serve.fetch", cat="phase", step=sched_step,
                               chunk=C) if sp else _NULLCTX):
@@ -1457,12 +1632,12 @@ class Server:
                 self._stats["steps"] += 1
                 self._stats["chunk_dispatches"] += 1
                 self._stats["chunk_steps" if C > 1 else "token_steps"] += 1
-                for i in active:
+                self._note_pass(width, fed)
+                for i, p, ids in feeds:
                     r = grp.slots[i]
                     if r is None or r.state in TERMINAL:
                         continue    # evicted/cancelled under the dispatch
-                    p = grp.pos[i]
-                    ni = int(n[i])
+                    ni = len(ids)
                     grp.pos[i] = p + ni
                     self._note_fed(p, ni)
                     lp = r.prompt.size
@@ -1482,48 +1657,60 @@ class Server:
     def _spec_round(self, grp, active, sched_step, sp=None):
         """One speculative decoding round: the drafter chains k greedy
         proposals per eligible slot, the target verifies them all in ONE
-        k+1-token chunk (full logits), and the host keeps the longest
-        agreeing prefix plus the bonus token — exact greedy acceptance,
-        so the emitted stream is bit-identical to plain greedy decode.
-        Non-greedy slots ride along with a single ordinary token."""
+        pass of k+1 virtual rows a slot (full logits), and the host keeps
+        the longest agreeing prefix plus the bonus token — exact greedy
+        acceptance, so the emitted stream is plain greedy decode's.
+        Non-greedy slots ride along with a single ordinary token, the
+        rest of their rows padding."""
         import jax.numpy as jnp
         k = self._spec_k
+        S = self._slots
+        width = S * (k + 1)
         with (_trace.span("serve.prepare", cat="phase", step=sched_step,
                           chunk=k + 1, slots=len(active))
               if sp else _NULLCTX):
-            tok0 = np.zeros((self._slots,), np.int32)
-            spec_row = np.zeros((self._slots,), bool)
-            toks, t0, n, tables = self._paged_inputs(grp, k + 1)
+            tok0 = np.zeros((S,), np.int32)
+            t0 = np.zeros((S,), np.int32)
+            spec_row = np.zeros((S,), bool)
+            n = np.zeros((S,), np.int32)
             for i in active:
                 r = grp.slots[i]
                 p = grp.pos[i]
                 tok0[i] = r.tokens[p - r.prompt.size]
                 t0[i] = p
-                tables[i, :len(grp.pages[i])] = grp.pages[i]
                 spec_row[i] = r.temperature == 0.0
+            tables_d = grp.device_tables()
             drafts_out = self._dispatch(
                 grp, self._draft_runner(grp.bucket),
                 (jnp.asarray(tok0), jnp.asarray(t0), jnp.asarray(spec_row),
-                 jnp.asarray(tables)), "draft")
+                 tables_d), "draft")
             drafts = _fetch(drafts_out, np.int32)[:, :k]       # (B, k)
+            # slot-major: rows i*(k+1) .. of slot i, what it does not feed
+            # padding through its own scratch page
+            toks = np.zeros((width,), np.int32)
+            pos = np.full((width,), -1, np.int32)
+            slot = np.repeat(np.arange(S, dtype=np.int32), k + 1)
             for i in active:
-                toks[i, 0] = tok0[i]
+                w = i * (k + 1)
+                n[i] = k + 1 if spec_row[i] else 1
+                toks[w] = tok0[i]
                 if spec_row[i]:
-                    toks[i, 1:] = drafts[i]
-                    n[i] = k + 1
-                else:
-                    n[i] = 1
-            run = self._runner(grp.bucket, k + 1, True)
+                    toks[w + 1:w + k + 1] = drafts[i]
+                pos[w:w + n[i]] = t0[i] + np.arange(n[i])
+            fed = int(n.sum())
+            run = self._runner(grp.bucket, width, True)
         with (_trace.span("serve.decode_step", cat="serve", step=sched_step,
                           chunk=k + 1, bucket=grp.bucket,
-                          slots=len(active), spec_k=k)
+                          slots=len(active), spec_k=k, width=width, fed=fed)
               if sp else _NULLCTX):
+            # `full`: every row's logits come back, no slot's head row
             logits = self._dispatch(
-                grp, run, (jnp.asarray(toks), jnp.asarray(t0),
-                           jnp.asarray(n), jnp.asarray(tables)), "target")
+                grp, run, (toks, pos, slot, np.zeros((S,), np.int32),
+                           tables_d), "target")
             with (_trace.span("serve.fetch", cat="phase", step=sched_step,
                               chunk=k + 1) if sp else _NULLCTX):
-                lgs = _fetch(logits, np.float32)           # (B, k+1, V)
+                lgs = _fetch(logits, np.float32) \
+                    .reshape(S, k + 1, -1)                 # (B, k+1, V)
         with (_trace.span("serve.stream", cat="serve", step=sched_step)
               if sp else _NULLCTX) as stream:
             if sp:
@@ -1531,6 +1718,7 @@ class Server:
             with self._lock:
                 self._stats["steps"] += 1
                 self._stats["spec_rounds"] += 1
+                self._note_pass(width, fed)
                 for i in active:
                     r = grp.slots[i]
                     if r is None or r.state in TERMINAL:
@@ -1576,7 +1764,8 @@ class Server:
         exclusively owned, and decode writes only land at positions past
         the prompt, so registered pages are immutable from here on)."""
         lp = req.prompt.size
-        self._tree.insert(req.prompt, grp.pages[i][:lp // self._page_size])
+        self._tree.insert(req.prompt,
+                          grp.pages_of(i)[:lp // self._page_size])
         grp.inserted[i] = True
 
     def _dispatch(self, grp, run, lead, tag):
@@ -1632,6 +1821,13 @@ class Server:
             p /= p.sum()
             return int(req._rng.choice(p.size, p=p))
         return int(lg.argmax())
+
+    def _note_pass(self, width, fed):
+        """Count one pass: its virtual rows, the tokens fed in them (the
+        rest was padding), and the dispatch under its width."""
+        self._stats["rows_dispatched"] += width
+        self._stats["rows_fed"] += fed
+        self._width_dispatches[width] += 1
 
     def _note_fed(self, p, ni):
         """Count `ni` tokens fed at positions p..: the token at position q

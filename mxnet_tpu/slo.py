@@ -629,26 +629,27 @@ def _append_record(rec):
     path = access_path()
     if path is None:
         return False
+    # the lock is held across the write: the meta line is decided and
+    # written in one piece, so a record from another thread (a request's
+    # streaming thread finalizing beside the scheduler's) cannot reach a
+    # new file before it
     with _lock:
         need_meta = path not in _meta_paths
-        _meta_paths.add(path)
-    try:
-        os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
-        with open(path, "a", buffering=1) as f:
-            if need_meta:
-                f.write(json.dumps(_meta_record()) + "\n")
-            f.write(json.dumps(rec) + "\n")
-        return True
-    except OSError as e:
-        with _lock:
-            if need_meta:
-                _meta_paths.discard(path)
-        if not _write_warned:
-            _write_warned = True
-            import warnings
-            warnings.warn(f"mx.slo: access log write to {path!r} failed: "
-                          f"{e}; exemplars are dropped (warning once)")
-        return False
+        try:
+            os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
+            with open(path, "a", buffering=1) as f:
+                if need_meta:
+                    f.write(json.dumps(_meta_record()) + "\n")
+                    _meta_paths.add(path)
+                f.write(json.dumps(rec) + "\n")
+            return True
+        except OSError as e:
+            if not _write_warned:
+                _write_warned = True
+                import warnings
+                warnings.warn(f"mx.slo: access log write to {path!r} failed: "
+                              f"{e}; exemplars are dropped (warning once)")
+            return False
 
 
 def flush_summary():

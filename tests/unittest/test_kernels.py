@@ -488,6 +488,34 @@ def test_paged_attention_idle_row_beside_live_rows(one_device):
     assert np.isfinite(np.asarray(got)[2]).all()
 
 
+@pytest.mark.parametrize("pages_a_wave", [16, 2])
+@pytest.mark.parametrize("where", ["first", "middle", "last", "runs", "all"])
+def test_paged_attention_padding_rows_walk_no_page(one_device, monkeypatch,
+                                                   where, pages_a_wave):
+    """Rows at position -1 pad a pass to its width: they bring no page
+    (everything their table names is NaN), come back as zeros, and the
+    live rows around them are bit for bit what they are without them —
+    wherever the padding sits, so the next live row's first wave is
+    handed on across any run of it."""
+    q, kp, vp, tables, t = _walk_case(16, 128, jnp.float32, B=7)
+    t = np.array([0, 7, 8, 60, 127, 33, 90], np.int32)
+    monkeypatch.setattr(pa, "_WAVE_BYTES",
+                        pages_a_wave * kp[0].size * kp.dtype.itemsize)
+    alone = _assert_paged_parity(q, kp, vp, tables, t, jnp.float32)
+    pad = {"first": [0], "middle": [3], "last": [6], "runs": [0, 1, 4, 5],
+           "all": list(range(7))}[where]
+    kp, vp, t = np.array(kp), np.array(vp), t.copy()
+    kp[tables[pad]] = np.nan
+    vp[tables[pad]] = np.nan
+    t[pad] = -1
+    got = np.asarray(pa.paged_attention(
+        q, jnp.asarray(kp), jnp.asarray(vp), jnp.asarray(tables),
+        jnp.asarray(t)))
+    live = np.setdiff1d(np.arange(7), pad)
+    assert np.array_equal(got[live], np.asarray(alone)[live])
+    assert not got[pad].any()
+
+
 def test_paged_attention_kernels_off_is_reference_path(one_device):
     config.set("kernels", "off")
     case = _paged_case()
@@ -539,6 +567,54 @@ def test_kv_page_write_bit_equal_to_scatter(one_device, dtype, Dp):
         idle = np.setdiff1d(np.arange(before.shape[0]), np.asarray(case[4]))
         assert np.array_equal(np.asarray(g, np.float32)[idle],
                               np.asarray(before, np.float32)[idle])
+
+
+@pytest.mark.parametrize("packing", ["pass", "slot_major"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("together", [2, 3, 5, 8])
+def test_kv_page_write_rows_that_share_a_page(one_device, dtype, together,
+                                              packing):
+    """A pass's virtual rows: `together` consecutive positions of one
+    request in ONE page, a span of eight across a page boundary (five
+    rows at the end of a page, three at the start of the next), a
+    decoding row on a page of its own, and padding rows that share a
+    scratch cell — packed as `Server._pass` packs (the decoding row, the
+    requests' runs, padding at the tail) and slot-major as the verify
+    pass does (each slot's run, then its padding through its own scratch
+    page). Either way the rows of one page are consecutive, the kernel's
+    contract. Every page but the scratch ones equals the reference
+    scatter, bit for bit."""
+    rng = np.random.RandomState(together)
+    P, H, ps, D = 24, 4, 8, 16
+    n_scratch = 4
+    pad = ((0, 0),) * 3 + ((0, 128 - D),)
+    kp = jnp.pad(jnp.asarray(rng.randn(P, H, ps, D), dtype), pad)
+    vp = jnp.pad(jnp.asarray(rng.randn(P, H, ps, D), dtype), pad)
+    run, span = [7] * together, [10] * 5 + [11] * 3
+    run_o, span_o = list(range(together)), [3, 4, 5, 6, 7, 0, 1, 2]
+    if packing == "pass":
+        wp = [9] + run + span + [0, 0, 0]
+        wo = [6] + run_o + span_o + [0, 0, 0]
+    else:
+        wp = run + [1, 1] + span + [2] + [9] + [3, 3, 3]
+        wo = run_o + [0, 0] + span_o + [0] + [6] + [0, 0, 0]
+    # the contract: a page is named by one run of consecutive rows
+    runs = [p for k, p in enumerate(wp) if k == 0 or wp[k - 1] != p]
+    assert len(runs) == len(set(runs))
+    wp, wo = jnp.asarray(wp, jnp.int32), jnp.asarray(wo, jnp.int32)
+    kn = jnp.asarray(rng.randn(wp.shape[0], H, 1, D), jnp.float32)
+    vn = jnp.asarray(rng.randn(wp.shape[0], H, 1, D), jnp.float32)
+    assert _holds_kernel(kw.kv_page_write, kp, vp, kn, vn, wp, wo)
+    got = jax.jit(kw.kv_page_write)(kp, vp, kn, vn, wp, wo)
+    ref = kw.kv_page_write_reference(kp, vp, kn, vn, wp, wo)
+    for g, r, before in zip(got, ref, (kp, vp)):
+        g, r = np.asarray(g, np.float32), np.asarray(r, np.float32)
+        before = np.asarray(before, np.float32)
+        assert np.array_equal(g[n_scratch:], r[n_scratch:])
+        # the scratch pages: one of the rows that named the cell, the
+        # rest of the page as it was
+        assert np.array_equal(g[:n_scratch, :, 1:], before[:n_scratch, :, 1:])
+        assert not np.array_equal(g[7], before[7])
 
 
 def test_kv_page_write_under_jit_with_donation(one_device):

@@ -294,8 +294,11 @@ def test_off_path_is_one_live_call_a_step(monkeypatch, path):
     if path == "serve":
         st = srv.stats()
         srv.stop()
+        # a step is one pass, but where a request's chunk (8) is wider
+        # than the wide pass (2 x 2 slots) and takes a second
         assert st["chunk_steps"] + st["token_steps"] \
-            == st["chunk_dispatches"] == n + 1
+            == st["chunk_dispatches"] >= n + 1
+        assert st["scheduler_steps"] == n + 1
         assert st["chunk_steps"] >= 2 and st["token_steps"] >= 1
     assert calls == {"live": n, "span": 0, "record_span": 0, "annotate": 0,
                      "skew_tick": 0, "_TraceMe": 0}
@@ -345,22 +348,34 @@ def test_profiler_session_arms_the_spans(tmp_path):
     by_name = {}
     for sp in recorded:
         by_name.setdefault(sp["name"], []).append(sp)
+    # one of each a step; of the four that make a pass, one a pass (a
+    # request's chunk of 8 is two passes of the wide width, 2 x 2 slots)
+    per_pass = serving[2:]
+    passes = len(by_name["serve.decode_step"])
+    assert passes >= 3
     for name in serving + training:
-        assert len(by_name.get(name, ())) == 3, (name, sorted(by_name))
+        assert len(by_name.get(name, ())) \
+            == (passes if name in per_pass else 3), (name, sorted(by_name))
     assert "step.fence" not in by_name
+
+    def iv(sp):
+        return sp["ts_us"], sp["dur_us"]
+
     # the buffer's nesting, step by step
-    for k in range(3):
-        iv = {n: (by_name[n][k]["ts_us"], by_name[n][k]["dur_us"])
-              for n in serving + training[:2]}
-        for child in ("serve.schedule", "serve.prepare",
-                      "serve.decode_step", "serve.stream"):
-            assert _inside(iv[child], iv["serve.step"], 0.2), child
-        assert _inside(iv["serve.fetch"], iv["serve.decode_step"], 0.2)
-        assert _inside(iv["step.dispatch"], iv["train.step"], 0.2)
-        assert by_name["serve.step"][k]["step"] \
-            == by_name["serve.fetch"][k]["step"]
-    chunks = [sp["chunk"] for sp in by_name["serve.step"]]
-    assert chunks == [sp["chunk"] for sp in by_name["serve.decode_step"]]
+    for k, step in enumerate(by_name["serve.step"]):
+        inside = [sp for name in serving[1:] for sp in by_name[name]
+                  if sp["step"] == step["step"]]
+        assert {sp["name"] for sp in inside} == set(serving[1:])
+        for sp in inside:
+            assert _inside(iv(sp), iv(step), 0.2), sp["name"]
+        rounds = [sp for sp in inside if sp["name"] == "serve.decode_step"]
+        fetches = [sp for sp in inside if sp["name"] == "serve.fetch"]
+        assert len(rounds) == len(fetches)
+        for rnd, fetch in zip(rounds, fetches):
+            assert _inside(iv(fetch), iv(rnd), 0.2)
+        assert step["chunk"] == max(sp["chunk"] for sp in rounds)
+        assert _inside(iv(by_name["step.dispatch"][k]),
+                       iv(by_name["train.step"][k]), 0.2)
     assert all(sp["tokens"] >= 0 for sp in by_name["serve.stream"])
     assert all("admitted" in sp for sp in by_name["serve.schedule"])
     # the same spans in the profiler's own file, on its clock
@@ -378,14 +393,18 @@ def test_profiler_session_arms_the_spans(tmp_path):
     # leaves it out, as it is no input stall)
     assert len(events.pop("input.batch_wait")) == 4
     for name in set(serving + training) - {"input.batch_wait"}:
-        assert len(events.get(name, ())) == 3, (name, sorted(events))
+        assert len(events.get(name, ())) \
+            == (passes if name in per_pass else 3), (name, sorted(events))
+    for name in serving[1:]:
+        for child in events[name]:
+            assert any(_inside(child, step)
+                       for step in events["serve.step"]), name
+    for fetch in events["serve.fetch"]:
+        assert any(_inside(fetch, rnd)
+                   for rnd in events["serve.decode_step"])
     for k in range(3):
-        iv = {n: sorted(events[n])[k] for n in serving + training[:2]}
-        for child in ("serve.schedule", "serve.prepare",
-                      "serve.decode_step", "serve.stream"):
-            assert _inside(iv[child], iv["serve.step"]), child
-        assert _inside(iv["serve.fetch"], iv["serve.decode_step"])
-        assert _inside(iv["step.dispatch"], iv["train.step"])
+        assert _inside(sorted(events["step.dispatch"])[k],
+                       sorted(events["train.step"])[k])
 
 
 def test_a_live_span_does_not_fence(monkeypatch):

@@ -195,14 +195,18 @@ def paged_parity(B=8, H=16, D=64, page_size=16, n_pg=32,
 
 def kv_write_parity(B=32, H=16, D=64, page_size=16, n_pages=2080,
                     dtype=jnp.bfloat16, expect_kernel=True):
-    """The arena write of a paged decode step as the server runs it —
-    arenas of the width the pool allocates (`arena_head_dim`), donated —
-    against the scatter on the same pools: every element of both arenas
-    equal, bit for bit, and the paged-attention kernel reading the written
-    arenas against its reference. Rows as `_paged_write_targets` makes
-    them: every fourth row masked (its scratch page b, offset 0), the
-    others on pages of their own at offsets that cover 0 and
-    page_size-1."""
+    """The arena write of a paged step as the server runs it — arenas of
+    the width the pool allocates (`arena_head_dim`), donated — against
+    the scatter on the same pools: every element of both arenas equal,
+    bit for bit, and the paged-attention kernel reading the written
+    arenas against its reference. Rows as `paged_write_targets` makes
+    them for a pass of virtual rows: every fourth row masked (its scratch
+    page b, offset 0), the others on pages of their own at offsets that
+    cover 0 and page_size-1 — and, where B allows, the rows of requests
+    inside their prompt, side by side as a pass packs them (the write's
+    contract: the rows of one page are consecutive): a span of eight
+    consecutive positions across a page boundary (five rows at the end of
+    one page, three at the start of the next) and two rows of one page."""
     rng = np.random.RandomState(7)
     Dp = arena_head_dim(D)
     pad = ((0, 0),) * 3 + ((0, Dp - D),)
@@ -213,11 +217,19 @@ def kv_write_parity(B=32, H=16, D=64, page_size=16, n_pages=2080,
     k_new = jnp.asarray(rng.randn(B, H, 1, D), dtype)
     v_new = jnp.asarray(rng.randn(B, H, 1, D), dtype)
     rows = np.arange(B)
-    masked = rows % 4 == 3
+    shares = B >= 16 and page_size >= 8
+    masked = (rows % 4 == 3) & (rows >= (10 if shares else 0))
     real = B + rng.permutation(n_pages - B)[:B]
-    wp = jnp.asarray(np.where(masked, rows, real), jnp.int32)
-    wo = jnp.asarray(np.where(masked, 0, (page_size - 1 - rows) % page_size),
-                     jnp.int32)
+    wp = np.where(masked, rows, real)
+    wo = np.where(masked, 0, (page_size - 1 - rows) % page_size)
+    if shares:
+        wp[:8] = [real[0]] * 5 + [real[1]] * 3
+        wo[:8] = list(range(page_size - 5, page_size)) + [0, 1, 2]
+        wp[8:10], wo[8:10] = real[8], [3, 4]
+        shared = " (8 + 2 rows share 3 pages)"
+    else:
+        shared = ""
+    wp, wo = jnp.asarray(wp, jnp.int32), jnp.asarray(wo, jnp.int32)
     want = jax.jit(kv_page_write_reference)(k_pg, v_pg, k_new, v_new, wp, wo)
     write = jax.jit(kv_page_write, donate_argnums=(0, 1))
     if expect_kernel:
@@ -229,20 +241,28 @@ def kv_write_parity(B=32, H=16, D=64, page_size=16, n_pages=2080,
     got = write(k_pg, v_pg, k_new, v_new, wp, wo)
     for name, a, b in zip("KV", got, want):
         check(f"kv page write {name} B{B} H{H} D{D}->{Dp} page{page_size} "
-              f"pool{n_pages}", bool(jnp.array_equal(a, b)),
+              f"pool{n_pages}{shared}", bool(jnp.array_equal(a, b)),
               "every element equal")
     # the attention kernel over the written, lane-padded arenas
     n_pg = min(16, (n_pages - B) // B)
     tables = jnp.asarray(B + rng.permutation(n_pages - B)[:B * n_pg]
                          .reshape(B, n_pg), jnp.int32)
     q = jnp.asarray(rng.randn(B, H, 1, D), dtype)
-    t = jnp.asarray(np.linspace(1, n_pg * page_size - 1, B), jnp.int32)
-    out = jax.jit(paged_attention)(q, *got, tables, t)
-    ref = jax.jit(paged_attention_reference)(q, *got, tables, t)
+    # a pass's rows: live ones at every depth, and padding (position -1)
+    # at the start, in the middle and at the end, which walks no page
+    t = np.linspace(1, n_pg * page_size - 1, B).astype(np.int32)
+    t[[0, B // 2, B // 2 + 1, B - 1]] = -1
+    live = t >= 0
+    out = jax.jit(paged_attention)(q, *got, tables, jnp.asarray(t))
+    ref = jax.jit(paged_attention_reference)(q, *got, tables, jnp.asarray(t))
     tol = 2 * float(jnp.finfo(dtype).eps)
-    err = rel_err(out, ref)
-    check(f"paged attention over arenas of width {Dp}", err <= tol,
+    err = rel_err(out[live], ref[live])
+    check(f"paged attention over arenas of width {Dp}, "
+          f"{int((~live).sum())} padding rows among {B}", err <= tol,
           f"rel={err:.2e} tol={tol:.1e}")
+    if expect_kernel:
+        check("padding rows come back as zeros",
+              not bool(jnp.any(out[~live])), "every element 0")
 
 
 def lamb_parity(shapes, expect_kernel=True):

@@ -192,11 +192,13 @@ def cross_rank_skews(ranks):
 def _window_stats(ranks, offsets, lo_us, hi_us):
     """Per-rank span-time aggregation restricted to [lo_us, hi_us) on the
     shared axis: {"by_cat": {cat: us}, "by_span": {name: us}, "steps":
-    [step dur_us]} per rank."""
+    [step dur_us], "rows": [virtual rows, tokens fed] of the window's
+    serving passes} per rank."""
     stats = {}
     for rank, (_meta, spans, _skews) in ranks.items():
         off = offsets[rank]
         by_cat, by_span, step_us = {}, {}, {}
+        rows = [0, 0]
         for s in spans:
             ts = off + float(s.get("ts_us", 0.0))
             if not (lo_us <= ts < hi_us):
@@ -208,8 +210,11 @@ def _window_stats(ranks, offsets, lo_us, hi_us):
             by_span[name] = by_span.get(name, 0.0) + dur
             if cat == "step" and "step" in s:
                 step_us[s["step"]] = step_us.get(s["step"], 0.0) + dur
+            if name == "serve.decode_step" and "width" in s:
+                rows[0] += int(s["width"])
+                rows[1] += int(s.get("fed", 0))
         stats[rank] = {"by_cat": by_cat, "by_span": by_span,
-                       "steps": sorted(step_us.values())}
+                       "steps": sorted(step_us.values()), "rows": rows}
     return stats
 
 
@@ -232,15 +237,22 @@ def _verdict(stats, skews_in_window):
     if serve_frac:
         rank = max(serve_frac, key=lambda r: serve_frac[r][0])
         frac, qwait, decode = serve_frac[rank]
+        # the fill of the passes: a step is one pass over its tokens,
+        # padded to one of two widths (`width`/`fed` on serve.decode_step)
+        width, fed = stats[rank].get("rows", (0, 0))
+        fill = f"; passes {fed / width:.1%} full ({fed} tokens in " \
+            f"{width} virtual rows)" if width else ""
         if frac > 0.5:
             return ("queue-bound", rank, "serve.queue_wait",
                     f"{frac:.1%} of request time waiting for admission "
                     f"({qwait / 1e6:.3f}s queued vs {decode / 1e6:.3f}s "
-                    "decoding) — add slots/capacity or shed earlier")
+                    "decoding) — add slots/capacity or shed earlier"
+                    + fill)
         return ("decode-bound", rank, "serve.decode_step",
                 f"{1 - frac:.1%} of request time in batched decode "
                 f"({decode / 1e6:.3f}s decoding vs {qwait / 1e6:.3f}s "
-                "queued) — the model/hardware is the latency floor")
+                "queued) — the model/hardware is the latency floor"
+                + fill)
     input_frac = {}
     for rank, st in stats.items():
         # only the CONSUMER-visible stall counts as input waiting:
