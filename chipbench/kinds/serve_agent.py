@@ -6,10 +6,10 @@ prefix tree holds, and a short suffix of its own; the (suffix, new
 tokens) pairs are the traffic file's cycle in order of submission and
 `--seed` makes the weights and the token ids only, so the composition of
 scheduler step k is a function of the cell's files, as in kind `serve`
-(whose `Client`, `ClosedLoop` and window protocol this imports). Set-up
-is by counts: one primer request (prefix + a few tokens) driven to its
-end, which leaves the prefix's pages in the tree; the clients; the
-warm-up steps.
+(whose `Client`, `ClosedLoop` and window protocol, closed by counts, this
+imports). Set-up is by counts too: one primer request (prefix + a few
+tokens) driven to its end, which leaves the prefix's pages in the tree;
+the clients; the warm-up steps.
 
 `correct` is decided at the timed sizes from what the timed path itself
 produced: the server keeps, for the audited requests, the float32 logits
@@ -24,6 +24,7 @@ import time
 
 import numpy as np
 
+from chipbench.kinds import serve
 from chipbench.kinds.serve import Client, ClosedLoop
 
 FED = ("attn_tokens", "attn_ctx_tokens", "attn_sel_tokens", "sparse_tokens",
@@ -66,10 +67,7 @@ class AgentLoop(ClosedLoop):
         self.prefix, self.audited = prefix, set(audited)
         self.fed = []           # per step: the server's counters, summed
         self.cached_rows = []   # per step: distinct rows in the cache
-        super().__init__(srv, cycle, 0, vocab, 0)
-        self.rng = rng
-        for _ in range(clients):
-            self.submit()
+        super().__init__(srv, cycle, clients, vocab, rng)
 
     def submit(self):
         k = len(self.requests)
@@ -113,12 +111,13 @@ def prime(srv, prompt, n_new):
 
 def audit(ctx, model, cfg, clients):
     """Compare the kept logits of the audited requests with the plain
-    reference's forward pass. Returns (ok, lines to say)."""
+    reference's forward pass. Returns (ok, lines to say, {name: [number
+    compared, its limit]})."""
     from chipbench.reference import glm5 as reference
 
     spec, limits = ctx.config["audit"], ctx.config["audit"]["limits"]
     layers, top = model.layer_weights()
-    ok, lines = True, []
+    ok, lines, checks = True, [], {}
     # one padded length for all: the reference compiles a layer once
     longest = max(c.req.prompt.size + len(c.req.tokens) for c in clients)
     for n, client in enumerate(clients):
@@ -142,6 +141,10 @@ def audit(ctx, model, cfg, clients):
             and med <= limits["median_relative_error"] \
             and worst <= limits["max_relative_error"]
         ok = ok and passed
+        checks[f"audit{n}_median_relative_error"] = [
+            med, limits["median_relative_error"]]
+        checks[f"audit{n}_max_relative_error"] = [
+            worst, limits["max_relative_error"]]
         lines.append(
             f"audit request {req.id}: {got.shape[0]} positions x "
             f"{got.shape[1]} logits at contexts {req.prompt.size}-"
@@ -167,11 +170,34 @@ def audit(ctx, model, cfg, clients):
                 lines.append(
                     f"  reference with {label}: median "
                     f"{float(np.median(e)):.6f}, largest {float(e.max()):.6f}")
-    return ok, lines
+    return ok, lines, checks
+
+
+def stretch_counts(step_fed, cached_rows, index_topk, emitted):
+    """What the traced stretch read and fed, for `work_latent`: per step
+    the growth of the server's counters (`step_fed`) and the rows the cache
+    held when the step began. A pass is one dispatch of either executable,
+    the wide one or the `slots`-wide one: each reads every cached indexer
+    key once and at least the `index_topk` latent rows one query selects.
+    `emitted` is the tokens the stretch gave back (one head row each)."""
+    passes = [f["chunk_steps"] + f["token_steps"] for f in step_fed]
+
+    def over(key):
+        return sum(f[key] for f in step_fed)
+
+    return {
+        "steps": len(step_fed), "tokens": over("attn_tokens"),
+        "ctx_tokens": over("attn_ctx_tokens"),
+        "sel_tokens": over("attn_sel_tokens"),
+        "sparse_tokens": over("sparse_tokens"), "emitted": emitted,
+        "passes": sum(passes),
+        "row_passes": sum(p * r for p, r in zip(passes, cached_rows)),
+        "sel_row_passes": sum(p * min(r, index_topk)
+                              for p, r in zip(passes, cached_rows))}
 
 
 def run(ctx):
-    from chipbench import stats, window
+    from chipbench import window
 
     traffic, server_args = ctx.traffic, ctx.config["server"]
     t_build = time.perf_counter()
@@ -198,10 +224,9 @@ def run(ctx):
 
     loop = AgentLoop(srv, cycle, traffic["clients"], cfg["vocab_size"], rng,
                      prefix, traffic["audited"])
-    n_warm = traffic["warmup_steps"]
-    win = window.measure(ctx, loop.step, lambda: None, n_warm,
-                         traffic["trace_steps"])
-    steps, t0, t1 = win.steps, win.t0, win.t1
+    win = window.measure(ctx, loop.step, lambda: None,
+                         traffic["warmup_steps"], traffic["trace_steps"],
+                         serve.by_count(ctx, loop))
     audited = [loop.requests[k] for k in traffic["audited"]]
     drained = 0     # outside every measurement: the audit needs its ends
     while not all(c.req.done for c in audited):
@@ -215,45 +240,18 @@ def run(ctx):
             f"{t_build - ctx.t_start:.1f}, model and server "
             f"{t_model - t_build:.1f}, the primer's "
             f"{n_prefix + n_primer} prompt tokens {t_primed - t_model:.1f}, "
-            f"{n_warm} warm-up steps {t0 - t_primed:.1f}; {drained} steps "
+            f"warm-up steps {win.t0 - t_primed:.1f}; {drained} steps "
             "after the measurements until the audited requests ended")
-
-    def inside(when):
-        return t0 < when <= t1
-
-    emitted = sum(inside(s) for c in loop.requests for s in c.stamps)
-    gaps = [gap for c in loop.requests for when, gap
-            in zip(c.stamps[1:], stats.token_gaps(c.stamps)) if inside(when)]
-    ttfts = [(c.req.ttft_s, c.n_prompt) for c in loop.requests
-             if c.stamps and inside(c.stamps[0])]
-    in_window = slice(n_warm, n_warm + steps)
-    traced = slice(n_warm + steps, n_warm + steps + win.traced_steps)
-    comp = loop.composition[in_window]
-    ctx.say(f"composition hash over the first {traffic['hash_steps']} "
-            f"measured steps: "
-            f"{stats.composition_hash(comp[:traffic['hash_steps']])}")
-    ctx.say(f"{steps} steps, {emitted} tokens, {len(ttfts)} first tokens, "
-            f"{len(gaps)} gaps in {t1 - t0:.3f}s; {loop.checked} requests "
-            f"finished and checked, {len(loop.failures)} failed; "
-            f"executables {st['executables']}; tree hits "
-            f"{st['prefix_hits']}, copies on write {st['cow_copies']}")
-    kinds = list(zip(loop.prefill_steps[in_window], loop.step_s[in_window]))
-    for label, flag in (("entered with a prefilling request", True),
-                        ("decode only", False)):
-        ms = [1e3 * s for f, s in kinds if f is flag]
-        if ms:
-            ctx.say(f"steps {label}: {len(ms)}, median "
-                    f"{stats.percentile(ms, 50):.1f} ms")
-    longest = sorted(enumerate(loop.step_s[in_window]), key=lambda e: -e[1])
-    ctx.say("longest steps of the window (step: ms): " + ", ".join(
-        f"{k}: {1e3 * sec:.1f}" for k, sec in longest[:6]))
-    for failure in loop.failures[:5]:
-        ctx.say(f"  FAILED {failure}")
+    end_to_end, in_window, held = serve.window_numbers(
+        ctx, loop, win, f"; executables {st['executables']}; tree hits "
+        f"{st['prefix_hits']}, copies on write {st['cow_copies']}")
+    steps = win.steps
+    traced = slice(in_window.stop, in_window.stop + win.traced_steps)
 
     # the pool's arenas go before the reference's temporaries come
     loop.srv = srv = None
     gc.collect()
-    agrees, lines = audit(ctx, model, cfg, audited)
+    agrees, lines, checks = audit(ctx, model, cfg, audited)
     for line in lines:
         ctx.say(line)
 
@@ -264,36 +262,41 @@ def run(ctx):
               "latent_width": cfg["kv_lora_rank"] + cfg["qk_rope_head_dim"],
               "index_heads": cfg["index_n_heads"],
               "index_dim": cfg["index_head_dim"],
-              "index_topk": cfg["index_topk"], "itemsize": 2}
+              "index_topk": cfg["index_topk"], "itemsize": 2,
+              # the rest of what `work_latent.serve_step` reads
+              "hidden": cfg["hidden_size"], "q_lora_rank": cfg["q_lora_rank"],
+              "qk_nope_head_dim": cfg["qk_nope_head_dim"],
+              "v_head_dim": cfg["v_head_dim"],
+              "dense_layers": cfg["first_k_dense_replace"],
+              "dense_width": cfg["intermediate_size"],
+              "expert_width": cfg["moe_intermediate_size"],
+              "shared_experts": cfg["n_shared_experts"],
+              "experts_per_token": cfg["num_experts_per_tok"],
+              "experts_held": cfg["experts_held"],
+              "router_width": cfg["n_routed_experts"],
+              "vocab": cfg["vocab_size"]}
     if win.traced_steps:
-        over = loop.fed_over(traced)
-        chunk = server_args["prefill_chunk"]
-        # token passes of each traced step: a chunk step scans `chunk`
         step_fed = [loop.fed_over(slice(k, k + 1))
                     for k in range(traced.start, traced.stop)]
-        passes = [chunk * f["chunk_steps"] + f["token_steps"]
-                  for f in step_fed]
-        rows = loop.cached_rows[traced]
-        shapes["traced"] = {
-            "steps": win.traced_steps, "tokens": over["attn_tokens"],
-            "ctx_tokens": over["attn_ctx_tokens"],
-            "sel_tokens": over["attn_sel_tokens"],
-            "sparse_tokens": over["sparse_tokens"],
-            "row_passes": sum(p * r for p, r in zip(passes, rows)),
-            "sel_row_passes": sum(p * min(r, cfg["index_topk"])
-                                  for p, r in zip(passes, rows))}
-        ctx.say(f"traced stretch: {shapes['traced']}")
+        shapes["traced"] = stretch_counts(
+            step_fed, loop.cached_rows[traced], cfg["index_topk"],
+            sum(loop.tokens_out[traced]))
+        ctx.say(f"traced stretch: {shapes['traced']}; per step (wide "
+                "passes, token passes, cached rows): " + ", ".join(
+                    f"({f['chunk_steps']}, {f['token_steps']}, {r})"
+                    for f, r in zip(step_fed, loop.cached_rows[traced])))
     lost = st["rejected"] + st["shed"] + st["failed"] + st["expired"]
     return {
         "correct": agrees and not loop.failures and lost == 0
-        and loop.checked > 0,
-        "attempted": loop.checked, "failed": len(loop.failures) + lost,
-        "end_to_end": {
-            "serve_tokens_per_s": emitted / (t1 - t0),
-            "token_gap_p95_ms": 1e3 * stats.percentile(gaps, 95),
-            "ttft_ms_per_prompt_token": stats.ttft_ms_per_prompt_token(
-                [s for s, _ in ttfts], [n for _, n in ttfts]),
-            "setup_s": win.setup_s},
+        and loop.checked > 0 and not win.overran,
+        "attempted": loop.checked,
+        "failed": len(loop.failures) + lost + win.overran,
+        "checks": dict(checks,
+                       requests_not_as_asked=[len(loop.failures), 0],
+                       requests_lost=[lost, 0],
+                       window_overran=[int(win.overran), 0]),
+        "end_to_end": end_to_end,
+        "window": held,
         "spans": {"bench.step": loop.step_s[in_window]},
         "counters": {
             "steps": steps,
@@ -306,7 +309,7 @@ def run(ctx):
             "prefix_tokens": fed["prefix_tokens"],
             "sparse_tokens": fed["sparse_tokens"],
             "attn_tokens": fed["attn_tokens"]},
-        "composition": comp,
+        "composition": loop.composition[in_window],
         "shapes": shapes,
         "peaks": ctx.peaks,
         **window.trace_result(win),

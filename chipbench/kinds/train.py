@@ -120,6 +120,9 @@ def run(ctx):
     return {
         "correct": not bad and fell,
         "attempted": len(values), "failed": len(bad),
+        "checks": {"losses_not_finite": [len(bad), 0],
+                   "loss_last_five_over_first": [
+                       float(np.mean(values[-5:])) / values[0], 1.0]},
         "end_to_end": {
             "train_tokens_per_s_per_chip": tokens_per_s / chips,
             "setup_s": win.setup_s},
@@ -128,7 +131,9 @@ def run(ctx):
         "shapes": {"batch_per_chip": traffic["per_chip_batch"],
                    "heads": cfg["num_heads"], "seq_len": seq_len,
                    "head_dim": cfg["units"] // cfg["num_heads"],
-                   "layers": cfg["num_layers"],
+                   "layers": cfg["num_layers"], "units": cfg["units"],
+                   "hidden": cfg["hidden_size"], "vocab": cfg["vocab_size"],
+                   "masked": ctx.config["job"]["masked"],
                    "itemsize": jax.numpy.dtype(cfg["dtype"]).itemsize},
         "peaks": ctx.peaks,
         **window.trace_result(win),

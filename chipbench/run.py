@@ -130,7 +130,7 @@ def layer_metrics(bench, workload, result):
 
 
 def context(workload, seed, seconds, trace, rehearsal, steps=None,
-            keep_trace=None):
+            keep_trace=None, dump_steps=None):
     """(bench, ctx): BENCHMARK.json and what a kind's driver is handed.
     `steps` closes the window after a count of steps instead of `seconds`
     (the tests use it; the command has no such flag)."""
@@ -142,6 +142,7 @@ def context(workload, seed, seconds, trace, rehearsal, steps=None,
         cell=cell, config=config, traffic=traffic, devices=devices,
         peaks=peaks, seed=seed % SEED_MOD, seconds=seconds, trace=trace,
         rehearsal=rehearsal, steps=steps, keep_trace=keep_trace,
+        dump_steps=dump_steps,
         t_start=T_START, say=say)
 
 
@@ -159,6 +160,10 @@ def main(argv=None):
     ap.add_argument("--keep-trace", metavar="DIR", default=None,
                     help="leave the profiler's files in DIR, to look at "
                          "a trace by hand (chipbench.xplane.describe)")
+    ap.add_argument("--dump-steps", metavar="FILE", default=None,
+                    help="serving kinds: write every step's time, width "
+                         "and tokens to FILE (JSON), to ask where a spread "
+                         "between runs comes from")
     args = ap.parse_args(argv)
 
     if args.rehearsal:      # before jax is imported
@@ -170,7 +175,8 @@ def main(argv=None):
 
     bench, ctx = context(args.workload, args.seed, args.seconds,
                          bool(args.trace), args.rehearsal,
-                         keep_trace=args.keep_trace)
+                         keep_trace=args.keep_trace,
+                         dump_steps=args.dump_steps)
     devices = ctx.devices
     result = driver_of(ctx).run(ctx)
 
@@ -210,6 +216,14 @@ def main(argv=None):
         line["breakdown"] = {
             "device_ops": xplane.top(ops),
             "idle_gaps": xplane.top(xplane.idle_gaps(trace))}
+    # every number that decided `correct`, beside its limit: the line's last
+    # key and the last lines of stderr, which is what the driver's record
+    # keeps of a run that was not correct
+    line["checks"] = {}
+    for name, (value, limit) in result["checks"].items():
+        line["checks"][name] = {"value": value, "limit": limit}
+        print(f"check {name}: {value} (limit {limit})", file=sys.stderr)
+    sys.stderr.flush()
     print(json.dumps(line), flush=True)
     return 0
 

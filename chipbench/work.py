@@ -28,6 +28,24 @@ def flash_attention_train(shapes):
     return flops, nbytes
 
 
+def encoder_train_step(shapes):
+    """The matrix products of one training step of a BERT-style encoder,
+    per chip, for the whole step's share of the chip's peak (`step_mfu`).
+    Forward, per token and layer: the four attention projections (4 U^2
+    multiply-adds) and the two feed-forward products (2 U I); per layer the
+    two attention products, 2 B H L^2 D multiply-adds each; the masked-LM
+    head on the `masked` positions of a sequence alone (a transform U^2 and
+    the decoder U V). Backward is twice the forward. Embedding lookups,
+    norms, softmax and the optimizer are not products and not counted, and
+    neither is anything an implementation recomputes. Bytes: none counted;
+    a training step of this size is compute-bound."""
+    b, l, u = shapes["batch_per_chip"], shapes["seq_len"], shapes["units"]
+    layer = b * l * (4 * u * u + 2 * u * shapes["hidden"]) \
+        + 2 * b * shapes["heads"] * l * l * shapes["head_dim"]
+    head = b * shapes["masked"] * (u * u + u * shapes["vocab"])
+    return 3 * 2 * (shapes["layers"] * layer + head), 0
+
+
 def least_seconds(flops, nbytes, peaks):
     """(seconds, which bound) the chip needs at its published peaks."""
     t_c = flops / peaks["bf16_flops_per_s"]

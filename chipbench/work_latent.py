@@ -38,3 +38,36 @@ def sparse_attention(shapes):
         shapes["index_dim"] * t["row_passes"]
         + shapes["latent_width"] * t["sel_row_passes"])
     return flops / t["steps"], nbytes / t["steps"]
+
+
+def serve_step(shapes):
+    """The operations of one serving step on this chip's share of the
+    model, for the whole step's share of the chip's peak (`step_mfu`), from
+    what the traced stretch was fed. Per fed token the matrix products of
+    every layer: the MLA projections in absorbed form and the indexer's,
+    then the dense feed-forward (the `dense_layers` leading layers) or the
+    router, the shared experts and as many routed experts as a token's
+    `experts_per_token` picks find on this chip on average (`experts_held`
+    of `router_width`); per emitted token the head over this chip's slice
+    of the vocabulary; and `sparse_attention`'s operations. Multiply-adds
+    count twice. Bytes: `sparse_attention`'s alone (the weights' bytes are
+    a floor of their own, not part of this share)."""
+    t, e = shapes["traced"], shapes["hidden"]
+    heads, kr = shapes["heads"], shapes["kv_lora_rank"]
+    nope, rope = shapes["qk_nope_head_dim"], shapes["latent_width"] - kr
+    qr, vd = shapes["q_lora_rank"], shapes["v_head_dim"]
+    attention = e * qr + qr * heads * (nope + rope) + e * (kr + rope) \
+        + heads * kr * (nope + vd) + heads * vd * e
+    indexer = qr * shapes["index_heads"] * shapes["index_dim"] \
+        + e * shapes["index_dim"] + e * shapes["index_heads"]
+    experts = shapes["shared_experts"] + shapes["experts_per_token"] \
+        * shapes["experts_held"] / shapes["router_width"]
+    expert_layer = e * shapes["router_width"] \
+        + 3 * e * shapes["expert_width"] * experts
+    dense = shapes["dense_layers"]
+    per_token = shapes["layers"] * (attention + indexer) \
+        + dense * 3 * e * shapes["dense_width"] \
+        + (shapes["layers"] - dense) * expert_layer
+    attn_flops, attn_bytes = sparse_attention(shapes)
+    flops = 2 * (per_token * t["tokens"] + e * shapes["vocab"] * t["emitted"])
+    return flops / t["steps"] + attn_flops, attn_bytes
