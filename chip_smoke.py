@@ -359,6 +359,21 @@ def serve_one_chip():
     require(st["chunk_steps"] > 0 and st["token_steps"] > 0,
             f"both widths should have run: {st['width_dispatches']}")
     srv.stop()
+    if REHEARSAL:
+        # one request through two page classes (window layers beside full)
+        from mxnet_tpu.models import laguna
+        model = laguna.LagunaForCausalLM(laguna.laguna_tiny_config())
+        model.initialize()
+        srv = serve.Server(model, slots=2, page_size=4, buckets=[64],
+                           pool_pages=32, prefill_chunk=4)
+        req = srv.submit(rng.randint(0, 96, (30,)), max_new_tokens=6)
+        srv.drain()
+        st = srv.stats()
+        say(f"Laguna 5L tiny, window 12: {req!r}; window pages returned "
+            f"{st['window_pages_freed']}, in use {st['pages_in_use']}")
+        require(req.state == serve.DONE and st["window_pages_freed"] > 0,
+                f"a request through both page classes: {req!r}, {st}")
+        srv.stop()
     del srv, model
     gc.collect()
     phase_end("serve_one_chip", start)

@@ -21,6 +21,15 @@ from ..gluon import HybridBlock
 #                            dt)`, no head axis)
 #   index_topk               tokens a learned sparse attention keeps, None
 #                            for full attention (the `sparse_tokens` counter)
+#   windows                  None, or one entry per stream: the stream's
+#                            page CLASS, named by its window. None keeps
+#                            every row of a request; an int W is a sliding-
+#                            window layer's cache, whose pages the server
+#                            returns once every row lies more than W behind
+#                            the request's position. Streams of one window
+#                            share an allocator and a page table; with more
+#                            than one class the step's `tables` are
+#                            (classes, slots, n_pg), None's first
 #   chunk_step               `decode_paged_chunk(toks, pos, slot, last,
 #                            tables, flat, page_size, full=)`: one pass over
 #                            the step's tokens as virtual rows (see
@@ -28,7 +37,7 @@ from ..gluon import HybridBlock
 #   draft_step               `decode_paged_draft(...)`, None: cannot draft
 ServingSpec = collections.namedtuple(
     "ServingSpec", "vocab_size max_length streams index_topk chunk_step "
-    "draft_step")
+    "draft_step windows", defaults=(None,))
 
 
 def paged_write_targets(pos_d, active_d, tb_d, page_size, scratch=None):
@@ -132,10 +141,12 @@ def paged_attention_step(q, k_new, v_new, k_pages, v_pages, tables, wp, wo,
     are distinct by construction but for scratch cells
     (`paged_write_targets`), which nothing reads.
 
-    q/k_new/v_new (B,H,1,D); k_pages/v_pages (P,H,ps,Dp), Dp >= D (the
-    lanes past D hold zeros); tables (B,n_pg) int32; wp/wo/t (B,) traced
-    int. Returns
-    (out (B,1,H*D), new_k_pages, new_v_pages)."""
+    q (B,H,1,D); k_new/v_new (B,Hkv,1,D), H a multiple of Hkv (grouped
+    queries); k_pages/v_pages (P,Hkv,ps,Dp), Dp >= D (the lanes past D
+    hold zeros); tables (B,n_pg) int32; wp/wo/t (B,) traced int. Returns
+    (out (B,1,H*D), new_k_pages, new_v_pages). (A model whose layers
+    differ in window or scope calls the two kernels itself:
+    `models/laguna.py`.)"""
     import jax
     import jax.numpy as jnp
 

@@ -33,12 +33,26 @@ runtime:
     pages go straight back to the pool — the "pages reclaimed" half of
     the serve degradation ladder, now at page granularity.
 
+  * **page classes** — a model whose layers do not all keep the same
+    rows (sliding-window layers beside full ones) names a CLASS for each
+    cache stream by its window (`ServingSpec.windows`). Each class is an
+    allocator of its own (`PageClass`: page count, free list, refcounts)
+    over arenas of its own, and a request holds a page table per class.
+    The class that keeps everything (window None) is the pool itself, as
+    before; a window class (`PagePool.windows[w]`) is sized for what the
+    slots can hold inside their windows, its pages are taken as a
+    request's position reaches them and returned once every row of a
+    page lies more than `w` behind it (`serve.Server`). Position ->
+    table index is `pos // page_size` in every class, no ring: a freed
+    entry points at scratch and is never walked.
+
 Layout invariant: page id `p` addresses physical row `p` in EVERY
-pooled array — all layers, K and V, and (when a drafter serves
-speculative decoding) the drafter's arrays too. One allocator, one
-refcount, one page table per request covers the whole model stack.
-Pages `0..scratch-1` are per-slot scratch: masked-out lanes of a
-batched step write there so real pages are never polluted.
+pooled array of its class — all layers, K and V, and (when a drafter
+serves speculative decoding) the drafter's arrays too. One allocator,
+one refcount, one page table per request and class covers the class's
+part of the model stack. Pages `0..scratch-1` of every class are
+per-slot scratch: masked-out lanes of a batched step write there so
+real pages are never polluted.
 """
 from __future__ import annotations
 
@@ -48,7 +62,7 @@ import itertools
 
 import numpy as np
 
-__all__ = ["PagePool", "PrefixTree", "PagesExhausted"]
+__all__ = ["PagePool", "PageClass", "PrefixTree", "PagesExhausted"]
 
 
 class PagesExhausted(RuntimeError):
@@ -76,46 +90,19 @@ def _block_digest(parent, block_bytes):
 # PagePool
 # ---------------------------------------------------------------------------
 
-class PagePool:
-    """Refcounted fixed-size KV pages over pooled per-layer arrays.
+class PageClass:
+    """One allocator: refcounts and a free list over the pages
+    `scratch .. num_pages - 1` of the arenas of one page class. Host-side
+    metadata only, guarded by the owning Server's lock."""
 
-    `streams` maps a tag ('target', and 'draft' when a speculative
-    drafter is attached) to a list of `(*lead, width, dtype)` specs — one
-    per pooled array, in the order of the model's flat state
-    (`models/_decode.ServingSpec.streams`). Every array is allocated as
-    (pages, *lead, page_size, width) zeros: `(heads, head_dim, dtype)` is
-    a key or value arena with a head axis (GPT: 2 * n_layers of them, K
-    then V), `(width, dtype)` one row a token with none (a latent cache
-    beside its indexer keys). Arenas of different shapes lie side by side
-    under ONE allocator: page id p is physical row p in all of them. The
-    last dimension is padded to the lane width where the paged kernels
-    run (`pallas_ops.kv_page_write.arena_head_dim`), so that the arenas'
-    default layout is the one the kernels read.
-
-    Page-table metadata (refcounts, free list) lives host-side and is
-    guarded by the owning Server's lock; the device arrays in
-    `self.state[tag]` are threaded (donated) through the paged step
-    executables by the scheduler thread only."""
-
-    def __init__(self, page_size, data_pages, scratch_pages, streams):
-        if page_size < 1 or data_pages < 1:
+    def __init__(self, data_pages, scratch_pages):
+        if data_pages < 1:
             raise ValueError(
-                f"PagePool needs page_size >= 1 and data_pages >= 1, got "
-                f"{page_size}/{data_pages}")
-        self.page_size = int(page_size)
+                f"a page class needs data_pages >= 1, got {data_pages}")
         self.scratch = int(scratch_pages)
         self.num_pages = self.scratch + int(data_pages)
         self.refcount = np.zeros(self.num_pages, np.int32)
         self.free = collections.deque(range(self.scratch, self.num_pages))
-        self.state = {}
-        self._specs = {tag: list(specs) for tag, specs in streams.items()}
-        import jax.numpy as jnp
-        from .pallas_ops.kv_page_write import arena_head_dim
-        for tag, specs in self._specs.items():
-            self.state[tag] = [
-                jnp.zeros((self.num_pages, *lead, self.page_size,
-                           arena_head_dim(width)), dt)
-                for (*lead, width, dt) in specs]
         self.stats = {"allocs": 0, "frees": 0, "cow_copies": 0,
                       "peak_used": 0}
 
@@ -129,10 +116,6 @@ class PagePool:
 
     def used_pages(self):
         return self.data_pages - len(self.free)
-
-    def pool_bytes(self):
-        return sum(int(a.nbytes) for arrs in self.state.values()
-                   for a in arrs)
 
     # -- alloc / refcount ------------------------------------------------
     def alloc(self, n):
@@ -166,14 +149,78 @@ class PagePool:
             self.free.append(int(page))
             self.stats["frees"] += 1
 
+
+class PagePool(PageClass):
+    """Refcounted fixed-size KV pages over pooled per-layer arrays.
+
+    `streams` maps a tag ('target', and 'draft' when a speculative
+    drafter is attached) to a list of `(*lead, width, dtype)` specs — one
+    per pooled array, in the order of the model's flat state
+    (`models/_decode.ServingSpec.streams`). Every array is allocated as
+    (pages, *lead, page_size, width) zeros: `(heads, head_dim, dtype)` is
+    a key or value arena with a head axis (GPT: 2 * n_layers of them, K
+    then V), `(width, dtype)` one row a token with none (a latent cache
+    beside its indexer keys). Arenas of different shapes lie side by side
+    under ONE allocator: page id p is physical row p in all of them. The
+    last dimension is padded to the lane width where the paged kernels
+    run (`pallas_ops.kv_page_write.arena_head_dim`), so that the arenas'
+    default layout is the one the kernels read.
+
+    The pool is itself the allocator of the class that keeps every row
+    (`PageClass`, window None). `windows` maps a tag to one entry per
+    stream, None or the stream's window, and `window_pages` each window
+    to the data pages of its class: those streams' arenas have that
+    class's page count and `self.windows[w]` is its allocator. Without
+    `windows` there is one class and everything is as it was.
+
+    Page-table metadata (refcounts, free list) lives host-side and is
+    guarded by the owning Server's lock; the device arrays in
+    `self.state[tag]` are threaded (donated) through the paged step
+    executables by the scheduler thread only."""
+
+    def __init__(self, page_size, data_pages, scratch_pages, streams,
+                 windows=None, window_pages=None):
+        if page_size < 1 or data_pages < 1:
+            raise ValueError(
+                f"PagePool needs page_size >= 1 and data_pages >= 1, got "
+                f"{page_size}/{data_pages}")
+        super().__init__(data_pages, scratch_pages)
+        self.page_size = int(page_size)
+        self.state = {}
+        self._specs = {tag: list(specs) for tag, specs in streams.items()}
+        self._windows = {tag: list((windows or {}).get(tag)
+                                   or [None] * len(specs))
+                         for tag, specs in self._specs.items()}
+        self.windows = {
+            w: PageClass(window_pages[w], scratch_pages)
+            for w in sorted({w for ws in self._windows.values()
+                             for w in ws if w is not None})}
+        import jax.numpy as jnp
+        from .pallas_ops.kv_page_write import arena_head_dim
+        for tag, specs in self._specs.items():
+            self.state[tag] = [
+                jnp.zeros((self.class_of(w).num_pages, *lead,
+                           self.page_size, arena_head_dim(width)), dt)
+                for (*lead, width, dt), w in zip(specs, self._windows[tag])]
+
+    def class_of(self, window):
+        """The allocator of the class named by `window` (None: the pool)."""
+        return self if window is None else self.windows[window]
+
+    def pool_bytes(self):
+        return sum(int(a.nbytes) for arrs in self.state.values()
+                   for a in arrs)
+
     def copy_page(self, src):
         """Copy-on-write: allocate a fresh page and device-copy `src`'s
-        row in every pooled array (all tags — the drafter's K/V for a
-        block must travel with the target's). Returns the new page id;
-        the caller drops its shared reference on `src`."""
+        row in every pooled array of this class (all tags — the drafter's
+        K/V for a block must travel with the target's). Returns the new
+        page id; the caller drops its shared reference on `src`."""
         (dst,) = self.alloc(1)
         for tag, arrs in self.state.items():
-            self.state[tag] = [a.at[dst].set(a[src]) for a in arrs]
+            self.state[tag] = [
+                a.at[dst].set(a[src]) if w is None else a
+                for a, w in zip(arrs, self._windows[tag])]
         self.stats["cow_copies"] += 1
         return dst
 

@@ -400,12 +400,23 @@ class _Group:
     len(prompt)` the slot is prefilling, after that it consumes its own
     sampled tokens. `matched[i]` records how many prompt tokens arrived
     pre-filled from the prefix tree; `inserted[i]` latches the one-time
-    tree insertion after the slot's prefill completes."""
+    tree insertion after the slot's prefill completes.
+
+    A model with window page classes (`windows`, their windows in order)
+    has one more table per class, `wtables[w]`, indexed like `tables` by
+    `pos // page_size`. A slot holds the entries `wlo[w][i] .. whi[w][i]
+    - 1` of its row there, one reference each in that class's allocator
+    (`Server._window_grow` takes them as the position reaches them,
+    `_window_trim` returns those the window has left); every other entry
+    is 0, a scratch page, and is never walked. `wheld[w][i]` is what
+    admission set aside for the slot in the class, a count. The
+    executables then take all tables stacked, `tables` first."""
 
     __slots__ = ("bucket", "n_pg", "slots", "pos", "owned", "matched",
-                 "inserted", "tables", "_tables_dev")
+                 "inserted", "tables", "wtables", "wlo", "whi", "wheld",
+                 "_tables_dev")
 
-    def __init__(self, bucket, n_slots, n_pg):
+    def __init__(self, bucket, n_slots, n_pg, windows=()):
         self.bucket = bucket
         self.n_pg = n_pg
         self.slots = [None] * n_slots
@@ -414,6 +425,11 @@ class _Group:
         self.matched = [0] * n_slots
         self.inserted = [False] * n_slots
         self.tables = np.zeros((n_slots, n_pg), np.int32)
+        self.wtables = {w: np.zeros((n_slots, n_pg), np.int32)
+                        for w in windows}
+        self.wlo = {w: [0] * n_slots for w in windows}
+        self.whi = {w: [0] * n_slots for w in windows}
+        self.wheld = {w: [0] * n_slots for w in windows}
         self._tables_dev = None
 
     def seat(self, i, req, pos0, pages, matched):
@@ -427,6 +443,9 @@ class _Group:
         self.slots[i] = None
         self.matched[i] = 0
         self.inserted[i] = False
+        for w, table in self.wtables.items():
+            table[i] = 0
+            self.wlo[w][i] = self.whi[w][i] = self.wheld[w][i] = 0
         self._set_pages(i, ())
 
     def _set_pages(self, i, pages):
@@ -439,12 +458,29 @@ class _Group:
         """The page ids slot i owns, in table order."""
         return self.tables[i, :self.owned[i]].tolist()
 
+    def window_pages_of(self, i, w):
+        """The page ids slot i holds in window class `w`, in table order."""
+        return self.wtables[w][i, self.wlo[w][i]:self.whi[w][i]].tolist()
+
+    def set_window_pages(self, i, w, lo, hi, pages=()):
+        """Slot i's row of class `w` now holds entries lo..hi-1; `pages`
+        are the ids of the last len(pages) of them, newly taken."""
+        row = self.wtables[w][i]
+        row[:lo] = 0
+        row[hi - len(pages):hi] = pages
+        self.wlo[w][i], self.whi[w][i] = lo, hi
+        self._tables_dev = None
+
     def device_tables(self):
-        """The page tables on the device: a copy (the host array goes on
-        changing), made again only after a slot was seated or cleared."""
+        """The page tables on the device: a copy (the host arrays go on
+        changing), made again only after a slot was seated or cleared or
+        a window class's row changed. One class: (slots, n_pg); more:
+        (classes, slots, n_pg), the class that keeps everything first."""
         if self._tables_dev is None:
             import jax.numpy as jnp
-            self._tables_dev = jnp.asarray(self.tables.copy())
+            self._tables_dev = jnp.asarray(
+                np.stack([self.tables, *self.wtables.values()])
+                if self.wtables else self.tables.copy())
         return self._tables_dev
 
     def free_slot(self):
@@ -549,6 +585,18 @@ class Server:
             "attn_tokens": 0, "attn_ctx_tokens": 0, "attn_sel_tokens": 0,
             "sparse_tokens": 0,
         }
+        # the model's window page classes, by window (`ServingSpec.windows`;
+        # none: one class, and everything below is as it was without them)
+        self._windows = tuple(sorted(
+            {w for w in (spec.windows or ()) if w is not None}))
+        if self._windows:
+            if drafter is not None:
+                raise ValueError(
+                    "a drafter beside a model with window page classes is "
+                    "not supported")
+            # of the contexts cut to each window class's window, and the
+            # pages those classes gave back (`stats()` shows them)
+            self._stats.update(attn_window_tokens=0, window_pages_freed=0)
         # the pool and its prefix tree. The usable position range rounds
         # DOWN to a page multiple and buckets round UP to one
         # (`_bucket_for`), so a bucket's page table covers it exactly.
@@ -573,7 +621,13 @@ class Server:
             self._params_bytes += self._measure_params(drafter)
         data = int(pool_pages or _config.get("pages_pool_pages")) \
             or self._slots * (self._max_len // ps)
-        self._pool = _pages.PagePool(ps, data, self._slots, streams)
+        # a window class holds what every slot can have inside its window:
+        # worked out, not a knob (`_window_need`)
+        self._pool = _pages.PagePool(
+            ps, data, self._slots, streams,
+            windows={"target": spec.windows} if self._windows else None,
+            window_pages={w: self._slots * self._window_need(w)
+                          for w in self._windows})
         self._tree = _pages.PrefixTree(self._pool)
         from . import check as _check
         if _check._enabled:
@@ -615,6 +669,16 @@ class Server:
                 [p.data()._data for p in model.collect_params().values()])
         except Exception:
             return 0
+
+    def _window_need(self, window, total=None):
+        """The most pages of the class of `window` a request ever holds
+        at once: those its window spans however it lies on the page grid
+        (`window / page_size`, and one more), and those the rows of one
+        pass (`prefill_chunk` at most) reach past it; never more than a
+        request of `total` positions has."""
+        ps = self._page_size
+        need = -(-(window + self._prefill_chunk - 1) // ps) + 1
+        return need if total is None else min(need, -(-total // ps))
 
     # -- client surface --------------------------------------------------
     def submit(self, prompt, max_new_tokens=32, eos=None, temperature=0.0,
@@ -709,6 +773,11 @@ class Server:
             out["pool_pages_free"] = self._pool.free_pages()
             out["tree_nodes"] = len(self._tree.nodes)
             out["cow_copies"] = self._pool.stats["cow_copies"]
+            if self._windows:
+                out["pages_in_use"] = {
+                    "full": self._pool.used_pages(),
+                    **{f"window{w}": c.used_pages()
+                       for w, c in self._pool.windows.items()}}
             pt = self._stats["prompt_tokens"]
             out["prefix_hit_rate"] = (
                 self._stats["prefix_tokens"] / pt if pt else 0.0)
@@ -736,6 +805,11 @@ class Server:
             return out
         with self._lock:
             out["pool_pages_free"] = self._pool.free_pages()
+            if self._windows:
+                # what admission has not set aside yet, per window class
+                out["window_pages_free"] = {
+                    w: c.data_pages - self._window_held(w)
+                    for w, c in self._pool.windows.items()}
         out["headroom_bytes"] = max(
             0, int(cap) - self._params_bytes - self._pool.pool_bytes())
         return out
@@ -990,7 +1064,7 @@ class Server:
             # verify pass; the draft chain below
             passes += [(w, False, "draft") for w in widths]
             passes.append((S * (self._spec_k + 1), True, "target"))
-        idle = _Group(bucket, S, n_pg)      # nobody seated: all padding
+        idle = _Group(bucket, S, n_pg, self._windows)   # all padding
         for width, full, tag in passes:
             self._dispatch(
                 idle, self._runner(bucket, width, full, draft=tag == "draft"),
@@ -1200,8 +1274,10 @@ class Server:
         import jax
         S = self._slots
         ints = lambda *shape: jax.ShapeDtypeStruct(shape, np.int32)  # noqa: E731
+        n_pg = bucket // self._page_size
         return (ints(width), ints(width), ints(width), ints(S),
-                ints(S, bucket // self._page_size),
+                ints(1 + len(self._windows), S, n_pg) if self._windows
+                else ints(S, n_pg),
                 [jax.ShapeDtypeStruct(tuple(a.shape), a.dtype)
                  for a in self._pool.state["target"]])
 
@@ -1335,7 +1411,18 @@ class Server:
         lp = req.prompt.size
         mn = req.max_new_tokens if max_new is None else max_new
         n_pg = min(-(-(lp + mn) // ps), bucket // ps)
-        matched_pages, matched = self._tree.match(req.prompt)
+        # a window class sets aside, as a count, the most the request will
+        # hold in it at once; its pages are taken as the position reaches
+        # them (`_window_grow`), which then cannot fail
+        for w, cls in self._pool.windows.items():
+            if self._window_held(w) + self._window_need(w, lp + mn) \
+                    > cls.data_pages:
+                return None
+        # prefix sharing is declined for a model with a window class: the
+        # tree would name pages of the full class only, and a hit would
+        # start past rows whose window-class pages are gone
+        matched_pages, matched = ([], 0) if self._windows \
+            else self._tree.match(req.prompt)
         cow = matched > 0 and matched == lp
         need = (n_pg - len(matched_pages)) + (1 if cow else 0)
         if self._pool.free_pages() < need:
@@ -1453,8 +1540,13 @@ class Server:
         grp = self._groups.get(bucket)
         if grp is None:
             grp = self._groups[bucket] = _Group(
-                bucket, self._slots, bucket // self._page_size)
-        grp.seat(grp.free_slot(), req, pos0, pages, matched)
+                bucket, self._slots, bucket // self._page_size,
+                self._windows)
+        i = grp.free_slot()
+        grp.seat(i, req, pos0, pages, matched)
+        for w in self._windows:
+            grp.wheld[w][i] = self._window_need(
+                w, req.prompt.size + req.max_new_tokens)
         self._stats["prompt_tokens"] += req.prompt.size
         self._stats["prefix_tokens"] += pos0
         if matched:
@@ -1481,10 +1573,43 @@ class Server:
     def _vacate(self, grp, i):
         """Release slot i of `grp`: drop one pool reference per owned
         page — tree-shared pages survive with the tree's reference,
-        exclusive ones return to the free list."""
+        exclusive ones return to the free list — and, in every window
+        class, the pages it holds and what admission set aside."""
         for p in grp.pages_of(i):
             self._pool.decref(p)
+        for w, cls in self._pool.windows.items():
+            for p in grp.window_pages_of(i, w):
+                cls.decref(p)
         grp.clear(i)
+
+    def _window_held(self, w):
+        """Pages of window class `w` set aside for the seated requests."""
+        return sum(sum(g.wheld[w]) for g in self._groups.values())
+
+    def _window_grow(self, grp, feeds):
+        """Before a pass: every request takes, in each window class, the
+        pages the positions it is about to write reach (table entries up
+        to `(p + n - 1) // page_size`). Admission set them aside."""
+        ps = self._page_size
+        for i, p, ids in feeds:
+            hi = (p + len(ids) - 1) // ps + 1
+            for w, cls in self._pool.windows.items():
+                have = grp.whi[w][i]    # 0 before a request's first pass
+                if hi > have:
+                    grp.set_window_pages(i, w, grp.wlo[w][i], hi,
+                                         cls.alloc(hi - have))
+
+    def _window_trim(self, grp, i, pos):
+        """After a pass: slot i's next row, at `pos`, sees positions above
+        `pos - window` only; the pages whose every row lies at or below
+        that go back to their class, and their entries to scratch."""
+        for w, cls in self._pool.windows.items():
+            lo, keep = grp.wlo[w][i], max(pos - w + 1, 0) // self._page_size
+            if keep > lo:
+                for p in grp.wtables[w][i, lo:keep].tolist():
+                    cls.decref(p)
+                self._stats["window_pages_freed"] += keep - lo
+                grp.set_window_pages(i, w, keep, grp.whi[w][i])
 
     def _remove_from_slots(self, req):
         for g in self._groups.values():
@@ -1595,6 +1720,9 @@ class Server:
         with (_trace.span("serve.prepare", cat="phase", step=sched_step,
                           slots=n_active)
               if sp else _NULLCTX) as prep:
+            if self._windows:
+                with self._lock:
+                    self._window_grow(grp, feeds)
             toks, pos, slot, last = [], [], [], [0] * self._slots
             for i, p, ids in feeds:
                 toks.extend(ids)
@@ -1640,8 +1768,11 @@ class Server:
                     ni = len(ids)
                     grp.pos[i] = p + ni
                     self._note_fed(p, ni)
+                    if self._windows:
+                        self._window_trim(grp, i, p + ni)
                     lp = r.prompt.size
-                    if p + ni >= lp and not grp.inserted[i]:
+                    if p + ni >= lp and not grp.inserted[i] \
+                            and not self._windows:
                         self._tree_insert(grp, i, r)
                     if p + ni < lp:
                         continue    # still prefilling the prompt
@@ -1837,6 +1968,11 @@ class Server:
         ctx = ni * p + ni * (ni + 1) // 2
         st["attn_tokens"] += ni
         st["attn_ctx_tokens"] += ctx
+        for w in self._windows:
+            # the contexts cut to the window: min(q + 1, w) over the rows
+            under = max(0, min(ni, w - p))
+            st["attn_window_tokens"] += \
+                under * p + under * (under + 1) // 2 + (ni - under) * w
         if k is None or p + ni <= k:
             st["attn_sel_tokens"] += ctx
         else:

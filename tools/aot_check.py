@@ -12,7 +12,7 @@ HBM); what it cannot catch is anything that only shows when the code RUNS
 — numerics, hangs, real memory — and that is the chip's word.
 
     JAX_PLATFORMS=cpu python tools/aot_check.py            # everything
-    JAX_PLATFORMS=cpu python tools/aot_check.py kernels    # or: train, serve, serve_glm
+    JAX_PLATFORMS=cpu python tools/aot_check.py kernels    # or: train, serve, serve_glm, serve_laguna
 
 Arguments are `jax.ShapeDtypeStruct`s whose shardings name the topology's
 devices; the two places the package asks JAX what it runs on (the kernel
@@ -335,8 +335,70 @@ def check_serve_glm(devices):
     srv.stop()
 
 
+def check_serve_laguna(devices):
+    """The benchmark's Laguna cell (chipbench/configs/
+    laguna-xs2-serve-pp8.json: 3.87 B parameters in bf16, 32 slots, one
+    bucket of 12,800 over a full class of 6,400 pages of 64 and a window
+    class of 320): the wide pass (64 virtual rows) and the `slots`-wide one
+    as the server builds them, from abstract parameters and arenas. Each
+    must hold both paged kernels once a layer, keep its ten arenas of two
+    page counts where they are (aliased, no arena-shaped copy) and fit the
+    chip."""
+    from chipbench.kinds import serve_mixed
+    from mxnet_tpu.models import laguna
+    from mxnet_tpu.ndarray import NDArray
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "chipbench", "configs",
+                           "laguna-xs2-serve-pp8.json")) as f:
+        cell = json.load(f)
+    cfg = serve_mixed.model_config(cell)
+    mesh = parallel.make_mesh(devices=devices[:1])
+    s1 = NamedSharding(mesh, P())
+    model = laguna.LagunaForCausalLM(cfg)
+    for _, p in model._iter_params():
+        p._data = NDArray(sds(p.shape, jnp.dtype(p.dtype), s1))
+    bucket = cell["server"]["buckets"][0]
+    real_zeros = jnp.zeros
+    jnp.zeros = lambda shape, dtype: sds(shape, jnp.dtype(dtype), s1)
+    try:                # the pool's arenas, as shapes
+        srv = serve.Server(model, **cell["server"])
+    finally:
+        jnp.zeros = real_zeros
+    n_l = cfg["num_hidden_layers"]
+    for width in (srv._wide(), srv._slots):
+        run, avals = srv._runner(bucket, width), \
+            srv._step_avals(bucket, width)
+        arenas = avals[-1]
+        avals = jax.tree.map(lambda a: sds(a.shape, a.dtype, s1), avals)
+        t0 = time.perf_counter()
+        lowered = run.lower(*avals)
+        found = pallas_kernels(lowered)
+        compiled = lowered.compile()
+        mem = compiled.memory_analysis()
+        text = compiled.as_text()
+        copies, aliased = arena_copies_and_aliases(text, arenas)
+        total = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+        print(f"  compiles: Laguna stage ({laguna.param_count(cfg) / 1e9:.3f}"
+              f" B parameters) paged step, bucket {bucket} width {width}  "
+              f"[{time.perf_counter() - t0:.1f}s]  kernels={found}  "
+              f"arena copies: {len(copies)}  aliased parameters: "
+              f"{len(aliased)}  temp={mem.temp_size_in_bytes / 2**30:.2f} "
+              f"GiB args={mem.argument_size_in_bytes / 2**30:.2f} GiB",
+              flush=True)
+        want = {"kv_page_write": n_l, "paged_attention": n_l}
+        assert found == want, f"expected {want} in the step, got {found}"
+        assert not copies, f"arena-shaped copies left: {copies[0]}"
+        assert len(aliased) == len(arenas), \
+            f"{len(aliased)} of {len(arenas)} arenas aliased to outputs"
+        assert total < 14 * 2**30, \
+            f"{total / 2**30:.2f} GiB leaves no room on a 16 GB chip"
+    srv.stop()
+
+
 def main():
-    which = sys.argv[1:] or ["kernels", "train", "serve", "serve_glm"]
+    which = sys.argv[1:] or ["kernels", "train", "serve", "serve_glm",
+                             "serve_laguna"]
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name=TOPOLOGY)
     devices = list(topo.devices)
@@ -351,7 +413,8 @@ def main():
     for name in which:
         print(f"== {name} ==")
         {"kernels": check_kernels, "train": check_train,
-         "serve": check_serve, "serve_glm": check_serve_glm}[name](devices)
+         "serve": check_serve, "serve_glm": check_serve_glm,
+         "serve_laguna": check_serve_laguna}[name](devices)
     print("aot_check: everything compiled for", devices[0].device_kind)
 
 
