@@ -41,11 +41,19 @@ vLLM-style block table:
     never depend on its neighbours, so a request's tokens are the same
     under load as alone (the tests hold them to `model.generate`'s on
     the CPU). Freshly sampled tokens stream to each request's consumer.
+  * **a token is chosen where its logits are** — the step executable
+    returns, beside the float32 logits, their argmax; the logits stay
+    on the device and the host fetches the int32 ids, 4 bytes a row.
+    A row of logits reaches the host only for a request that samples
+    (`temperature > 0`: top-k softmax from the request's own seeded
+    rng, on the host) or keeps them (`keep_logits`): those rows and no
+    others, in one gather and one copy a pass (`Server._fetch_out`).
   * **a drafter** (`Server(drafter=...)`) adds draft-verify speculative
     decoding with exact greedy acceptance: the drafter chains `spec_k`
     proposals into the pool's `draft` stream, the target verifies them
     in one pass of `slots * (spec_k + 1)` rows, the host keeps the
-    longest agreeing prefix. It changes WHEN tokens are computed, never which.
+    longest prefix that agrees with the ids of that pass. It changes
+    WHEN tokens are computed, never which.
 
 Robustness — the request lifecycle:
 
@@ -156,7 +164,8 @@ def _close_round(step_span, stream_span, chunk, tokens):
 
 
 def _fetch(out, dtype):
-    """A step's result on the host: the scheduler's wait for the device.
+    """A step's result on the host (the token ids of its rows; the few
+    logits rows asked for): the scheduler's wait for the device.
 
     It polls (`is_ready`, yielding the processor and the interpreter lock
     between polls) where `np.asarray` alone would sleep until the runtime
@@ -167,10 +176,30 @@ def _fetch(out, dtype):
     long as they slept here; every one ran at the fast rate from its
     first polled wait on, and no polled stretch was ever slow). A
     scheduler thread that waits tens of milliseconds a step for its chip
-    spends one host core on that; other threads run whenever it yields."""
+    spends one host core on that; other threads run whenever it yields.
+
+    The copy is asked for before the wait, so it follows the result with
+    no round trip of its own (what a second round trip costs: PERF.md
+    section 6, PR 35)."""
+    out.copy_to_host_async()
     while not out.is_ready():
         time.sleep(0)
     return np.asarray(out, dtype)
+
+
+_take = None
+
+
+def _take_rows(logits, want):
+    """`logits[want]` on the device: the rows of a pass the host asked
+    for, gathered so that one copy brings them. One jit for the process
+    (its executables differ by the shapes alone: one for each number of
+    rows a pass of that shape has been asked for)."""
+    global _take
+    if _take is None:
+        import jax
+        _take = jax.jit(lambda lg, i: lg[i])
+    return _take(logits, np.asarray(want, np.int32))
 
 
 def servers():
@@ -258,7 +287,8 @@ class Request:
     '504 deadline ...', '499 cancelled', '500 ...'), `tokens` (generated
     so far), `max_new_tokens` (EFFECTIVE — the shrink rung may clamp it,
     recorded in `degraded`), `logits` (under `submit(keep_logits=True)`:
-    the float32 row behind each token), `requeues`, and the timing
+    the float32 row behind each token, fetched for it), `requeues`, and
+    the timing
     properties `queue_wait_s` / `ttft_s`.
 
     Consume results with `stream()` (yields tokens as they are
@@ -282,8 +312,8 @@ class Request:
         self.state = QUEUED
         self.verdict = None
         self.tokens = []
-        # the float32 logits row each token was sampled from, kept on
-        # request (`submit(keep_logits=True)`): the host fetched it anyway
+        # the float32 logits row each token was chosen from, fetched and
+        # kept on request (`submit(keep_logits=True)`)
         self.logits = [] if keep_logits else None
         self.degraded = None
         self.requeues = 0
@@ -578,6 +608,11 @@ class Server:
             # the fill of the passes: virtual rows dispatched (the sum of
             # the widths) and the tokens fed in them
             "rows_dispatched": 0, "rows_fed": 0,
+            # what the passes brought to the host: tokens emitted from the
+            # ids the device chose, the float32 logits rows copied (for
+            # requests that sample or keep them), and all bytes copied
+            "rows_sampled_on_device": 0, "logit_rows_fetched": 0,
+            "fetched_bytes": 0,
             "drafts_proposed": 0, "drafts_accepted": 0,
             # what attention was fed, worked out from positions alone:
             # tokens, the sum of their context lengths, of the contexts
@@ -688,7 +723,8 @@ class Server:
         under `serve_shed=reject`, or rejected when the request cannot
         fit the device even alone). Never raises for overload.
         `keep_logits` keeps on `Request.logits` the float32 row each
-        token was sampled from (an audit; nothing more is fetched)."""
+        token was chosen from (an audit: that row is fetched beside the
+        pass's token ids, 4 x vocabulary bytes a token)."""
         prompt = np.asarray(prompt, np.int32).reshape(-1)
         if prompt.size == 0 or int(max_new_tokens) <= 0:
             raise ValueError("submit needs a non-empty prompt and "
@@ -1054,7 +1090,10 @@ class Server:
         scratch page, which nothing reads). So no executable is first
         built inside a timed stretch of steps, whichever width a loop
         needs first, and `stats()["executables"]` does not grow after
-        the step of the admission."""
+        the step of the admission. The small gathers of `_take_rows` are
+        compiled here for the requests held at that step, and for a
+        later one when its first token asks (PERF.md section 6, PR 35,
+        has what that costs)."""
         import jax.numpy as jnp
         S, n_pg = self._slots, bucket // self._page_size
         widths = sorted({S, self._wide()})
@@ -1065,10 +1104,21 @@ class Server:
             passes += [(w, False, "draft") for w in widths]
             passes.append((S * (self._spec_k + 1), True, "target"))
         idle = _Group(bucket, S, n_pg, self._windows)   # all padding
+        with self._lock:
+            held = [*self._queue, *(r for g in self._groups.values()
+                                    for r in g.slots)]
+        asking = sum(self._wants_row(r) for r in held)
         for width, full, tag in passes:
-            self._dispatch(
+            logits, _ = self._dispatch(
                 idle, self._runner(bucket, width, full, draft=tag == "draft"),
                 (*self._pack_rows(width), idle.device_tables()), tag)
+            if tag == "target":
+                # the gathers that bring the logits rows the requests held
+                # now can ask of such a pass (`_fetch_out`); a count that
+                # a later request adds is compiled when it first occurs
+                most = asking * (self._spec_k + 1 if full else 1)
+                for n in range(1, min(most, logits.shape[0]) + 1):
+                    _take_rows(logits, [0] * n)
         if self._drafter is not None:
             blank = jnp.zeros((S,), jnp.int32)
             self._dispatch(idle, self._draft_runner(bucket),
@@ -1215,8 +1265,16 @@ class Server:
             ps = self._page_size
 
             def step(toks, pos, slot, last, tables, flat):
-                return spec.chunk_step(toks, pos, slot, last, tables, flat,
-                                       ps, full=full)
+                import jax.numpy as jnp
+                lg, new = spec.chunk_step(toks, pos, slot, last, tables,
+                                          flat, ps, full=full)
+                # the token a greedy row emits is chosen where its logits
+                # are (the lowest id on a tie, as np.argmax): the host
+                # fetches the ids, and a row of logits only when it needs
+                # one (`_fetch_out`). The ids leave the executable behind
+                # the pool's arrays; `_dispatch` takes them off again
+                ids = jnp.argmax(lg._data, -1).astype(jnp.int32)
+                return lg, [*new, ids]
 
             # the label joins a device trace's instructions to this
             # program's named scopes (mx.trace.scope_map); the
@@ -1258,7 +1316,7 @@ class Server:
         """(runner, argument avals) of the HEAVIEST step executable
         `bucket` can run: the speculative verify pass when a drafter is
         attached (`slots * (spec_k + 1)` rows and float32 logits for
-        every one of them), else the wide pass."""
+        every one of them, on the device), else the wide pass."""
         S = self._slots
         if self._drafter is not None:
             width, full = S * (self._spec_k + 1), True
@@ -1730,6 +1788,8 @@ class Server:
                 slot.extend([i] * len(ids))
                 last[i] = len(toks) - 1
             fed = len(toks)
+            want = [i for i, p, ids in feeds
+                    if self._wants_row(grp.slots[i], p + len(ids))]
             # the `slots`-wide pass whenever the tokens fit it
             width = self._slots if fed <= self._slots else self._wide()
             C = self._chunk_of(width)
@@ -1742,16 +1802,14 @@ class Server:
                           chunk=C, bucket=grp.bucket, slots=n_active,
                           width=width, fed=fed)
               if sp else _NULLCTX):
-            logits = self._dispatch(grp, run, lead, "target")
+            out = self._dispatch(grp, run, lead, "target")
             if self._drafter is not None:
                 # mirror the pass on the drafter so its cache tracks the
                 # target position-for-position (gap-0: a later speculative
                 # round can start its chain with no catch-up work)
                 drun = self._runner(grp.bucket, width, draft=True)
                 self._dispatch(grp, drun, lead, "draft")
-            with (_trace.span("serve.fetch", cat="phase", step=sched_step,
-                              chunk=C) if sp else _NULLCTX):
-                lg = _fetch(logits, np.float32)     # host fetch = the fence
+            chosen, rows = self._fetch_out(out, want, sched_step, C, sp)
         with (_trace.span("serve.stream", cat="serve", step=sched_step)
               if sp else _NULLCTX) as stream:
             if sp:
@@ -1776,8 +1834,7 @@ class Server:
                         self._tree_insert(grp, i, r)
                     if p + ni < lp:
                         continue    # still prefilling the prompt
-                    nxt = self._sample(r, lg[i])
-                    self._emit(r, nxt, lg[i])
+                    nxt = self._emit(r, chosen[i], rows.get(i))
                     if (r.eos is not None and nxt == r.eos) \
                             or len(r.tokens) >= r.max_new_tokens:
                         self._vacate(grp, i)
@@ -1788,11 +1845,14 @@ class Server:
     def _spec_round(self, grp, active, sched_step, sp=None):
         """One speculative decoding round: the drafter chains k greedy
         proposals per eligible slot, the target verifies them all in ONE
-        pass of k+1 virtual rows a slot (full logits), and the host keeps
-        the longest agreeing prefix plus the bonus token — exact greedy
+        pass of k+1 virtual rows a slot (the head on every row), and the
+        host keeps, comparing the drafts with that pass's token ids, the
+        longest agreeing prefix plus the bonus token — exact greedy
         acceptance, so the emitted stream is plain greedy decode's.
         Non-greedy slots ride along with a single ordinary token, the
-        rest of their rows padding."""
+        rest of their rows padding; their row of logits, and a
+        `keep_logits` slot's rows, come over as in any pass
+        (`_fetch_out`)."""
         import jax.numpy as jnp
         k = self._spec_k
         S = self._slots
@@ -1815,7 +1875,8 @@ class Server:
                 grp, self._draft_runner(grp.bucket),
                 (jnp.asarray(tok0), jnp.asarray(t0), jnp.asarray(spec_row),
                  tables_d), "draft")
-            drafts = _fetch(drafts_out, np.int32)[:, :k]       # (B, k)
+            # (B, k+1): the chain's last proposal only fills the cache
+            drafts = _fetch(drafts_out, np.int32)
             # slot-major: rows i*(k+1) .. of slot i, what it does not feed
             # padding through its own scratch page
             toks = np.zeros((width,), np.int32)
@@ -1826,22 +1887,22 @@ class Server:
                 n[i] = k + 1 if spec_row[i] else 1
                 toks[w] = tok0[i]
                 if spec_row[i]:
-                    toks[w + 1:w + k + 1] = drafts[i]
+                    toks[w + 1:w + k + 1] = drafts[i, :k]
                 pos[w:w + n[i]] = t0[i] + np.arange(n[i])
             fed = int(n.sum())
+            want = [i * (k + 1) + j for i in active
+                    if self._wants_row(grp.slots[i]) for j in range(n[i])]
             run = self._runner(grp.bucket, width, True)
         with (_trace.span("serve.decode_step", cat="serve", step=sched_step,
                           chunk=k + 1, bucket=grp.bucket,
                           slots=len(active), spec_k=k, width=width, fed=fed)
               if sp else _NULLCTX):
-            # `full`: every row's logits come back, no slot's head row
-            logits = self._dispatch(
+            # `full`: the head runs on every row, no slot's head row
+            out = self._dispatch(
                 grp, run, (toks, pos, slot, np.zeros((S,), np.int32),
                            tables_d), "target")
-            with (_trace.span("serve.fetch", cat="phase", step=sched_step,
-                              chunk=k + 1) if sp else _NULLCTX):
-                lgs = _fetch(logits, np.float32) \
-                    .reshape(S, k + 1, -1)                 # (B, k+1, V)
+            chosen, rows = self._fetch_out(out, want, sched_step, k + 1, sp)
+            chosen = chosen.reshape(S, k + 1)
         with (_trace.span("serve.stream", cat="serve", step=sched_step)
               if sp else _NULLCTX) as stream:
             if sp:
@@ -1850,16 +1911,17 @@ class Server:
                 self._stats["steps"] += 1
                 self._stats["spec_rounds"] += 1
                 self._note_pass(width, fed)
+                self._stats["fetched_bytes"] += drafts.nbytes
                 for i in active:
                     r = grp.slots[i]
                     if r is None or r.state in TERMINAL:
                         continue
                     p = grp.pos[i]
                     self._note_fed(p, int(n[i]))
+                    w = i * (k + 1)
                     if not spec_row[i]:
                         grp.pos[i] = p + 1
-                        nxt = self._sample(r, lgs[i, 0])
-                        self._emit(r, nxt, lgs[i, 0])
+                        nxt = self._emit(r, chosen[i, 0], rows.get(w))
                         if (r.eos is not None and nxt == r.eos) \
                                 or len(r.tokens) >= r.max_new_tokens:
                             self._vacate(grp, i)
@@ -1869,10 +1931,9 @@ class Server:
                     emitted = 0
                     done = False
                     for j in range(k + 1):
-                        # same argmax as _sample's greedy path — exact
+                        # the target's own choice for the row: exact
                         # acceptance means verify-then-keep, never trust
-                        nxt = int(lgs[i, j].argmax())
-                        self._emit(r, nxt, lgs[i, j])
+                        nxt = self._emit(r, chosen[i, j], rows.get(w + j))
                         emitted += 1
                         if (r.eos is not None and nxt == r.eos) \
                                 or len(r.tokens) >= r.max_new_tokens:
@@ -1914,9 +1975,11 @@ class Server:
                     "mx.serve: the failed dispatch consumed the donated "
                     f"page-pool buffers ('{tag}' stream) — cannot retry "
                     f"in place (bucket {grp.bucket})")
+            n = len(pool.state[tag])
             out, new_state = run(*lead, pool.state[tag])
-            pool.state[tag] = new_state
-            return out
+            pool.state[tag] = new_state[:n]
+            # a chunk pass returns its rows' token ids behind the arrays
+            return (out, *new_state[n:]) if len(new_state) > n else out
 
         def on_retry(exc, attempt, delay):
             with self._lock:
@@ -1937,21 +2000,55 @@ class Server:
                                 on_retry=on_retry)
 
     def _sample(self, req, lg):
-        """Next token from one slot's logits row (host-side, so each
-        request's stream is deterministic and independent of what else
-        shares the batch): greedy at temperature 0, else top-k softmax
-        sampling from the request's own seeded rng."""
-        if req.temperature > 0.0:
-            if req._rng is None:
-                req._rng = np.random.RandomState(req.seed)
-            if req.top_k:
-                kth = np.partition(lg, -req.top_k)[-req.top_k]
-                lg = np.where(lg < kth, -np.inf, lg)
-            lg = lg / req.temperature
-            p = np.exp(lg - lg.max())
-            p /= p.sum()
-            return int(req._rng.choice(p.size, p=p))
-        return int(lg.argmax())
+        """Next token of a request with `temperature > 0` from its logits
+        row: top-k softmax sampling from the request's own seeded rng, on
+        the host, so its stream is deterministic and independent of what
+        else shares the batch."""
+        if req._rng is None:
+            req._rng = np.random.RandomState(req.seed)
+        if req.top_k:
+            kth = np.partition(lg, -req.top_k)[-req.top_k]
+            lg = np.where(lg < kth, -np.inf, lg)
+        lg = lg / req.temperature
+        p = np.exp(lg - lg.max())
+        p /= p.sum()
+        return int(req._rng.choice(p.size, p=p))
+
+    @staticmethod
+    def _wants_row(req, pos=None):
+        """True when the host needs the float32 logits of the row `req`
+        emits from: to sample (`temperature > 0`) or to keep
+        (`keep_logits`). A greedy request that keeps nothing needs the
+        row's token id alone. `pos`: where the pass leaves the request;
+        inside its prompt it emits nothing."""
+        if req is None or (pos is not None and pos < req.prompt.size):
+            return False
+        return req.temperature > 0.0 or req.logits is not None
+
+    def _fetch_out(self, out, want, sched_step, chunk, sp):
+        """What the host needs of a pass's output `(logits, ids)`, both on
+        the device: the int32 ids of all its rows (4 bytes a row; the wait
+        for them is the step's fence) and, of the float32 logits, the rows
+        `want` names and no others, gathered on the device and brought
+        in one copy (`_take_rows`). Returns (ids, {row: its logits})."""
+        with (_trace.span("serve.fetch", cat="phase", step=sched_step,
+                          chunk=chunk) if sp else _NULLCTX) as span:
+            logits, ids = out
+            if want:
+                picked = _take_rows(logits, want)   # queued behind the step
+                picked.copy_to_host_async()         # and its copy behind it
+            ids = _fetch(ids, np.int32)
+            rows, nbytes = {}, ids.nbytes
+            if want:
+                picked = _fetch(picked, np.float32)
+                rows = dict(zip(want, picked))
+                nbytes += picked.nbytes
+            if sp:
+                span.attrs.update(bytes=nbytes, rows=len(want))
+        with self._lock:
+            self._stats["fetched_bytes"] += nbytes
+            self._stats["logit_rows_fetched"] += len(want)
+        return ids, rows
 
     def _note_pass(self, width, fed):
         """Count one pass: its virtual rows, the tokens fed in them (the
@@ -1982,9 +2079,18 @@ class Server:
                 + over * k
             st["sparse_tokens"] += over
 
-    def _emit(self, req, tok, row=None):
-        req.tokens.append(int(tok))
-        if req.logits is not None and row is not None:
+    def _emit(self, req, tok, row):
+        """`req` emits the token of one row of a pass and returns it:
+        `tok`, the row's argmax as the device took it, unless the request
+        samples, which it does from `row`, the row's logits (fetched for a
+        request that samples or keeps them, None for every other)."""
+        if req.temperature > 0.0:
+            tok = self._sample(req, row)
+        else:
+            tok = int(tok)
+            self._stats["rows_sampled_on_device"] += 1
+        req.tokens.append(tok)
+        if req.logits is not None:
             req.logits.append(np.array(row, np.float32))
         self._stats["tokens"] += 1
         if _telemetry._enabled:
@@ -1997,7 +2103,8 @@ class Server:
                 req._first_token_perf = time.perf_counter()
                 if _telemetry._enabled:
                     _M_TTFT.observe(req.ttft_s)
-            req._stream_q.put(int(tok))
+            req._stream_q.put(tok)
+        return tok
 
     # -- terminal transitions -------------------------------------------
     _OUTCOME = {DONE: "completed", REJECTED: "rejected", SHED: "shed",

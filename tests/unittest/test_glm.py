@@ -122,6 +122,35 @@ def test_served_logits_agree_with_the_reference(dtype):
     assert np.median(reference.relative_errors(half, want)) > 3 * limit
 
 
+def test_only_an_audited_request_brings_logits_to_the_host():
+    """The benchmark's mix: greedy requests, some audited. All emit the
+    tokens they emit with every request audited (the device's argmax is
+    the kept row's), and only the audited ones' rows are copied."""
+    model, cfg = build("float32")
+    rng = np.random.RandomState(0)
+    prompts = [rng.randint(0, cfg["vocab_size"], (lp,)) for lp, _ in LENGTHS]
+    runs = []
+    for audited in ((0, 1, 2, 3), (1,)):
+        srv = server(model)
+        reqs = [srv.submit(p, max_new_tokens=mn, keep_logits=i in audited)
+                for i, (p, (_, mn)) in enumerate(zip(prompts, LENGTHS))]
+        srv.drain()
+        runs.append((reqs, srv.stats()))
+        srv.stop()
+    (every, _), (one, st) = runs
+    assert [r.tokens for r in one] == [r.tokens for r in every]
+    assert [r.logits is None for r in one] == [True, False, True, True]
+    np.testing.assert_array_equal(np.stack(one[1].logits),
+                                  np.stack(every[1].logits))
+    assert all(int(row.argmax()) == t
+               for row, t in zip(one[1].logits, one[1].tokens))
+    new = LENGTHS[1][1]
+    assert st["logit_rows_fetched"] == new
+    assert st["rows_sampled_on_device"] == st["tokens"]
+    assert st["fetched_bytes"] == 4 * 4 * st["chunk_dispatches"] \
+        + 4 * cfg["vocab_size"] * new
+
+
 def test_the_shares_of_the_experts_add_up_to_the_uncut_layer():
     """(b) Four chips of two experts each: the parts their
     `moe_share_ffn` give, plus the shared expert counted once, are the

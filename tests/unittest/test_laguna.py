@@ -104,6 +104,32 @@ def test_served_logits_agree_with_the_reference(monkeypatch, kernels):
     srv.stop()
 
 
+def test_only_an_audited_request_brings_logits_to_the_host():
+    """Greedy requests of which one is audited emit what they emit when
+    all are (`served`), several passes a step included, and only the
+    audited request's rows are copied to the host."""
+    model, _ = tiny()
+    srv, every = served(model)
+    srv.stop()
+    srv = server(model)
+    rng = np.random.RandomState(0)
+    one = [srv.submit(rng.randint(0, 96, (n,)), max_new_tokens=m,
+                      keep_logits=i == 3) for i, (n, m) in enumerate(LENGTHS)]
+    srv.drain()
+    st = srv.stats()
+    srv.stop()
+    assert [r.tokens for r in one] == [r.tokens for r in every]
+    assert [r.logits is not None for r in one] == [i == 3 for i in range(5)]
+    np.testing.assert_array_equal(np.stack(one[3].logits),
+                                  np.stack(every[3].logits))
+    assert all(int(row.argmax()) == t
+               for row, t in zip(one[3].logits, one[3].tokens))
+    new = LENGTHS[3][1]
+    assert st["logit_rows_fetched"] == new
+    assert st["rows_sampled_on_device"] == st["tokens"]
+    assert st["fetched_bytes"] == 4 * 4 * st["chunk_dispatches"] + 4 * 96 * new
+
+
 def test_reference_controls_fail_the_served_logits():
     """The comparison is no invariant check: the reference with the window
     ignored, with the queries grouped wrongly (h % Hkv), or with the full
@@ -408,7 +434,8 @@ def test_a_drafter_beside_window_classes_is_refused():
 
 def test_a_one_class_model_keeps_its_pool_tables_and_stats():
     """GPT through the same server: one allocator (the pool itself), 2-D
-    page tables, and the `stats()` keys it had before page classes."""
+    page tables, and the `stats()` keys it had before page classes (and
+    the three of the fetch, which every model has)."""
     parallel.make_mesh(devices=jax.devices()[:1])
     model = gpt_mod.GPTForCausalLM(gpt_mod.gpt_tiny_config())
     mx.random.seed(0)
@@ -429,6 +456,7 @@ def test_a_one_class_model_keeps_its_pool_tables_and_stats():
         "retries", "prompt_tokens", "prefix_tokens", "prefix_hits",
         "chunk_dispatches", "chunk_steps", "token_steps", "spec_rounds",
         "rows_dispatched", "rows_fed", "drafts_proposed", "drafts_accepted",
+        "rows_sampled_on_device", "logit_rows_fetched", "fetched_bytes",
         "attn_tokens", "attn_ctx_tokens", "attn_sel_tokens", "sparse_tokens",
         "queued", "running", "buckets_allocated", "executables",
         "width_dispatches", "scheduler_steps", "pages", "page_size",

@@ -130,6 +130,143 @@ def test_temperature_sampling_deterministic_per_request(model):
     assert all(o.state == serve.DONE for o in others)
 
 
+# -- where a token is chosen: ids from the device, logits rows on request -----
+
+_MIXED = [
+    dict(max_new_tokens=7),
+    dict(max_new_tokens=6, temperature=0.8, top_k=5, seed=42),
+    dict(max_new_tokens=8, keep_logits=True),
+    dict(max_new_tokens=5, temperature=1.3, seed=7, keep_logits=True),
+    dict(max_new_tokens=9),
+]
+
+
+def test_mixed_batch_emits_what_each_request_emits_alone(model):
+    """Greedy rows take the ids the executable chose, sampling and
+    `keep_logits` rows get their logits row: in one batch, token for token
+    and row for row what each request gets served alone."""
+    prompts = [_prompt(4 + 3 * i, seed=30 + i) for i in range(len(_MIXED))]
+    alone = []
+    for p, kw in zip(prompts, _MIXED):
+        solo = serve.Server(model, slots=3, prefill_chunk=4)
+        alone.append(solo.submit(p, **kw))
+        solo.drain()
+    srv = serve.Server(model, slots=3, prefill_chunk=4)
+    reqs = [srv.submit(p, **kw) for p, kw in zip(prompts, _MIXED)]
+    srv.drain()
+    st = srv.stats()
+    for r, a, kw in zip(reqs, alone, _MIXED):
+        assert r.state == a.state == serve.DONE
+        assert r.tokens == a.tokens and len(r.tokens) == kw["max_new_tokens"]
+        if not kw.get("keep_logits"):
+            assert r.logits is None
+            continue
+        assert len(r.logits) == len(r.tokens)
+        for row, solo_row, tok in zip(r.logits, a.logits, r.tokens):
+            assert row.shape == (_VOCAB,) and row.dtype == np.float32
+            np.testing.assert_array_equal(row, solo_row)
+            if not kw.get("temperature"):
+                assert int(np.argmax(row)) == tok    # the row argmax read
+    greedy = sum(kw["max_new_tokens"] for kw in _MIXED
+                 if not kw.get("temperature"))
+    assert st["rows_sampled_on_device"] == greedy
+    assert st["tokens"] == sum(kw["max_new_tokens"] for kw in _MIXED)
+    # every sampled or kept token brought its row, and no other row came
+    assert st["logit_rows_fetched"] == st["tokens"] - 7 - 9
+    assert st["fetched_bytes"] == 4 * 3 * st["chunk_dispatches"] \
+        + 4 * _VOCAB * st["logit_rows_fetched"]
+
+
+@pytest.mark.parametrize("special", [None, "keep_logits", "temperature"])
+def test_a_pass_fetches_ids_and_only_the_rows_asked_for(model, special):
+    """A pass of greedy rows copies 4 bytes a row of the pass and not one
+    logit; a request that keeps its logits (or samples) adds its own row,
+    in the passes it emits in, and no other."""
+    slots = 4
+    srv = serve.Server(model, slots=slots, prefill_chunk=4)
+    for i in range(3):
+        srv.submit(_prompt(5 + i, seed=i), max_new_tokens=6)
+    odd = None
+    if special:
+        kw = {"keep_logits": True} if special == "keep_logits" \
+            else {"temperature": 0.7, "seed": 1}
+        odd = srv.submit(_prompt(9, seed=9), max_new_tokens=4, **kw)
+    before = srv.stats()
+    while srv.busy():
+        emitted = len(odd.tokens) if odd else 0
+        srv.step()
+        st = srv.stats()
+        passes = st["chunk_dispatches"] - before["chunk_dispatches"]
+        rows = len(odd.tokens) - emitted if odd else 0
+        assert passes >= 1 and rows in (0, 1)
+        assert st["logit_rows_fetched"] - before["logit_rows_fetched"] == rows
+        assert st["fetched_bytes"] - before["fetched_bytes"] \
+            == 4 * slots * passes + 4 * _VOCAB * rows
+        before = st
+    assert st["logit_rows_fetched"] == (4 if odd else 0)
+    assert st["rows_sampled_on_device"] \
+        == st["tokens"] - (4 if special == "temperature" else 0)
+
+
+def test_a_tie_in_the_logits_emits_the_lowest_id(model):
+    """`jnp.argmax` in the executable and `np.argmax` on the host both take
+    the first index on a tie, so the move changes no token."""
+    import jax.numpy as jnp
+    from mxnet_tpu.ndarray import NDArray
+    srv = serve.Server(model, slots=2)
+    real = srv._spec.chunk_step
+
+    def tied(*args, **kw):
+        lg, new = real(*args, **kw)
+        d = lg._data
+        top = jnp.broadcast_to(d.max(-1, keepdims=True) + 1.0,
+                               (d.shape[0], 3))
+        return NDArray(d.at[:, jnp.array([9, 4, 100])].set(top)), new
+
+    srv._spec = srv._spec._replace(chunk_step=tied)
+    plain = srv.submit(_prompt(5), max_new_tokens=5)
+    kept = srv.submit(_prompt(6, seed=1), max_new_tokens=5, keep_logits=True)
+    srv.drain()
+    assert plain.tokens == kept.tokens == [4] * 5
+    for row in kept.logits:
+        assert row[4] == row[9] == row[100] == row.max()
+        assert int(np.argmax(row)) == 4
+
+
+@pytest.mark.parametrize("kept", [False, True], ids=["plain", "kept"])
+def test_spec_round_takes_its_tokens_from_the_ids_of_the_verify_pass(
+        model, kept):
+    """Accepted and bonus tokens are plain greedy decode's, read from the
+    verify pass's ids: no logits row crosses unless a request keeps them."""
+    slots, k = 2, 3
+    prompts = [_prompt(6, seed=41), _prompt(9, seed=42)]
+    ref = [model.generate(p[None], max_new_tokens=10,
+                          on_device=False)[0].tolist() for p in prompts]
+    srv = serve.Server(model, slots=slots, page_size=4, prefill_chunk=4,
+                       drafter=model, spec_k=k)
+    reqs = [srv.submit(p, max_new_tokens=10, keep_logits=kept and i == 0)
+            for i, p in enumerate(prompts)]
+    srv.drain()
+    st = srv.stats()
+    srv.stop()
+    assert [r.tokens for r in reqs] == ref
+    assert st["spec_rounds"] > 0 and st["drafts_accepted"] > 0
+    assert st["rows_sampled_on_device"] == st["tokens"] == 20
+    ids = 4 * slots * st["chunk_dispatches"] \
+        + 4 * slots * (k + 1) * 2 * st["spec_rounds"]   # drafts, verify ids
+    if not kept:
+        assert st["logit_rows_fetched"] == 0
+        assert st["fetched_bytes"] == ids
+        return
+    assert reqs[1].logits is None and len(reqs[0].logits) == 10
+    assert all(int(np.argmax(row)) == t
+               for row, t in zip(reqs[0].logits, reqs[0].tokens))
+    # a verify pass brings the kept request's k + 1 rows: which of them
+    # it emits is known only once the ids are here
+    assert 10 <= st["logit_rows_fetched"] <= 10 + k * st["spec_rounds"]
+    assert st["fetched_bytes"] == ids + 4 * _VOCAB * st["logit_rows_fetched"]
+
+
 def test_streaming_tokens_arrive_incrementally(model):
     srv = serve.Server(model, slots=2)
     r = srv.submit(_prompt(4), max_new_tokens=6)
@@ -610,9 +747,12 @@ def test_trace_report_serve_verdicts():
     decode_bound = {0: {"by_cat": {"serve": 300e3},
                         "by_span": {"serve.queue_wait": 40e3,
                                     "serve.decode_step": 260e3},
-                        "steps": []}}
-    kind, rank, dom, _detail = tr._verdict(decode_bound, [])
+                        "steps": [], "rows": [128, 96],
+                        "fetched": [1536, 2, 2]}}
+    kind, rank, dom, detail = tr._verdict(decode_bound, [])
     assert (kind, rank, dom) == ("decode-bound", 0, "serve.decode_step")
+    assert "passes 75.0% full" in detail
+    assert "fetched 768 bytes a step (2 logits rows in 2 steps)" in detail
     # a TRAINING window with step spans keeps its old verdicts even if a
     # serve span leaked into it
     train = {0: {"by_cat": {"step": 100e3, "serve": 10e3},

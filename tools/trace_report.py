@@ -193,12 +193,14 @@ def _window_stats(ranks, offsets, lo_us, hi_us):
     """Per-rank span-time aggregation restricted to [lo_us, hi_us) on the
     shared axis: {"by_cat": {cat: us}, "by_span": {name: us}, "steps":
     [step dur_us], "rows": [virtual rows, tokens fed] of the window's
-    serving passes} per rank."""
+    serving passes, "fetched": [bytes, logits rows, scheduler steps] that
+    those passes copied to the host} per rank."""
     stats = {}
     for rank, (_meta, spans, _skews) in ranks.items():
         off = offsets[rank]
         by_cat, by_span, step_us = {}, {}, {}
         rows = [0, 0]
+        fetched = [0, 0, 0]
         for s in spans:
             ts = off + float(s.get("ts_us", 0.0))
             if not (lo_us <= ts < hi_us):
@@ -213,8 +215,14 @@ def _window_stats(ranks, offsets, lo_us, hi_us):
             if name == "serve.decode_step" and "width" in s:
                 rows[0] += int(s["width"])
                 rows[1] += int(s.get("fed", 0))
+            if name == "serve.fetch" and "bytes" in s:
+                fetched[0] += int(s["bytes"])
+                fetched[1] += int(s.get("rows", 0))
+            if name == "serve.step":
+                fetched[2] += 1
         stats[rank] = {"by_cat": by_cat, "by_span": by_span,
-                       "steps": sorted(step_us.values()), "rows": rows}
+                       "steps": sorted(step_us.values()), "rows": rows,
+                       "fetched": fetched}
     return stats
 
 
@@ -242,6 +250,13 @@ def _verdict(stats, skews_in_window):
         width, fed = stats[rank].get("rows", (0, 0))
         fill = f"; passes {fed / width:.1%} full ({fed} tokens in " \
             f"{width} virtual rows)" if width else ""
+        # what the passes copied to the host (`bytes`/`rows` on
+        # serve.fetch): token ids, and logits rows only where a request
+        # samples or keeps them
+        nbytes, lrows, steps = stats[rank].get("fetched", (0, 0, 0))
+        if nbytes:
+            fill += f"; fetched {nbytes / max(steps, 1):,.0f} bytes a " \
+                f"step ({lrows} logits rows in {steps} steps)"
         if frac > 0.5:
             return ("queue-bound", rank, "serve.queue_wait",
                     f"{frac:.1%} of request time waiting for admission "
