@@ -374,6 +374,22 @@ def serve_one_chip():
         require(req.state == serve.DONE and st["window_pages_freed"] > 0,
                 f"a request through both page classes: {req!r}, {st}")
         srv.stop()
+        # one request through a latent cache with no indexer (every row
+        # attends its whole context through paged_latent_attention)
+        from mxnet_tpu.models import deepseek
+        model = deepseek.DeepseekForCausalLM(deepseek.deepseek_tiny_config())
+        model.initialize()
+        srv = serve.Server(model, slots=2, page_size=4, buckets=[64],
+                           pool_pages=32, prefill_chunk=4)
+        req = srv.submit(rng.randint(0, 96, (30,)), max_new_tokens=6)
+        srv.drain()
+        st = srv.stats()
+        say(f"DeepSeek-V2 3L tiny, 16 experts in 8 groups: {req!r}; rows "
+            f"fed {st['attn_tokens']}, keys they saw {st['attn_ctx_tokens']}")
+        require(req.state == serve.DONE
+                and st["attn_sel_tokens"] == st["attn_ctx_tokens"],
+                f"a request through the latent cache: {req!r}, {st}")
+        srv.stop()
     del srv, model
     gc.collect()
     phase_end("serve_one_chip", start)

@@ -118,20 +118,27 @@ def layer_norm(x, gamma, beta, eps):
             + beta.astype(jnp.float32)).astype(x.dtype)
 
 
-def rope_interleaved(x, pos, theta):
+def rope_pairs(x, pos, inv_freq):
     """Rotary embedding over the last dimension of x (..., d), pairs
-    (x[2i], x[2i+1]) turned by pos * theta^(-2i/d). x (B, ..., d) with
-    pos (B,) broadcast over the middle dimensions."""
+    (x[2i], x[2i+1]) turned by pos * inv_freq[i]. x (B, ..., d) with
+    pos (B,) broadcast over the middle dimensions; inv_freq (d/2,)."""
     import jax.numpy as jnp
     d = x.shape[-1]
-    inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
-    ang = pos.astype(jnp.float32)[:, None] * inv[None, :]       # (B, d/2)
+    ang = pos.astype(jnp.float32)[:, None] * inv_freq[None, :]  # (B, d/2)
     ang = ang.reshape((x.shape[0],) + (1,) * (x.ndim - 2) + (d // 2,))
     cos, sin = jnp.cos(ang), jnp.sin(ang)
     pair = x.astype(jnp.float32).reshape(x.shape[:-1] + (d // 2, 2))
     a, b = pair[..., 0], pair[..., 1]
     out = jnp.stack([a * cos - b * sin, a * sin + b * cos], -1)
     return out.reshape(x.shape).astype(x.dtype)
+
+
+def rope_interleaved(x, pos, theta):
+    """`rope_pairs` at the default frequencies theta^(-2i/d)."""
+    import jax.numpy as jnp
+    d = x.shape[-1]
+    return rope_pairs(
+        x, pos, theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d))
 
 
 def rope_first(x, n, pos, theta):

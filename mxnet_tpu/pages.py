@@ -58,6 +58,7 @@ from __future__ import annotations
 
 import collections
 import hashlib
+import heapq
 import itertools
 
 import numpy as np
@@ -333,14 +334,22 @@ class PrefixTree:
         nodes evicted (a node whose page is still shared by a running
         request is evicted from the TREE but only returns to the pool
         when that request drains)."""
+        if self.pool.free_pages() >= need_free:
+            return 0
+        # one pass over the nodes, then a heap: a parent joins it when its
+        # last child goes (a tree of thousands of pages gives back a
+        # document's hundreds in one admission)
+        heap = [(n.last_used, n.digest) for n in self.nodes.values()
+                if not n.children]
+        heapq.heapify(heap)
         evicted = 0
-        while self.pool.free_pages() < need_free:
-            leaves = [n for n in self.nodes.values() if not n.children]
-            if not leaves:
-                break
-            victim = min(leaves, key=lambda n: n.last_used)
+        while heap and self.pool.free_pages() < need_free:
+            victim = self.nodes[heapq.heappop(heap)[1]]
+            parent = self.nodes.get(victim.parent)
             self._drop(victim)
             evicted += 1
+            if parent is not None and not parent.children:
+                heapq.heappush(heap, (parent.last_used, parent.digest))
         return evicted
 
     def clear(self):

@@ -18,6 +18,11 @@ generic lowering loses (the TVM/Relay argument, PAPERS.md 1802.04799):
                             an mx.pages block table (the paged serve
                             path), scalar-prefetch indexed so the dense
                             gathered operand never hits HBM
+  * `paged_latent_attention` — the same walk over a LATENT cache (one
+                            row a token with no head axis, key and value
+                            of every head: multi-head latent attention in
+                            absorbed form), a wave of pages brought once
+                            and reduced by two matrix products
   * `kv_page_write`       — that step's write of the new keys and values
                             into the page arenas, in place and in the
                             layout `paged_attention` reads (and
@@ -47,9 +52,12 @@ from .flash_attention import flash_attention, mha_reference
 from .int8_matmul import int8_matmul, int8_matmul_reference
 from .kv_page_write import kv_page_write, kv_page_write_reference
 from .paged_attention import paged_attention, paged_attention_reference
+from .paged_latent_attention import (paged_latent_attention,
+                                     paged_latent_attention_reference)
 
 __all__ = ["flash_attention", "mha_reference", "int8_matmul",
            "int8_matmul_reference", "paged_attention",
-           "paged_attention_reference", "kv_page_write",
+           "paged_attention_reference", "paged_latent_attention",
+           "paged_latent_attention_reference", "kv_page_write",
            "kv_page_write_reference", "fused_update", "moe_kernels",
            "_common"]

@@ -17,7 +17,8 @@ Layout contract (inside shard_map over `ep`, n = axis size):
 
 Beside it, the share-aware top-k layer that today's sparse models use
 (`moe_topk_route` + `moe_share_ffn`): sigmoid scores with a selection
-bias, k experts a token, no capacity and no drops, and a device that is
+bias, or softmax scores with the choice limited to the best groups of
+experts, k experts a token, no capacity and no drops, and a device that is
 told WHICH experts it holds and computes their part of the result alone.
 On one chip it runs as it is; under an `ep` axis it is the same function
 inside the exchange (tokens gathered in, parts summed out), with
@@ -175,20 +176,43 @@ def moe_apply(x, router_w, w1, w2, mesh=None, axis_name="ep",
 # ---------------------------------------------------------------------------
 
 def moe_topk_route(x, router_w, select_bias, top_k, scale=1.0,
-                   norm_topk=True):
-    """Sigmoid top-k routing over ALL experts (`noaux_tc`, one group):
-    scores = sigmoid(x @ router_w) in float32; the `top_k` experts of
-    largest score + select_bias are chosen (the bias steers the choice
+                   norm_topk=True, scoring="sigmoid", n_group=1,
+                   topk_group=1):
+    """Top-k routing over ALL experts, nothing dropped: scores =
+    `scoring`(x @ router_w) in float32, "sigmoid" (`noaux_tc`) or
+    "softmax" over the experts; the `top_k` experts of largest score +
+    select_bias are chosen (the bias, None for none, steers the choice
     only); the gates are the chosen experts' scores, normalised over all
     `top_k` chosen when `norm_topk` and multiplied by `scale`.
 
-    x (N, D); router_w (D, E); select_bias (E,). Returns (expert (N, k)
-    int32, gate (N, k) float32). Nothing is dropped: every token keeps
-    its k experts whatever the load."""
-    scores = jax.nn.sigmoid(jnp.matmul(
+    `n_group` > 1 limits the choice by group (`group_limited_greedy`):
+    the experts lie in `n_group` groups of equal size (a device's experts
+    in an expert-parallel deployment), a group's score is its largest
+    expert score, and only experts of the `topk_group` best groups can be
+    chosen, so a token is sent to at most `topk_group` devices.
+
+    x (N, D); router_w (D, E); select_bias (E,) or None. Returns (expert
+    (N, k) int32, gate (N, k) float32). Every token keeps its k experts
+    whatever the load."""
+    logits = jnp.matmul(
         x.astype(jnp.float32), router_w.astype(jnp.float32),
-        precision=lax.Precision.HIGHEST))                       # (N, E)
-    _, expert = lax.top_k(scores + select_bias.astype(jnp.float32), top_k)
+        precision=lax.Precision.HIGHEST)                        # (N, E)
+    if scoring == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+    elif scoring == "softmax":
+        scores = jax.nn.softmax(logits, axis=-1)
+    else:
+        raise ValueError(f"scoring {scoring!r}: sigmoid or softmax")
+    choice = scores if select_bias is None \
+        else scores + select_bias.astype(jnp.float32)
+    if n_group > 1:
+        n, e = choice.shape
+        by_group = choice.reshape(n, n_group, e // n_group).max(-1)
+        _, best = lax.top_k(by_group, topk_group)               # (N, g)
+        kept = jax.nn.one_hot(best, n_group, dtype=jnp.bool_).any(1)
+        choice = jnp.where(jnp.repeat(kept, e // n_group, axis=1),
+                           choice, -jnp.inf)
+    _, expert = lax.top_k(choice, top_k)
     gate = jnp.take_along_axis(scores, expert, axis=-1)
     if norm_topk:
         gate = gate / gate.sum(-1, keepdims=True)

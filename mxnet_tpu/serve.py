@@ -808,6 +808,7 @@ class Server:
             out["pool_pages_total"] = self._pool.data_pages
             out["pool_pages_free"] = self._pool.free_pages()
             out["tree_nodes"] = len(self._tree.nodes)
+            out["tree_evicted_pages"] = self._tree.stats["evicted_pages"]
             out["cow_copies"] = self._pool.stats["cow_copies"]
             if self._windows:
                 out["pages_in_use"] = {

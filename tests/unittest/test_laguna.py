@@ -460,8 +460,8 @@ def test_a_one_class_model_keeps_its_pool_tables_and_stats():
         "attn_tokens", "attn_ctx_tokens", "attn_sel_tokens", "sparse_tokens",
         "queued", "running", "buckets_allocated", "executables",
         "width_dispatches", "scheduler_steps", "pages", "page_size",
-        "pool_pages_total", "pool_pages_free", "tree_nodes", "cow_copies",
-        "prefix_hit_rate", "accepted_draft_rate", "dispatches"}
+        "pool_pages_total", "pool_pages_free", "tree_nodes",
+        "tree_evicted_pages", "cow_copies", "prefix_hit_rate", "accepted_draft_rate", "dispatches"}
     srv.stop()
 
 

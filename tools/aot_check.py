@@ -12,7 +12,7 @@ HBM); what it cannot catch is anything that only shows when the code RUNS
 — numerics, hangs, real memory — and that is the chip's word.
 
     JAX_PLATFORMS=cpu python tools/aot_check.py            # everything
-    JAX_PLATFORMS=cpu python tools/aot_check.py kernels    # or: train, serve, serve_glm, serve_laguna
+    JAX_PLATFORMS=cpu python tools/aot_check.py kernels    # or: train, serve, serve_glm, serve_laguna, serve_deepseek
 
 Arguments are `jax.ShapeDtypeStruct`s whose shardings name the topology's
 devices; the two places the package asks JAX what it runs on (the kernel
@@ -124,6 +124,15 @@ def check_kernels(devices):
                  sds((W,), i32, s1), sds((W,), i32, s1),
                  sds((W, H, 1, D), bf, s1), sds((W, n_pg), i32, s1),
                  sds((W,), i32, s1))
+
+    # the latent cache's kernel at the DeepSeek-V2 cell's shapes: 128 heads
+    # over rows of 576 in 640 lanes, a bucket of 17,408 (272 pages a row)
+    for W in (32, 64):
+        compile_(f"paged_latent_attention W{W} H128 576->640 page64 n_pg272",
+                 lambda q, lat, tb, t: pallas_ops.paged_latent_attention(
+                     q, lat, tb, t, 0.1147, 512),
+                 sds((W, 128, 576), bf, s1), sds((4128, 64, 640), bf, s1),
+                 sds((W, 272), i32, s1), sds((W,), i32, s1))
 
     # fused LAMB at BERT-base's flat size (110M f32), fused Adam f32 + bf16
     from mxnet_tpu.parallel.fused_lamb import FusedLamb
@@ -276,6 +285,21 @@ def check_serve(devices):
     srv.stop()
 
 
+def server_of_shapes(model, server_args, s1):
+    """`serve.Server(model, **server_args)` with the model's parameters and
+    the pool's arenas as SHAPES: nothing is drawn or placed, `lower` takes
+    them as they are."""
+    from mxnet_tpu.ndarray import NDArray
+    for _, p in model._iter_params():
+        p._data = NDArray(sds(p.shape, jnp.dtype(p.dtype), s1))
+    real_zeros = jnp.zeros
+    jnp.zeros = lambda shape, dtype: sds(shape, jnp.dtype(dtype), s1)
+    try:
+        return serve.Server(model, **server_args)
+    finally:
+        jnp.zeros = real_zeros
+
+
 def check_serve_glm(devices):
     """The benchmark's GLM-5 cell (chipbench/configs/glm-5-serve-ep16.json:
     4.7 B parameters in bf16, 32 slots, one bucket of 6,272 over a pool of
@@ -287,7 +311,6 @@ def check_serve_glm(devices):
     output, no `copy` of an arena-shaped operand."""
     from chipbench.kinds import serve_agent
     from mxnet_tpu.models import glm
-    from mxnet_tpu.ndarray import NDArray
 
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(root, "chipbench", "configs",
@@ -297,15 +320,8 @@ def check_serve_glm(devices):
     mesh = parallel.make_mesh(devices=devices[:1])
     s1 = NamedSharding(mesh, P())
     model = glm.GLMForCausalLM(cfg)
-    for _, p in model._iter_params():
-        p._data = NDArray(sds(p.shape, jnp.dtype(p.dtype), s1))
+    srv = server_of_shapes(model, cell["server"], s1)
     bucket = cell["server"]["buckets"][0]
-    real_zeros = jnp.zeros
-    jnp.zeros = lambda shape, dtype: sds(shape, jnp.dtype(dtype), s1)
-    try:                # the pool's arenas, as shapes
-        srv = serve.Server(model, **cell["server"])
-    finally:
-        jnp.zeros = real_zeros
     for width in (srv._wide(), srv._slots):
         run, avals = srv._runner(bucket, width), \
             srv._step_avals(bucket, width)
@@ -346,7 +362,6 @@ def check_serve_laguna(devices):
     chip."""
     from chipbench.kinds import serve_mixed
     from mxnet_tpu.models import laguna
-    from mxnet_tpu.ndarray import NDArray
 
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(root, "chipbench", "configs",
@@ -356,15 +371,8 @@ def check_serve_laguna(devices):
     mesh = parallel.make_mesh(devices=devices[:1])
     s1 = NamedSharding(mesh, P())
     model = laguna.LagunaForCausalLM(cfg)
-    for _, p in model._iter_params():
-        p._data = NDArray(sds(p.shape, jnp.dtype(p.dtype), s1))
+    srv = server_of_shapes(model, cell["server"], s1)
     bucket = cell["server"]["buckets"][0]
-    real_zeros = jnp.zeros
-    jnp.zeros = lambda shape, dtype: sds(shape, jnp.dtype(dtype), s1)
-    try:                # the pool's arenas, as shapes
-        srv = serve.Server(model, **cell["server"])
-    finally:
-        jnp.zeros = real_zeros
     n_l = cfg["num_hidden_layers"]
     for width in (srv._wide(), srv._slots):
         run, avals = srv._runner(bucket, width), \
@@ -396,9 +404,68 @@ def check_serve_laguna(devices):
     srv.stop()
 
 
+def check_serve_deepseek(devices):
+    """The benchmark's DeepSeek-V2 cell (chipbench/configs/
+    deepseek-v2-serve-ep8.json: 3.81 B parameters in bf16, 32 slots, one
+    bucket of 17,408 over a pool of 4,128 pages of 64): the wide pass (64
+    virtual rows) and the `slots`-wide one as the server builds them, from
+    abstract parameters and arenas. Each must hold the paged
+    latent-attention kernel once a layer and NO gather of the bucket (a
+    latent row has no head axis: `paged_attention` cannot read it, and the
+    fallback would gather 17,408 rows for every virtual row), keep its six
+    latent arenas where they are (aliased, no arena-shaped copy) and fit
+    the chip."""
+    from chipbench.kinds import serve_docs
+    from mxnet_tpu.models import deepseek
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "chipbench", "configs",
+                           "deepseek-v2-serve-ep8.json")) as f:
+        cell = json.load(f)
+    cfg = serve_docs.model_config(cell)
+    mesh = parallel.make_mesh(devices=devices[:1])
+    s1 = NamedSharding(mesh, P())
+    model = deepseek.DeepseekForCausalLM(cfg)
+    srv = server_of_shapes(model, cell["server"], s1)
+    bucket = cell["server"]["buckets"][0]
+    n_l = cfg["num_hidden_layers"]
+    for width in (srv._wide(), srv._slots):
+        run, avals = srv._runner(bucket, width), \
+            srv._step_avals(bucket, width)
+        arenas = avals[-1]
+        avals = jax.tree.map(lambda a: sds(a.shape, a.dtype, s1), avals)
+        t0 = time.perf_counter()
+        lowered = run.lower(*avals)
+        found = pallas_kernels(lowered)
+        compiled = lowered.compile()
+        mem = compiled.memory_analysis()
+        text = compiled.as_text()
+        copies, aliased = arena_copies_and_aliases(text, arenas)
+        # the fallback's gather: (width, bucket, 640) rows of the arena
+        gathered = re.findall(r"\[%d,%d,\d+\]" % (width, bucket), text)
+        total = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+        print(f"  compiles: DeepSeek-V2 share "
+              f"({deepseek.param_count(cfg) / 1e9:.3f} B parameters) paged "
+              f"step, bucket {bucket} width {width}  "
+              f"[{time.perf_counter() - t0:.1f}s]  kernels={found}  "
+              f"arena copies: {len(copies)}  aliased parameters: "
+              f"{len(aliased)}  bucket-wide gathers: {len(gathered)}  temp="
+              f"{mem.temp_size_in_bytes / 2**30:.2f} GiB args="
+              f"{mem.argument_size_in_bytes / 2**30:.2f} GiB", flush=True)
+        want = {"paged_latent_attention": n_l}
+        assert found == want, f"expected {want} in the step, got {found}"
+        assert not gathered, f"a gather of the bucket: {gathered[0]}"
+        assert not copies, f"arena-shaped copies left: {copies[0]}"
+        assert len(aliased) == len(arenas) == n_l, \
+            f"{len(aliased)} of {len(arenas)} arenas aliased to outputs"
+        assert total < 14 * 2**30, \
+            f"{total / 2**30:.2f} GiB leaves no room on a 16 GB chip"
+    srv.stop()
+
+
 def main():
     which = sys.argv[1:] or ["kernels", "train", "serve", "serve_glm",
-                             "serve_laguna"]
+                             "serve_laguna", "serve_deepseek"]
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name=TOPOLOGY)
     devices = list(topo.devices)
@@ -414,7 +481,8 @@ def main():
         print(f"== {name} ==")
         {"kernels": check_kernels, "train": check_train,
          "serve": check_serve, "serve_glm": check_serve_glm,
-         "serve_laguna": check_serve_laguna}[name](devices)
+         "serve_laguna": check_serve_laguna,
+         "serve_deepseek": check_serve_deepseek}[name](devices)
     print("aot_check: everything compiled for", devices[0].device_kind)
 
 
