@@ -321,8 +321,8 @@ def serve_one_chip():
     for bucket in buckets:
         lowered = srv.lower_step(bucket)
         found = tpu_validate.pallas_kernels(lowered)
-        say(f"bucket {bucket}: Pallas kernels in the lowered wide step "
-            f"({srv._wide()} virtual rows): {found or 'none'}")
+        say(f"bucket {bucket}: Pallas kernels in the lowered step of the "
+            f"top rung ({srv._wide()} virtual rows): {found or 'none'}")
         if not REHEARSAL:
             # once through the layers: each kernel once a layer, in no loop
             for kernel in ("paged_attention", "kv_page_write"):
@@ -356,8 +356,10 @@ def serve_one_chip():
         f"{st['width_dispatches']}, {st['rows_fed']} tokens fed in "
         f"{st['rows_dispatched']} virtual rows; executables "
         f"{st['executables']}")
-    require(st["chunk_steps"] > 0 and st["token_steps"] > 0,
-            f"both widths should have run: {st['width_dispatches']}")
+    require(st["chunk_steps"] > 0 and st["token_steps"] > 0
+            and set(st["width_dispatches"]) <= set(srv._rungs),
+            f"the `slots` rung and a wider one of {srv._rungs} should have "
+            f"run: {st['width_dispatches']}")
     srv.stop()
     if REHEARSAL:
         # one request through two page classes (window layers beside full)
@@ -423,9 +425,10 @@ def kernels():
         # GPT-2 345M's decode shapes: 16 heads of 64, page 16, a 512 bucket
         tpu_validate.paged_parity(B=8, H=16, D=64, page_size=16, n_pg=32)
         # the benchmark's serving cell: 32 slots over a pool of 2,080
-        # pages; then its wide pass, 64 virtual rows
+        # pages; then its passes of 64 and of 128 virtual rows
         tpu_validate.kv_write_parity()
         tpu_validate.kv_write_parity(B=64)
+        tpu_validate.kv_write_parity(B=128)
         # BERT-base's own parameter layout (110M)
         cfg = bert_case()[0]
         model = bert_mod.BERTForPretraining(cfg)

@@ -710,10 +710,13 @@ register_option(
     "request cap, not the width of a step). A step is one pass over its "
     "tokens: a token of every decoding request and up to this many "
     "prompt tokens of every request still inside its prompt, in "
-    "admission order. The width of a pass is derived, not set: `slots` "
-    "rows when the tokens fit, else 2 x slots — two executables a "
-    "bucket; a burst with more prompt tokens than that takes further "
-    "passes in the same step.")
+    "admission order. The width of a pass is derived, not set: the "
+    "narrowest rung that holds its tokens of a ladder `slots`, 2 x slots "
+    "and doublings while a rung stays within 256 rows (where a pass stops "
+    "being bound by its weights on a TPU v5e) and under slots x this — "
+    "one executable a rung and bucket (32 slots: 32, 64, 128 for chunks "
+    "of 8, and 256 for longer ones); a step with more tokens than the top "
+    "rung takes further passes.")
 register_option(
     "pages_spec_k", 4,
     "Draft tokens per speculative decoding round (a serve.Server "

@@ -64,7 +64,7 @@ __all__ = [
     "enable", "disable", "enabled", "maybe_enable", "reset",
     "MemoryBudgetError", "SimulatedResourceExhausted", "is_oom",
     "capacity_bytes", "resident_bytes", "compiled_exec_peak",
-    "aot_exec_peak", "preflight_step", "preflight_jit", "check_budget",
+    "preflight_step", "preflight_jit", "check_budget",
     "POLICIES", "LADDER", "validate_policy", "effective_policy",
     "jax_policy", "policy_marker", "block_wrap_policy",
     "recover_trainer", "note_eager_oom", "transitions", "last_check",
@@ -351,19 +351,6 @@ def _analyze(jitted, args, traced=None):
     except Exception as e:  # noqa: BLE001 — degrade, never block dispatch
         return None, None, f"{type(e).__name__}: {e}"
     return compiled_exec_peak(compiled), compiled, None
-
-
-def aot_exec_peak(jitted, args):
-    """Execution-peak bytes of `jitted` AOT lowered+compiled at `args`
-    (concrete arrays or jax.ShapeDtypeStructs) — the public spelling of
-    the analysis `_analyze` runs at every preflight, for callers that
-    budget BEFORE building state (mx.serve admission control sizes KV
-    caches this way; `ShardedTrainer.predict_step_bytes` is the training
-    twin). Nothing is dispatched; the real first call deserializes
-    the same executable warm from the persistent cache. None when the
-    backend withholds analysis — never raises."""
-    peak, _compiled, _err = _analyze(jitted, args)
-    return peak
 
 
 def _preflight(name, key, jitted, args, collectives=None, traced=None):

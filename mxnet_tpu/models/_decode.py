@@ -169,6 +169,50 @@ def paged_attention_step(q, k_new, v_new, k_pages, v_pages, tables, wp, wo,
     return apply_op(f, q, k_new, v_new, k_pages, v_pages, tables, wp, wo, t)
 
 
+def layer_call(layers, i, method, *args):
+    """`getattr(layers[i], method)(*args)` for a stack of layers built
+    alike, traced ONCE a shape for the whole stack: the call goes through
+    one `jax.jit` of layer 0's method with the layer's parameters as
+    arguments, so every layer after the first finds the trace made, and
+    the lowered module holds the layer once and a call a layer (XLA
+    inlines them: the executable is the one a Python loop over the layers
+    gives). A step of 24 layers traced and lowered layer by layer spends
+    most of a second of set-up there, for every executable of every
+    bucket (PERF.md section 6, PR 38). `args` and the returns are
+    NDArrays (tuples of them); the layers hold the same parameters by
+    name, and the method reads nothing else that differs between them.
+    Runs while a step is traced, never on a step."""
+    import jax
+
+    from ..ndarray import NDArray
+
+    proto = layers[0]
+    shared = proto.__dict__.setdefault("_layer_calls", {})
+    if method not in shared:
+        names = [name for name, _ in proto._iter_params()]
+        held = [p for _, p in proto._iter_params()]
+
+        def layer(pdata, *data):
+            saved = [p._data._data for p in held]
+            for p, d in zip(held, pdata):
+                p._data._data = d
+            try:
+                out = getattr(proto, method)(*[NDArray(d) for d in data])
+            finally:
+                for p, d in zip(held, saved):
+                    p._data._data = d
+            return tuple(o._data for o in out)
+
+        shared[method] = names, jax.jit(layer)
+    names, fn = shared[method]
+    mine = list(layers[i]._iter_params())
+    if [name for name, _ in mine] != names:
+        raise ValueError(
+            f"layer_call: layer {i} does not hold layer 0's parameters")
+    out = fn([p.data()._data for _, p in mine], *[a._data for a in args])
+    return tuple(NDArray(o) for o in out)
+
+
 def beam_search_loop(logits0, step, reorder, B, beam, eos, max_steps,
                      alpha=0.6, seqs0=None, lengths0=1):
     """Host-side beam bookkeeping shared by TransformerNMT.beam_search and
@@ -256,11 +300,13 @@ def jit_flat_step(model, step_fn, n_state, donate_state=0, label=None):
     Returns run(*leading_arrays, state_list) -> (primary, new_state) with
     everything jitted; `leading` are the per-call scalars/arrays before the
     flat state (token ids, step index, masks...). The runner also carries
-    `run.aot_exec_peak(*leading_avals, state_avals)` — AOT lower+compile
-    at those (shape, dtype)s purely for XLA memory analysis (mx.serve's
-    admission control budgets KV-cache growth with it; nothing is
-    dispatched and no batch transfers) — and `run.lower(...)`, the same
-    call stopped at the `jax.stages.Lowered` form.
+    `run.lower(*leading_avals, state_avals)`, the `jax.stages.Lowered`
+    form of a call at those (shape, dtype)s: nothing is dispatched and no
+    batch transfers. It goes through the jit the calls use, so what its
+    `compile()` builds is what a call at the same avals runs, and that
+    call builds nothing (mx.serve builds a bucket's executables ahead of
+    their first call so, and reads the admission budget's memory analysis
+    from them: `Server._build`).
 
     `label`: hand the executable of the first call to
     `mx.trace.note_executable` under this name (the jit and the call's
@@ -295,33 +341,38 @@ def jit_flat_step(model, step_fn, n_state, donate_state=0, label=None):
         return whole(*args)[0]
 
     rng = jax.random.key(0)
-    # donate_argnums are positional, so the jit is built per leading
-    # arity (fixed per call site in practice) on the first call
-    cache = {}
+    # donate_argnums are positional, so there is one jit per leading arity
+    # (fixed per call site in practice), made once: the calls and `lower`
+    # go through it, so an executable lowered and compiled ahead of time
+    # IS the one the call runs (jax keeps a jit's trace, lowering and
+    # executable by its avals; nothing is built twice)
+    jits = {}
+    called = set()      # leading arities whose first call has been made
+
+    def jit_of(n_leading):
+        """(the jit, its donate_argnums) for this leading arity."""
+        if n_leading not in jits:
+            base = 3 + n_leading    # gp_data, aux_data, rng come first
+            donate = tuple(range(base, base + int(donate_state)))
+            jits[n_leading] = jax.jit(pure, donate_argnums=donate), donate
+        return jits[n_leading]
 
     def run(*args):
         leading, state = args[:-1], list(args[-1])
         gp_data = [p.data()._data for _, p in gp]
         aux_data = [p.data()._data for _, p in aux]
-        base = 3 + len(leading)     # gp_data, aux_data, rng come first
-        donate = tuple(range(base, base + int(donate_state)))
-        entry = cache.get(len(leading))
-        is_miss = entry is None
-        if is_miss:
-            entry = cache[len(leading)] = jax.jit(
-                pure, donate_argnums=donate)
+        entry, donate = jit_of(len(leading))
+        is_miss = len(leading) not in called
         if is_miss and _check._enabled:
-            try:
-                _check.check_jit(
-                    f"decode_step({type(model).__name__})",
-                    (len(leading), n_state,
-                     tuple(tuple(getattr(s, "shape", ())) for s in state)),
-                    entry, (gp_data, aux_data, rng) + leading
-                    + tuple(state), donate_argnums=donate,
-                    can_donate=True)
-            except _check.CheckError:
-                cache.pop(len(leading), None)
-                raise
+            _check.check_jit(
+                f"decode_step({type(model).__name__})",
+                (len(leading), n_state,
+                 tuple(tuple(getattr(s, "shape", ())) for s in state)),
+                entry, (gp_data, aux_data, rng) + leading
+                + tuple(state), donate_argnums=donate,
+                can_donate=True)
+        if is_miss:
+            called.add(len(leading))    # after the lint: refused, it runs again
         if is_miss and label is not None:
             # before the call: the state buffers are donated to it
             _trace.note_executable(
@@ -338,40 +389,19 @@ def jit_flat_step(model, step_fn, n_state, donate_state=0, label=None):
             _trace.note_setup("compile_s", time.perf_counter() - t_compile)
         return outs[0], list(outs[1:])
 
-    def _aot(args):
-        """(fresh jit, full argument tuple) for a call with these
-        (shape, dtype) arguments — jax.ShapeDtypeStructs or arrays.
-        Nothing is installed into the call cache: the real first call
-        still runs the mx.check lint, and deserializes this same
-        executable warm from the persistent compile cache."""
+    def lower(*args):
+        """`jax.stages.Lowered` of a call with these (shape, dtype)
+        arguments: jax.ShapeDtypeStructs, or arrays, of which the avals
+        are kept with the sharding of those committed to a device
+        (`trace.avals_of`: what makes the avals the call's). No dispatch,
+        no transfer. Its `compile()` is the build the call with the same
+        avals then finds done; that call still runs the mx.check lint."""
         leading, state = args[:-1], list(args[-1])
         gp_data = [p.data()._data for _, p in gp]
         aux_data = [p.data()._data for _, p in aux]
-        base = 3 + len(leading)
-        donate = tuple(range(base, base + int(donate_state)))
-
-        def aval(x):
-            if isinstance(x, jax.ShapeDtypeStruct):
-                return x
-            return jax.ShapeDtypeStruct(tuple(x.shape), x.dtype)
-
-        return jax.jit(pure, donate_argnums=donate), \
-            (gp_data, aux_data, rng) + tuple(aval(a) for a in leading) \
-            + tuple(aval(s) for s in state)
-
-    def lower(*args):
-        """`jax.stages.Lowered` of a call with these arguments; no
-        dispatch, no transfer."""
-        jitted, full = _aot(args)
-        return jitted.lower(*full)
-
-    def aot_exec_peak(*args):
-        """Execution-peak bytes (beyond argument buffers) of a call with
-        these arguments — pure AOT analysis via mx.memsafe. None when the
-        backend withholds memory analysis."""
-        from .. import memsafe as _memsafe
-        return _memsafe.aot_exec_peak(*_aot(args))
+        return jit_of(len(leading))[0].lower(
+            gp_data, aux_data, rng,
+            *_trace.avals_of(tuple(leading) + tuple(state)))
 
     run.lower = lower
-    run.aot_exec_peak = aot_exec_peak
     return run

@@ -19,7 +19,8 @@ from ..gluon import nn, HybridBlock
 from ..gluon.parameter import Parameter
 from ..ndarray import NDArray
 from ..ndarray import ndarray as F
-from ._decode import ServingSpec, paged_write_targets, virtual_rows
+from ._decode import (ServingSpec, layer_call, paged_write_targets,
+                      virtual_rows)
 from .bert import BERTAttention, _positions, _scan_layers_call
 from .bert import tp_rules as _bert_tp_rules
 
@@ -316,8 +317,10 @@ class GPTForCausalLM(HybridBlock):
             NDArray(g.position_embed.data()._data), t)
         x = x + pos
         nk, nv = [], []
-        for i, layer in enumerate(g.layers):
-            x, k, v = layer.step_slots_paged(
+        layers = list(g.layers)
+        for i in range(len(layers)):
+            x, k, v = layer_call(
+                layers, i, "step_slots_paged",
                 x, NDArray(ks[i]), NDArray(vs[i]), NDArray(tb_d),
                 NDArray(wp_d), NDArray(wo_d), t)
             nk.append(k._data)

@@ -13,7 +13,7 @@ runtime:
     request sharing a block hold one reference each, and a page returns
     to the free list when the last reference drops. The pool is sized
     once at server construction and priced through mx.memsafe at
-    every admission (`Server._admit_budget` / `aot_exec_peak`).
+    every admission (`Server._admit_budget` / `_exec_peak`).
   * **PrefixTree** — a content-hashed radix tree over FULL prompt
     blocks (SGLang-style radix cache). A finished prefill inserts its
     full prompt pages; a later request walks its prompt block-by-block

@@ -111,8 +111,8 @@ def _kv_page_write_pallas(k_pages, v_pages, k_new, v_new, wp, wo):
 
 
 # A step executable calls this once a layer with the same shapes: under an
-# inlined jit the kernel is traced once a process (a trace is 46 ms, and a
-# server's two executables hold 48 calls) and every call site still gets a
+# inlined jit the kernel is traced once a width (a trace is 46 ms, and each
+# of a bucket's executables holds 24 calls) and every call site still gets a
 # `pallas_call` of its own; the interpreter flag, which the trace reads
 # besides shapes, is a static argument (as `paged_attention._paged_call`).
 @functools.partial(jax.jit, static_argnames=("interpret",), inline=True)

@@ -32,12 +32,17 @@ vLLM-style block table:
     and up to `pages_prefill_chunk` prompt tokens of every request
     still inside its prompt. The tokens are packed as VIRTUAL ROWS
     (token, position, the slot whose page-table row it goes through)
-    and go through the model's `chunk_step` ONCE: a pass is `slots`
-    rows wide when the tokens fit, `2 * slots` otherwise
-    (`Server._wide`) — two executables per bucket, both compiled when
-    the bucket's first request is admitted. Only when more prompt
-    tokens are waiting than a wide pass holds (a burst of admissions)
-    does a step take further passes, in admission order. A row's logits
+    and go through the model's `chunk_step` in the fewest passes a
+    LADDER of widths allows (`Server._ladder`): `slots` rows, `2 *
+    slots`, and doublings while a rung stays within 256 rows, the width
+    past which a pass is no longer bound by its weights, and under
+    `slots * pages_prefill_chunk`, the most a step can owe (a pass's
+    weights and its dispatch are paid once a PASS). A pass runs the
+    narrowest rung that holds its rows — one executable a rung and
+    bucket (three or four for 32 slots), each built once when the
+    bucket's first request is admitted (`Server._build`). Only a step
+    that owes more than the top rung holds takes further passes, in
+    admission order. A row's logits
     never depend on its neighbours, so a request's tokens are the same
     under load as alone (the tests hold them to `model.generate`'s on
     the CPU). Freshly sampled tokens stream to each request's consumer.
@@ -58,9 +63,10 @@ vLLM-style block table:
 Robustness — the request lifecycle:
 
   * **admission control** — every accept is gated twice: mx.memsafe
-    `check_budget` over resident parameters + the pool + the bucket's
-    step executable's AOT-compiled execution peak
-    (`jit_flat_step(...).aot_exec_peak`), then the pool's free pages
+    `check_budget` over resident parameters + the pool + the execution
+    peak of the bucket's heaviest step executable (read from the
+    builds the passes will run: the probe adds none), then the pool's
+    free pages
     (after evicting unreferenced prefix-tree leaves). A predicted
     overrun is a `429`-style verdict on the request — never a device
     OOM, never a dispatched predicted-overrun batch.
@@ -133,6 +139,15 @@ __all__ = [
     "CANCELLED", "FAILED", "TERMINAL",
 ]
 
+# The width, in rows, at which a pass stops being bound by its weights: a
+# bf16 weight is 2 bytes read from HBM and 2 operations a row, so reading
+# and using it take the same time at peak-operations / peak-bytes rows, 197e12
+# / 819e9 = 240 on a TPU v5e (`inspect._PEAK_FLOPS_TABLE`, `_PEAK_BW_TABLE`),
+# as a power of two. A pass narrower than this costs what its weights cost
+# to read, however few rows it has; the ladder of widths (`Server._ladder`)
+# does not go past it.
+_RIDGE_ROWS = 256
+
 # request lifecycle states
 QUEUED = "queued"        # accepted, waiting for a slot
 RUNNING = "running"      # owns a batch slot, decoding
@@ -156,8 +171,8 @@ _servers = weakref.WeakSet()
 def _close_round(step_span, stream_span, chunk, tokens):
     """Late attrs of one LIVE decode round: the tokens its `serve.stream`
     emitted, and on the step's `serve.step` span the largest `chunk` of
-    its rounds' executables (`chunk` > 1: the step ran a wide pass, or a
-    speculative verify)."""
+    its rounds' executables (`chunk` > 1: the step ran a pass wider than
+    `slots` rows, or a speculative verify)."""
     stream_span.attrs["tokens"] = tokens
     if chunk > step_span.attrs.get("chunk", 0):
         step_span.attrs["chunk"] = chunk
@@ -594,6 +609,7 @@ class Server:
         self._unfit = {}           # bucket -> the device's refusal at _warm
         self._width_dispatches = collections.Counter()   # width -> passes
         self._exec_peaks = {}      # bucket -> AOT exec-peak bytes (or None)
+        self._built = {}           # bucket -> its passes' Compiled (`_build`)
         self._by_id = {}
         self._pending_cancels = []
         self._seq = 0
@@ -644,6 +660,7 @@ class Server:
         self._prefill_chunk = max(
             1, int(prefill_chunk or _config.get("pages_prefill_chunk")))
         self._spec_k = max(1, int(spec_k or _config.get("pages_spec_k")))
+        self._rungs = self._ladder(self._slots, self._prefill_chunk)
         if self._max_len < ps:
             raise ValueError(
                 f"pages_page_size {ps} exceeds the model's max_length "
@@ -1029,10 +1046,10 @@ class Server:
     def _prewarm_buckets(self):
         """The AOT exec-peak probe of every bucket the queue will ask
         for, before the locked admission pass: what `_admit_budget`
-        reads. It compiles and analyses and dispatches nothing; the
-        executables are compiled for the device once a request has been
-        seated (`_warm_seated`). Only the scheduler thread touches
-        _runners / _exec_peaks, so no lock is required here."""
+        reads. It builds the bucket's executables (`_build`), analyses
+        them and dispatches nothing; `_warm_seated` runs them on padding
+        once a request has been seated. Only the scheduler thread touches
+        _runners / _built / _exec_peaks, so no lock is required here."""
         if _memsafe.capacity_bytes() is None:
             return
         with self._lock:
@@ -1041,13 +1058,33 @@ class Server:
             self._exec_peak(
                 self._bucket_for(r.prompt.size + r.max_new_tokens))
 
+    @staticmethod
+    def _ladder(slots, prefill_chunk):
+        """The widths, in virtual rows, a bucket's passes may take: `slots`
+        (every decode-only step fits it), `2 * slots`, and doublings
+        while a rung stays within `_RIDGE_ROWS` (past it a pass is no
+        longer bound by its weights, and two passes cost what one of
+        twice the width does) and under `slots * prefill_chunk`, the
+        most a step can owe: the one step in which every slot feeds a
+        whole chunk takes two passes of the rung below, and an
+        executable nothing else would fill is not built (a build is
+        1-2 s of set-up a bucket, PERF.md section 6, PR 38). One
+        executable a rung and bucket. Derived, not a knob. With
+        `prefill_chunk` 1 no step has more tokens than slots and there is
+        one width."""
+        if prefill_chunk == 1:
+            return (slots,)
+        rungs = [slots, 2 * slots]
+        while 2 * rungs[-1] <= _RIDGE_ROWS \
+                and 2 * rungs[-1] < slots * prefill_chunk:
+            rungs.append(2 * rungs[-1])
+        return tuple(rungs)
+
     def _wide(self):
-        """The wider of a bucket's two pass widths: `2 * slots` virtual
-        rows. Tokens that fit `slots` rows run the `slots`-wide
-        executable (every decode-only step does), any others the wide
-        one. Derived, not a knob. With `prefill_chunk` 1 no step has
-        more tokens than slots and there is one width."""
-        return self._slots * (2 if self._prefill_chunk > 1 else 1)
+        """The top rung of the ladder: the most rows one pass takes, where
+        `_passes` cuts a step's feeds, and the executable `lower_step`
+        lowers."""
+        return self._rungs[-1]
 
     def _warm_seated(self, grp):
         """`_warm` the bucket of a group that holds requests; True when
@@ -1084,32 +1121,62 @@ class Server:
                        for arenas in self._pool.state.values()
                        for a in arenas)
 
+    def _bucket_passes(self, bucket):
+        """Every pass `bucket` can run, as (width, full, tag): the
+        ladder's rungs; under a drafter also its mirror of each (gap-0
+        sync) and the verify pass (the draft chain is `_draft_runner`'s)."""
+        passes = [(w, False, "target") for w in self._rungs]
+        if self._drafter is not None:
+            passes += [(w, False, "draft") for w in self._rungs]
+            passes.append((self._slots * (self._spec_k + 1), True, "target"))
+        return passes
+
+    def _build(self, bucket):
+        """Build the executable of every pass of `bucket`, each ONCE, and
+        return them as {(width, full, tag): jax.stages.Compiled}. A pass
+        is traced, lowered and compiled through the jit its calls use
+        (`jit_flat_step`'s `lower`, at `_step_avals`, the calls' avals),
+        so the call finds the executable there and builds none, and the
+        memory analysis of these very executables is the admission's
+        probe (`_exec_peak`), which adds no build. The seconds go to
+        `mx.trace.setup()["compile_s"]`. (Compiling a rung on a worker
+        thread while the next is traced was measured and lost: PERF.md
+        section 6, PR 38.)"""
+        built = self._built.get(bucket)
+        if built is not None:
+            return built
+        t0 = time.perf_counter()
+        built = {
+            (width, full, tag):
+            self._runner(bucket, width, full, draft=tag == "draft")
+            .lower(*self._step_avals(bucket, width, tag)).compile()
+            for width, full, tag in self._bucket_passes(bucket)}
+        _trace.note_setup("compile_s", time.perf_counter() - t0)
+        self._built[bucket] = built
+        return built
+
     def _warm(self, bucket):
-        """Compile every executable `bucket` can run, once, when its
-        first request has been seated: each is dispatched on a pass of
-        padding rows (position -1: they walk no page and write slot 0's
-        scratch page, which nothing reads). So no executable is first
-        built inside a timed stretch of steps, whichever width a loop
-        needs first, and `stats()["executables"]` does not grow after
-        the step of the admission. The small gathers of `_take_rows` are
-        compiled here for the requests held at that step, and for a
-        later one when its first token asks (PERF.md section 6, PR 35,
-        has what that costs)."""
+        """Make every executable `bucket` can run ready, once, when its
+        first request has been seated: built (`_build`; by the
+        admission's probe already, where the device's capacity is known)
+        and each dispatched on a pass of padding rows (position -1: they
+        walk no page and write slot 0's scratch page, which nothing
+        reads). So no executable is first built inside a timed stretch of
+        steps, whichever width a loop needs first, and
+        `stats()["executables"]` does not grow after the step of the
+        admission. The small gathers of `_take_rows` are compiled here
+        for the requests held at that step, and for a later one when its
+        first token asks (PERF.md section 6, PR 35, has what that
+        costs)."""
         import jax.numpy as jnp
         S, n_pg = self._slots, bucket // self._page_size
-        widths = sorted({S, self._wide()})
-        passes = [(w, False, "target") for w in widths]
-        if self._drafter is not None:
-            # the drafter mirrors every target pass (gap-0 sync); the
-            # verify pass; the draft chain below
-            passes += [(w, False, "draft") for w in widths]
-            passes.append((S * (self._spec_k + 1), True, "target"))
+        self._build(bucket)
         idle = _Group(bucket, S, n_pg, self._windows)   # all padding
         with self._lock:
             held = [*self._queue, *(r for g in self._groups.values()
                                     for r in g.slots)]
         asking = sum(self._wants_row(r) for r in held)
-        for width, full, tag in passes:
+        for width, full, tag in self._bucket_passes(bucket):
             logits, _ = self._dispatch(
                 idle, self._runner(bucket, width, full, draft=tag == "draft"),
                 (*self._pack_rows(width), idle.device_tables()), tag)
@@ -1243,19 +1310,23 @@ class Server:
         """The `chunk` an executable is known by, in its jit label and on
         the spans of the steps that run it: 1 for the `slots`-wide pass,
         `prefill_chunk` (the prompt tokens a request may feed in one
-        step) for the wide one, `spec_k + 1` for the verify pass."""
+        step) for the one of `2 * slots` rows, twice that for each
+        further rung (a name: one value a rung, all above 1), `spec_k +
+        1` for the verify pass."""
         if full:
             return self._spec_k + 1
-        return 1 if width == self._slots else self._prefill_chunk
+        if width == self._slots:
+            return 1
+        return self._prefill_chunk * width // (2 * self._slots)
 
     def _runner(self, bucket, width, full=False, draft=False):
         """Step executable of `bucket` for a pass of `width` virtual
         rows: the model's `chunk_step` under jit_flat_step with the pool
-        arrays donated. Three widths at most exist per bucket (`slots`,
-        `_wide()`, and `slots * (spec_k + 1)` with full logits under a
-        drafter), so serving compiles O(buckets) executables, never one
-        per length; `_warm` compiles them all at the bucket's first
-        admission."""
+        arrays donated. A bucket has the widths of the ladder
+        (`_ladder`: three or four for 32 slots) and, under a drafter,
+        `slots * (spec_k + 1)` with full logits, so serving compiles
+        O(buckets) executables, never one per length; `_warm` compiles
+        them all at the bucket's first admission."""
         key = ("chunk", bucket, width, full, draft)
         r = self._runners.get(key)
         if r is None:
@@ -1313,23 +1384,12 @@ class Server:
             self._runners[key] = r
         return r
 
-    def _bucket_step(self, bucket):
-        """(runner, argument avals) of the HEAVIEST step executable
-        `bucket` can run: the speculative verify pass when a drafter is
-        attached (`slots * (spec_k + 1)` rows and float32 logits for
-        every one of them, on the device), else the wide pass."""
-        S = self._slots
-        if self._drafter is not None:
-            width, full = S * (self._spec_k + 1), True
-        else:
-            width, full = self._wide(), False
-        return self._runner(bucket, width, full), \
-            self._step_avals(bucket, width)
-
-    def _step_avals(self, bucket, width):
-        """Argument avals of `bucket`'s pass of `width` virtual rows: the
-        rows' tokens, positions and slots and each slot's head row
-        (`_pack_rows`), the page tables, the pool's target arenas."""
+    def _step_avals(self, bucket, width, tag="target"):
+        """Argument avals of `bucket`'s pass of `width` virtual rows, as
+        a call has them: the rows' tokens, positions and slots and each
+        slot's head row (`_pack_rows`: host arrays), the page tables, the
+        pool's `tag` arenas (with their placement, where they have
+        one)."""
         import jax
         S = self._slots
         ints = lambda *shape: jax.ShapeDtypeStruct(shape, np.int32)  # noqa: E731
@@ -1337,28 +1397,38 @@ class Server:
         return (ints(width), ints(width), ints(width), ints(S),
                 ints(1 + len(self._windows), S, n_pg) if self._windows
                 else ints(S, n_pg),
-                [jax.ShapeDtypeStruct(tuple(a.shape), a.dtype)
-                 for a in self._pool.state["target"]])
+                _trace.avals_of(self._pool.state[tag]))
 
     def lower_step(self, bucket):
-        """The `jax.stages.Lowered` form of `bucket`'s step executable
-        (see `_bucket_step`) — for ahead-of-time checks (which Pallas
-        kernels it holds: chip_smoke.py reads it). Dispatches nothing."""
-        run, avals = self._bucket_step(bucket)
-        return run.lower(*avals)
+        """The `jax.stages.Lowered` form of the HEAVIEST step executable
+        `bucket` can run: the speculative verify pass when a drafter is
+        attached (`slots * (spec_k + 1)` rows and float32 logits for
+        every one of them, on the device), else the ladder's top rung —
+        for ahead-of-time checks (which Pallas kernels it holds:
+        chip_smoke.py reads it). Dispatches nothing."""
+        if self._drafter is not None:
+            width, full = self._slots * (self._spec_k + 1), True
+        else:
+            width, full = self._wide(), False
+        return self._runner(bucket, width, full).lower(
+            *self._step_avals(bucket, width))
 
     def _exec_peak(self, bucket):
-        """AOT execution-peak bytes of the bucket's step executable
-        (beyond its argument buffers) — `predict_step_bytes`-style
-        analysis, no dispatch. Cached per bucket; None when the backend
-        withholds analysis (the budget then checks resident bytes
-        alone) — the `memsafe.aot_exec_peak` path pages are admitted
-        through."""
+        """AOT execution-peak bytes of the bucket's heaviest step
+        executable (beyond its argument buffers; the largest over the
+        target's passes) — `predict_step_bytes`-style analysis, no
+        dispatch. Cached per bucket; None when the backend withholds
+        analysis or refuses the build (the budget then checks resident
+        bytes alone, and `_warm_seated` hears the refusal again). The
+        probe adds no build: it reads the executables `_build` made,
+        which are the ones the passes will call."""
         if bucket in self._exec_peaks:
             return self._exec_peaks[bucket]
         try:
-            run, avals = self._bucket_step(bucket)
-            peak = run.aot_exec_peak(*avals)
+            peaks = [_memsafe.compiled_exec_peak(compiled)
+                     for (_, _, tag), compiled in self._build(bucket).items()
+                     if tag == "target"]
+            peak = None if None in peaks else max(peaks)
         except Exception:   # noqa: BLE001 — degrade to resident-only
             peak = None
         self._exec_peaks[bucket] = peak
@@ -1749,11 +1819,11 @@ class Server:
 
     def _passes(self, feeds):
         """The step's feeds cut into passes of at most `_wide()` virtual
-        rows, in order: one pass whenever the tokens fit it (every
-        steady step), further ones when more prompt tokens are waiting
-        than a pass holds (a burst of admissions). A request's chunk may
-        continue in the next pass: its earlier tokens are in the cache
-        by then."""
+        rows (the ladder's top rung), in order: the fewest passes the
+        ladder allows, one whenever the tokens fit the top rung (nearly
+        every step), further ones when more prompt tokens are waiting
+        than it holds. A request's chunk may continue in the next pass:
+        its earlier tokens are in the cache by then."""
         wide = self._wide()
         passes, rows, n = [], [], 0
         for i, p, ids in feeds:
@@ -1791,8 +1861,9 @@ class Server:
             fed = len(toks)
             want = [i for i, p, ids in feeds
                     if self._wants_row(grp.slots[i], p + len(ids))]
-            # the `slots`-wide pass whenever the tokens fit it
-            width = self._slots if fed <= self._slots else self._wide()
+            # the narrowest rung that holds them: padding rows walk no
+            # page, but the matrix products see them
+            width = next(w for w in self._rungs if w >= fed)
             C = self._chunk_of(width)
             run = self._runner(grp.bucket, width)
             lead = (*self._pack_rows(width, toks, pos, slot, last),

@@ -85,7 +85,7 @@ from . import util as _util
 __all__ = [
     "enable", "disable", "enabled", "live", "reset",
     "sampled", "span", "record_span", "annotate", "skew_tick",
-    "setup", "note_setup", "note_executable", "scope_map",
+    "setup", "note_setup", "note_executable", "avals_of", "scope_map",
     "flush", "trace_path", "spans", "skews", "snapshot",
     "skew_p99_ms", "skew_verdict", "critical_path",
 ]
@@ -669,7 +669,7 @@ def setup():
     return out
 
 
-def _avals_of(args):
+def avals_of(args):
     """The (shape, dtype, sharding) pytree of a jitted call's arguments:
     it holds no buffer, and it keeps which arguments were committed to
     their device, so `jit.lower(*avals).compile()` answers from jax's
@@ -694,7 +694,7 @@ def note_executable(label, compiled, args=None):
     its state), and only the arguments' avals are kept. Called at compile
     time only; nothing is lowered, compiled or read here."""
     _executables[label] = compiled if args is None \
-        else (compiled, _avals_of(args))
+        else (compiled, avals_of(args))
 
 
 def scope_map(label=None):

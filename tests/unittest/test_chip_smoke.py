@@ -54,7 +54,9 @@ def test_rehearsal_runs_every_phase_and_claims_nothing():
         assert f"== {name} ==" in out and f"-- {name}:" in out, name
     assert "REHEARSAL complete" in out.splitlines()[-1]
     assert "first-step loss, dropout 0" in out        # the 1-vs-4 comparison
-    assert out.count("state='done'") == 8             # every request DONE
+    assert out.count("state='done'") == 10    # every request DONE: two waves
+    # of four through GPT-2, one through Laguna's two page classes, one
+    # through DeepSeek-V2's latent cache
     assert not _result_lines(out)                     # no result line
     assert not re.search(r"\bpass(ed|es)?\b", out, re.I)
 
@@ -88,3 +90,47 @@ def test_pallas_kernels_reads_names_from_a_lowered_module():
 
     assert pallas_kernels(Lowered()) == {"flash_fwd": 2, "flash_dq": 1,
                                          "lamb_pass1": 1}
+
+
+def test_pallas_kernels_counts_a_shared_function_once_a_call():
+    """A stack of layers traced once (`models/_decode.layer_call`) is one
+    function in the module and a call a layer: its kernels count a call,
+    through calls of calls too."""
+    sys.path.insert(0, ROOT)
+    from tools.tpu_validate import pallas_kernels
+
+    class Lowered:
+        def as_text(self, debug_info=False):
+            return "\n".join([
+                '#loc1 = loc("x")',
+                '#loc7 = loc("jit(pure)/jit(layer)/paged_attention/'
+                'pallas_call"(#loc1))',
+                '#loc8 = loc("jit(pure)/jit(layer)/kv_page_write/'
+                'pallas_call"(#loc1))',
+                '#loc9 = loc("jit(pure)/head/pallas_call"(#loc1))',
+                '  func.func public @main(%arg0: tensor<8xf32>) {',
+                '    %1:2 = call @layer(%arg0) : (tensor<8xf32>) -> '
+                'tensor<8xf32> loc(#loc1)',
+                '    %2:2 = call @layer(%1) : (tensor<8xf32>) -> '
+                'tensor<8xf32> loc(#loc1)',
+                '    %3 = call @twice(%2) : (tensor<8xf32>) -> '
+                'tensor<8xf32> loc(#loc1)',
+                '    %4 = stablehlo.custom_call @tpu_custom_call(%3) {} : '
+                '(tensor<8xf32>) -> tensor<8xf32> loc(#loc9)',
+                '  }',
+                '  func.func private @layer(%arg0: tensor<8xf32>) {',
+                '    %1 = stablehlo.custom_call @tpu_custom_call(%arg0) {} '
+                ': (tensor<8xf32>) -> tensor<8xf32> loc(#loc8)',
+                '    %2 = stablehlo.custom_call @tpu_custom_call(%1) {} : '
+                '(tensor<8xf32>) -> tensor<8xf32> loc(#loc7)',
+                '  }',
+                '  func.func private @twice(%arg0: tensor<8xf32>) {',
+                '    %1 = call @layer(%arg0) : (tensor<8xf32>) -> '
+                'tensor<8xf32> loc(#loc1)',
+                '    %2 = call @layer(%1) : (tensor<8xf32>) -> '
+                'tensor<8xf32> loc(#loc1)',
+                '  }',
+            ])
+
+    assert pallas_kernels(Lowered()) == {"paged_attention": 4,
+                                         "kv_page_write": 4, "head": 1}

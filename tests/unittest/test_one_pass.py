@@ -157,8 +157,13 @@ def _watch_passes(srv):
     return seen
 
 
-def test_a_burst_of_three_times_the_slots(gpt):
-    slots, chunk = 4, 4
+@pytest.mark.parametrize("chunk,rungs", [(4, (4, 8)), (6, (4, 8, 16))],
+                         ids=["two-rungs", "three"])
+def test_a_burst_of_three_times_the_slots(gpt, chunk, rungs):
+    """The burst owes more than the top rung holds and takes several
+    passes a step, whatever the ladder: `slots` and `2 * slots` (all a
+    server of 4 slots and chunks of 4 has), or a third rung."""
+    slots = 4
     rng = np.random.RandomState(9)
     shared = _prompt(12, 1)
     prompts = [_prompt(n, 20 + n) for n in (5, 9, 14, 17, 3, 11, 6)] \
@@ -182,8 +187,8 @@ def test_a_burst_of_three_times_the_slots(gpt):
     assert all(r.state == serve.DONE for r in reqs)
     assert [list(r.tokens) for r in reqs] == ref
     assert st["prefix_hits"] >= 5 and st["cow_copies"] >= 2
-    wide = 2 * slots
-    assert srv._wide() == wide
+    wide = srv._wide()
+    assert srv._rungs == rungs and wide == rungs[-1]
     assert max(len(step) for step in seen) > 1, "the burst took two passes"
     for step in seen:
         fed = {}
@@ -212,7 +217,10 @@ def test_a_burst_of_three_times_the_slots(gpt):
     assert st["rows_fed"] == st["attn_tokens"]
     assert st["rows_dispatched"] == sum(
         w * n for w, n in st["width_dispatches"].items())
-    assert st["width_dispatches"][wide] == st["chunk_steps"] > 0
+    assert set(st["width_dispatches"]) <= set(srv._rungs)
+    assert sum(n for w, n in st["width_dispatches"].items()
+               if w > slots) == st["chunk_steps"] > 0
+    assert st["width_dispatches"][wide] > 0
     assert st["width_dispatches"][slots] == st["token_steps"] > 0
     assert st["rows_fed"] <= st["rows_dispatched"]
     assert st["chunk_dispatches"] - alone == sum(len(step) for step in seen)
@@ -229,8 +237,9 @@ def test_executables_are_built_at_the_first_admission(gpt, drafter):
     srv.submit(_prompt(2, 1), max_new_tokens=3)     # fits the narrow pass
     srv.step()
     built = srv.stats()["executables"]
-    # slots-wide and wide; with a drafter its mirrors, the verify pass
-    # and the draft chain
+    # one a rung of the ladder; with a drafter its mirrors, the verify
+    # pass and the draft chain
+    assert srv._rungs == (2, 4)
     assert built == (6 if drafter else 2)
     reqs = [srv.submit(_prompt(n, n), max_new_tokens=5) for n in (9, 11, 3)]
     srv.drain()
@@ -271,7 +280,7 @@ def test_a_refused_bucket_is_neither_compiled_nor_dispatched(gpt):
     assert big.state == serve.DONE and big.degraded \
         and big.max_new_tokens == 22
     assert over.state == serve.REJECTED and "429" in over.verdict
-    assert srv._warmed == {32} and st["executables"] == 2
+    assert srv._warmed == {32} and st["executables"] == len(srv._rungs)
     assert set(calls) == {32}
     assert not [key for key in srv._runners if key[1] == 64]
     assert 64 not in st["buckets_allocated"]
@@ -338,10 +347,12 @@ def test_spans_name_the_executable_that_ran(gpt):
     assert len(rounds) == st["steps"] > 0
     maps = trace.scope_map()
     ran_wide = 0
+    chunk_of = {2: 1, 4: 4}             # a rung's name: one value each
+    assert srv._rungs == tuple(chunk_of)
     for s in rounds:
-        assert s["chunk"] in (1, 4) and s["fed"] <= s["width"]
-        assert (s["chunk"] > 1) == (s["width"] == srv._wide()) \
-            == (s["fed"] > 2)
+        assert s["chunk"] == chunk_of[s["width"]] and s["fed"] <= s["width"]
+        # the narrowest rung that holds the pass's rows
+        assert s["width"] == min(w for w in srv._rungs if w >= s["fed"])
         names = maps[label.format(**s)]
         assert any("/kv_arena_update/" in path for path in names.values())
         assert steps[s["step"]]["chunk"] == max(

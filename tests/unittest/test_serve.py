@@ -282,9 +282,10 @@ def test_streaming_tokens_arrive_incrementally(model):
 
 
 def test_bucketing_bounds_executables_and_check_quiet(model):
-    """A stream of novel prompt/generation lengths compiles at most two
-    executables per bucket (two pow2 buckets here), and mx.check's
-    retrace-hazard rule stays quiet on the bucketed stream."""
+    """A stream of novel prompt/generation lengths compiles at most one
+    executable a rung of the ladder per bucket (two pow2 buckets here),
+    and mx.check's retrace-hazard rule stays quiet on the bucketed
+    stream."""
     import jax
     mxcheck.enable("warn")
     srv = serve.Server(model, slots=2)
@@ -306,9 +307,9 @@ def test_bucketing_bounds_executables_and_check_quiet(model):
         jax.jit = real_jit
     assert all(r.state == serve.DONE for r in reqs)
     st = srv.stats()
-    # a chunk and a token executable per bucket, however many lengths
-    assert st["executables"] <= 2 * 2, st
-    assert jits["n"] <= 2 * 2, jits
+    # one executable a rung and bucket, however many lengths
+    assert st["executables"] <= 2 * len(srv._rungs), st
+    assert jits["n"] <= 2 * len(srv._rungs), jits
     assert {key[1] for key in srv._runners} == {32, 64}
     bad = [f for f in mxcheck.findings()
            if f["rule"] in ("retrace-hazard", "donation-miss")]
@@ -568,7 +569,6 @@ def _flaky(srv, fails, exc=OSError("transient fabric glitch")):
                 raise exc
             return run(*args)
 
-        wrapped.aot_exec_peak = run.aot_exec_peak
         wrapped.lower = run.lower
         return wrapped
 
@@ -747,11 +747,14 @@ def test_trace_report_serve_verdicts():
     decode_bound = {0: {"by_cat": {"serve": 300e3},
                         "by_span": {"serve.queue_wait": 40e3,
                                     "serve.decode_step": 260e3},
-                        "steps": [], "rows": [128, 96],
+                        "steps": [],
+                        "rungs": {32: [2, 40], 64: [1, 56]},
                         "fetched": [1536, 2, 2]}}
     kind, rank, dom, detail = tr._verdict(decode_bound, [])
     assert (kind, rank, dom) == ("decode-bound", 0, "serve.decode_step")
     assert "passes 75.0% full" in detail
+    assert "1.50 passes a step, by rung: 32 x 2 62% full, 64 x 1 88% full" \
+        in detail
     assert "fetched 768 bytes a step (2 logits rows in 2 steps)" in detail
     # a TRAINING window with step spans keeps its old verdicts even if a
     # serve span leaked into it
@@ -775,6 +778,7 @@ def test_trace_report_end_to_end_serve_window(model, tmp_path):
     offsets, _ref = tr._offsets_us(ranks)
     text = tr.report(ranks, offsets)
     assert "verdict: decode-bound" in text or "verdict: queue-bound" in text
+    assert "passes a step, by rung: " in text
 
 
 # -- overload acceptance smoke ----------------------------------------------

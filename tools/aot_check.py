@@ -110,9 +110,9 @@ def check_kernels(devices):
                  sds((n_pages, H, ps, Dp), bf, s1),
                  sds((n_pages, H, ps, Dp), bf, s1),
                  sds((B, n_pg), i32, s1), sds((B,), i32, s1))
-    # both at the wide pass's 64 virtual rows (a row a fed token, its
+    # both at a pass's 64 and 128 virtual rows (a row a fed token, its
     # table row repeated), and the verify pass's 160 at bucket 1024
-    for W, n_pg in ((64, 16), (64, 64), (160, 64)):
+    for W, n_pg in ((64, 16), (64, 64), (128, 64), (160, 64)):
         compile_(f"kv_page_write + paged_attention W{W} n_pg{n_pg}",
                  lambda kp, vp, kn, vn, wp, wo, q, tb, t:
                  pallas_ops.paged_attention(
@@ -230,8 +230,9 @@ def arena_copies_and_aliases(text, arenas):
 def check_serve(devices):
     """The benchmark's serving cell (chipbench/configs/gpt2-medium-serve.json:
     GPT-2 medium, 32 slots over a pool of 2,080 pages of 16): the bucket-256
-    step executables as the server builds them, the wide pass (64 virtual
-    rows) and the `slots`-wide one. Beyond compiling, each must be ONE pass
+    step executables as the server builds them, one a rung of the ladder
+    of pass widths (128, 64 and 32 virtual rows). Beyond compiling, each
+    must be ONE pass
     that keeps the arenas where they are: both paged kernels once a layer
     (24 calls, in no loop body), every arena parameter aliased to an
     output, and no `copy` of an arena-shaped operand left anywhere. That
@@ -252,7 +253,7 @@ def check_serve(devices):
     bucket = min(cell["server"]["buckets"])
 
     srv = serve.Server(model, **cell["server"])
-    for width in (srv._wide(), srv._slots):
+    for width in reversed(srv._rungs):
         run, avals = srv._runner(bucket, width), \
             srv._step_avals(bucket, width)
         arena = avals[-1][0]
@@ -303,9 +304,9 @@ def server_of_shapes(model, server_args, s1):
 def check_serve_glm(devices):
     """The benchmark's GLM-5 cell (chipbench/configs/glm-5-serve-ep16.json:
     4.7 B parameters in bf16, 32 slots, one bucket of 6,272 over a pool of
-    2,080 pages of 64): the wide pass (64 virtual rows) and the
-    `slots`-wide one as the server builds them, from abstract parameters
-    and arenas (nothing is
+    2,080 pages of 64): one executable a rung of the ladder of pass
+    widths (128, 64 and 32 virtual rows) as the server builds them, from
+    abstract parameters and arenas (nothing is
     drawn or placed; `lower` takes shapes). Each must fit the chip and
     keep its twelve arenas where they are: every arena aliased to an
     output, no `copy` of an arena-shaped operand."""
@@ -322,7 +323,7 @@ def check_serve_glm(devices):
     model = glm.GLMForCausalLM(cfg)
     srv = server_of_shapes(model, cell["server"], s1)
     bucket = cell["server"]["buckets"][0]
-    for width in (srv._wide(), srv._slots):
+    for width in reversed(srv._rungs):
         run, avals = srv._runner(bucket, width), \
             srv._step_avals(bucket, width)
         arenas = avals[-1]
@@ -355,8 +356,9 @@ def check_serve_laguna(devices):
     """The benchmark's Laguna cell (chipbench/configs/
     laguna-xs2-serve-pp8.json: 3.87 B parameters in bf16, 32 slots, one
     bucket of 12,800 over a full class of 6,400 pages of 64 and a window
-    class of 320): the wide pass (64 virtual rows) and the `slots`-wide one
-    as the server builds them, from abstract parameters and arenas. Each
+    class of 320): one executable a rung of the ladder of pass widths (256
+    to 32 virtual rows) as the server builds them, from abstract
+    parameters and arenas. Each
     must hold both paged kernels once a layer, keep its ten arenas of two
     page counts where they are (aliased, no arena-shaped copy) and fit the
     chip."""
@@ -374,7 +376,7 @@ def check_serve_laguna(devices):
     srv = server_of_shapes(model, cell["server"], s1)
     bucket = cell["server"]["buckets"][0]
     n_l = cfg["num_hidden_layers"]
-    for width in (srv._wide(), srv._slots):
+    for width in reversed(srv._rungs):
         run, avals = srv._runner(bucket, width), \
             srv._step_avals(bucket, width)
         arenas = avals[-1]
@@ -407,9 +409,10 @@ def check_serve_laguna(devices):
 def check_serve_deepseek(devices):
     """The benchmark's DeepSeek-V2 cell (chipbench/configs/
     deepseek-v2-serve-ep8.json: 3.81 B parameters in bf16, 32 slots, one
-    bucket of 17,408 over a pool of 4,128 pages of 64): the wide pass (64
-    virtual rows) and the `slots`-wide one as the server builds them, from
-    abstract parameters and arenas. Each must hold the paged
+    bucket of 17,408 over a pool of 4,128 pages of 64): one executable a
+    rung of the ladder of pass widths (256 to 32 virtual rows) as the
+    server builds them, from abstract parameters and arenas. Each must hold
+    the paged
     latent-attention kernel once a layer and NO gather of the bucket (a
     latent row has no head axis: `paged_attention` cannot read it, and the
     fallback would gather 17,408 rows for every virtual row), keep its six
@@ -429,7 +432,7 @@ def check_serve_deepseek(devices):
     srv = server_of_shapes(model, cell["server"], s1)
     bucket = cell["server"]["buckets"][0]
     n_l = cfg["num_hidden_layers"]
-    for width in (srv._wide(), srv._slots):
+    for width in reversed(srv._rungs):
         run, avals = srv._runner(bucket, width), \
             srv._step_avals(bucket, width)
         arenas = avals[-1]

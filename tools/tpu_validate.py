@@ -57,17 +57,37 @@ def pallas_kernels(lowered):
     drop while the outputs depend on it. Every pallas_call in pallas_ops/
     carries `name=`, which lowering keeps as the location's component
     before `pallas_call` (`.../lamb_pass1/pallas_call`, or wrapped by
-    autodiff as `.../transpose(jvp(flash_dq))/pallas_call`)."""
+    autodiff as `.../transpose(jvp(flash_dq))/pallas_call`). A custom
+    call inside a function the module calls several times counts once a
+    call (a stack of layers traced once, `models/_decode.layer_call`, is
+    one function and a call a layer; XLA inlines them)."""
     text = lowered.as_text(debug_info=True)
     locs = dict(re.findall(r'^#loc(\d+) = loc\("([^"]*)"', text, re.M))
-    found = collections.Counter()
+    # per function: its own custom calls, and the functions it calls
+    own = collections.defaultdict(collections.Counter)
+    calls = collections.defaultdict(collections.Counter)
+    fn = "main"
     for line in text.splitlines():
-        if "@tpu_custom_call" in line:
+        head = re.match(r'\s*func\.func \w+ @([\w.$-]+)\(', line)
+        if head:
+            fn = head.group(1)
+        elif "@tpu_custom_call" in line:
             ref = re.search(r'loc\(#loc(\d+)\)\s*$', line)
             name = re.search(r'([A-Za-z0-9_]+)\)*/pallas_call',
                              locs.get(ref.group(1), "") if ref else "")
-            found[name.group(1) if name else "unnamed"] += 1
-    return dict(found)
+            own[fn][name.group(1) if name else "unnamed"] += 1
+        else:
+            for callee in re.findall(r'\bcall @([\w.$-]+)\(', line):
+                calls[fn][callee] += 1
+
+    def total(fn):
+        found = collections.Counter(own[fn])
+        for callee, n in calls[fn].items():
+            for kernel, k in total(callee).items():
+                found[kernel] += n * k
+        return found
+
+    return dict(total("main"))
 
 
 def flash_parity(B=2, H=4, L=512, D=64):

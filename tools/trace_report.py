@@ -192,14 +192,14 @@ def cross_rank_skews(ranks):
 def _window_stats(ranks, offsets, lo_us, hi_us):
     """Per-rank span-time aggregation restricted to [lo_us, hi_us) on the
     shared axis: {"by_cat": {cat: us}, "by_span": {name: us}, "steps":
-    [step dur_us], "rows": [virtual rows, tokens fed] of the window's
+    [step dur_us], "rungs": {width: [passes, tokens fed]} of the window's
     serving passes, "fetched": [bytes, logits rows, scheduler steps] that
     those passes copied to the host} per rank."""
     stats = {}
     for rank, (_meta, spans, _skews) in ranks.items():
         off = offsets[rank]
         by_cat, by_span, step_us = {}, {}, {}
-        rows = [0, 0]
+        rungs = {}      # width -> [passes, tokens fed in them]
         fetched = [0, 0, 0]
         for s in spans:
             ts = off + float(s.get("ts_us", 0.0))
@@ -213,15 +213,16 @@ def _window_stats(ranks, offsets, lo_us, hi_us):
             if cat == "step" and "step" in s:
                 step_us[s["step"]] = step_us.get(s["step"], 0.0) + dur
             if name == "serve.decode_step" and "width" in s:
-                rows[0] += int(s["width"])
-                rows[1] += int(s.get("fed", 0))
+                rung = rungs.setdefault(int(s["width"]), [0, 0])
+                rung[0] += 1
+                rung[1] += int(s.get("fed", 0))
             if name == "serve.fetch" and "bytes" in s:
                 fetched[0] += int(s["bytes"])
                 fetched[1] += int(s.get("rows", 0))
             if name == "serve.step":
                 fetched[2] += 1
         stats[rank] = {"by_cat": by_cat, "by_span": by_span,
-                       "steps": sorted(step_us.values()), "rows": rows,
+                       "steps": sorted(step_us.values()), "rungs": rungs,
                        "fetched": fetched}
     return stats
 
@@ -245,15 +246,25 @@ def _verdict(stats, skews_in_window):
     if serve_frac:
         rank = max(serve_frac, key=lambda r: serve_frac[r][0])
         frac, qwait, decode = serve_frac[rank]
-        # the fill of the passes: a step is one pass over its tokens,
-        # padded to one of two widths (`width`/`fed` on serve.decode_step)
-        width, fed = stats[rank].get("rows", (0, 0))
-        fill = f"; passes {fed / width:.1%} full ({fed} tokens in " \
-            f"{width} virtual rows)" if width else ""
+        # the fill of the passes: a step's tokens go through the layer
+        # stack in the fewest passes the ladder of widths allows, each
+        # padded to the narrowest rung that holds it (`width`/`fed` on
+        # serve.decode_step)
+        nbytes, lrows, steps = stats[rank].get("fetched", (0, 0, 0))
+        rungs = stats[rank].get("rungs")
+        fill = ""
+        if rungs:
+            width = sum(w * n for w, (n, _) in rungs.items())
+            fed = sum(f for _, f in rungs.values())
+            passes = sum(n for n, _ in rungs.values())
+            fill = f"; passes {fed / width:.1%} full ({fed} tokens in " \
+                f"{width} virtual rows); {passes / max(steps, 1):.2f} " \
+                "passes a step, by rung: " + ", ".join(
+                    f"{w} x {n} {f / (w * n):.0%} full"
+                    for w, (n, f) in sorted(rungs.items()))
         # what the passes copied to the host (`bytes`/`rows` on
         # serve.fetch): token ids, and logits rows only where a request
         # samples or keeps them
-        nbytes, lrows, steps = stats[rank].get("fetched", (0, 0, 0))
         if nbytes:
             fill += f"; fetched {nbytes / max(steps, 1):,.0f} bytes a " \
                 f"step ({lrows} logits rows in {steps} steps)"
