@@ -164,7 +164,7 @@ _NULLCTX = contextlib.nullcontext()
 # the step table's row (`mx.trace.STEP_FIELDS`), by field
 (_R_STEP, _R_T0, _R_TOTAL, _R_SCHED, _R_PREP, _R_DISP, _R_FETCH, _R_STREAM,
  _R_PASSES, _R_FED, _R_WIDE, _R_ADMIT, _R_COMPILES, _R_COMPILE_S, _R_GAP,
- _R_POLLS) = range(len(_trace.STEP_FIELDS))
+ _R_POLLS, _R_WALKS) = range(len(_trace.STEP_FIELDS))
 _ROW0 = (0.0,) * len(_trace.STEP_FIELDS)
 _lock = _locklint.make_lock("serve.module")
 _enabled = False          # the fast-path bool; the decode hook reads it
@@ -1957,7 +1957,8 @@ class Server:
                         self._finish(r, DONE, "200 ok")
             if sp:
                 _close_round(sp, stream, C, self._stats["tokens"] - tokens0)
-        self._note_row(c0, c1, c2, c3, time.perf_counter(), fed, width)
+        self._note_row(c0, c1, c2, c3, time.perf_counter(), fed, width,
+                       len(feeds))
 
     def _spec_round(self, grp, active, sched_step, sp=None):
         """One speculative decoding round: the drafter chains k greedy
@@ -2073,7 +2074,8 @@ class Server:
             if sp:
                 _close_round(sp, stream, k + 1,
                              self._stats["tokens"] - tokens0)
-        self._note_row(c0, c1, c2, c3, time.perf_counter(), fed, width)
+        self._note_row(c0, c1, c2, c3, time.perf_counter(), fed, width,
+                       len(active))
 
     def _tree_insert(self, grp, i, req):
         """One-time prefix-tree registration of a slot's fully-prefilled
@@ -2175,11 +2177,13 @@ class Server:
             self._stats["logit_rows_fetched"] += len(want)
         return ids, rows
 
-    def _note_row(self, c0, c1, c2, c3, c4, fed, width):
+    def _note_row(self, c0, c1, c2, c3, c4, fed, width, walks):
         """Add one pass to the step's row of the step table: its prepare
         (c0-c1), dispatch (c1-c2), fetch (c2-c3) and stream (c3-c4) on
-        the clock, the tokens fed, and whether it was wider than `slots`
-        rows."""
+        the clock, the tokens fed, the runs of one request's rows they
+        came in (a feed of `_pass`, a slot of a speculative round: each
+        one walk of the request's pages in the paged kernel), and whether
+        it was wider than `slots` rows."""
         row = self._row
         row[_R_PREP] += c1 - c0
         row[_R_DISP] += c2 - c1
@@ -2187,6 +2191,7 @@ class Server:
         row[_R_STREAM] += c4 - c3
         row[_R_PASSES] += 1
         row[_R_FED] += fed
+        row[_R_WALKS] += walks
         if width > self._slots:
             row[_R_WIDE] = 1
 

@@ -671,15 +671,17 @@ def setup():
 # one row a `Server.step`, always on: `step` is the `step` attr of the
 # step's live spans; `t0` its start in microseconds on the span buffer's
 # epoch (a span's `ts_us`); the `_s` fields seconds on the host's clock;
-# `prepare_s` .. `stream_s`, `passes`, `fed`, `polls` sums over the step's
-# passes; `wide` 1 when a pass was wider than `slots` rows; `compiles`,
-# `compile_s` the compile listener's events inside the step
+# `prepare_s` .. `stream_s`, `passes`, `fed`, `polls`, `walks` sums over
+# the step's passes; `wide` 1 when a pass was wider than `slots` rows;
+# `compiles`, `compile_s` the compile listener's events inside the step;
+# `walks` the runs of one request's rows the passes hand the paged
+# kernel, each a walk of that request's pages a layer
 STEP_FIELDS = ("step", "t0", "total_s", "schedule_s", "prepare_s",
                "dispatch_s", "fetch_s", "stream_s", "passes", "fed", "wide",
                "admitted", "compiles", "compile_s", "poll_gap_max_s",
-               "polls")
+               "polls", "walks")
 _INT_FIELDS = frozenset({"step", "passes", "fed", "wide", "admitted",
-                         "compiles", "polls"})
+                         "compiles", "polls", "walks"})
 MAX_STEPS = 16_384
 _steps = np.zeros((MAX_STEPS, len(STEP_FIELDS)))
 _steps_written = 0        # rows ever written; row k lives at k % MAX_STEPS

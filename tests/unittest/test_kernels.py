@@ -12,6 +12,7 @@ fused updates, the kernels=on strictness contract, and an mx.check
 graph lint over each kernel's traced form (no baked constants, no
 silent promotions, donation-safe).
 """
+import hashlib
 import importlib
 
 import numpy as np
@@ -514,6 +515,195 @@ def test_paged_attention_padding_rows_walk_no_page(one_device, monkeypatch,
     live = np.setdiff1d(np.arange(7), pad)
     assert np.array_equal(got[live], np.asarray(alone)[live])
     assert not got[pad].any()
+
+
+def _run_case(runs, H=4, Hkv=4, D=16, ps=8, n_pg=8, dtype=jnp.float32,
+              shared=0, seed=5):
+    """A pass's rows as a server packs them: `runs` lists, in row order,
+    (first position, rows) of one request each (its rows at consecutive
+    positions through its table row) or (None, rows) of padding. Each
+    request has pages of its own but the first `shared` of its table,
+    which every request shares (a prefix from the tree); page 0 belongs
+    to nobody. Arenas at the lane width."""
+    rng = np.random.RandomState(seed)
+    n_req = sum(p is not None for p, _ in runs)
+    own = n_pg - shared
+    P = 1 + shared + n_req * own
+    pad = ((0, 0),) * 3 + ((0, 128 - D),)
+    kp = jnp.pad(jnp.asarray(rng.randn(P, Hkv, ps, D), dtype), pad)
+    vp = jnp.pad(jnp.asarray(rng.randn(P, Hkv, ps, D), dtype), pad)
+    pages = 1 + shared + rng.permutation(n_req * own).reshape(n_req, own)
+    prefix = list(range(1, 1 + shared))
+    t, tables, r = [], [], 0
+    for p, n in runs:
+        if p is None:
+            t += [-1] * n
+            tables += [[0] * n_pg] * n
+        else:
+            t += range(p, p + n)
+            tables += [prefix + pages[r].tolist()] * n
+            r += 1
+    q = jnp.asarray(rng.randn(len(t), H, 1, D), dtype)
+    return (q, kp, vp, np.array(tables, np.int32), np.array(t, np.int32))
+
+
+def _walks(tables, t):
+    run = pa._runs(jnp.asarray(tables), jnp.asarray(t), 10 ** 6)[0]
+    return np.asarray(run)
+
+
+RUN_CASES = {
+    # runs of 1, 2, 8 and 32 rows beside padding, a KV head a query head
+    "lengths": dict(runs=[(5, 1), (None, 2), (9, 2), (20, 8), (None, 1),
+                          (0, 32), (40, 1), (None, 3)]),
+    # the same with four query heads to a KV head
+    "groups": dict(runs=[(5, 1), (None, 2), (9, 2), (20, 8), (None, 1),
+                         (0, 32), (40, 1), (None, 3)], H=8, Hkv=2),
+    # a run across a page boundary (12 .. 19) and, at two pages a wave,
+    # a wave boundary (16); its last row's page ends the walk
+    "page_and_wave": dict(runs=[(12, 8), (3, 1), (29, 6)], pages_a_wave=2),
+    # a window of 12 over rows at 30 .. 45: their windows begin on pages
+    # 2 .. 4, the walk on the first row's
+    "window": dict(runs=[(30, 16), (None, 1), (7, 3), (50, 1)], H=8, Hkv=2,
+                   window=12, pages_a_wave=2),
+    # two requests whose tables share their first two pages, the second's
+    # rows right after the first's at the next positions: two runs
+    "shared_prefix": dict(runs=[(10, 8), (18, 8), (26, 1)], shared=2),
+    # bf16 pages: bf16 scores, p in three bf16 terms for P.V (group 1)
+    "bf16": dict(runs=[(20, 8), (None, 2), (3, 1), (0, 32)],
+                 dtype=jnp.bfloat16, D=64, ps=16, n_pg=4),
+    "bf16_groups": dict(runs=[(20, 8), (None, 2), (3, 1), (0, 32)],
+                        dtype=jnp.bfloat16, H=8, Hkv=2, D=64, ps=16, n_pg=4),
+}
+
+
+@pytest.mark.parametrize("name", list(RUN_CASES))
+def test_paged_attention_run_parity(one_device, monkeypatch, name):
+    """Rows of one request at consecutive positions share ONE walk of its
+    pages, reduced against a query block of the rows: kernel against
+    reference, every row a run where the tables say so, padding zeros."""
+    kw = dict(RUN_CASES[name])
+    window = kw.pop("window", None)
+    pages_a_wave = kw.pop("pages_a_wave", None)
+    q, kp, vp, tables, t = _run_case(**kw)
+    if pages_a_wave:
+        monkeypatch.setattr(pa, "_WAVE_BYTES",
+                            pages_a_wave * kp[0].size * kp.dtype.itemsize)
+    runs = [n for p, n in kw["runs"] if p is not None]
+    walks = _walks(tables, t)
+    assert sorted(walks[walks > 0].tolist()) == sorted(runs)
+    args = (q, kp, vp, jnp.asarray(tables), jnp.asarray(t))
+    assert _holds_kernel(lambda *a: pa.paged_attention(*a, window), *args)
+    got = np.asarray(pa.paged_attention(*args, window), np.float32)
+    ref = np.asarray(pa.paged_attention_reference(*args, window), np.float32)
+    live = t >= 0
+    tol = 2e-6 if kw.get("dtype", jnp.float32) == jnp.float32 else 2e-2
+    np.testing.assert_allclose(got[live], ref[live], rtol=tol, atol=tol)
+    assert not got[~live].any()
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_paged_attention_run_reads_no_page_past_its_last_row(
+        one_device, monkeypatch, dtype):
+    """Poison: every page a run's LAST row does not see holds NaN, and
+    the wave buffers' slots the walk does not fill are left from nothing
+    the pool holds. The run's rows come back finite and as clean."""
+    q, kp, vp, tables, t = _run_case([(3, 10), (None, 1), (0, 2)],
+                                     dtype=dtype, n_pg=8)
+    monkeypatch.setattr(pa, "_WAVE_BYTES", 3 * kp[0].size * kp.dtype.itemsize)
+    clean = np.asarray(pa.paged_attention(
+        q, kp, vp, jnp.asarray(tables), jnp.asarray(t)), np.float32)
+    kp, vp = np.array(kp), np.array(vp)
+    ps = kp.shape[2]
+    for b in np.flatnonzero(t >= 0):
+        past = tables[b, t[b] // ps + 1:]
+        if b + 1 == len(t) or tables[b + 1, 0] != tables[b, 0]:
+            kp[past] = np.nan
+            vp[past] = np.nan
+    got = np.asarray(pa.paged_attention(
+        q, jnp.asarray(kp), jnp.asarray(vp), jnp.asarray(tables),
+        jnp.asarray(t)), np.float32)
+    assert np.isfinite(got).all()
+    assert np.array_equal(got, clean)
+
+
+def test_paged_attention_long_run_is_cut_at_the_state_it_can_hold(
+        one_device, monkeypatch):
+    """A run longer than the blocks of online-softmax state VMEM holds is
+    cut into runs that fit: with room for one block of 8 rows, a run of
+    20 is three walks (8, 8, 4), and every row is what it is in one."""
+    q, kp, vp, tables, t = _run_case([(4, 20), (None, 4)])
+    args = (q, kp, vp, jnp.asarray(tables), jnp.asarray(t))
+    whole = np.asarray(pa.paged_attention(*args))
+    H, Dp = q.shape[1], kp.shape[3]
+    monkeypatch.setattr(pa, "_STATE_BYTES", H * 8 * (2 * 128 + Dp) * 4)
+    run = pa._runs(jnp.asarray(tables), jnp.asarray(t), 8)[0]
+    assert np.asarray(run)[:20].tolist() == [8] + [0] * 7 + [8] + [0] * 7 \
+        + [4, 0, 0, 0]
+    cut = np.asarray(pa.paged_attention(*args))
+    np.testing.assert_allclose(cut, whole, rtol=2e-6, atol=2e-6)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_paged_attention_rows_in_no_run_are_the_rows_alone(one_device,
+                                                           dtype):
+    """Every row its own run (other table rows, or positions that do not
+    follow): the batch's bits are each row's alone, the per-row walk as
+    it was before runs."""
+    q, kp, vp, tables, t = _run_case(
+        [(5, 1), (None, 1), (9, 1), (30, 1), (0, 1), (17, 1)], dtype=dtype)
+    tables[3] = tables[2]                    # one table, positions 9, 30
+    assert _walks(tables, t).tolist() == [1, 0, 1, 1, 1, 1]
+    got = np.asarray(pa.paged_attention(
+        q, kp, vp, jnp.asarray(tables), jnp.asarray(t)), np.float32)
+    for b in range(len(t)):
+        alone = pa.paged_attention(q[b:b + 1], kp, vp,
+                                   jnp.asarray(tables[b:b + 1]),
+                                   jnp.asarray(t[b:b + 1]))
+        assert np.array_equal(got[b], np.asarray(alone, np.float32)[0])
+
+
+# rows in no run beside padding: each its own table row, but rows 2 and
+# 3, one table row at positions that do not follow
+_NO_RUNS = [(5, 1), (None, 1), (9, 1), (30, 1), (0, 1), (17, 1), (None, 2),
+            (40, 1)]
+
+# the case, the window, and the digest of the PARENT commit's kernel (PR
+# 39's, before runs) on it, through the interpreter
+NO_RUN_DIGESTS = {
+    "f32": (dict(), None, "0dc478bfc59e15aa"),
+    "bf16": (dict(dtype=jnp.bfloat16, D=64, ps=16, n_pg=4), None,
+             "9096ae60a8a39813"),
+    "f32_group4": (dict(H=8, Hkv=2), None, "d4f44ac548c16424"),
+    "bf16_group6": (dict(H=12, Hkv=2, dtype=jnp.bfloat16, D=64, ps=16,
+                         n_pg=4), None, "4e2aa62783145a0d"),
+    "f32_group3_window": (dict(H=6, Hkv=2), 12, "67055254e17231a0"),
+    "bf16_group8_window": (dict(H=16, Hkv=2, dtype=jnp.bfloat16, D=64,
+                                ps=16, n_pg=4), 20, "50907366e5c15dc3"),
+}
+
+
+def _no_run_digest(case, window):
+    q, kp, vp, tables, t = _run_case(_NO_RUNS, **case)
+    tables[3] = tables[2]
+    out = pa.paged_attention(q, kp, vp, jnp.asarray(tables), jnp.asarray(t),
+                             window)
+    return hashlib.sha256(
+        np.asarray(out.astype(jnp.float32)).tobytes()).hexdigest()[:16]
+
+
+@pytest.mark.parametrize("name", list(NO_RUN_DIGESTS))
+def test_paged_attention_rows_in_no_run_are_the_parent_kernels_bits(
+        one_device, name):
+    """A call with no run longer than one row gives the bits the kernel
+    gave before runs: group 1 and groups (padded, and with a window), f32
+    and bf16."""
+    case, window, digest = NO_RUN_DIGESTS[name]
+    assert _walks(*_run_case(_NO_RUNS, **case)[3:]).tolist()[:4] \
+        == [1, 0, 1, 1]
+    assert _no_run_digest(case, window) == digest
 
 
 def test_paged_attention_kernels_off_is_reference_path(one_device):

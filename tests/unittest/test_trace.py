@@ -489,6 +489,44 @@ def test_step_table_one_row_a_step_joined_to_the_spans():
             + 0.2
 
 
+def test_step_table_walks_are_the_runs_the_passes_hand_the_kernel():
+    """`walks`: the runs of one request's rows each step's passes hand the
+    paged kernel, on the host. Steps that mix decoding rows with prompt
+    chunks: every step's `walks` is its passes' feeds, and the kernel's
+    own reading of the packed rows (`_runs`: consecutive positions through
+    one table row) finds as many runs."""
+    import importlib
+    pa = importlib.import_module("mxnet_tpu.pallas_ops.paged_attention")
+    srv = _paged_server(slots=3)
+    handed = []
+    real = srv._pass
+
+    def watched(grp, feeds, *a, **k):
+        tables = np.asarray(grp.device_tables())
+        pos = [q for i, p, ids in feeds for q in range(p, p + len(ids))]
+        rows = [tables[i] for i, p, ids in feeds for _ in ids]
+        run = pa._runs(np.array(rows), np.array(pos, np.int32), 10 ** 6)[0]
+        handed.append((len(feeds), int((np.asarray(run) > 0).sum()),
+                       len(pos)))
+        return real(grp, feeds, *a, **k)
+
+    srv._pass = watched
+    n = srv.drain()
+    srv.stop()
+    rows = trace.steps(tail=n)
+    assert sum(r["passes"] for r in rows) == len(handed)
+    assert all(f == runs for f, runs, _ in handed)
+    k = 0
+    for r in rows:
+        mine = handed[k:k + r["passes"]]
+        k += r["passes"]
+        assert r["walks"] == sum(f for f, _, _ in mine)
+        assert r["fed"] == sum(fed for _, _, fed in mine)
+    # a step of chunks and decoding rows: fewer walks than rows fed
+    assert any(r["walks"] < r["fed"] for r in rows)
+    assert sum(r["walks"] for r in rows) < sum(r["fed"] for r in rows)
+
+
 def test_step_table_drops_the_oldest_and_allocates_nothing():
     """A preallocated ring: past `MAX_STEPS` rows the oldest go, the
     array stays the one made at import, and writing a row keeps nothing."""
